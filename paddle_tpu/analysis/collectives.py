@@ -65,11 +65,13 @@ class CollectiveEvent(NamedTuple):
         return s
 
 
-# jaxpr primitives that lower to cross-rank communication.  psum2 is
-# jax's current name for the general psum; pbroadcast is shard_map's
-# replication MARKER (device-local), deliberately excluded.
+# jaxpr primitives that lower to cross-rank communication.
+# psum_invariant is what jax.lax.psum traces to inside a shard_map that
+# checks varying-ness (the default); pvary is shard_map's device-local
+# varying-ness MARKER, deliberately excluded.
 COLLECTIVE_PRIMS = {
-    "psum": "psum", "psum2": "psum", "pmax": "pmax", "pmin": "pmin",
+    "psum": "psum", "psum_invariant": "psum", "pmax": "pmax",
+    "pmin": "pmin",
     "ppermute": "ppermute", "pgather": "pgather",
     "all_gather": "all_gather",
     "all_gather_invariant": "all_gather",

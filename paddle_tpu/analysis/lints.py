@@ -32,6 +32,7 @@ import re
 from typing import Callable, List, Optional, Sequence
 
 import jax
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from .base import Finding, RecompileError
 
@@ -47,11 +48,11 @@ __all__ = ["iter_eqns", "lint_dtype_promotion", "lint_transfers",
 
 def _sub_jaxprs(params):
     for val in params.values():
-        if isinstance(val, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+        if isinstance(val, (Jaxpr, ClosedJaxpr)):
             yield val
         elif isinstance(val, (tuple, list)):
             for v in val:
-                if isinstance(v, (jax.core.Jaxpr, jax.core.ClosedJaxpr)):
+                if isinstance(v, (Jaxpr, ClosedJaxpr)):
                     yield v
 
 
@@ -69,7 +70,7 @@ def iter_eqns(jaxpr, _seen=None):
     depends on, same as the one-scan-iteration convention)."""
     if _seen is None:
         _seen = set()
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     if id(jaxpr) in _seen:
         return
@@ -82,7 +83,7 @@ def iter_eqns(jaxpr, _seen=None):
 
 def as_jaxpr(fn_or_jaxpr, *args, **kw):
     """Accept a ClosedJaxpr as-is, or trace a callable over `args`."""
-    if isinstance(fn_or_jaxpr, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+    if isinstance(fn_or_jaxpr, (ClosedJaxpr, Jaxpr)):
         return fn_or_jaxpr
     return jax.make_jaxpr(fn_or_jaxpr)(*args, **kw)
 
@@ -119,7 +120,7 @@ def lint_dtype_promotion(fn_or_jaxpr, *args,
     """
     jaxpr = as_jaxpr(fn_or_jaxpr, *args)
     findings: List[Finding] = []
-    closed = jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else None
+    closed = jaxpr if isinstance(jaxpr, ClosedJaxpr) else None
     if check_x64 and closed is not None:
         for v in closed.jaxpr.invars:
             aval = getattr(v, "aval", None)
@@ -589,7 +590,8 @@ def lint_serve_programs(batcher) -> List[Finding]:
 
 
 _COMPILE_LOGGERS = ("jax._src.interpreters.pxla", "jax._src.dispatch")
-_COMPILE_PAT = re.compile(r"Compiling ([\w<>\-.]+) (?:with|for)")
+# the installed jax logs "Compiling jit(<name>) with global shapes ..."
+_COMPILE_PAT = re.compile(r"Compiling jit\((.+?)\) with ")
 
 
 class _CompileLogHandler(logging.Handler):

@@ -162,15 +162,17 @@ define_flag("bf16_adamw_moments", False,
 # telemetry plane / cold-start killer (paddle_tpu/telemetry): defined
 # HERE so env pickup happens at interpreter start — a relaunched worker
 # sets FLAGS_compile_cache_dir before any trainer compiles.  Unset, the
-# whole cache layer is one flag lookup per trainer build and the
-# compiled programs stay byte-identical (bench-asserted).
+# AOT layer is one flag lookup per trainer build and the compiled
+# programs stay byte-identical (bench-asserted).
 define_flag("compile_cache_dir", "",
-            "directory for the persistent XLA compilation cache AND the "
-            "AOT serialized-executable store (<dir>/aot/): a second "
-            "process pointed at the same dir skips trace+compile on "
-            "every cached program — telemetry.compile_report() records "
-            "per-program trace/compile ms and hit/miss; empty disables "
-            "both layers entirely")
+            "non-empty arms the AOT serialized-executable store: a "
+            "second process skips trace+compile on every program "
+            "stored by the first — telemetry.compile_report() records "
+            "per-program trace/compile ms and hit/miss.  The value "
+            "names no directory: the store is <cache dir>/aot/, beside "
+            "the persistent XLA compilation cache, and the cache dir "
+            "is JAX_COMPILATION_CACHE_DIR where set, else the fixed "
+            "<repo>/.jax_cache (telemetry.cache_dir())")
 # paged KV cache (ISSUE 7, inference/serving.py + ops.paged_attention):
 # the serving tier's KV pool layout/precision.  Every entry of
 # generation._model_program_cache is fingerprinted with these three

@@ -367,8 +367,8 @@ class TrainStep:
 
     def _build_multi(self):
         """K optimizer steps fused into ONE device program via lax.scan —
-        host-loop elision: per-step dispatch latency (large on remote /
-        tunneled accelerators) is paid once per K steps.  The learning
+        host-loop elision: per-step dispatch latency is paid once per K
+        steps (what it costs on today's chip: not measured).  The learning
         rate is a scanned [K] array (per-step schedulers advance inside
         the fused window); step_i advances inside the scan so Adam bias
         correction stays exact."""

@@ -563,19 +563,21 @@ class LlamaModel(nn.Layer):
     def init_paged_cache(self, num_pages: int, page_size: int,
                          kv_dtype=None):
         """Paged KV pool (ISSUE 7): ONE device-resident page pool per
-        K and V, [num_pages, page_size, layers, n_kv, head_dim], shared
-        by every serving slot through per-slot page tables.  Page 0 is
+        K and V, [num_pages, layers, n_kv, page_size, head_dim] — one
+        (page, layer, kv head) is a contiguous [page_size, head_dim]
+        tile, the block the paged-attention kernel DMAs — shared by
+        every serving slot through per-slot page tables.  Page 0 is
         the reserved null page (unmapped table entries point there;
         reads of its rows are position-masked).  kv_dtype: None reads
         FLAGS_kv_cache_dtype ('auto' = compute dtype; 'int8' adds
         per-page per-head fp32 scales alongside the pool)."""
         cfg = self.config
         dt, quant = _resolve_kv_dtype(cfg, kv_dtype)
-        shape = (num_pages, page_size, len(self.layers),
-                 cfg.num_key_value_heads, cfg.head_dim)
+        shape = (num_pages, len(self.layers), cfg.num_key_value_heads,
+                 page_size, cfg.head_dim)
         cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
         if quant:
-            sshape = shape[:1] + shape[2:4]
+            sshape = shape[:3]
             # scale 1.0 on untouched pages: dequant of the zero pool
             # stays zero, mirroring the dense zero-init cache
             cache["k_scale"] = jnp.ones(sshape, jnp.float32)

@@ -188,7 +188,7 @@ def _convert_jaxpr(jaxpr, consts, in_names, prefix="", opset=None):
         for v, nm in zip(eqn.outvars, outs):
             env[v] = nm
         p = eqn.params
-        if prim in ("pjit", "jit", "closed_call", "custom_jvp_call",
+        if prim in ("jit", "closed_call", "custom_jvp_call",
                     "custom_vjp_call", "remat", "checkpoint"):
             inner = p.get("jaxpr") or p.get("call_jaxpr")
             closed = inner if hasattr(inner, "jaxpr") else None

@@ -13,15 +13,18 @@ eager autograd and jit tracing both work.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 __all__ = ["attention", "cached_attention", "rms_norm", "layer_norm",
            "fused_add_rms_norm", "xla_fused_add_rms_norm",
            "rope", "apply_rope",
            "paged_attention", "xla_paged_attention", "paged_kv_update",
            "swiglu", "get_attention_backend", "set_attention_backend",
+           "kernel_mesh_scope",
            "gqa_scores", "gqa_weighted_v",
            "quant_matmul", "xla_quant_matmul",
            "pack_int4", "unpack_int4", "dequant_weight"]
@@ -38,11 +41,76 @@ def set_attention_backend(b):
     _attention_backend = b
 
 
-def _on_tpu(*arrays) -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
+_KERNEL_MESH: list = []     # (mesh, batch axes, head axes), innermost last
+
+
+class kernel_mesh_scope:
+    """Entered by a trainer while it traces a program for a mesh of more
+    than one device.  GSPMD cannot partition a Mosaic kernel (the
+    lowering raises NotImplementedError), so inside the scope dispatch
+    runs flash attention, rms norm and rope under `jax.shard_map` over
+    `mesh`, as optimizer/jit_update.py does for the fused AdamW update:
+    dim 0 over `batch_axes`, the heads dim over the tensor-parallel
+    axis "mp", every other dim whole.  Axes that are absent, of size 1, or do not divide
+    their dim stay out of the spec (the kernel then runs replicated
+    over them), and each kernel's `supports` is asked about the shapes
+    ONE device sees.  A kernel dispatched here without a layout (paged
+    attention, quant matmul) is called as it is and the lowering's
+    refusal reaches the caller."""
+
+    def __init__(self, mesh, batch_axes=("dp", "sharding")):
+        live = [a for a in mesh.axis_names if mesh.shape[a] > 1]
+        self._entry = (mesh, tuple(a for a in live if a in batch_axes),
+                       ("mp",) if "mp" in live else ())
+
+    def __enter__(self):
+        _KERNEL_MESH.append(self._entry)
+        return self
+
+    def __exit__(self, *exc):
+        _KERNEL_MESH.pop()
         return False
+
+
+def _run_kernel(kernel, supports, args, layouts, out_layouts):
+    """`kernel(*args)` if `supports(*shapes)` holds for the shapes one
+    device sees, else None (the caller takes the XLA twin).  A layout
+    names each dim of an argument or result: "b" batch, "h" heads, "."
+    whole.  Outside a `kernel_mesh_scope` the kernel is called as it
+    is; inside, per device under shard_map."""
+    if not _KERNEL_MESH:
+        return kernel(*args) if supports(*(a.shape for a in args)) else None
+    mesh, batch_axes, head_axes = _KERNEL_MESH[-1]
+    if any(a.ndim != len(lay) for a, lay in zip(args, layouts)):
+        return None
+
+    def fits(axes, letter):
+        n = math.prod(mesh.shape[a] for a in axes)
+        return all(a.shape[i] % n == 0 for a, lay in zip(args, layouts)
+                   for i, c in enumerate(lay) if c == letter)
+
+    axes_of = {"b": batch_axes if fits(batch_axes, "b") else (),
+               "h": head_axes if fits(head_axes, "h") else (),
+               ".": ()}
+
+    def spec(lay):
+        return PartitionSpec(*(axes_of[c] or None for c in lay))
+
+    def local(a, lay):
+        return tuple(d // math.prod(mesh.shape[x] for x in axes_of[c])
+                     for d, c in zip(a.shape, lay))
+
+    if not supports(*(local(a, lay) for a, lay in zip(args, layouts))):
+        return None
+    outs = tuple(spec(lay) for lay in out_layouts)
+    return jax.shard_map(
+        kernel, mesh=mesh, in_specs=tuple(spec(lay) for lay in layouts),
+        out_specs=outs if len(outs) > 1 else outs[0],
+        check_vma=False)(*args)
 
 
 # ---------------------------------------------------------------------------
@@ -152,9 +220,9 @@ def cached_attention(q, k_cache, v_cache, q_pos0, scale=None):
 # paged KV (ISSUE 7): fixed-size page pool + per-slot page table
 # ---------------------------------------------------------------------------
 def _dequant_pages(pages, scales):
-    """pages [..., ps, n_kv, hd] int8 × per-page per-head scales
+    """pages [..., n_kv, ps, hd] int8 × per-page per-head scales
     [..., n_kv] → fp32."""
-    return pages.astype(jnp.float32) * scales[..., None, :, None]
+    return pages.astype(jnp.float32) * scales[..., None, None]
 
 
 def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
@@ -162,7 +230,7 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
     """Write one step's K/V rows into the paged pool (the paged twin of
     the dense path's per-slot dynamic_update_slice).
 
-    k_pool/v_pool: [P, ps, L, n_kv, hd] (int8 pools carry per-page
+    k_pool/v_pool: [P, L, n_kv, ps, hd] (int8 pools carry per-page
     per-head scales [P, L, n_kv] fp32; None otherwise); page_table
     [B, P_slot] int32 (entry 0 = reserved null page); pos [B] int32;
     k_new/v_new [B, C, n_kv, hd] in the compute dtype; layer: python
@@ -176,7 +244,7 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
     being written).  int8 pages requantize against the page's new
     running amax, so a page's scale is always consistent with every
     row it holds."""
-    P, ps, L, n_kv, hd = k_pool.shape
+    P, L, n_kv, ps, hd = k_pool.shape
     B, C = k_new.shape[0], k_new.shape[1]
     P_slot = page_table.shape[1]
     n_t = -(-C // ps) + 1          # pages a C-row write can straddle
@@ -192,37 +260,37 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
         & ((start + ps) > pos[:, None])                      # [B, n_t]
 
     def upd(pool, scales, rows):
-        layer_pool = pool[:, :, layer]                # [P, ps, n_kv, hd]
-        raw = jnp.take(layer_pool, ids, axis=0)       # [B, n_t, ps, ...]
+        layer_pool = pool[:, layer]                   # [P, n_kv, ps, hd]
+        raw = jnp.take(layer_pool, ids, axis=0)   # [B, n_t, n_kv, ps, hd]
         if quant:
             sc = jnp.take(scales[:, layer], ids, axis=0)  # [B, n_t, n_kv]
             w = _dequant_pages(raw, sc).astype(rows.dtype)
         else:
             w = raw
-        w = w.reshape(B, n_t * ps, n_kv, hd)
+        # the window as per-head logical rows [B, n_kv, n_t*ps, hd]
+        w = w.transpose(0, 2, 1, 3, 4).reshape(B, n_kv, n_t * ps, hd)
 
         def dus(buf, r, r0):
+            z = jnp.zeros((), jnp.int32)
             return jax.lax.dynamic_update_slice(
-                buf, r.astype(buf.dtype),
-                (r0, jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32)))
-        w = jax.vmap(dus)(w, rows, rel0)
-        w = w.reshape(B, n_t, ps, n_kv, hd)
+                buf, r.astype(buf.dtype), (z, r0, z))
+        w = jax.vmap(dus)(w, rows.transpose(0, 2, 1, 3), rel0)
+        w = w.reshape(B, n_kv, n_t, ps, hd).transpose(0, 2, 1, 3, 4)
+        m = touched[:, :, None, None, None]
         if quant:
-            amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=(2, 4))
+            amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=(3, 4))
             sc_new = jnp.maximum(amax, 1e-8) / 127.0      # [B, n_t, n_kv]
             q8 = jnp.clip(jnp.round(
-                w.astype(jnp.float32) / sc_new[:, :, None, :, None]),
+                w.astype(jnp.float32) / sc_new[..., None, None]),
                 -127, 127).astype(jnp.int8)
-            m = touched[:, :, None, None, None]
             pages_out = jnp.where(m, q8, raw)
             sc_out = jnp.where(touched[..., None], sc_new, sc)
             sl = scales[:, layer].at[ids].set(sc_out)
             scales = scales.at[:, layer].set(sl)
         else:
-            m = touched[:, :, None, None, None]
             pages_out = jnp.where(m, w.astype(pool.dtype), raw)
         layer_pool = layer_pool.at[ids].set(pages_out)
-        return pool.at[:, :, layer].set(layer_pool), scales
+        return pool.at[:, layer].set(layer_pool), scales
 
     k_pool, k_scale = upd(k_pool, k_scale, k_new)
     v_pool, v_scale = upd(v_pool, v_scale, v_new)
@@ -231,9 +299,8 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
 
 def _check_paged_args(q, k_pool, k_scale, v_scale):
     """Shared argument validation for both paged-attention paths —
-    raised HERE so a bad call fails identically on and off TPU (the
-    kernel's tiling ValueError is the only fallback trigger)."""
-    n_kv = k_pool.shape[3]
+    raised HERE so a bad call fails identically on and off TPU."""
+    n_kv = k_pool.shape[2]
     if q.shape[2] % n_kv:
         raise ValueError(f"q heads {q.shape[2]} not a multiple of kv "
                          f"heads {n_kv}")
@@ -251,16 +318,18 @@ def xla_paged_attention(q, k_pool, v_pool, page_table, pos, layer,
     the paged path stays bit-identical to the dense one off-TPU."""
     _check_paged_args(q, k_pool, k_scale, v_scale)
     B = q.shape[0]
-    P, ps, L, n_kv, hd = k_pool.shape
+    P, L, n_kv, ps, hd = k_pool.shape
     P_slot = page_table.shape[1]
     quant = k_pool.dtype == jnp.int8
 
     def gather(pool, scales):
-        lg = jnp.take(pool[:, :, layer], page_table, axis=0)
+        lg = jnp.take(pool[:, layer], page_table, axis=0)
         if quant:
             sc = jnp.take(scales[:, layer], page_table, axis=0)
             lg = _dequant_pages(lg, sc).astype(q.dtype)
-        return lg.reshape(B, P_slot * ps, n_kv, hd)
+        # [B, P_slot, n_kv, ps, hd] -> logical rows [B, P_slot*ps, ...]
+        return lg.transpose(0, 1, 3, 2, 4).reshape(
+            B, P_slot * ps, n_kv, hd)
 
     return cached_attention(q, gather(k_pool, k_scale),
                             gather(v_pool, v_scale), pos, scale)
@@ -270,33 +339,36 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                     k_scale=None, v_scale=None, scale=None):
     """Decode attention against the paged KV pool: Pallas kernel on TPU
     (gather-by-page-table in the DMA index map, int8 dequant fused —
-    see ops/pallas/paged_attention.py), `take`-gather twin elsewhere.
-    Capability-gated like ops.attention: tiling-incompatible shapes
-    fall back to the twin (argument errors are validated FIRST, so the
-    fallback can never swallow them)."""
+    see ops/pallas/paged_attention.py), `take`-gather twin elsewhere
+    and for shapes the kernel's `supports` predicate refuses.  The
+    choice is made from the shapes alone: whatever the kernel raises —
+    a lowering or compiler refusal included — reaches the caller."""
     _check_paged_args(q, k_pool, k_scale, v_scale)
     if _on_tpu():
-        from .pallas.paged_attention import paged_attention as _ppa
-        try:
-            return _ppa(q, k_pool, v_pool, page_table, pos, layer,
-                        k_scale, v_scale, scale)
-        except ValueError:
-            pass  # unsupported tiling → twin; real errors propagate
+        from .pallas import paged_attention as _k
+        if _k.supports(k_pool.shape):
+            return _k.paged_attention(q, k_pool, v_pool, page_table, pos,
+                                      layer, k_scale, v_scale, scale)
     return xla_paged_attention(q, k_pool, v_pool, page_table, pos,
                                layer, k_scale, v_scale, scale)
 
 
 def attention(q, k, v, mask=None, causal=False, scale=None, dropout_p=0.0):
+    """Flash kernel or XLA, chosen from the backend setting and the
+    shapes (flash_attention.supports) — never from an exception: what
+    the kernel raises reaches the caller."""
     backend = _attention_backend
     if backend == "auto":
-        backend = "pallas" if (_on_tpu() and mask is None
-                               and dropout_p == 0.0) else "xla"
+        backend = "pallas" if _on_tpu() else "xla"
     if backend == "pallas" and mask is None and dropout_p == 0.0:
-        from .pallas.flash_attention import flash_attention as _pfa
-        try:
-            return _pfa(q, k, v, causal=causal, scale=scale)
-        except ValueError:
-            pass  # unsupported shape → XLA path; real errors propagate
+        from .pallas import flash_attention as _k
+        out = _run_kernel(
+            lambda q_, k_, v_: _k.flash_attention(q_, k_, v_, causal=causal,
+                                                  scale=scale),
+            lambda qs, ks, vs: _k.supports(qs, ks, causal),
+            (q, k, v), ("b.h.",) * 3, ("b.h.",))
+        if out is not None:
+            return out
     return xla_attention(q, k, v, mask, causal, scale, dropout_p)
 
 
@@ -317,11 +389,14 @@ def rms_norm(x, weight=None, epsilon=1e-6):
     """Reference: incubate fused_rms_norm (phi fused kernel).  Pallas kernel
     on TPU for the [*, hidden] LLM case."""
     if _on_tpu() and weight is not None and x.ndim >= 2:
-        from .pallas.rms_norm import rms_norm as _prn
-        try:
-            return _prn(x, weight, epsilon)
-        except ValueError:
-            pass  # tiling-incompatible shape → XLA path
+        from .pallas import rms_norm as _k
+        rows = "b" + "." * (x.ndim - 1)
+        out = _run_kernel(
+            lambda x_, w_: _k.rms_norm(x_, w_, epsilon),
+            lambda xs, ws: _k.supports(xs, x.dtype),
+            (x, weight), (rows, "."), (rows,))
+        if out is not None:
+            return out
     return xla_rms_norm(x, weight, epsilon)
 
 
@@ -340,11 +415,15 @@ def fused_add_rms_norm(x, y, weight, epsilon=1e-6):
     never re-read — one fewer [tokens, H] HBM round-trip per transformer
     block, a PROFILE_r05 non-matmul gap item).  XLA twin elsewhere."""
     if _on_tpu() and weight is not None and x.ndim >= 2:
-        from .pallas.rms_norm import fused_add_rms_norm as _parn
-        try:
-            return _parn(x, y, weight, epsilon)
-        except ValueError:
-            pass  # tiling-incompatible shape → XLA path
+        from .pallas import rms_norm as _k
+        rows = "b" + "." * (x.ndim - 1)
+        out = _run_kernel(
+            lambda x_, y_, w_: _k.fused_add_rms_norm(x_, y_, w_, epsilon),
+            lambda xs, ys, ws: _k.supports(
+                xs, jnp.promote_types(x.dtype, y.dtype)),
+            (x, y, weight), (rows, rows, "."), (rows, rows))
+        if out is not None:
+            return out
     return xla_fused_add_rms_norm(x, y, weight, epsilon)
 
 
@@ -388,14 +467,17 @@ def apply_rope(q, k, cos, sin):
     On TPU the q/k rotation runs as ONE Pallas pass per row block
     (pallas/rope.py — each operand read once, written once; the XLA
     path's concat/slice rotate-half shuffles are a PROFILE_r05
-    non-matmul gap item); shapes its tiling cannot serve (e.g. the
-    batch·seq < 8 decode case) fall back to XLA here."""
-    if _on_tpu() and q.ndim == 4 and k.ndim == 4:
-        from .pallas.rope import rope_apply as _prope
-        try:
-            return _prope(q, k, cos, sin)
-        except ValueError:
-            pass  # tiling-incompatible shape → XLA path
+    non-matmul gap item); shapes rope.supports refuses (e.g. the
+    batch·seq < 8 decode case) take the XLA path here."""
+    if _on_tpu():
+        from .pallas import rope as _k
+        table = "." * cos.ndim if cos.ndim == 2 else "b.."
+        out = _run_kernel(
+            _k.rope_apply, lambda qs, ks, cs, ss: _k.supports(qs, ks, cs),
+            (q, k, cos, sin), ("b.h.", "b.h.", table, table),
+            ("b.h.", "b.h."))
+        if out is not None:
+            return out
     if cos.ndim == 2:      # [s, d] → [1, s, 1, d]
         cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     elif cos.ndim == 3:    # [b, s, d] → [b, s, 1, d]
@@ -489,18 +571,16 @@ def quant_matmul(x, qw, scales, fmt, group_size=None):
     dequant fused into the matmul (the weight is read from HBM at 1
     byte (int8) or half a byte (int4) per element — the decode-path
     bandwidth multiplier).  Pallas kernel on TPU (dequant in VMEM right
-    after the DMA), jnp twin elsewhere / for tiling-incompatible
-    shapes."""
+    after the DMA), jnp twin elsewhere and for shapes
+    quant_matmul.supports refuses."""
     if fmt not in ("int8", "int4"):
         raise ValueError(f"unknown weight-only format {fmt!r}")
     if fmt == "int4" and group_size is None:
         raise ValueError("int4 quant_matmul needs group_size")
     if _on_tpu():
-        from .pallas.quant_matmul import quant_matmul as _pqm
-        try:
-            return _pqm(x, qw, scales, fmt, group_size)
-        except ValueError:
-            pass  # unsupported tiling → twin; real errors propagate
+        from .pallas import quant_matmul as _k
+        if _k.supports(x.shape, qw.shape[1]):
+            return _k.quant_matmul(x, qw, scales, fmt, group_size)
     return xla_quant_matmul(x, qw, scales, fmt, group_size)
 
 

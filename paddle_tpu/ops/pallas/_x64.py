@@ -1,28 +1,11 @@
-"""x64-off context compatible across jax versions.
+"""x64-off context for the Pallas kernels.
 
 paddle_tpu enables x64 globally (paddle int64/float64 semantics), but
 the Pallas kernels must trace with x64 semantics disabled so weak
-python constants stay 32-bit — Mosaic rejects 64-bit avals.  Newer jax
-exposes `jax.enable_x64(False)` as a trace-safe context manager; this
-environment's jax (0.4.37) removed it, and BOTH remaining spellings
-are broken there:
-
-  - `jax.experimental.disable_x64()` leaves `jax_enable_x64=True` on
-    exit, flipping the whole process into x64 mode permanently;
-  - toggling via `jax.config.update` mid-trace corrupts interpret-mode
-    lowering (weak f32 literals in the traced kernel canonicalize to
-    f64 at lowering time, outside the context — "expected tensor<f32>,
-    provided tensor<f64>").
-
-So: use the native context manager when it exists (every Mosaic-
-capable jax), otherwise a no-op — the CPU interpret path tolerates
-64-bit avals, and the kernels keep their accumulation math explicitly
-typed (jnp.float32(...)) so ambient-x64 tracing computes identical
-numerics.
+python constants stay 32-bit — Mosaic rejects 64-bit avals.
+`jax.enable_x64(False)` is the trace-safe context manager for that.
 """
 from __future__ import annotations
-
-import contextlib
 
 import jax
 
@@ -30,6 +13,4 @@ __all__ = ["x64_off"]
 
 
 def x64_off():
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64(False)
-    return contextlib.nullcontext()
+    return jax.enable_x64(False)

@@ -20,8 +20,9 @@ this is the TPU-native equivalent, written directly against the MXU:
 Layout contract: [b, s, h, d] at the API (paddle flash-attn layout),
 transposed to [b*h, s, d] (queries) / [b*h_kv, s, d] (keys, values).
 Requires a block size dividing each sequence length (picked from
-{512..8} automatically) and d a multiple of 64; callers
-(paddle_tpu.ops.attention) fall back to the XLA path otherwise.
+{512..8} automatically) and d a multiple of 64: `supports` is that
+predicate, and paddle_tpu.ops.attention asks it to choose between this
+kernel and the XLA path.
 """
 from __future__ import annotations
 
@@ -34,14 +35,10 @@ from ._x64 import x64_off
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-INTERPRET = None  # resolved lazily: True off-TPU (CPU tests)
-
 
 def _interpret():
-    global INTERPRET
-    if INTERPRET is None:
-        INTERPRET = jax.default_backend() != "tpu"
-    return INTERPRET
+    # interpret mode is the CPU backend's (the tests'); never a chip's
+    return jax.default_backend() != "tpu"
 
 
 DEFAULT_BLOCK_Q = 512
@@ -152,6 +149,7 @@ def _fwd_small(q3, k2, v2, scale, causal, block_q, block_k, h, hk):
                 jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
                 jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
             ],
+            name="flash_attention_fwd",
             interpret=_interpret(),
         )(q3, k2, v2)
     return out, lse
@@ -294,6 +292,7 @@ def _bwd_small(scale, causal, block_q, block_k, h, hk, res, do3):
             ],
             out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
+            name="flash_attention_bwd_dq",
             interpret=_interpret(),
         )(q3, k2, v2, do3, lse, delta)
 
@@ -326,6 +325,7 @@ def _bwd_small(scale, causal, block_q, block_k, h, hk, res, do3):
                 pltpu.VMEM((sk, d), jnp.float32),
                 pltpu.VMEM((sk, d), jnp.float32),
             ],
+            name="flash_attention_bwd_dkv",
             interpret=_interpret(),
         )(q3, k2, v2, do3, lse, delta)
     return dq, dk, dv
@@ -386,6 +386,7 @@ def _fwd_1b(q3, k2, v2, scale, causal, gh):
                 jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
                 jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
             ],
+            name="flash_attention_fwd",
             interpret=_interpret(),
         )(q3, k2, v2)
     return out, lse
@@ -452,6 +453,7 @@ def _bwd_1b(scale, causal, gh, res, do3):
                 jax.ShapeDtypeStruct((bh, sk, d), k2.dtype),
                 jax.ShapeDtypeStruct((bh, sk, d), v2.dtype),
             ],
+            name="flash_attention_bwd",
             interpret=_interpret(),
         )(q3, k2, v2, do3, lse, delta)
     return dq, dk, dv
@@ -574,6 +576,7 @@ def _fwd(q3, k2, v2, scale, causal, block_q, block_k, h, hk):
                 pltpu.VMEM((block_q, 1), jnp.float32),
                 pltpu.VMEM((block_q, 1), jnp.float32),
             ],
+            name="flash_attention_fwd",
             interpret=_interpret(),
         )(q3, k2, v2)
     return out, lse
@@ -711,6 +714,7 @@ def _bwd(scale, causal, block_q, block_k, h, hk, res, do3):
                                    lambda b, i, j: (b, i, 0)),
             out_shape=jax.ShapeDtypeStruct((bh, sq, d), q3.dtype),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            name="flash_attention_bwd_dq",
             interpret=_interpret(),
         )(q3, k2, v2, do3, lse, delta)
 
@@ -759,6 +763,7 @@ def _bwd(scale, causal, block_q, block_k, h, hk, res, do3):
                 pltpu.VMEM((block_k, d), jnp.float32),
                 pltpu.VMEM((block_k, d), jnp.float32),
             ],
+            name="flash_attention_bwd_dkv",
             interpret=_interpret(),
         )(q3, k2, v2, do3, lse, delta)
     return dq, dk, dv
@@ -813,28 +818,41 @@ SMALL_KV_BYTES = 4 * 1024 * 1024       # K+V for one kv head (fwd, dq)
 SMALL_DKV_SCRATCH_BYTES = 4 * 1024 * 1024  # fp32 dk+dv row scratch (dkv)
 
 
+def _blocks(sq, sk, d, block_q=None, block_k=None):
+    # keep the working set (q, k, v tiles + fp32 acc) well under VMEM:
+    # shrink blocks as head_dim grows
+    pref = DEFAULT_BLOCK_Q if d <= 128 else max(128, 32768 // d)
+    return (_pick_block(sq, block_q or pref),
+            _pick_block(sk, block_k or pref))
+
+
+def supports(q_shape, k_shape, causal=False) -> bool:
+    """Shape predicate for ops.attention's kernel-or-XLA choice: a
+    block size must divide each sequence length, head_dim must be a
+    multiple of 64, and causal masking needs sq == sk (the kernel masks
+    top-left aligned; the framework convention, ops.xla_attention, is
+    bottom-right for cross lengths)."""
+    sq, d = q_shape[1], q_shape[3]
+    sk = k_shape[1]
+    return (None not in _blocks(sq, sk, d) and d % 64 == 0
+            and not (causal and sq != sk))
+
+
 def flash_attention(q, k, v, causal=False, scale=None,
                     block_q=None, block_k=None):
     """q/k/v: [b, s, h, d] (paddle layout; k/v may have fewer heads for
-    GQA/MQA — h % h_kv == 0).  Returns [b, s, h, d]."""
+    GQA/MQA — h % h_kv == 0).  Returns [b, s, h, d].  Raises ValueError
+    for shapes `supports` refuses."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     hk = k.shape[2]
     if h % hk:
         raise ValueError("num q heads must be a multiple of num kv heads")
-    # keep the working set (q, k, v tiles + fp32 acc) well under VMEM:
-    # shrink blocks as head_dim grows
-    pref = DEFAULT_BLOCK_Q if d <= 128 else max(128, 32768 // d)
-    bq = _pick_block(sq, block_q or pref)
-    bk = _pick_block(sk, block_k or pref)
-    if bq is None or bk is None or d % 64:
-        raise ValueError("unsupported shape for pallas flash attention")
-    if causal and sq != sk:
-        # the kernel masks top-left aligned; the framework convention
-        # (ops.xla_attention) is bottom-right for cross lengths — refuse and
-        # let dispatch fall back rather than silently diverge
-        raise ValueError("causal cross-attention not supported by the "
-                         "pallas kernel (sq != sk)")
+    if not supports(q.shape, k.shape, causal):
+        raise ValueError(
+            f"unsupported shape for pallas flash attention (q {q.shape}, "
+            f"k {k.shape}, causal={causal})")
+    bq, bk = _blocks(sq, sk, d, block_q, block_k)
     s = scale if scale is not None else 1.0 / math.sqrt(d)
 
     esize = jnp.dtype(q.dtype).itemsize

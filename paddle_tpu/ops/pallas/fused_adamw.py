@@ -231,6 +231,7 @@ def fused_adamw(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
                     jax.ShapeDtypeStruct(work_shape, v.dtype),
                 ],
                 input_output_aliases={6: 0, 4: 1, 5: 2},
+                name="fused_adamw",
                 interpret=_interpret(),
             )(lr1, c1, c2, g1, m1, v1, mst1)
             mst1 = p1
@@ -247,6 +248,7 @@ def fused_adamw(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
                     jax.ShapeDtypeStruct(work_shape, ef.dtype),
                 ],
                 input_output_aliases={6: 0, 4: 1, 5: 2, 7: 3},
+                name="fused_adamw",
                 interpret=_interpret(),
             )(lr1, c1, c2, g1, m1, v1, mst1, ef1)
             mst1 = p1
@@ -263,6 +265,7 @@ def fused_adamw(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
                     jax.ShapeDtypeStruct(work_shape, jnp.float32),
                 ],
                 input_output_aliases={4: 1, 5: 2, 6: 3},
+                name="fused_adamw",
                 interpret=_interpret(),
             )(lr1, c1, c2, g1, m1, v1, mst1)
         else:
@@ -279,6 +282,7 @@ def fused_adamw(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
                     jax.ShapeDtypeStruct(work_shape, ef.dtype),
                 ],
                 input_output_aliases={4: 1, 5: 2, 6: 3, 7: 4},
+                name="fused_adamw",
                 interpret=_interpret(),
             )(lr1, c1, c2, g1, m1, v1, mst1, ef1)
     outs = (p1, m1, v1, mst1) + ((ef1,) if ef is not None else ())

@@ -108,6 +108,7 @@ def _ce_rows_pallas(logits, labels, scale, out_dtype):
                        pl.BlockSpec((br, v), lambda i: (i, 0))],
             out_shape=[jax.ShapeDtypeStruct((rows,), jnp.float32),
                        jax.ShapeDtypeStruct((rows, v), out_dtype)],
+            name="fused_ce_rows",
             interpret=_interpret(),
         )(scale.reshape(1), logits, labels)
     return loss_rows, dlog

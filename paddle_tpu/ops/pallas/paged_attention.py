@@ -12,7 +12,9 @@ and the [B, S_max] logical KV view is never materialized in HBM
 
 Layout contract (paddle_tpu.models.llama.init_paged_cache):
 
-  k_pool/v_pool  [num_pages, page_size, layers, n_kv, head_dim]
+  k_pool/v_pool  [num_pages, layers, n_kv, page_size, head_dim] —
+                 (page_size, head_dim) minor, so one page of one kv
+                 head is a whole-tile block the TPU lowering accepts
   k/v scales     [num_pages, layers, n_kv] fp32  (int8 pools only)
   page_table     [B, pages_per_slot] int32; entry 0 is the reserved
                  null page (reads masked by position)
@@ -24,9 +26,11 @@ Grid: (B, n_kv, pages_per_slot) — the page walk is the innermost
 kv head) in VMEM scratch, flash-attention style.  Pages past a slot's
 frontier clamp their index map to the last useful page — Mosaic elides
 the repeated-block DMA, so dead pages cost neither bandwidth nor
-(via pl.when) compute.  int8 dequant is fused: the page's per-head
-scale rides a (1,1,1) VMEM block and multiplies the tile right after
-the DMA, so the HBM read stays 1 byte/element.
+(via pl.when) compute.  int8 dequant is fused: the wrapper gathers the
+slot's per-page scales of this layer into a [P_slot, 1] column per
+(slot, kv head) — a block of whole minor dims — and the kernel reduces
+row j of it to a scalar that multiplies the tile right after the DMA,
+so the HBM read stays 1 byte/element.
 """
 from __future__ import annotations
 
@@ -40,14 +44,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 
-INTERPRET = None
-
 
 def _interpret():
-    global INTERPRET
-    if INTERPRET is None:
-        INTERPRET = jax.default_backend() != "tpu"
-    return INTERPRET
+    # interpret mode is the CPU backend's (the tests'); never a chip's
+    return jax.default_backend() != "tpu"
 
 
 def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
@@ -73,11 +73,17 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         # q rows are pre-arranged [C*group, d] by the wrapper (row =
         # c*group + g) — no in-kernel reshape across sublanes
         q = q_ref[0, 0]
-        k = k_ref[0, :, 0, 0, :]                          # [ps, d]
-        v = v_ref[0, :, 0, 0, :]
+        k = k_ref[0, 0, 0]                                # [ps, d]
+        v = v_ref[0, 0, 0]
         if quant:
-            k = k.astype(jnp.float32) * ks_ref[0, 0, 0]
-            v = v.astype(jnp.float32) * vs_ref[0, 0, 0]
+            # this page's scale: row j of the slot's [P_slot, 1] column
+            # (cast back to the compute dtype, as the twin does)
+            row = jax.lax.broadcasted_iota(jnp.int32, ks_ref.shape[2:], 0)
+
+            def page_scale(ref):
+                return jnp.sum(jnp.where(row == j, ref[0, 0], 0.0))
+            k = (k.astype(jnp.float32) * page_scale(ks_ref)).astype(q.dtype)
+            v = (v.astype(jnp.float32) * page_scale(vs_ref)).astype(q.dtype)
         s = jax.lax.dot_general(
             q, k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * jnp.float32(scale)
@@ -105,33 +111,40 @@ def _kernel(pt_ref, pos_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         o_ref[0, 0] = (acc_ref[...] / l[:, None]).astype(o_ref.dtype)
 
 
+def supports(pool_shape, interpret=None) -> bool:
+    """Shape predicate for ops.paged_attention's kernel-or-twin choice:
+    the K/V block is one page of one kv head, (page_size, head_dim) —
+    the pool's two minor dims — so Mosaic needs head_dim on whole
+    128-lane tiles and page_size on whole 8-row sublane tiles.
+    Interpret mode (CPU tests) has no tiling."""
+    interp = _interpret() if interpret is None else interpret
+    ps, d = pool_shape[3], pool_shape[4]
+    return interp or (d % 128 == 0 and ps % 8 == 0)
+
+
 def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                     k_scale=None, v_scale=None, scale=None,
                     interpret=None):
-    """q: [B, C, h, d]; pools [P, ps, L, n_kv, d]; page_table
+    """q: [B, C, h, d]; pools [P, L, n_kv, ps, d]; page_table
     [B, P_slot] int32; pos [B] int32.  Returns [B, C, h, d] in
-    q.dtype.  Raises ValueError for shapes the TPU tiling cannot
-    serve — callers (ops.paged_attention) fall back to the jnp twin."""
+    q.dtype.  Raises ValueError for shapes `supports` refuses —
+    ops.paged_attention asks the predicate first and takes the jnp
+    twin for those."""
     interp = _interpret() if interpret is None else interpret
     B, C, h, d = q.shape
-    P, ps, L, n_kv, _ = k_pool.shape
+    P, L, n_kv, ps, _ = k_pool.shape
     P_slot = page_table.shape[1]
     group = h // n_kv
     if h % n_kv:
         raise ValueError(f"q heads {h} not a multiple of kv heads "
                          f"{n_kv}")
-    if not interp and (d % 128 or ps % 8):
+    if not supports(k_pool.shape, interp):
         raise ValueError(
             f"paged_attention tiling needs head_dim % 128 == 0 and "
             f"page_size % 8 == 0 (got d={d}, page_size={ps})")
     quant = k_pool.dtype == jnp.int8
     if quant and (k_scale is None or v_scale is None):
         raise ValueError("int8 KV pool needs k_scale/v_scale")
-    if not quant:
-        # dummy (1,1,1)-blocked operand keeps ONE kernel signature;
-        # never read when quant=False
-        k_scale = jnp.ones((P, L, n_kv), jnp.float32)
-        v_scale = k_scale
     s = scale if scale is not None else 1.0 / (d ** 0.5)
 
     pt = jnp.asarray(page_table, jnp.int32)
@@ -139,15 +152,25 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
     if posv.ndim == 0:
         posv = jnp.broadcast_to(posv, (B,))
 
+    def slot_scales(scales):
+        # [P, L, n_kv] -> this layer's scale of every page the slot
+        # maps, as a [P_slot, 1] column per (slot, kv head): a block of
+        # whole minor dims, so the kernel picks row j with a dynamic
+        # sublane slice.  B*n_kv*P_slot floats — noise beside the pool.
+        if not quant:
+            # dummy operand keeps ONE kernel signature; never read
+            return jnp.ones((B, n_kv, P_slot, 1), jnp.float32)
+        sc = jnp.take(scales[:, layer], pt, axis=0)    # [B, P_slot, n_kv]
+        return sc.transpose(0, 2, 1)[..., None].astype(jnp.float32)
+
     def page_ix(b, kvh, j, pt_ref, pos_ref):
         # clamp the walk to the slot's frontier page: repeated block
         # index => Mosaic elides the DMA for dead pages
         last = jnp.maximum(pos_ref[b] + (C - 1), 0) // ps
-        return (pt_ref[b, jnp.minimum(j, last)], 0, layer, kvh, 0)
+        return (pt_ref[b, jnp.minimum(j, last)], layer, kvh, 0, 0)
 
-    def scale_ix(b, kvh, j, pt_ref, pos_ref):
-        last = jnp.maximum(pos_ref[b] + (C - 1), 0) // ps
-        return (pt_ref[b, jnp.minimum(j, last)], layer, kvh)
+    def slot_ix(b, kvh, j, pt_ref, pos_ref):
+        return (b, kvh, 0, 0)
 
     # pre-arrange q per kv head with rows row = c*group + g — the
     # kernel then reads a ready [C*group, d] tile (an in-kernel
@@ -164,17 +187,13 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                 num_scalar_prefetch=2,
                 grid=grid,
                 in_specs=[
-                    pl.BlockSpec((1, 1, C * group, d),
-                                 lambda b, kvh, j, pt, pos:
-                                 (b, kvh, 0, 0)),
-                    pl.BlockSpec((1, ps, 1, 1, d), page_ix),
-                    pl.BlockSpec((1, ps, 1, 1, d), page_ix),
-                    pl.BlockSpec((1, 1, 1), scale_ix),
-                    pl.BlockSpec((1, 1, 1), scale_ix),
+                    pl.BlockSpec((1, 1, C * group, d), slot_ix),
+                    pl.BlockSpec((1, 1, 1, ps, d), page_ix),
+                    pl.BlockSpec((1, 1, 1, ps, d), page_ix),
+                    pl.BlockSpec((1, 1, P_slot, 1), slot_ix),
+                    pl.BlockSpec((1, 1, P_slot, 1), slot_ix),
                 ],
-                out_specs=pl.BlockSpec((1, 1, C * group, d),
-                                       lambda b, kvh, j, pt, pos:
-                                       (b, kvh, 0, 0)),
+                out_specs=pl.BlockSpec((1, 1, C * group, d), slot_ix),
                 scratch_shapes=[
                     pltpu.VMEM((C * group, d), jnp.float32),
                     pltpu.VMEM((C * group, 1), jnp.float32),
@@ -183,7 +202,9 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
             ),
             out_shape=jax.ShapeDtypeStruct((B, n_kv, C * group, d),
                                            q.dtype),
+            name="paged_attention",
             interpret=interp,
-        )(pt, posv, qr, k_pool, v_pool, k_scale, v_scale)
+        )(pt, posv, qr, k_pool, v_pool, slot_scales(k_scale),
+          slot_scales(v_scale))
     return out.reshape(B, n_kv, C, group, d).transpose(0, 2, 1, 3, 4) \
         .reshape(B, C, h, d)

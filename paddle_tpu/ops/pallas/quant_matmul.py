@@ -26,6 +26,7 @@ stays CPU-exact.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -55,11 +56,24 @@ def _kernel_int4(x_ref, w_ref, s_ref, o_ref, *, group):
         preferred_element_type=jnp.float32).astype(o_ref.dtype)
 
 
-def quant_matmul(x, qw, scales, fmt, group_size=None, block_n=512,
+BLOCK_N = 512
+
+
+def supports(x_shape, n, block_n=BLOCK_N) -> bool:
+    """Shape predicate for ops.quant_matmul's kernel-or-twin choice —
+    MXU/VPU tiling: whole [K, block_n] weight tiles (lanes want
+    block_n % 128, int8 sublanes K % 256) and M % 8 activation rows.
+    N = 11008 (llama-7B ffn) fails it at block_n = 512."""
+    K, M = x_shape[-1], math.prod(x_shape[:-1])
+    bn = min(int(block_n), n)
+    return not (n % bn or bn % 128 or K % 256 or M % 8)
+
+
+def quant_matmul(x, qw, scales, fmt, group_size=None, block_n=BLOCK_N,
                  interpret=None):
     """x [..., K] @ packed weight → [..., N] in x.dtype.  Raises
-    ValueError for shapes the TPU tiling cannot serve — the dispatcher
-    (ops.quant_matmul) falls back to the jnp twin."""
+    ValueError for shapes `supports` refuses — ops.quant_matmul asks
+    the predicate first and takes the jnp twin for those."""
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     lead = x.shape[:-1]
@@ -83,8 +97,7 @@ def quant_matmul(x, qw, scales, fmt, group_size=None, block_n=512,
         raise ValueError(f"unknown weight-only format {fmt!r}")
     bn = min(int(block_n), N)
     if not interpret:
-        # MXU/VPU tiling: lanes want N % 128, int8 sublanes want 32
-        if N % bn or bn % 128 or K % 256 or M % 8:
+        if not supports(x.shape, N, block_n):
             raise ValueError(
                 f"quant_matmul tiling needs N % 128 == 0, K % 256 == 0 "
                 f"and M % 8 == 0 (got M={M}, K={K}, N={N})")
@@ -110,6 +123,7 @@ def quant_matmul(x, qw, scales, fmt, group_size=None, block_n=512,
                       w_spec, s_spec],
             out_specs=pl.BlockSpec((M, bn), lambda j: (0, j)),
             out_shape=jax.ShapeDtypeStruct((M, N), x.dtype),
+            name="quant_matmul",
             interpret=interpret,
         )(x2, qw, s_in)
     return out.reshape(*lead, N)

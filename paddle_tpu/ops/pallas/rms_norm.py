@@ -21,6 +21,7 @@ the norm's dx kernel.
 from __future__ import annotations
 
 import functools
+import math
 
 import jax
 import jax.numpy as jnp
@@ -30,14 +31,10 @@ from jax.experimental.pallas import tpu as pltpu
 
 BLOCK_ROWS = 256
 
-INTERPRET = None
-
 
 def _interpret():
-    global INTERPRET
-    if INTERPRET is None:
-        INTERPRET = jax.default_backend() != "tpu"
-    return INTERPRET
+    # interpret mode is the CPU backend's (the tests'); never a chip's
+    return jax.default_backend() != "tpu"
 
 
 def _fwd_kernel(x_ref, w_ref, o_ref, *, eps):
@@ -62,22 +59,40 @@ def _bwd_kernel(x_ref, w_ref, g_ref, dx_ref, dw_ref, *, eps):
     dw_ref[0, 0] = jnp.sum(g * x * r, axis=0)
 
 
-def _pick_block_rows(rows, h):
+def _pick_block_rows(rows, h, itemsize):
     """Largest divisor of rows that is sublane-aligned (multiple of 8) and
-    keeps the kernel's fp32 temporaries (~6 live [br, h] f32 buffers in
-    the backward) inside scoped VMEM."""
-    cap = min(BLOCK_ROWS, max(8, ((512 * 1024 // max(h, 1)) // 8) * 8))
+    keeps the double-buffered [br, h] operand/result blocks (four of them
+    in the fused-add kernels) plus the fp32 temporaries inside scoped
+    VMEM: 1 MiB per block — 128 rows of bf16 at h=4096, 64 of fp32 (128
+    fp32 rows are refused by the compiler there).  None when no such
+    block exists."""
+    cap = min(BLOCK_ROWS,
+              max(8, ((1024 * 1024 // max(h * itemsize, 1)) // 8) * 8))
     for br in range(min(cap, rows), 7, -1):
         if rows % br == 0 and br % 8 == 0:
             return br
     if rows <= cap:
         return rows
-    raise ValueError(f"no tiling-compatible row block for {rows} rows")
+    return None
+
+
+def supports(x_shape, dtype) -> bool:
+    """Shape predicate for ops.rms_norm / ops.fused_add_rms_norm's
+    kernel-or-XLA choice: the flattened [rows, H] view needs a
+    sublane-aligned row block (or few enough rows for one block)."""
+    return _pick_block_rows(math.prod(x_shape[:-1]), x_shape[-1],
+                            jnp.dtype(dtype).itemsize) is not None
+
+
+def _check(x_shape, dtype):
+    if not supports(x_shape, dtype):
+        raise ValueError(
+            f"no tiling-compatible row block for {dtype} {x_shape}")
 
 
 def _rms2(x2, w, eps):
     rows, h = x2.shape
-    br = _pick_block_rows(rows, h)
+    br = _pick_block_rows(rows, h, x2.dtype.itemsize)
     grid = (rows // br,)
     with x64_off():
         out = pl.pallas_call(
@@ -88,6 +103,7 @@ def _rms2(x2, w, eps):
             out_specs=pl.BlockSpec((br, h), lambda i: (i, 0)),
             out_shape=jax.ShapeDtypeStruct(
                 (rows, h), jnp.promote_types(x2.dtype, w.dtype)),
+            name="rms_norm_fwd",
             interpret=_interpret(),
         )(x2, w)
     return out
@@ -105,7 +121,7 @@ def _rms_fwd(x2, w, eps):
 def _rms_bwd(eps, res, g2):
     x2, w = res
     rows, h = x2.shape
-    br = _pick_block_rows(rows, h)
+    br = _pick_block_rows(rows, h, x2.dtype.itemsize)
     nblocks = rows // br
     with x64_off():
         dx, dw_part = pl.pallas_call(
@@ -118,6 +134,7 @@ def _rms_bwd(eps, res, g2):
                        pl.BlockSpec((1, 1, h), lambda i: (i, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct((rows, h), x2.dtype),
                        jax.ShapeDtypeStruct((nblocks, 1, h), jnp.float32)],
+            name="rms_norm_bwd",
             interpret=_interpret(),
         )(x2, w, g2)
     dw = jnp.sum(dw_part, axis=(0, 1)).astype(w.dtype)
@@ -128,8 +145,10 @@ _rms_core.defvjp(_rms_fwd, _rms_bwd)
 
 
 def rms_norm(x, weight, epsilon=1e-6):
-    """x: [..., H]; weight: [H]."""
+    """x: [..., H]; weight: [H].  Raises ValueError for shapes
+    `supports` refuses."""
     shape = x.shape
+    _check(shape, x.dtype)
     h = shape[-1]
     x2 = x.reshape(-1, h)
     out = _rms_core(x2, weight, float(epsilon))
@@ -169,7 +188,7 @@ def _add_bwd_kernel(x_ref, w_ref, g_ref, gr_ref, dx_ref, dw_ref, *, eps):
 
 def _add_rms2(x2, y2, w, eps):
     rows, h = x2.shape
-    br = _pick_block_rows(rows, h)
+    br = _pick_block_rows(rows, h, x2.dtype.itemsize)
     res_dt = jnp.promote_types(x2.dtype, y2.dtype)
     with x64_off():
         resid, out = pl.pallas_call(
@@ -183,6 +202,7 @@ def _add_rms2(x2, y2, w, eps):
             out_shape=[jax.ShapeDtypeStruct((rows, h), res_dt),
                        jax.ShapeDtypeStruct(
                            (rows, h), jnp.promote_types(res_dt, w.dtype))],
+            name="add_rms_norm_fwd",
             interpret=_interpret(),
         )(x2, y2, w)
     return resid, out
@@ -202,7 +222,7 @@ def _add_rms_bwd(eps, res, g):
     resid, w = res
     g_resid, g_out = g
     rows, h = resid.shape
-    br = _pick_block_rows(rows, h)
+    br = _pick_block_rows(rows, h, resid.dtype.itemsize)
     nblocks = rows // br
     with x64_off():
         dresid, dw_part = pl.pallas_call(
@@ -216,6 +236,7 @@ def _add_rms_bwd(eps, res, g):
                        pl.BlockSpec((1, 1, h), lambda i: (i, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct((rows, h), resid.dtype),
                        jax.ShapeDtypeStruct((nblocks, 1, h), jnp.float32)],
+            name="add_rms_norm_bwd",
             interpret=_interpret(),
         )(resid, w, g_out, g_resid)
     dw = jnp.sum(dw_part, axis=(0, 1)).astype(w.dtype)
@@ -237,6 +258,7 @@ def fused_add_rms_norm(x, y, weight, epsilon=1e-6):
     if y.shape != shape:
         raise ValueError(f"residual shapes differ: {shape} vs {y.shape}")
     res_dt = jnp.promote_types(x.dtype, y.dtype)
+    _check(shape, res_dt)
     resid, out = _add_rms_core(x.reshape(-1, h).astype(res_dt),
                                y.reshape(-1, h).astype(res_dt),
                                weight, float(epsilon))

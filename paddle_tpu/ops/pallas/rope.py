@@ -32,7 +32,7 @@ import jax.numpy as jnp
 from ._x64 import x64_off
 from jax.experimental import pallas as pl
 
-__all__ = ["rope_apply"]
+__all__ = ["rope_apply", "supports"]
 
 # fp32 working-set budget per grid step (q+k+outs+cos/sin+temps)
 _VMEM_BUDGET = 6 * 1024 * 1024
@@ -62,19 +62,33 @@ def _rope_kernel(q_ref, k_ref, c_ref, s_ref, oq_ref, ok_ref, *, neg_sin):
     rot(k_ref, ok_ref)
 
 
-def _pick_rows(rows, per_row_f32):
-    cap = max(8, (_VMEM_BUDGET // max(per_row_f32, 1) // 8) * 8)
+def _pick_rows(rows, h, hk, d):
+    per_row = 4 * d * (3 * (h + hk) + 4)   # operands+outputs+temps, f32
+    cap = max(8, (_VMEM_BUDGET // max(per_row, 1) // 8) * 8)
     for br in (512, 256, 128, 64, 32, 16, 8):
         if br <= cap and rows % br == 0:
             return br
-    raise ValueError(f"no sublane-aligned row block for {rows} rows")
+    return None
+
+
+def supports(q_shape, k_shape, cos_shape) -> bool:
+    """Shape predicate for ops.apply_rope's kernel-or-XLA choice: q/k
+    [b, s, h|hk, d] with an even d, cos/sin [s, d] or [b, s, d], and a
+    sublane-aligned block of the b·s rows (the batch·seq < 8 decode
+    case has none)."""
+    if len(q_shape) != 4 or len(k_shape) != 4:
+        return False
+    b, s, h, d = q_shape
+    return (d >= 2 and d % 2 == 0
+            and tuple(k_shape[:2]) == (b, s) and k_shape[3] == d
+            and tuple(cos_shape) in ((s, d), (b, s, d))
+            and _pick_rows(b * s, h, k_shape[2], d) is not None)
 
 
 def _rope3(q3, k3, c2, s2, neg_sin):
     rows, h, d = q3.shape
     hk = k3.shape[1]
-    per_row = 4 * d * (3 * (h + hk) + 4)   # operands+outputs+temps, f32
-    br = _pick_rows(rows, per_row)
+    br = _pick_rows(rows, h, hk, d)
     grid = (rows // br,)
     with x64_off():
         oq, ok = pl.pallas_call(
@@ -88,6 +102,7 @@ def _rope3(q3, k3, c2, s2, neg_sin):
                        pl.BlockSpec((br, hk, d), lambda i: (i, 0, 0))],
             out_shape=[jax.ShapeDtypeStruct(q3.shape, q3.dtype),
                        jax.ShapeDtypeStruct(k3.shape, k3.dtype)],
+            name="rope",
             interpret=_interpret(),
         )(q3, k3, c2, s2)
     return oq, ok
@@ -141,22 +156,20 @@ _rope_core.defvjp(_rope_fwd, _rope_bwd)
 
 def rope_apply(q, k, cos, sin):
     """Pallas twin of ops.apply_rope: q [b, s, h, d], k [b, s, hk, d],
-    cos/sin [s, d] or [b, s, d].  Raises ValueError for shapes the
-    tiling cannot serve (caller falls back to the XLA path)."""
+    cos/sin [s, d] or [b, s, d].  Raises ValueError for shapes
+    `supports` refuses — ops.apply_rope asks the predicate first."""
+    if not supports(q.shape, k.shape, cos.shape):
+        raise ValueError(
+            f"unsupported shapes for the rope kernel (q {q.shape}, "
+            f"k {k.shape}, cos {cos.shape})")
     b, s, h, d = q.shape
     hk = k.shape[2]
-    if d % 2 or d < 2:
-        raise ValueError("rope kernel needs an even head_dim")
-    if k.shape[:2] != (b, s) or k.shape[3] != d:
-        raise ValueError("q/k shape mismatch for the rope kernel")
     if cos.ndim == 2:
         c2 = jnp.broadcast_to(cos[None], (b, s, d)).reshape(b * s, d)
         s2 = jnp.broadcast_to(sin[None], (b, s, d)).reshape(b * s, d)
-    elif cos.ndim == 3 and cos.shape == (b, s, d):
+    else:
         c2 = cos.reshape(b * s, d)
         s2 = sin.reshape(b * s, d)
-    else:
-        raise ValueError(f"unsupported cos/sin shape {cos.shape}")
     oq, ok = _rope_core(q.reshape(b * s, h, d), k.reshape(b * s, hk, d),
                         c2, s2)
     return oq.reshape(q.shape), ok.reshape(k.shape)

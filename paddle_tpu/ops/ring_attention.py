@@ -91,18 +91,9 @@ def ring_attention(q, k, v, mesh: Mesh, seq_axis: str = "sep",
                    causal: bool = False, scale=None):
     """Global entry: q/k/v [b, s, h, d] (sharded or shardable on
     `seq_axis` along dim 1); returns [b, s, h, d] sharded the same way."""
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     spec = P(None, seq_axis, None, None)
     body = functools.partial(ring_attention_local, axis_name=seq_axis,
                              causal=causal, scale=scale)
-    try:
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
                        out_specs=spec, check_vma=False)
-    except TypeError:  # older shard_map API
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
     return fn(q, k, v)

@@ -122,7 +122,6 @@ def apply_update(upd, p, g, s, lr, wd, step_i, hp, fused_ok=True,
             out = fused_adamw(g, s["moment1"], s["moment2"], master,
                               lr, step_i, ef=ef, **kw)
         else:
-            from jax.experimental.shard_map import shard_map
             sp = _pad_spec(spec, g.ndim)
             n_state = 4 if ef is None else 5
 
@@ -130,12 +129,12 @@ def apply_update(upd, p, g, s, lr, wd, step_i, hp, fused_ok=True,
                 return fused_adamw(g_, m_, v_, mst_, lr_, st_,
                                    ef=ef_[0] if ef_ else None, **kw)
 
-            out = shard_map(
+            out = jax.shard_map(
                 local, mesh=mesh,
                 in_specs=(sp, sp, sp, sp, P(), P())
                 + ((sp,) if ef is not None else ()),
                 out_specs=(sp,) * n_state,
-                check_rep=False,
+                check_vma=False,
             )(g, s["moment1"], s["moment2"], master,
               jnp.asarray(lr, jnp.float32), jnp.asarray(step_i, jnp.int32),
               *(() if ef is None else (ef,)))

@@ -14,6 +14,7 @@ e.g. a q_proj [h, mp] weight at stage 3 becomes [sharding → h, mp].
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from typing import Callable, Optional, Sequence
 
@@ -24,6 +25,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..framework.tensor import Tensor
 from ..framework import random as prandom
+from ..ops import kernel_mesh_scope
 
 __all__ = ["ShardedTrainStep", "make_batch_sharding",
            "activation_sharding_scope", "constrain_activation",
@@ -53,12 +55,18 @@ class activation_sharding_scope:
 
     def __init__(self, mesh, batch_axes, seq_axis=None, seq_dim=1):
         self._entry = (mesh, batch_axes, seq_axis, seq_dim)
+        # on more than one device the Pallas kernels the model
+        # dispatches run per shard (ops.kernel_mesh_scope)
+        self._kernels = kernel_mesh_scope(mesh, batch_axes) \
+            if mesh.size > 1 else contextlib.nullcontext()
 
     def __enter__(self):
         _ACT_SCOPE.append(self._entry)
+        self._kernels.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._kernels.__exit__(*exc)
         _ACT_SCOPE.pop()
         return False
 

@@ -42,8 +42,7 @@ from .registry import (MetricsRegistry, Counter, Gauge, Histogram,  # noqa: F401
 from .exporters import (JsonlSink, ChromeTraceSink, MemorySink,  # noqa: F401
                         attach_jsonl, attach_chrome_trace, chrome_event)
 from .compile_cache import (cache_dir, maybe_enable_persistent_cache,  # noqa: F401
-                            disable_persistent_cache, aot_compile,
-                            compile_report, clear_report)
+                            aot_compile, compile_report, clear_report)
 from . import probe  # noqa: F401
 from . import memledger  # noqa: F401
 from .memledger import memory_report  # noqa: F401
@@ -62,7 +61,7 @@ __all__ = ["MetricsRegistry", "Counter", "Gauge", "Histogram",
            "JsonlSink", "ChromeTraceSink", "MemorySink",
            "attach_jsonl", "attach_chrome_trace", "chrome_event",
            "cache_dir", "maybe_enable_persistent_cache",
-           "disable_persistent_cache", "aot_compile", "compile_report",
+           "aot_compile", "compile_report",
            "clear_report", "probe", "memledger", "memory_report",
            "costledger", "cost_report",
            "fleet", "flightrec", "FlightRecorder", "numerics",
@@ -111,15 +110,10 @@ def dump(compact: bool = False) -> dict:
     return out
 
 
-# a process launched with FLAGS_compile_cache_dir in its environment
-# (relaunched worker, fleet job) arms jax's persistent cache at import —
-# BEFORE any subsystem compiles; unset, this is one dict lookup.
-# Runtime set_flags() arming is picked up lazily at the next trainer
-# build or program-cache miss (aot_for / _model_program_cache).
-try:
-    maybe_enable_persistent_cache()
-except Exception:                       # cache must never break import
-    pass
+# jax's persistent compilation cache is pointed at cache_dir() at
+# import — BEFORE any subsystem compiles (nothing is set in code where
+# JAX_COMPILATION_CACHE_DIR already configured it).
+maybe_enable_persistent_cache()
 
 # same idiom for the incident flight recorder: FLAGS_flightrec_dir in
 # the environment arms the recorder before any subsystem emits; unset,

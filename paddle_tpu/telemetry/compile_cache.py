@@ -1,19 +1,26 @@
 """Persistent compilation cache + AOT executable serialization — the
 cold-start killer (ROADMAP item 5a: MULTICHIP_r05 logged a 3-minute XLA
 compile for ONE step; a 128-chip relaunch or re-elected elastic worker
-must not pay trace+compile again).
+must not pay trace+compile again — and every call to the chip starts a
+new machine, so without a cache on disk it compiles everything).
 
-Two layers, both armed by ``FLAGS_compile_cache_dir`` and both inert
-(one flag lookup) when it is unset:
+Two layers over ONE directory, `cache_dir()` — the environment's
+``JAX_COMPILATION_CACHE_DIR`` where set (jax reads it itself and this
+module sets NOTHING in code), else one fixed path inside the checkout
+(``<repo>/.jax_cache``, computed from this package's location — the
+path is part of the cache key's value: a directory that moves never
+hits):
 
-  1. **XLA persistent cache** — `jax.config` compilation-cache setup
-     pointed at ``<dir>``: every `jax.jit` in the process (trainers,
-     generate(), the serving batcher's scan programs) transparently
-     reuses compiled modules across processes.  Hit/miss counts are
-     scraped from jax's monitoring events into `compile_report()`.
-  2. **AOT executable store** — trainers additionally `.lower()` their
-     step once, fingerprint the StableHLO, and serialize the compiled
-     executable to ``<dir>/aot/``; a relaunched worker deserializes and
+  1. **XLA persistent cache** — on by default, with jax's own
+     thresholds.  Every `jax.jit` in the process (trainers, generate(),
+     the serving batcher's scan programs) transparently reuses compiled
+     modules across processes.  Hit/miss counts are scraped from jax's
+     monitoring events into `compile_report()`.
+  2. **AOT executable store** — armed by a non-empty
+     ``FLAGS_compile_cache_dir`` (the flag only arms it; it names no
+     directory): trainers additionally `.lower()` their step once,
+     fingerprint the StableHLO, and serialize the compiled executable
+     to ``<cache_dir()>/aot/``; a relaunched worker deserializes and
      SKIPS the XLA compile.  NOTE the hit path still pays tracing +
      lowering (the fingerprint requires the StableHLO) — seconds for a
      big model, vs the minutes-scale compile it skips; per-program
@@ -40,23 +47,34 @@ from ..framework.flags import get_flag
 # a `registry()` accessor that shadows the submodule attribute
 from .registry import counter as _counter, emit as _emit
 
-__all__ = ["cache_dir", "maybe_enable_persistent_cache",
-           "disable_persistent_cache", "aot_compile", "aot_for",
-           "compile_report", "clear_report"]
+__all__ = ["cache_dir", "maybe_enable_persistent_cache", "aot_compile",
+           "aot_for", "compile_report", "clear_report"]
+
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+# <repo>/.jax_cache — fixed, derived from where this package lives
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _lock = threading.Lock()
 _records: List[dict] = []
 _xla_counts = {"hits": 0, "misses": 0}
-_enabled_dir: Optional[str] = None
-_listener_installed = False
-_prior_jax_config: Optional[dict] = None
+_enabled = False
 
 
-def cache_dir() -> Optional[str]:
-    """The armed cache directory, or None.  THE fast-path guard: every
-    producer calls this first, and unset it is one dict lookup."""
-    d = get_flag("compile_cache_dir") or ""
-    return d or None
+def cache_dir() -> str:
+    """The directory in force for both layers: the environment's
+    JAX_COMPILATION_CACHE_DIR, else the fixed in-checkout default."""
+    return os.environ.get(_ENV) or DEFAULT_DIR
+
+
+def _aot_dir() -> Optional[str]:
+    """The AOT store's directory, or None while FLAGS_compile_cache_dir
+    is unset.  THE fast-path guard of the trainers: unset it is one
+    dict lookup."""
+    if not get_flag("compile_cache_dir"):
+        return None
+    return os.path.join(cache_dir(), "aot")
 
 
 def _on_jax_event(event: str):
@@ -68,75 +86,21 @@ def _on_jax_event(event: str):
         _counter("compile.xla_cache_misses").inc()
 
 
-def maybe_enable_persistent_cache() -> Optional[str]:
-    """Point jax's persistent compilation cache at FLAGS_compile_cache_dir
-    (idempotent; re-arms on a changed dir).  Returns the dir or None.
-
-    min_compile_time/min_entry_size are zeroed so even small programs
-    (and the CPU-backend tier-1 programs) persist — the default 1s
-    threshold would silently exclude exactly the quick-compiling
-    programs tests use to prove the wiring."""
-    global _enabled_dir, _listener_installed
-    d = cache_dir()
-    if d == _enabled_dir:
-        return _enabled_dir
-    if d is None:
-        # flag cleared after a previous arming: honor the documented
-        # "empty disables both layers" — otherwise every later jit
-        # keeps writing the stale (possibly deleted temp) dir
-        disable_persistent_cache()
-        return None
+def maybe_enable_persistent_cache() -> str:
+    """Once per process (the telemetry package calls it at import):
+    count jax's persistent-cache hits and misses, and — unless
+    JAX_COMPILATION_CACHE_DIR already configured jax — point its cache
+    at the fixed default directory.  Returns `cache_dir()`."""
+    global _enabled
     with _lock:
-        global _prior_jax_config
-        if d == _enabled_dir:
-            return _enabled_dir
-        import jax
-        os.makedirs(d, exist_ok=True)
-        if _prior_jax_config is None:
-            # snapshot whatever the user/env configured so disarming
-            # restores it instead of clobbering an independently-set
-            # jax cache (JAX_COMPILATION_CACHE_DIR etc.)
-            _prior_jax_config = {
-                "jax_compilation_cache_dir":
-                    jax.config.jax_compilation_cache_dir,
-                "jax_enable_compilation_cache":
-                    jax.config.jax_enable_compilation_cache,
-                "jax_persistent_cache_min_compile_time_secs":
-                    jax.config.jax_persistent_cache_min_compile_time_secs,
-                "jax_persistent_cache_min_entry_size_bytes":
-                    jax.config.jax_persistent_cache_min_entry_size_bytes,
-            }
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_enable_compilation_cache", True)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-        if not _listener_installed:
-            try:
-                from jax._src import monitoring
-                monitoring.register_event_listener(_on_jax_event)
-                _listener_installed = True
-            except Exception:
-                pass     # report simply lacks XLA-level counts
-        _enabled_dir = d
-    return d
-
-
-def disable_persistent_cache():
-    """Disarm the jax-level cache, restoring the config exactly as it
-    was before arming — including any user/env-configured cache dir and
-    the persistence thresholds (the zero-overhead bench assert and
-    flag-toggle tests restore pristine state through this)."""
-    global _enabled_dir, _prior_jax_config
-    with _lock:
-        if _enabled_dir is None:
-            return
-        import jax
-        for k, v in (_prior_jax_config or
-                     {"jax_compilation_cache_dir": None}).items():
-            jax.config.update(k, v)
-        _prior_jax_config = None
-        _enabled_dir = None
+        if not _enabled:
+            import jax
+            from jax._src import monitoring
+            monitoring.register_event_listener(_on_jax_event)
+            if not os.environ.get(_ENV):
+                jax.config.update("jax_compilation_cache_dir", DEFAULT_DIR)
+            _enabled = True
+    return cache_dir()
 
 
 # ---------------------------------------------------------------------------
@@ -164,7 +128,7 @@ def _fingerprint(lowered, label: str) -> str:
 def _aot_path(d: str, label: str, key: str) -> str:
     safe = "".join(c if c.isalnum() or c in "-_." else "_"
                    for c in label)
-    return os.path.join(d, "aot", f"{safe}-{key}.pdexec")
+    return os.path.join(d, f"{safe}-{key}.pdexec")
 
 
 def _record(rec: dict):
@@ -178,16 +142,30 @@ def _record(rec: dict):
     _emit("compile.program", rec)
 
 
-def aot_compile(jitfn, args: tuple, label: str):
+def _execution_devices(args, mesh):
+    """The devices a stored executable runs on, in assignment order:
+    the mesh's, else the ONE device its arguments live on (None when
+    that cannot be told).  Left unsaid, deserialize_and_load assumes
+    every device of the backend — wrong for a one-device program on a
+    host with several."""
+    if mesh is not None:
+        return list(mesh.devices.flat)
+    import jax
+    devs = {d for leaf in jax.tree.leaves(args)
+            if isinstance(leaf, jax.Array)
+            for d in leaf.sharding.device_set}
+    return list(devs) if len(devs) == 1 else None
+
+
+def aot_compile(jitfn, args: tuple, label: str, mesh=None):
     """Lower `jitfn` for `args`, then load-or-compile the executable
     through the AOT store.  Returns the compiled callable, or None when
     the flag is unset or anything in the AOT path fails (callers fall
     back to the plain jitted function — the cache must never be able to
     break a step).  Every outcome lands in `compile_report()`."""
-    d = cache_dir()
+    d = _aot_dir()
     if d is None:
         return None
-    maybe_enable_persistent_cache()
     try:
         t0 = time.perf_counter()
         lowered = jitfn.lower(*args)
@@ -199,7 +177,9 @@ def aot_compile(jitfn, args: tuple, label: str):
             t0 = time.perf_counter()
             with open(path, "rb") as f:
                 blob, in_tree, out_tree = pickle.load(f)
-            compiled = se.deserialize_and_load(blob, in_tree, out_tree)
+            compiled = se.deserialize_and_load(
+                blob, in_tree, out_tree,
+                execution_devices=_execution_devices(args, mesh))
             load_ms = (time.perf_counter() - t0) * 1e3
             _record({"label": label, "key": key, "cache": "hit",
                      "trace_ms": round(trace_ms, 2),
@@ -251,7 +231,7 @@ def aot_for(store: Dict[Any, Any], kind: str, jitfn, args: tuple,
     a batch shape change simply compiles a second entry.  `mesh` wraps
     the lowering so shardings resolve exactly as the jit path's
     would."""
-    if cache_dir() is None:
+    if _aot_dir() is None:
         return jitfn
     sig = (kind,) + tuple((tuple(b.shape), str(b.dtype))
                           for b in batch_vals)
@@ -259,7 +239,7 @@ def aot_for(store: Dict[Any, Any], kind: str, jitfn, args: tuple,
     if fn is None:
         if mesh is not None:
             with mesh:
-                fn = aot_compile(jitfn, args, label) or jitfn
+                fn = aot_compile(jitfn, args, label, mesh) or jitfn
         else:
             fn = aot_compile(jitfn, args, label) or jitfn
         store[sig] = fn
@@ -275,7 +255,7 @@ def compile_report() -> dict:
     hits = sum(1 for r in programs if r.get("cache") == "hit")
     misses = sum(1 for r in programs if r.get("cache") == "miss")
     return {
-        "dir": _enabled_dir or cache_dir(),
+        "dir": cache_dir(),
         "programs": programs,
         "aot_hits": hits,
         "aot_misses": misses,

@@ -276,7 +276,7 @@ def rank_info() -> Optional[tuple]:
 #   sync_steps: trainers block_until_ready the loss inside the step
 #     span so wall_ms is exact step wall (default off: with donated
 #     buffers steady-state dispatch wall tracks step wall, and a forced
-#     sync costs a relay round trip per step on tunneled accelerators)
+#     sync stalls the host's dispatch-ahead every step)
 _CONFIG_DEFAULTS = {"step_phases": True, "sync_steps": False}
 _CONFIG = dict(_CONFIG_DEFAULTS)
 

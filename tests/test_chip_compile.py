@@ -1,0 +1,207 @@
+"""The main path's Pallas kernels compile for the real chip, at the widths
+`chip_smoke.py` runs (llama_7b_config: hidden 4096, ffn 11008, 32 heads of
+128; seq 2048; page_size 16).
+
+The TPU's compiler is installed here and compiles for a chip that is
+DESCRIBED, not attached (`v5e:2x2`): what Mosaic or XLA would refuse on the
+chip — a block that does not tile, too much VMEM — it refuses here, at no
+chip time.  Interpret-mode tests cannot see any of that.  Nothing runs, so
+these tests say nothing about results or speed.
+
+Only one process may load the TPU's library, so the topology is described
+inside a module-scoped fixture (never at import), every compile happens in
+this process, and all of these tests live in this ONE file.
+"""
+import os
+import sys
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+HIDDEN, FFN, HEADS, HEAD_DIM, SEQ = 4096, 11008, 32, 128, 2048
+PAGE_SIZE, PAGES, SLOTS, PAGES_PER_SLOT, LAYERS, CHUNK = 16, 256, 8, 20, 4, 64
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def for_chip(one_chip, monkeypatch):
+    """compile(fn, *shapes) -> optimized HLO text of `fn` compiled for one
+    described v5e chip.  The kernels pick interpret mode from the backend
+    being the CPU; for the length of one test they are told it is a TPU.
+    The persistent compilation cache is off around the compile: an entry
+    written for a described chip cannot be read back without one."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+
+    def compile_(fn, *shapes):
+        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                for s, d in shapes]
+        return jax.jit(fn).lower(*args).compile().as_text()
+
+    yield compile_
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def _kernels(text):
+    """The names chip_smoke.py finds in a compiled program — its own
+    parser, so the smoke's kernel check is held to real compiler output."""
+    sys.path.insert(0, REPO)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.pop(0)
+    return set(chip_smoke.kernels_in(text))
+
+
+def _sum32(*outs):
+    return sum(jnp.sum(o.astype(jnp.float32)) for o in outs)
+
+
+@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])
+def test_flash_attention_fwd_bwd(for_chip, kv_heads):
+    from paddle_tpu.ops.pallas.flash_attention import flash_attention
+
+    def f(q, k, v):
+        return jax.value_and_grad(
+            lambda q, k, v: _sum32(flash_attention(q, k, v, causal=True)),
+            argnums=(0, 1, 2))(q, k, v)
+    bf = jnp.bfloat16
+    text = for_chip(f, ((1, SEQ, HEADS, HEAD_DIM), bf),
+                    ((1, SEQ, kv_heads, HEAD_DIM), bf),
+                    ((1, SEQ, kv_heads, HEAD_DIM), bf))
+    assert {"flash_attention_fwd", "flash_attention_bwd_dq",
+            "flash_attention_bwd_dkv"} <= _kernels(text)
+
+
+def test_rms_norm_fwd_bwd(for_chip):
+    from paddle_tpu.ops.pallas.rms_norm import rms_norm
+
+    def f(x, w):
+        return jax.value_and_grad(
+            lambda x, w: _sum32(rms_norm(x, w)), argnums=(0, 1))(x, w)
+    text = for_chip(f, ((2, SEQ, HIDDEN), jnp.bfloat16),
+                    ((HIDDEN,), jnp.bfloat16))
+    assert {"rms_norm_fwd", "rms_norm_bwd"} <= _kernels(text)
+
+
+def test_add_rms_norm_fwd_bwd(for_chip):
+    from paddle_tpu.ops.pallas.rms_norm import fused_add_rms_norm
+
+    def f(x, y, w):
+        return jax.value_and_grad(
+            lambda x, y, w: _sum32(*fused_add_rms_norm(x, y, w)),
+            argnums=(0, 1, 2))(x, y, w)
+    bf = jnp.bfloat16
+    text = for_chip(f, ((2, SEQ, HIDDEN), bf), ((2, SEQ, HIDDEN), bf),
+                    ((HIDDEN,), bf))
+    assert {"add_rms_norm_fwd", "add_rms_norm_bwd"} <= _kernels(text)
+
+
+def test_rope_fwd_bwd(for_chip):
+    from paddle_tpu.ops.pallas.rope import rope_apply
+
+    def f(q, k, cos, sin):
+        return jax.value_and_grad(
+            lambda q, k: _sum32(*rope_apply(q, k, cos, sin)),
+            argnums=(0, 1))(q, k)
+    bf = jnp.bfloat16
+    text = for_chip(f, ((2, SEQ, HEADS, HEAD_DIM), bf),
+                    ((2, SEQ, HEADS, HEAD_DIM), bf),
+                    ((SEQ, HEAD_DIM), jnp.float32),
+                    ((SEQ, HEAD_DIM), jnp.float32))
+    assert "rope" in _kernels(text)
+
+
+def test_kernels_per_shard_on_four_chips(topo, for_chip):
+    """What a four-chip trainer traces (chip_smoke --chips 4: sharding 2
+    x mp 2): inside ops.kernel_mesh_scope the dispatch runs rope, flash
+    attention and both norms under shard_map, and the program compiled
+    for the 2x2 mesh holds every kernel, forward and backward."""
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu import ops
+    mesh = Mesh(np.array(topo.devices).reshape(2, 2), ("sharding", "mp"))
+
+    def loss(x, y, w, q, k, v, cos, sin):
+        with ops.kernel_mesh_scope(mesh):
+            q, k = ops.apply_rope(q, k, cos, sin)
+            a = ops.attention(q, k, v, causal=True)
+            resid, h = ops.fused_add_rms_norm(x, y, w)
+            return _sum32(a, resid, ops.rms_norm(h, w))
+    bf = jnp.bfloat16
+    acts, heads, whole = (P("sharding", None, None),
+                          P("sharding", None, "mp", None), P())
+    shapes = ([((2, SEQ, HIDDEN), bf, acts)] * 2
+              + [((HIDDEN,), jnp.float32, whole)]
+              + [((2, SEQ, HEADS, HEAD_DIM), bf, heads)] * 3
+              + [((SEQ, HEAD_DIM), jnp.float32, whole)] * 2)
+    args = [jax.ShapeDtypeStruct(s, d, sharding=NamedSharding(mesh, sp))
+            for s, d, sp in shapes]
+    text = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4, 5))) \
+        .lower(*args).compile().as_text()
+    assert {"flash_attention_fwd", "flash_attention_bwd_dq",
+            "flash_attention_bwd_dkv", "rope", "rms_norm_fwd",
+            "rms_norm_bwd", "add_rms_norm_fwd",
+            "add_rms_norm_bwd"} <= _kernels(text)
+
+
+@pytest.mark.parametrize("master", [False, True],
+                         ids=["fp32_param", "bf16_param_fp32_master"])
+def test_fused_adamw(for_chip, master):
+    """One [hidden, ffn] leaf, bf16 moments: the fp32-param variant (the
+    param IS the master — what chip_smoke trains with) and the
+    half-param + fp32-master variant."""
+    from paddle_tpu.ops.pallas.fused_adamw import fused_adamw
+    leaf, bf, f32 = (HIDDEN, FFN), jnp.bfloat16, jnp.float32
+
+    def f(g, m, v, mst, lr, step):
+        return fused_adamw(g, m, v, mst, lr, step, wd=0.1,
+                           out_dtype=bf if master else f32)
+    text = for_chip(f, (leaf, bf if master else f32), (leaf, bf),
+                    (leaf, bf), (leaf, f32), ((), f32), ((), jnp.int32))
+    assert "fused_adamw" in _kernels(text)
+
+
+@pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])
+@pytest.mark.parametrize("width", [1, CHUNK], ids=["decode", "chunk"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_paged_attention(for_chip, pool, width, kv_heads):
+    """The serve step's kernel over the init_paged_cache layout
+    [pages, layers, kv_heads, page_size, head_dim]: decode (C = 1) and
+    chunked prefill (C = chunk), bf16 and int8 pools, MHA and GQA."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    quant = pool == "int8"
+    pool_s = ((PAGES, LAYERS, kv_heads, PAGE_SIZE, HEAD_DIM),
+              jnp.int8 if quant else jnp.bfloat16)
+    scale_s = ((PAGES, LAYERS, kv_heads), jnp.float32)
+
+    def f(q, kp, vp, pt, pos, ks, vs):
+        return paged_attention(q, kp, vp, pt, pos, LAYERS - 1,
+                               ks if quant else None,
+                               vs if quant else None)
+    text = for_chip(f, ((SLOTS, width, HEADS, HEAD_DIM), jnp.bfloat16),
+                    pool_s, pool_s, ((SLOTS, PAGES_PER_SLOT), jnp.int32),
+                    ((SLOTS,), jnp.int32), scale_s, scale_s)
+    assert "paged_attention" in _kernels(text)

@@ -689,7 +689,8 @@ class TestMemoryLedger:
         assert lint_peak_hbm(lowered.compile(),
                              budget_bytes=10 ** 12) == []
 
-    def test_aot_capture_not_clobbered_and_matches_lazy(self, tmp_path):
+    def test_aot_capture_not_clobbered_and_matches_lazy(self, tmp_path,
+                                                        monkeypatch):
         """With FLAGS_compile_cache_dir armed the AOT path captures
         stats for FREE at its own compile — note_jit (registered
         before aot_for) must not clobber them back to pending, and the
@@ -701,14 +702,16 @@ class TestMemoryLedger:
             "jit.TrainStep.step"]
         assert lazy["status"] == "ok"
         telemetry.reset()
-        set_flags({"FLAGS_compile_cache_dir": str(tmp_path / "c")})
+        # the store goes to the cache directory in force: keep it out
+        # of the checkout's
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
+        set_flags({"FLAGS_compile_cache_dir": "1"})
         try:
             paddle.seed(0)
             step2, x2 = _mlp_step()
             step2(x2, x2)
         finally:
             set_flags({"FLAGS_compile_cache_dir": ""})
-            telemetry.disable_persistent_cache()
         snap = telemetry.memledger.snapshot()["programs"][
             "jit.TrainStep.step"]
         assert snap["status"] == "ok", snap     # free capture survived

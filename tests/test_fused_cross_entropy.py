@@ -124,7 +124,7 @@ class TestKernelParity:
         hidden AND the local weight shard must match the unsharded
         reference."""
         from jax.sharding import Mesh, PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
 
         n_shards = 4
         h, w, _, lbl = _data(n=16, h=8, v=64)
@@ -147,7 +147,7 @@ class TestKernelParity:
             local, mesh=mesh,
             in_specs=(P(), P(None, "mp"), P()),
             out_specs=(P(), P(), P(None, "mp")),
-            check_rep=False))(h, w, lbl)
+            check_vma=False))(h, w, lbl)
 
         def ref(h, w):
             return _ref_loss(h, w, None, lbl)

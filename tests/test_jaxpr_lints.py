@@ -65,7 +65,7 @@ class TestDtypeLint:
 
 class TestIterEqnsDedupe:
     def test_shared_subjaxpr_walked_once(self):
-        # two pjit call sites of one jitted fn reference the SAME
+        # two jit call sites of one jitted fn reference the SAME
         # ClosedJaxpr: the walk must yield its body once (r22 dedupe)
         from paddle_tpu.analysis.lints import iter_eqns
         inner = jax.jit(lambda x: jnp.sin(x) * 2.0)
@@ -76,7 +76,7 @@ class TestIterEqnsDedupe:
         jaxpr = jax.make_jaxpr(outer)(jnp.float32(1.0))
         eqns = list(iter_eqns(jaxpr))
         assert len([e for e in eqns
-                    if e.primitive.name == "pjit"]) == 2
+                    if e.primitive.name == "jit"]) == 2
         assert len([e for e in eqns
                     if e.primitive.name == "sin"]) == 1
 
@@ -86,7 +86,7 @@ class TestIterEqnsDedupe:
         once = lint_dtype_promotion(lambda v: inner(v), x)
         twice = lint_dtype_promotion(lambda v: inner(v) + inner(v), x)
         assert "fp32-upcast" in _codes(once)
-        # each pjit CALL SITE is still its own finding, but the shared
+        # each jit CALL SITE is still its own finding, but the shared
         # body's convert_element_type must not double
         def body_hits(findings):
             return [g for g in findings
@@ -201,7 +201,7 @@ class TestCollectiveOrder:
         return Mesh(np.array(jax.devices()[:4]).reshape(4), ("dp",))
 
     def test_schedule_extraction_in_program_order(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = self._mesh()
 
         def f(x):
@@ -217,7 +217,7 @@ class TestCollectiveOrder:
         assert all(e.domain == ("dp",) for e in sched)
 
     def test_identical_schedules_pass(self):
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         mesh = self._mesh()
         fm = shard_map(lambda x: jax.lax.psum(x, "dp"), mesh=mesh,
                        in_specs=P("dp"), out_specs=P())

@@ -62,6 +62,16 @@ def _batch(n=2, s=16):
         rng.randint(0, 64, (n, s)).astype(np.int32))
 
 
+def _first_grads(x, L=3, seed=7):
+    """|dloss/dw| of the freshly seeded model on ``x``, by eager
+    backward — independent of both trainers under test."""
+    paddle.seed(seed)
+    m = LlamaForCausalLM(_cfg(L))
+    m.compute_loss(m(x), x).backward()
+    return {n: np.abs(np.asarray(p.grad.value))
+            for n, p in m.named_parameters()}
+
+
 class TestParity:
     def test_three_step_losses_match_in_hbm_trainer(self):
         """Same wire dtype as storage → the satellite's parity bar:
@@ -76,10 +86,27 @@ class TestParity:
         np.testing.assert_allclose(pipe, base, rtol=2e-6, atol=1e-7)
         s2.sync_to_model()
         sd1, sd2 = m1.state_dict(), m2.state_dict()
+        # The weights hold atol 1e-6 except where the first gradient is
+        # within 1000x of AdamW's eps (1e-8): step 1 moves a weight by
+        # lr*g/(|g|+eps), whose slope lr*eps/(|g|+eps)^2 turns a
+        # last-bit reassociation of g into a visible fraction of lr.
+        # Measured on this tree: the only two elements off by more than
+        # 1e-6 (3.3e-6, 2.5e-5, both set in step 1 and constant after)
+        # are the two smallest |g| of layers.2.mlp.up_proj, 4.0e-7 and
+        # 9.6e-8 against a median of 4e-3.  Those few (28 of 35040
+        # here) get 1% of one lr step instead.
+        g0 = _first_grads(x)
+        n_sensitive = n_total = 0
         for n in sd1:
-            np.testing.assert_allclose(
-                np.asarray(sd2[n].value), np.asarray(sd1[n].value),
-                rtol=1e-5, atol=1e-6, err_msg=n)
+            a, b = np.asarray(sd1[n].value), np.asarray(sd2[n].value)
+            sens = (g0[n] > 0) & (g0[n] < 1e-5)
+            n_sensitive += int(sens.sum())
+            n_total += sens.size
+            np.testing.assert_allclose(b[~sens], a[~sens],
+                                       rtol=1e-5, atol=1e-6, err_msg=n)
+            np.testing.assert_allclose(b[sens], a[sens],
+                                       rtol=0, atol=1e-4, err_msg=n)
+        assert n_sensitive <= n_total // 500, (n_sensitive, n_total)
 
     def test_bf16_wire_cast_stays_close(self):
         """bf16 wire: params cross host→HBM as bf16 (half the DMA
@@ -158,6 +185,20 @@ class TestOneProgram:
         # fwd streams L wire layers; bwd streams L (param+state) bundles
         assert sb["h2d_bytes"] > sb["d2h_bytes"] > 0
         assert p.dma_probe(reps=1) > 0.0
+
+    def test_packed_wire_stack_in_pinned_host_is_refused(self,
+                                                         monkeypatch):
+        """Where the stacks are placed in pinned_host (the TPU), a bf16
+        wire stack makes the TPU compiler abort the process (PR 21):
+        the constructor says so before anything is built.  The stored
+        dtype streams fine."""
+        from paddle_tpu.parallel import offload_pipeline as op
+        monkeypatch.setattr(op, "supports_memory_kinds", lambda: True)
+        with pytest.raises(NotImplementedError, match="pinned_host"):
+            _make("pipe", cast_dtype="bfloat16")
+        with pytest.raises(NotImplementedError, match="pinned_host"):
+            _make("front", offload_cast_dtype="bfloat16")
+        _make("pipe", cast_dtype=None)
 
     def test_depth_must_be_positive(self):
         with pytest.raises(ValueError, match="prefetch_depth"):

@@ -346,15 +346,15 @@ class TestPagedAttention:
     def _pool(self, P=10, ps=8, L=2, n_kv=2, d=16, quant=False):
         if quant:
             kp = jnp.asarray(_rng.randint(-127, 128,
-                                          (P, ps, L, n_kv, d)), jnp.int8)
+                                          (P, L, n_kv, ps, d)), jnp.int8)
             vp = jnp.asarray(_rng.randint(-127, 128,
-                                          (P, ps, L, n_kv, d)), jnp.int8)
+                                          (P, L, n_kv, ps, d)), jnp.int8)
             ks = jnp.asarray(_rng.rand(P, L, n_kv) * 0.05 + 0.01,
                              jnp.float32)
             vs = jnp.asarray(_rng.rand(P, L, n_kv) * 0.05 + 0.01,
                              jnp.float32)
             return kp, vp, ks, vs
-        return r(P, ps, L, n_kv, d), r(P, ps, L, n_kv, d), None, None
+        return r(P, L, n_kv, ps, d), r(P, L, n_kv, ps, d), None, None
 
     @pytest.mark.parametrize("C,h", [(1, 4), (4, 8), (4, 2)])
     def test_forward_vs_twin(self, C, h):
@@ -414,8 +414,8 @@ class TestPagedKVUpdate:
     def test_rows_land_exactly(self):
         from paddle_tpu.ops import paged_kv_update
         B, C, P, ps, P_slot, L, n_kv, d = 2, 3, 12, 4, 5, 2, 2, 8
-        kp = jnp.zeros((P, ps, L, n_kv, d), jnp.float32)
-        vp = jnp.zeros((P, ps, L, n_kv, d), jnp.float32)
+        kp = jnp.zeros((P, L, n_kv, ps, d), jnp.float32)
+        vp = jnp.zeros((P, L, n_kv, ps, d), jnp.float32)
         pt = jnp.asarray(_rng.permutation(P - 1)[:B * P_slot]
                          .reshape(B, P_slot) + 1, jnp.int32)
         pos = jnp.asarray([2, 6], jnp.int32)
@@ -423,20 +423,21 @@ class TestPagedKVUpdate:
         kp2, vp2, _, _ = paged_kv_update(kp, vp, None, None, pt, pos,
                                          kn, vn, layer=1)
         # logical view must hold exactly the written rows
-        lg = np.asarray(jnp.take(kp2[:, :, 1], pt, axis=0)
+        lg = np.asarray(jnp.take(kp2[:, 1], pt, axis=0)
+                        .transpose(0, 1, 3, 2, 4)
                         .reshape(B, P_slot * ps, n_kv, d))
         for b in range(B):
             p0 = int(pos[b])
             np.testing.assert_array_equal(lg[b, p0:p0 + C],
                                           np.asarray(kn[b]))
         # layer 0 untouched
-        assert not np.asarray(kp2[:, :, 0]).any()
+        assert not np.asarray(kp2[:, 0]).any()
 
     def test_untouched_pages_keep_bytes(self):
         from paddle_tpu.ops import paged_kv_update
         B, C, P, ps, P_slot, L, n_kv, d = 1, 2, 8, 4, 4, 1, 2, 8
-        kp = r(P, ps, L, n_kv, d)
-        vp = r(P, ps, L, n_kv, d)
+        kp = r(P, L, n_kv, ps, d)
+        vp = r(P, L, n_kv, ps, d)
         pt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
         pos = jnp.asarray([5], jnp.int32)     # rows 5,6 → page 1 only
         kn, vn = r(B, C, n_kv, d), r(B, C, n_kv, d)
@@ -450,8 +451,8 @@ class TestPagedKVUpdate:
     def test_int8_requant_roundtrip(self):
         from paddle_tpu.ops import paged_kv_update, xla_paged_attention
         B, C, P, ps, P_slot, L, n_kv, d = 1, 4, 8, 4, 4, 1, 2, 8
-        kp = jnp.zeros((P, ps, L, n_kv, d), jnp.int8)
-        vp = jnp.zeros((P, ps, L, n_kv, d), jnp.int8)
+        kp = jnp.zeros((P, L, n_kv, ps, d), jnp.int8)
+        vp = jnp.zeros((P, L, n_kv, ps, d), jnp.int8)
         ks = jnp.ones((P, L, n_kv), jnp.float32)
         vs = jnp.ones((P, L, n_kv), jnp.float32)
         pt = jnp.asarray([[1, 2, 3, 4]], jnp.int32)
@@ -459,10 +460,174 @@ class TestPagedKVUpdate:
         kp, vp, ks, vs = paged_kv_update(kp, vp, ks, vs, pt,
                                          jnp.asarray([0], jnp.int32),
                                          kn, vn, layer=0)
-        lg = np.asarray(jnp.take(kp[:, :, 0], pt, axis=0)
-                        .astype(np.float32)
-                        * np.asarray(jnp.take(ks[:, 0], pt, axis=0)
-                                     )[:, :, None, :, None]) \
-            .reshape(B, P_slot * ps, n_kv, d)
+        lg = (np.asarray(jnp.take(kp[:, 0], pt, axis=0)
+                         .astype(np.float32))
+              * np.asarray(jnp.take(ks[:, 0], pt, axis=0)
+                           )[:, :, :, None, None]) \
+            .transpose(0, 1, 3, 2, 4).reshape(B, P_slot * ps, n_kv, d)
         np.testing.assert_allclose(lg[0, :C], np.asarray(kn[0]),
                                    atol=0.03, rtol=0.05)
+
+
+class TestDispatchDoesNotHideTheKernel:
+    """paddle_tpu.ops picks kernel-or-twin from the backend and the shapes
+    (each kernel module's `supports` predicate) and calls the kernel
+    outside any try: what its lowering raises reaches the caller.
+
+    Here the process is told that its backend is a TPU, so dispatch picks
+    the kernel out of interpret mode, while the arrays live on the CPU —
+    the Pallas lowering refuses with a ValueError, the very type the old
+    dispatch caught to carry on with the XLA twin."""
+
+    def test_attention_lowering_error_reaches_caller(self, monkeypatch):
+        from paddle_tpu import ops
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        q = r(1, 128, 2, 128)
+        with pytest.raises(ValueError, match="interpret mode"):
+            ops.attention(q, q, q, causal=True)
+
+    def test_paged_attention_lowering_error_reaches_caller(self,
+                                                           monkeypatch):
+        from paddle_tpu import ops
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        kp = r(8, 1, 2, 8, 128)
+        pt = jnp.zeros((2, 3), jnp.int32)
+        pos = jnp.zeros((2,), jnp.int32)
+        with pytest.raises(ValueError, match="interpret mode"):
+            ops.paged_attention(r(2, 1, 2, 128), kp, kp, pt, pos, 0)
+
+    def test_unsupported_shape_takes_the_twin_by_predicate(self,
+                                                           monkeypatch):
+        """head_dim 16 does not tile: the predicate says so and the twin
+        answers — no kernel is tried, nothing is caught."""
+        from paddle_tpu import ops
+        from paddle_tpu.ops.pallas import paged_attention as k
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        kp = r(8, 1, 2, 8, 16)
+        q = r(2, 1, 2, 16)
+        assert not k.supports(kp.shape)
+        pt = jnp.zeros((2, 3), jnp.int32)
+        pos = jnp.zeros((2,), jnp.int32)
+        out = ops.paged_attention(q, kp, kp, pt, pos, 0)
+        ref = ops.xla_paged_attention(q, kp, kp, pt, pos, 0)
+        np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+class TestKernelsPerShard:
+    """On a mesh of more than one device a trainer enters
+    ops.kernel_mesh_scope and dispatch runs each kernel under shard_map
+    (GSPMD cannot partition a Mosaic kernel).  Four virtual devices,
+    sharding 2 x mp 2 as chip_smoke's four-chip phase: values and
+    gradients through ops' own entry points equal the XLA paths'.
+    Inside the scope dispatch is told it may pick kernels; the kernels
+    themselves still see a CPU backend and run in interpret mode."""
+
+    @pytest.fixture()
+    def scoped(self, monkeypatch):
+        """scoped(fn) -> fn traced as a four-device trainer traces it."""
+        from jax.sharding import Mesh
+        from paddle_tpu import ops
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2),
+                    ("sharding", "mp"))
+
+        def wrap(fn):
+            def inner(*a):
+                with monkeypatch.context() as m, \
+                        ops.kernel_mesh_scope(mesh):
+                    m.setattr(ops, "_on_tpu", lambda: True)
+                    return fn(*a)
+            return inner
+        return wrap
+
+    @staticmethod
+    def _check(per_shard, xla, args, argnums, tol):
+        assert "shard_map" in str(jax.make_jaxpr(per_shard)(*args))
+        assert "pallas_call" not in str(jax.make_jaxpr(xla)(*args))
+        got = jax.jit(jax.value_and_grad(per_shard, argnums))(*args)
+        want = jax.value_and_grad(xla, argnums)(*args)
+        for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("kv_heads", [4, 2, 1],
+                             ids=["mha", "gqa", "mqa_heads_stay_whole"])
+    def test_attention(self, scoped, kv_heads):
+        from paddle_tpu import ops
+        q, k, v = (r(2, 256, 4, 128), r(2, 256, kv_heads, 128),
+                   r(2, 256, kv_heads, 128))
+
+        def loss(q, k, v):
+            return jnp.sum(ops.attention(q, k, v, causal=True) ** 2)
+        self._check(scoped(loss), loss, (q, k, v), (0, 1, 2), 1e-3)
+
+    def test_rms_norm_and_add_rms_norm(self, scoped):
+        from paddle_tpu import ops
+        x, y, w = r(4, 16, 256), r(4, 16, 256), r(256)
+        probe = jnp.arange(256, dtype=jnp.float32)
+
+        def loss(x, y, w):
+            resid, h = ops.fused_add_rms_norm(x, y, w)
+            return jnp.sum(ops.rms_norm(h, w) * probe) + jnp.sum(resid ** 2)
+        self._check(scoped(loss), loss, (x, y, w), (0, 1, 2), 2e-3)
+
+    def test_rope(self, scoped):
+        from paddle_tpu import ops
+        q, k = r(2, 16, 4, 128), r(2, 16, 2, 128)
+        cos, sin = rope_cos_sin(16, 128)
+
+        def loss(q, k):
+            a, b = ops.apply_rope(q, k, cos, sin)
+            return jnp.sum(a ** 2 * 0.5) + jnp.sum(b ** 3)
+        self._check(scoped(loss), loss, (q, k), (0, 1), 1e-4)
+
+    def test_shapes_one_device_sees_decide(self, scoped):
+        """Eight rows over two batch shards leave four per device: the
+        rope kernel has no sublane-aligned block for that, `supports`
+        says so about the LOCAL shape, and the XLA path answers."""
+        from paddle_tpu import ops
+        from paddle_tpu.ops.pallas import rope as k
+        q = r(2, 4, 4, 128)
+        cos, sin = rope_cos_sin(4, 128)
+        assert k.supports(q.shape, q.shape, cos.shape)
+        assert not k.supports((1, 4, 2, 128), (1, 4, 2, 128), cos.shape)
+        f = scoped(lambda q: ops.apply_rope(q, q, cos, sin)[0])
+        assert "pallas_call" not in str(jax.make_jaxpr(f)(q))
+
+    def test_four_device_trainer_matches_xla_paths(self, monkeypatch):
+        """The whole path: ShardedTrainStep (ZeRO-3, sharding 2 x mp 2,
+        GQA llama) enters the scope by itself; with the kernels picked
+        its first three losses equal the XLA paths' to fp32 rounding,
+        and the lowered step holds one manual region per kernel call
+        (2 layers x (rope, flash, add+rms, rms) + the final norm,
+        forward and backward)."""
+        import paddle_tpu as paddle
+        from paddle_tpu import ops
+        from paddle_tpu.distributed.topology import build_mesh
+        from paddle_tpu.models.llama import (LlamaConfig, LlamaForCausalLM,
+                                             shard_llama_tp)
+        from paddle_tpu.parallel import ShardedTrainStep
+        x = paddle.to_tensor(np.random.RandomState(0).randint(
+            0, 128, (4, 128)).astype(np.int32))
+
+        def run():
+            cfg = LlamaConfig(
+                vocab_size=128, hidden_size=256, intermediate_size=512,
+                num_hidden_layers=2, num_attention_heads=4,
+                num_key_value_heads=2, max_position_embeddings=128,
+                dtype="float32")
+            paddle.seed(3)
+            model = LlamaForCausalLM(cfg)
+            mesh = build_mesh(sharding=2, mp=2, devices=jax.devices()[:4])
+            shard_llama_tp(model, mesh)
+            opt = paddle.optimizer.AdamW(
+                1e-3, parameters=model.parameters(), weight_decay=0.1)
+            step = ShardedTrainStep(model, opt, mesh, sharding_stage=3)
+            manual = step.compiled_hlo(x, x, optimized=False).count(
+                "manual_computation")
+            return manual, [float(np.asarray(step(x, x).value))
+                            for _ in range(3)]
+        n_xla, want = run()
+        monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+        n_kernels, got = run()
+        assert (n_xla, n_kernels) == (0, 18)
+        np.testing.assert_allclose(got, want, rtol=1e-5)

@@ -48,7 +48,6 @@ def _clean_plane():
     for s in telemetry.sinks():
         telemetry.remove_sink(s)
     set_flags({"FLAGS_compile_cache_dir": ""})
-    telemetry.disable_persistent_cache()
 
 
 def _mlp_step():
@@ -241,7 +240,8 @@ print("RESULT " + json.dumps({
 class TestCompileCache:
     def _run(self, cache_dir):
         env = dict(os.environ, JAX_PLATFORMS="cpu",
-                   FLAGS_compile_cache_dir=cache_dir,
+                   JAX_COMPILATION_CACHE_DIR=cache_dir,
+                   FLAGS_compile_cache_dir="1",
                    PYTHONPATH=REPO + os.pathsep
                    + os.environ.get("PYTHONPATH", ""))
         out = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT],
@@ -268,14 +268,18 @@ class TestCompileCache:
         # and the cached executable computes the same training step
         assert second["loss"] == pytest.approx(first["loss"])
 
-    def test_aot_in_process_flags_off_identical(self, tmp_path):
-        """Arming + disarming the cache leaves the flags-off path
-        untouched, and the armed path really serves from the store."""
+    def test_aot_in_process_flags_off_identical(self, tmp_path,
+                                                monkeypatch):
+        """Arming + disarming the AOT store leaves the flags-off path
+        untouched, and the armed path really serves from the store —
+        which lives in the cache directory in force, not in one the
+        flag names."""
         from paddle_tpu.framework.flags import set_flags
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "c"))
         step, x = _mlp_step()
         l_off = float(np.asarray(step(x, x).value))
         telemetry.clear_report()
-        set_flags({"FLAGS_compile_cache_dir": str(tmp_path / "c")})
+        set_flags({"FLAGS_compile_cache_dir": str(tmp_path / "flag")})
         try:
             paddle.seed(0)
             step2, x2 = _mlp_step()
@@ -283,28 +287,78 @@ class TestCompileCache:
             rep = telemetry.compile_report()
             assert rep["programs"], "armed flag produced no AOT records"
             assert os.path.isdir(str(tmp_path / "c" / "aot"))
+            assert not os.path.exists(str(tmp_path / "flag"))
         finally:
             set_flags({"FLAGS_compile_cache_dir": ""})
-            telemetry.disable_persistent_cache()
         assert l_on == pytest.approx(l_off)
 
-    def test_flag_clear_disarms_jax_cache(self, tmp_path):
-        """Clearing FLAGS_compile_cache_dir must disarm the jax-level
-        persistent cache on the next arming check — 'empty disables
-        both layers' (regression: it used to stay pointed at the stale
-        dir)."""
+    def test_flag_moves_nothing_in_jax(self, tmp_path):
+        """The flag only arms the AOT store: setting and clearing it
+        leaves the directory in force, and jax's own cache settings,
+        where they were (it used to re-point the XLA cache and zero
+        jax's thresholds, and had to restore both)."""
         import jax
         from paddle_tpu.framework.flags import set_flags
         from paddle_tpu.telemetry import compile_cache as cc
+        assert cc.cache_dir() == cc.DEFAULT_DIR \
+            == os.path.join(REPO, ".jax_cache")
+        assert cc._aot_dir() is None
+
+        def settings():
+            return (jax.config.jax_compilation_cache_dir,
+                    jax.config.jax_persistent_cache_min_compile_time_secs,
+                    jax.config.jax_persistent_cache_min_entry_size_bytes)
+        before = settings()
+        assert before[0] == cc.DEFAULT_DIR
         set_flags({"FLAGS_compile_cache_dir": str(tmp_path / "c")})
         try:
-            assert cc.maybe_enable_persistent_cache() is not None
-            assert jax.config.jax_compilation_cache_dir \
-                == str(tmp_path / "c")
+            assert cc.maybe_enable_persistent_cache() == cc.DEFAULT_DIR
+            assert cc._aot_dir() == os.path.join(cc.DEFAULT_DIR, "aot")
+            assert settings() == before
         finally:
             set_flags({"FLAGS_compile_cache_dir": ""})
-        assert cc.maybe_enable_persistent_cache() is None
-        assert jax.config.jax_compilation_cache_dir is None
+        assert cc._aot_dir() is None and settings() == before
+
+    def test_env_dir_wins_over_flag_and_default_is_fixed(self, tmp_path):
+        """Where JAX_COMPILATION_CACHE_DIR is set the program uses that
+        directory and sets no other in code — FLAGS_compile_cache_dir
+        only arms the AOT store, INSIDE the environment's directory.
+        Without either, every process uses the one fixed path inside
+        the checkout."""
+        script = (
+            "import json, jax, paddle_tpu\n"
+            "from paddle_tpu import telemetry\n"
+            "from paddle_tpu.telemetry import compile_cache as cc\n"
+            "print('RESULT ' + json.dumps({\n"
+            "    'dir': telemetry.cache_dir(),\n"
+            "    'jax': jax.config.jax_compilation_cache_dir,\n"
+            "    'aot': cc._aot_dir(),\n"
+            "    'report': telemetry.compile_report()['dir']}))\n")
+
+        def run(**extra):
+            env = {k: v for k, v in os.environ.items()
+                   if k not in ("JAX_COMPILATION_CACHE_DIR",
+                                "FLAGS_compile_cache_dir")}
+            env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO, **extra)
+            out = subprocess.run([sys.executable, "-c", script], env=env,
+                                 text=True, capture_output=True,
+                                 timeout=300)
+            assert out.returncode == 0, out.stderr[-2000:]
+            line = next(l for l in out.stdout.splitlines()
+                        if l.startswith("RESULT "))
+            return json.loads(line[len("RESULT "):])
+
+        env_dir, flag_dir = str(tmp_path / "env"), str(tmp_path / "flag")
+        both = run(JAX_COMPILATION_CACHE_DIR=env_dir,
+                   FLAGS_compile_cache_dir=flag_dir)
+        assert both["dir"] == both["jax"] == both["report"] == env_dir
+        assert both["aot"] == os.path.join(env_dir, "aot")
+        assert not os.path.exists(flag_dir)
+        default = os.path.join(REPO, ".jax_cache")
+        plain = run()
+        assert plain["dir"] == plain["jax"] == plain["report"] == default
+        assert plain["aot"] is None
+        assert run() == plain                 # the same path every time
 
 
 # ---------------------------------------------------------------------------

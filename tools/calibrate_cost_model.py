@@ -80,14 +80,11 @@ def _bert_point():
 
 
 def _chip_name():
-    import jax
-    if jax.default_backend() != "tpu":
-        return "v5e"  # placeholder on CPU runs
-    kind = jax.devices()[0].device_kind.lower()
-    for name in ("v6e", "v5p", "v5e", "v4"):
-        if name in kind.replace(" ", ""):
-            return name
-    return "v5e"
+    """The generation the cost model is asked about: this device's row
+    of the cost ledger's table (an unknown TPU raises there); off-TPU the
+    v5e the r05 calibration rows were measured on."""
+    from paddle_tpu.telemetry.costledger import backend_peaks
+    return backend_peaks()["chip"] or "v5e"
 
 
 def calibrate():

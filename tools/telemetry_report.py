@@ -533,7 +533,8 @@ def _selftest():
     with tempfile.TemporaryDirectory() as d:
         log = os.path.join(d, "steps.jsonl")
         from paddle_tpu.framework.flags import set_flags
-        set_flags({"FLAGS_compile_cache_dir": os.path.join(d, "cache")})
+        # arms the AOT store (in telemetry.cache_dir(), a few KB)
+        set_flags({"FLAGS_compile_cache_dir": "1"})
         try:
             import paddle_tpu as paddle
             from paddle_tpu import telemetry
@@ -577,8 +578,6 @@ def _selftest():
                 telemetry.remove_sink(sink)
         finally:
             set_flags({"FLAGS_compile_cache_dir": ""})
-            from paddle_tpu.telemetry import disable_persistent_cache
-            disable_persistent_cache()
 
         events = load_events(log)
         steps = [e for e in events if e.get("event") == "train.step"]
