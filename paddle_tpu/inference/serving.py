@@ -94,6 +94,7 @@ per-sequence outputs are testable against isolated `generate()` runs.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import threading
 import time
@@ -157,6 +158,12 @@ def unpack_handoff(blob: bytes):
 # admission priority order, highest first; shedding walks it in reverse
 SLO_CLASSES = ("interactive", "batch", "best_effort")
 
+# what one step() spends its time in, each a `serve.<phase>` span.
+# `deliver` lies inside `harvest` as a span; as a duration `harvest` is
+# what is left without it, so the six add up to at most the step
+PHASES = ("evict", "admit", "dispatch", "device_wait", "harvest",
+          "deliver")
+
 
 @dataclass
 class Request:
@@ -182,6 +189,13 @@ class Request:
     t_admit: Optional[float] = None
     t_first: Optional[float] = None
     t_done: Optional[float] = None
+    # the chunk (the batcher's running number, the `chunk` id of its
+    # `serve.step` span) in which each of those happened, -1 until it
+    # has: a request's queue / prefill / decode intervals name the
+    # chunk spans that caused them
+    admit_chunk: int = -1
+    first_token_chunk: int = -1
+    done_chunk: int = -1
     # -- streaming (ISSUE 11 satellite): per-request token callback,
     # fired as chunks complete with each NEW burst of output-surviving
     # tokens (speculation delivers a whole accepted run in one burst);
@@ -478,6 +492,13 @@ class ContinuousBatcher:
         # otherwise grow per-chunk lists forever); p50 is over the
         # window, max/counts/occupancy over the whole lifetime
         self._chunk_times: deque = deque(maxlen=1024)
+        # beside it, each chunk's step() split by phase (ms, in PHASES'
+        # order), taken whether or not anything listens: whether a slow
+        # stretch was the host's or the device's
+        self._phase_times: deque = deque(maxlen=1024)
+        self._phase_ms = dict.fromkeys(PHASES, 0.0)
+        self._chunk_no = 0              # the chunk step() is working on
+        self._chunk_event = None        # serve.chunk's fields
         # per-request latency windows (bounded, same discipline as the
         # chunk times) + per-SLO-class deadline attainment — host
         # aggregates that always accumulate so stats() answers sink-less
@@ -696,24 +717,61 @@ class ContinuousBatcher:
         Once `guard.drain_requested()` is set (SIGTERM), admissions
         close: queued requests are shed with reason "drain" and only
         the in-flight slots keep decoding."""
+        from .. import telemetry as _tel
         from ..distributed import guard
-        if not self._draining and guard.drain_requested():
-            self._begin_drain()
-        newly = self._evict()
-        if not self._draining:
-            self._shed_deadline_missed()
-            self._admit()
-        # frozen hand-off slots (prefill role, prompt consumed, waiting
-        # for a decode worker) are done=True device-side and need no
-        # chunks — a prefill batcher whose live slots are all frozen
-        # parks until export_handoff() frees them
-        if any(r is not None and r.req_id not in self._handoff_ready
-               for r in self._slots):
-            self._run_chunk(mixed=bool(self._mode_host.any()))
-            # pre-chunk evictions cleared their slots, so the two
-            # harvests are disjoint
-            newly += self._evict()
+        self._chunk_no = self._chunk_count + 1
+        self._phase_ms = dict.fromkeys(PHASES, 0.0)
+        with _tel.span("serve.step", chunk=self._chunk_no):
+            if not self._draining and guard.drain_requested():
+                self._begin_drain()
+            with self._phase("evict"):
+                newly = self._evict()
+            if not self._draining:
+                with self._phase("admit") as admit:
+                    before = self._admissions
+                    self._shed_deadline_missed()
+                    self._admit()
+                    admit.set(admitted=self._admissions - before)
+            # frozen hand-off slots (prefill role, prompt consumed,
+            # waiting for a decode worker) are done=True device-side and
+            # need no chunks — a prefill batcher whose live slots are
+            # all frozen parks until export_handoff() frees them
+            if any(r is not None and r.req_id not in self._handoff_ready
+                   for r in self._slots) \
+                    and self._run_chunk(mixed=bool(self._mode_host.any())):
+                # pre-chunk evictions cleared their slots, so the two
+                # harvests are disjoint
+                with self._phase("evict"):
+                    newly += self._evict()
+                self._close_chunk()
         return newly
+
+    @contextlib.contextmanager
+    def _phase(self, name: str, **ids):
+        """One phase of a step(): the span `serve.<name>`, and its
+        duration added to the chunk's record whether or not anything
+        listens (two clock reads)."""
+        from .. import telemetry as _tel
+        t0 = time.perf_counter()
+        try:
+            with _tel.span("serve." + name, **ids) as sp:
+                yield sp
+        finally:
+            self._phase_ms[name] += (time.perf_counter() - t0) * 1e3
+
+    def _close_chunk(self):
+        """The chunk's phase durations into the bounded window (steady
+        chunks only, as `_chunk_times`) and onto its `serve.chunk`
+        event, which waits for the second eviction to be counted."""
+        ph = self._phase_ms
+        ph["harvest"] -= ph["deliver"]
+        if not self._first_use:
+            self._phase_times.append(tuple(ph[k] for k in PHASES))
+        fields, self._chunk_event = self._chunk_event, None
+        if fields is not None:
+            from .. import telemetry as _tel
+            fields.update((f"{k}_ms", round(ph[k], 3)) for k in PHASES)
+            _tel.emit("serve.chunk", fields)
 
     def run(self) -> Dict[int, np.ndarray]:
         """Drive until queue and slots drain; returns {req_id: tokens}
@@ -754,23 +812,24 @@ class ContinuousBatcher:
         return self._draining
 
     # -- streaming delivery (ISSUE 11 satellite) ---------------------------
-    def _deliver(self, req: Request, done: bool):
+    def _deliver(self, req: Request, done: bool) -> int:
         """Hand the request's NEW output-surviving tokens to its
         on_token callback: the deliverable prefix is EOS-trimmed and
         capped at max_new_tokens (exactly what output() will return),
         so a streamed consumer never sees a token the final result
         drops.  `done=True` fires exactly once, at the terminal
         delivery.  Host-plane only — the compiled programs cannot
-        tell a streaming request from a plain one."""
+        tell a streaming request from a plain one.  Returns how many
+        tokens it handed out."""
         if req.on_token is None:
-            return
+            return 0
         cap = req.max_new_tokens
         if self.eos is not None and self.eos in req.tokens:
             cap = min(cap, req.tokens.index(self.eos) + 1)
         end = min(len(req.tokens), cap)
         burst = [int(t) for t in req.tokens[req.delivered:end]]
         if not burst and not done:
-            return
+            return 0
         req.delivered_tokens.extend(burst)
         try:
             req.on_token(req.req_id, burst, done)
@@ -778,6 +837,7 @@ class ContinuousBatcher:
             self._cb_errors += 1
             from .. import telemetry as _tel
             _tel.counter("serve.callback_errors").inc()
+        return len(burst)
 
     # -- robustness plumbing (ISSUE 9) -------------------------------------
     def _shed(self, req: Request, reason: str):
@@ -898,6 +958,7 @@ class ContinuousBatcher:
         # must describe the decode the user actually received
         req.t_admit = None
         req.t_first = None
+        req.admit_chunk = req.first_token_chunk = -1
         if shedding:
             self._shed(req, reason)
         else:
@@ -910,8 +971,11 @@ class ContinuousBatcher:
         `serve.request` event (sink-gated; the host aggregates always
         accumulate so stats() answers sink-less).  Shed requests never
         come through here — no service, no latency sample."""
+        from .. import telemetry as _tel
         now = self._now()
         req.t_done = now
+        req.done_chunk = self._chunk_no
+        _tel.mark("serve.req.done", req=req.req_id, chunk=self._chunk_no)
         self._terminal_window.append(0.0)
         queue_ms = ((req.t_admit if req.t_admit is not None else now)
                     - req.t_submit) * 1e3
@@ -944,12 +1008,14 @@ class ContinuousBatcher:
                    and req.t_admit <= req.deadline)
             if met:
                 slo["deadline_met"] += 1
-        from .. import telemetry as _tel
         if _tel.active():
             fields = dict(req=req.req_id, slo=req.slo, tokens=n,
                           queue_ms=round(queue_ms, 3),
                           e2e_ms=round(e2e_ms, 3),
-                          requeues=req.requeues, partial=req.partial)
+                          requeues=req.requeues, partial=req.partial,
+                          admit_chunk=req.admit_chunk,
+                          first_token_chunk=req.first_token_chunk,
+                          done_chunk=req.done_chunk)
             if ttft_ms is not None:
                 fields["ttft_ms"] = round(ttft_ms, 3)
             if tpot_ms is not None:
@@ -1142,6 +1208,7 @@ class ContinuousBatcher:
             "tokens_produced": self.tokens_produced,
             "chunk_time_p50": times[len(times) // 2] if times else 0.0,
             "chunk_time_max": self._chunk_time_max,
+            "phase_ms": self._phase_summary(),
             "compiled_programs": self.compiled_programs,
             "kv_layout": self.kv_layout,
             "kv_bytes": self.kv_cache_bytes(),
@@ -1242,6 +1309,16 @@ class ContinuousBatcher:
                        grafted_pages=0, evictions=0, cow_copies=0)
         return out
 
+    def _phase_summary(self) -> Dict[str, Dict[str, float]]:
+        """{phase: {p50, max}} in ms over the window of chunks: which
+        part of step() a slow stretch sat in."""
+        from ..telemetry import summary_of
+        out = {}
+        for k, column in zip(PHASES, zip(*self._phase_times)):
+            s = summary_of(column, qs=(50,))
+            out[k] = {"p50": round(s["p50"], 3), "max": round(s["max"], 3)}
+        return out or {k: {"p50": 0.0, "max": 0.0} for k in PHASES}
+
     # -- scheduling --------------------------------------------------------
     def _evict(self) -> List[Request]:
         out = []
@@ -1323,6 +1400,7 @@ class ContinuousBatcher:
 
     def _admit_locked(self):
         from ..distributed import fault
+        from .. import telemetry as _tel
         free = [i for i in range(self.B) if self._slots[i] is None]
 
         def retry_exhausted(q, req, reason):
@@ -1399,6 +1477,9 @@ class ContinuousBatcher:
                 self._admissions += 1
                 self._slots[i] = req
                 req.t_admit = self._now()   # re-stamped on re-admission
+                req.admit_chunk = self._chunk_no
+                _tel.mark("serve.req.admit", req=req.req_id,
+                          chunk=self._chunk_no)
                 buf = np.zeros((self.max_len,), np.int32)
                 buf[: len(req.prompt)] = req.prompt
                 self._prompts = self._prompts.at[i].set(
@@ -1649,6 +1730,7 @@ class ContinuousBatcher:
                                  or self._now())
             req.t_first = meta.get("t_first")
             req.t_admit = self._now()
+            req.admit_chunk = self._chunk_no
             i = free[0]
             self._slots[i] = req
             self._submitted += 1       # arrives as a hand-off, so the
@@ -2094,53 +2176,60 @@ class ContinuousBatcher:
                             *self._carry_args())
         return fn.lower(self._param_vals(), *self._carry_args())
 
-    def _run_chunk(self, mixed: bool):
+    def _run_chunk(self, mixed: bool) -> bool:
+        """One scan chunk, in the phases `serve.dispatch`,
+        `serve.device_wait` and `serve.harvest` (the `on_token`
+        callbacks inside it as ONE `serve.deliver`).  False where an
+        injected chunk fault stopped it before the program ran: the
+        chunk retries at the next boundary."""
         from ..distributed import fault
-        if mixed:
-            fn = self._step_fn(self.prefill_chunk, self.admit_steps)
-        elif self.spec_k:
-            fn = self._spec_step_fn()
-        else:
-            fn = self._step_fn(1, self.chunk)
+        from .. import telemetry as _tel
         t0 = time.perf_counter()
         kind = "admit" if mixed else "decode"
+        ck = self._chunk_no
         n_emit = n_acc = None
         try:
-            # the chunk dispatch runs under the serve watchdog
-            # (FLAGS_stop_check_timeout): a hang dumps thread stacks /
-            # aborts per the r9 contract, and a delay-injected chunk
-            # that ages past the deadline is counted as hung below.
-            # The serve.chunk fault fires INSIDE the watched window
-            # but BEFORE fn touches the donated carries — an injected
-            # chunk fault loses nothing; the chunk retries at the next
-            # boundary (under speculation that includes a fault
-            # mid-verify: no draft token ever leaks from a chunk that
-            # never returned)
-            with self._watch:
-                fault.hit("serve.chunk", key=kind)
-                if self.spec_k:
-                    out = fn(self._param_vals(),
-                             self._draft_param_vals(),
-                             *self._carry_args())
-                    if mixed:
-                        (self._cache, self._dcache, page_table,
-                         self._tok, self._pos, self._mode, self._plen,
-                         self._prompts, self._done, toks, n_pref,
-                         n_dec) = out
-                    else:
-                        (self._cache, self._dcache, page_table,
-                         self._tok, self._pos, self._mode, self._plen,
-                         self._prompts, self._done, toks, n_emit,
-                         n_acc, n_pref, n_dec) = out
+            with self._phase("dispatch", kind=kind, chunk=ck):
+                if mixed:
+                    fn = self._step_fn(self.prefill_chunk, self.admit_steps)
+                elif self.spec_k:
+                    fn = self._spec_step_fn()
                 else:
-                    (self._cache, page_table, self._tok, self._pos,
-                     self._mode, self._plen, self._prompts, self._done,
-                     toks, n_pref, n_dec) = fn(self._param_vals(),
-                                               *self._carry_args())
+                    fn = self._step_fn(1, self.chunk)
+                # the chunk dispatch runs under the serve watchdog
+                # (FLAGS_stop_check_timeout): a hang dumps thread stacks
+                # / aborts per the r9 contract, and a delay-injected
+                # chunk that ages past the deadline is counted as hung
+                # below.  The serve.chunk fault fires INSIDE the watched
+                # window but BEFORE fn touches the donated carries — an
+                # injected chunk fault loses nothing; the chunk retries
+                # at the next boundary (under speculation that includes
+                # a fault mid-verify: no draft token ever leaks from a
+                # chunk that never returned)
+                with self._watch:
+                    fault.hit("serve.chunk", key=kind)
+                    if self.spec_k:
+                        out = fn(self._param_vals(),
+                                 self._draft_param_vals(),
+                                 *self._carry_args())
+                        if mixed:
+                            (self._cache, self._dcache, page_table,
+                             self._tok, self._pos, self._mode,
+                             self._plen, self._prompts, self._done, toks,
+                             n_pref, n_dec) = out
+                        else:
+                            (self._cache, self._dcache, page_table,
+                             self._tok, self._pos, self._mode,
+                             self._plen, self._prompts, self._done, toks,
+                             n_emit, n_acc, n_pref, n_dec) = out
+                    else:
+                        (self._cache, page_table, self._tok, self._pos,
+                         self._mode, self._plen, self._prompts,
+                         self._done, toks, n_pref, n_dec) = fn(
+                            self._param_vals(), *self._carry_args())
         except fault.FaultError:
             self._chunk_retries += 1
             self._consecutive_chunk_faults += 1
-            from .. import telemetry as _tel
             _tel.counter("serve.chunk_retries").inc()
             if _tel.active():
                 _tel.emit("serve.chunk_fault", kind=kind,
@@ -2151,11 +2240,10 @@ class ContinuousBatcher:
             if self._consecutive_chunk_faults > int(
                     get_flag("serve_retry_budget") or 3):
                 raise
-            return
+            return False
         self._consecutive_chunk_faults = 0
         if self._watch.last_reported:
             self._hung_chunks += 1
-            from .. import telemetry as _tel
             _tel.counter("serve.hung_chunks").inc()
             if _tel.active():
                 _tel.emit("serve.hung", kind=kind,
@@ -2166,11 +2254,24 @@ class ContinuousBatcher:
         # ONE batched host transfer per chunk — each device_get is a
         # blocking round trip, so fetching tokens/mode/done/pos/counters
         # separately would pay it six times per boundary
-        (toks, mode_h, done_h, pos_h, n_pref, n_dec, n_emit,
-         n_acc) = jax.device_get(
-            (toks, self._mode, self._done, self._pos, n_pref, n_dec,
-             n_emit, n_acc))
-        toks = np.asarray(toks)                 # [B, K] / [B, K*(k+1)]
+        with self._phase("device_wait", kind=kind, chunk=ck):
+            (toks, mode_h, done_h, pos_h, n_pref, n_dec, n_emit,
+             n_acc) = jax.device_get(
+                (toks, self._mode, self._done, self._pos, n_pref, n_dec,
+                 n_emit, n_acc))
+        with self._phase("harvest", chunk=ck):
+            self._harvest(kind, t0, np.asarray(toks), mode_h, done_h,
+                          pos_h, int(n_pref), int(n_dec), n_emit, n_acc)
+        return True
+
+    def _harvest(self, kind, t0, toks, mode_h, done_h, pos_h, n_pref,
+                 n_dec, n_emit, n_acc):
+        """What the host does with a chunk's outputs (`toks` is [B, K],
+        or [B, K*(k+1)] under speculation): the fault sweep, the
+        accounting, the prefix trie's progress, each slot's new tokens
+        and their delivery."""
+        from ..distributed import fault
+        from .. import telemetry as _tel
         self._mode_host = np.array(mode_h)
         self._done_host = np.array(done_h)
         self._pos_host = np.array(pos_h)
@@ -2203,10 +2304,10 @@ class ContinuousBatcher:
             self._chunk_times.append(dt)
             self._chunk_time_max = max(self._chunk_time_max, dt)
         self._chunk_count += 1
-        self._chunk_kind_counts["admit" if mixed else "decode"] += 1
+        self._chunk_kind_counts[kind] += 1
         self._occupancy_total += self.active
-        self._prefill_tok_total += int(n_pref)
-        self._decode_tok_total += int(n_dec)
+        self._prefill_tok_total += n_pref
+        self._decode_tok_total += n_dec
         if n_emit is not None:
             # speculation accounting (ISSUE 11): n_emit [B, K_steps] is
             # tokens emitted per slot per scan step (0 = inactive);
@@ -2222,7 +2323,6 @@ class ContinuousBatcher:
             self._spec_accepted += accepted
             self._spec_steps += n_active
             self._spec_emit_window.extend(int(v) for v in ne[active])
-            from .. import telemetry as _tel
             _tel.counter("serve.spec_drafted").inc(drafted)
             _tel.counter("serve.spec_accepted").inc(accepted)
             if _tel.active():
@@ -2240,24 +2340,23 @@ class ContinuousBatcher:
                 if plan is not None and plan.nodes:
                     self._alloc.mark_progress(plan,
                                               int(self._pos_host[i]))
-        from .. import telemetry as _tel
         _tel.counter("serve.chunks").inc()       # sink or not
         if _tel.active():
-            _tel.emit("serve.chunk",
-                      kind="admit" if mixed else "decode",
-                      wall_ms=round(dt * 1e3, 3),
-                      occupancy=self.active, slots=self.B,
-                      prefill_tokens=int(n_pref),
-                      decode_tokens=int(n_dec),
-                      first_use=self._first_use)
+            # published by _close_chunk, once the step's last phase is
+            # counted
+            self._chunk_event = dict(
+                kind=kind, chunk=self._chunk_no,
+                wall_ms=round(dt * 1e3, 3),
+                occupancy=self.active, slots=self.B,
+                prefill_tokens=n_pref, decode_tokens=n_dec,
+                first_use=self._first_use)
             _tel.histogram("serve.chunk_ms").observe(dt * 1e3)
             # cost ledger measured-wall feed (ISSUE 12): the chunk
             # wall lands on the ledger label of the very program that
             # ran it; first_use walls (may include the compile) are
             # excluded like the chunk-time stats above
-            _tel.costledger.observe(
-                "serve_step.admit" if mixed else "serve_step.decode",
-                dt * 1e3, cold=self._first_use)
+            _tel.costledger.observe(f"serve_step.{kind}", dt * 1e3,
+                                    cold=self._first_use)
             if self.kv_layout == "paged":
                 _tel.emit("serve.kv",
                           pages=self.num_pages,
@@ -2269,15 +2368,20 @@ class ContinuousBatcher:
                           evictions=self._alloc.evictions,
                           kv_bytes=self.kv_cache_bytes())
         t_harvest = self._now()
+        live = []
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
             req.tokens.extend(int(t) for t in toks[i] if t >= 0)
             if req.t_first is None and req.tokens:
                 req.t_first = t_harvest
-            # streaming: hand out this chunk's burst now — TTFT for an
-            # interactive caller is the FIRST chunk boundary, not
-            # run()'s return (speculation lands accepted runs here in
-            # one burst)
-            self._deliver(req, done=False)
-
+                req.first_token_chunk = self._chunk_no
+                _tel.mark("serve.req.first_token", req=req.req_id,
+                          chunk=self._chunk_no)
+            live.append(req)
+        # streaming: hand out this chunk's bursts now — TTFT for an
+        # interactive caller is the FIRST chunk boundary, not run()'s
+        # return (speculation lands accepted runs here in one burst)
+        with self._phase("deliver") as deliver:
+            deliver.set(tokens=sum(self._deliver(req, done=False)
+                                   for req in live))

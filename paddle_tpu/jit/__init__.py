@@ -456,8 +456,7 @@ class TrainStep:
             _tel.step_event(self, label="jit", kind="multi",
                             step=self.optimizer._step_count, k=k,
                             wall_ms=wall_ms,
-                            batch_vals=tuple(b[0] for b in batch_vals),
-                            loss_fn=self.loss_fn)
+                            batch_vals=tuple(b[0] for b in batch_vals))
         if nstats is not None:
             from ..telemetry import numerics as _numerics
             _numerics.record("jit", self.optimizer._step_count, k,
@@ -553,8 +552,7 @@ class TrainStep:
         if tel_on:
             _tel.step_event(self, label="jit", kind="step",
                             step=self.optimizer._step_count, k=1,
-                            wall_ms=wall_ms, batch_vals=batch_vals,
-                            loss_fn=self.loss_fn)
+                            wall_ms=wall_ms, batch_vals=batch_vals)
         if nstats is not None:
             from ..telemetry import numerics as _numerics
             _numerics.record("jit", self.optimizer._step_count, 1,

@@ -864,10 +864,6 @@ class OffloadPipelineStep:
                 self._num_bundles, nstats)
         self._guard_record(loss, layer=bad_layer)
         if tel_on:
-            # no phase probe (batch_vals omitted): re-jitting the
-            # streamed model outside its per-layer pipeline would
-            # materialize every host stack in HBM — exactly what this
-            # trainer exists to avoid
             _tel.step_event(self, label="offload", kind="step",
                             step=self.optimizer._step_count, k=1,
                             wall_ms=(time.perf_counter() - t0) * 1e3,

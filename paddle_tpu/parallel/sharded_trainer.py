@@ -581,40 +581,50 @@ class ShardedTrainStep:
                 lambda n, o: jnp.where(finite, n, o), new_tree, old_tree)
 
         def step(param_vals, opt_states, buf_vals, lr, step_i, key, batch):
+            # the scopes are metadata alone (`op_name` in the HLO and in
+            # a device trace): forward and backward are told apart by
+            # what jax writes there itself, `jvp(..)`, `transpose(jvp(..))`
             (loss, new_bufs), grads = jax.value_and_grad(
                 loss_of, has_aux=True)(param_vals, buf_vals, key, batch)
-            if overlap_plan is not None:
-                # bucketed reduction: fused all-reduce (stage 0/1) or
-                # reduce-scatter (stage 2) per bucket, barrier-chained
-                # in reverse-topological issue order; stage 2
-                # re-applies the per-leaf sharded-grad constraint;
-                # stage 3 chains layout-neutrally (grad_shardings is
-                # None there — shard_map materializes the RS)
-                grads = overlap_plan.reduce_grads(
-                    grads, self.mesh, leaf_shardings=grad_shardings)
-            elif grad_shardings is not None:
-                grads = [jax.lax.with_sharding_constraint(g, gs)
-                         for g, gs in zip(grads, grad_shardings)]
-            if fused_ok and not offload and not stream_params:
-                # single device, nothing host-resident: multi-tensor
-                # batching of the small params (see jit_update)
-                new_params, new_states = apply_updates(
-                    upd, param_vals, grads, opt_states, lr, wds, step_i,
-                    hp, lr_scales=lr_scales)
-                if numerics_on:
-                    # stats read the ATTEMPTED update (pre-guard
-                    # selection): a refused step still reports which
-                    # layer's grad went nonfinite
-                    nstats = _numerics_stats(param_vals, grads,
-                                             new_params)
-                if guard_on:
+            with jax.named_scope("train.grad_reduce"):
+                if overlap_plan is not None:
+                    # bucketed reduction: fused all-reduce (stage 0/1) or
+                    # reduce-scatter (stage 2) per bucket, barrier-chained
+                    # in reverse-topological issue order; stage 2
+                    # re-applies the per-leaf sharded-grad constraint;
+                    # stage 3 chains layout-neutrally (grad_shardings is
+                    # None there — shard_map materializes the RS)
+                    grads = overlap_plan.reduce_grads(
+                        grads, self.mesh, leaf_shardings=grad_shardings)
+                elif grad_shardings is not None:
+                    grads = [jax.lax.with_sharding_constraint(g, gs)
+                             for g, gs in zip(grads, grad_shardings)]
+            with jax.named_scope("train.optimizer"):
+                if fused_ok and not offload and not stream_params:
+                    # single device, nothing host-resident: multi-tensor
+                    # batching of the small params (see jit_update)
+                    new_params, new_states = apply_updates(
+                        upd, param_vals, grads, opt_states, lr, wds,
+                        step_i, hp, lr_scales=lr_scales)
+                else:
+                    new_params, new_states = per_leaf_updates(
+                        param_vals, grads, opt_states, lr, step_i)
+            if numerics_on:
+                # stats read the ATTEMPTED update (pre-guard selection):
+                # a refused step still reports which layer's grad went
+                # nonfinite
+                nstats = _numerics_stats(param_vals, grads, new_params)
+            if guard_on:
+                with jax.named_scope("train.guard"):
                     ok = _finite_pred(loss, grads)
                     new_params = _guarded(ok, new_params, param_vals)
                     new_states = _guarded(ok, new_states, opt_states)
                     new_bufs = _guarded(ok, new_bufs, buf_vals)
-                if numerics_on:
-                    return loss, new_params, new_states, new_bufs, nstats
-                return loss, new_params, new_states, new_bufs
+            if numerics_on:
+                return loss, new_params, new_states, new_bufs, nstats
+            return loss, new_params, new_states, new_bufs
+
+        def per_leaf_updates(param_vals, grads, opt_states, lr, step_i):
             new_params, new_states = [], []
             token = None
             for i, (p, g, s, wd, ls, sp) in enumerate(
@@ -653,16 +663,7 @@ class ShardedTrainStep:
                 new_states.append(ns)
                 if chain_updates and (i + 1) % chain_every == 0:
                     token = np_
-            if numerics_on:
-                nstats = _numerics_stats(param_vals, grads, new_params)
-            if guard_on:
-                ok = _finite_pred(loss, grads)
-                new_params = _guarded(ok, new_params, param_vals)
-                new_states = _guarded(ok, new_states, opt_states)
-                new_bufs = _guarded(ok, new_bufs, buf_vals)
-            if numerics_on:
-                return loss, new_params, new_states, new_bufs, nstats
-            return loss, new_params, new_states, new_bufs
+            return new_params, new_states
 
         param_sh = [self._param_store_shardings[n] if stream_params
                     else self._param_shardings[n] for n in names]
@@ -920,78 +921,86 @@ class ShardedTrainStep:
         if self._pipeline is not None:
             return self._pipeline.run_steps(
                 *stacked_batch, advance_lr_scheduler=advance_lr_scheduler)
-        param_vals, buf_vals, _ = self._prepare(
-            tuple(Tensor(b.value[0] if isinstance(b, Tensor)
-                         else jnp.asarray(b)[0])
-                  for b in stacked_batch))
-        if getattr(self, "_compiled_multi", None) is None:
-            self._build_multi()
-        stacked = self._step_faults(tuple(
-            self._stack_shard(b.value if isinstance(b, Tensor)
-                              else jnp.asarray(b))
-            for b in stacked_batch))
-        k = int(stacked[0].shape[0])
-        from ..jit import per_step_lrs
-        lrs, commit_lr = per_step_lrs(self.optimizer, k,
-                                      advance=advance_lr_scheduler)
-        step0 = jnp.asarray(self.optimizer._step_count + 1, jnp.int32)
-        key = prandom.next_key()
-        from ..distributed.watchdog import watched
-        args = (param_vals, self._states_for_call(), buf_vals, lrs,
-                step0, key, stacked)
-        from ..telemetry import compile_cache as _cc, memledger as _ml
-        # ledger registration BEFORE aot_for: an armed AOT compile then
-        # overwrites the pending provider with free measured stats
-        _ml.note_jit(self, "multi", self._compiled_multi, args,
-                     f"ShardedTrainStep.multi.s{self.stage}",
-                     mesh=self.mesh,
-                     sig=tuple(b.shape for b in stacked))
-        if self._comm_profile is not None:
-            # (re)attach the grad-comm profile — registration above
-            # clears per-program cost state, and the profile is a
-            # build-time property of THIS program
-            from ..telemetry import costledger as _cl
-            _cl.note_comm(f"ShardedTrainStep.multi.s{self.stage}",
-                          self._comm_profile)
-        fn = _cc.aot_for(self._aot, "multi", self._compiled_multi, args,
-                         stacked, f"ShardedTrainStep.multi.s{self.stage}",
-                         mesh=self.mesh)
         from .. import telemetry as _tel
-        _tel.counter("train.steps").inc(k)   # lifetime total, sink or not
-        tel_on = _tel.active()
-        t0 = time.perf_counter()
-        with watched(f"sharded train run_steps(k={k})"):
-            out = fn(*args)
-            if getattr(self, "_numerics", False):
-                losses, new_params, new_states, new_bufs, nstats = out
-            else:
-                (losses, new_params, new_states, new_bufs), nstats = \
-                    out, None
-            if tel_on and _tel.config("sync_steps"):
-                jax.block_until_ready(losses)
-        wall_ms = (time.perf_counter() - t0) * 1e3
-        commit_lr()
-        self.optimizer._step_count += k
-        sd = self._sd
-        for n, v in zip(self._names, self._park_params(new_params)):
-            sd[n]._value = v
-        for n, v in zip(self._buf_names, new_bufs):
-            sd[n]._value = v
-        self._opt_states = self._park_states(new_states)
-        bad_layer = None
-        if nstats is not None:
-            from ..telemetry import numerics as _numerics
-            bad_layer = _numerics.record(
-                "sharded", self.optimizer._step_count, k,
-                self._num_bundles, nstats, extra={"stage": self.stage})
-        self._guard_record(losses, layer=bad_layer)
-        if tel_on:
-            _tel.step_event(self, label="sharded", kind="multi",
-                            step=self.optimizer._step_count, k=k,
-                            wall_ms=wall_ms,
-                            batch_vals=tuple(b[0] for b in stacked),
-                            loss_fn=self.loss_fn,
-                            extra={"stage": self.stage})
+        from ..distributed.watchdog import watched
+        from ..jit import per_step_lrs
+        from ..telemetry import compile_cache as _cc, memledger as _ml
+        with _tel.span("train.step") as call:
+            with _tel.span("train.prepare"):
+                param_vals, buf_vals, _ = self._prepare(
+                    tuple(Tensor(b.value[0] if isinstance(b, Tensor)
+                                 else jnp.asarray(b)[0])
+                          for b in stacked_batch))
+                if getattr(self, "_compiled_multi", None) is None:
+                    self._build_multi()
+                stacked = self._step_faults(tuple(
+                    self._stack_shard(b.value if isinstance(b, Tensor)
+                                      else jnp.asarray(b))
+                    for b in stacked_batch))
+                k = int(stacked[0].shape[0])
+                call.set(step=self.optimizer._step_count + k, k=k)
+                lrs, commit_lr = per_step_lrs(self.optimizer, k,
+                                              advance=advance_lr_scheduler)
+                step0 = jnp.asarray(self.optimizer._step_count + 1,
+                                    jnp.int32)
+                key = prandom.next_key()
+                args = (param_vals, self._states_for_call(), buf_vals, lrs,
+                        step0, key, stacked)
+                # ledger registration BEFORE aot_for: an armed AOT compile
+                # then overwrites the pending provider with free measured
+                # stats
+                _ml.note_jit(self, "multi", self._compiled_multi, args,
+                             f"ShardedTrainStep.multi.s{self.stage}",
+                             mesh=self.mesh,
+                             sig=tuple(b.shape for b in stacked))
+                if self._comm_profile is not None:
+                    # (re)attach the grad-comm profile — registration above
+                    # clears per-program cost state, and the profile is a
+                    # build-time property of THIS program
+                    from ..telemetry import costledger as _cl
+                    _cl.note_comm(f"ShardedTrainStep.multi.s{self.stage}",
+                                  self._comm_profile)
+                fn = _cc.aot_for(self._aot, "multi", self._compiled_multi,
+                                 args, stacked,
+                                 f"ShardedTrainStep.multi.s{self.stage}",
+                                 mesh=self.mesh)
+            _tel.counter("train.steps").inc(k)   # lifetime total, sink or not
+            tel_on = _tel.active()
+            t0 = time.perf_counter()
+            with _tel.span("train.dispatch"), \
+                    watched(f"sharded train run_steps(k={k})"):
+                out = fn(*args)
+                if getattr(self, "_numerics", False):
+                    losses, new_params, new_states, new_bufs, nstats = out
+                else:
+                    (losses, new_params, new_states, new_bufs), nstats = \
+                        out, None
+                if tel_on and _tel.config("sync_steps"):
+                    jax.block_until_ready(losses)
+            wall_ms = (time.perf_counter() - t0) * 1e3
+            with _tel.span("train.writeback"):
+                commit_lr()
+                self.optimizer._step_count += k
+                sd = self._sd
+                for n, v in zip(self._names, self._park_params(new_params)):
+                    sd[n]._value = v
+                for n, v in zip(self._buf_names, new_bufs):
+                    sd[n]._value = v
+                self._opt_states = self._park_states(new_states)
+                bad_layer = None
+                if nstats is not None:
+                    from ..telemetry import numerics as _numerics
+                    bad_layer = _numerics.record(
+                        "sharded", self.optimizer._step_count, k,
+                        self._num_bundles, nstats,
+                        extra={"stage": self.stage})
+                self._guard_record(losses, layer=bad_layer)
+            if tel_on:
+                _tel.step_event(self, label="sharded", kind="multi",
+                                step=self.optimizer._step_count, k=k,
+                                wall_ms=wall_ms,
+                                batch_vals=tuple(b[0] for b in stacked),
+                                extra={"stage": self.stage}, span=call)
         return Tensor(losses)
 
     def _stack_shard(self, arr):
@@ -1090,58 +1099,63 @@ class ShardedTrainStep:
         from ..distributed.watchdog import watched
         if self._pipeline is not None:
             return self._pipeline(*batch)
-        param_vals, buf_vals, batch_vals = self._prepare(batch)
-        batch_vals = self._step_faults(batch_vals)
-        sd = self._sd
-        self.optimizer._step_count += 1
-        lr = self.optimizer.get_lr()
-        key = prandom.next_key()
-        args = (param_vals, self._states_for_call(), buf_vals,
-                jnp.asarray(lr, jnp.float32),
-                jnp.asarray(self.optimizer._step_count, jnp.int32), key,
-                batch_vals)
-        from ..telemetry import compile_cache as _cc, memledger as _ml
-        _ml.note_jit(self, "step", self._compiled, args,
-                     f"ShardedTrainStep.step.s{self.stage}",
-                     mesh=self.mesh,
-                     sig=tuple(b.shape for b in batch_vals))
-        if self._comm_profile is not None:
-            from ..telemetry import costledger as _cl
-            _cl.note_comm(f"ShardedTrainStep.step.s{self.stage}",
-                          self._comm_profile)
-        fn = _cc.aot_for(self._aot, "step", self._compiled, args,
-                         batch_vals, f"ShardedTrainStep.step.s{self.stage}",
-                         mesh=self.mesh)
         from .. import telemetry as _tel
-        _tel.counter("train.steps").inc()    # lifetime total, sink or not
-        tel_on = _tel.active()
-        t0 = time.perf_counter()
-        with watched("sharded train step"):
-            out = fn(*args)
-            if getattr(self, "_numerics", False):
-                loss, new_params, new_states, new_bufs, nstats = out
-            else:
-                (loss, new_params, new_states, new_bufs), nstats = \
-                    out, None
-            if tel_on and _tel.config("sync_steps"):
-                jax.block_until_ready(loss)
-        wall_ms = (time.perf_counter() - t0) * 1e3
-        for n, v in zip(self._names, self._park_params(new_params)):
-            sd[n]._value = v
-        for n, v in zip(self._buf_names, new_bufs):
-            sd[n]._value = v
-        self._opt_states = self._park_states(new_states)
-        bad_layer = None
-        if nstats is not None:
-            from ..telemetry import numerics as _numerics
-            bad_layer = _numerics.record(
-                "sharded", self.optimizer._step_count, 1,
-                self._num_bundles, nstats, extra={"stage": self.stage})
-        self._guard_record(loss, layer=bad_layer)
-        if tel_on:
-            _tel.step_event(self, label="sharded", kind="step",
-                            step=self.optimizer._step_count, k=1,
-                            wall_ms=wall_ms, batch_vals=batch_vals,
-                            loss_fn=self.loss_fn,
-                            extra={"stage": self.stage})
+        from ..telemetry import compile_cache as _cc, memledger as _ml
+        with _tel.span("train.step", step=self.optimizer._step_count + 1,
+                       k=1) as call:
+            with _tel.span("train.prepare"):
+                param_vals, buf_vals, batch_vals = self._prepare(batch)
+                batch_vals = self._step_faults(batch_vals)
+                sd = self._sd
+                self.optimizer._step_count += 1
+                lr = self.optimizer.get_lr()
+                key = prandom.next_key()
+                args = (param_vals, self._states_for_call(), buf_vals,
+                        jnp.asarray(lr, jnp.float32),
+                        jnp.asarray(self.optimizer._step_count, jnp.int32),
+                        key, batch_vals)
+                _ml.note_jit(self, "step", self._compiled, args,
+                             f"ShardedTrainStep.step.s{self.stage}",
+                             mesh=self.mesh,
+                             sig=tuple(b.shape for b in batch_vals))
+                if self._comm_profile is not None:
+                    from ..telemetry import costledger as _cl
+                    _cl.note_comm(f"ShardedTrainStep.step.s{self.stage}",
+                                  self._comm_profile)
+                fn = _cc.aot_for(self._aot, "step", self._compiled, args,
+                                 batch_vals,
+                                 f"ShardedTrainStep.step.s{self.stage}",
+                                 mesh=self.mesh)
+            _tel.counter("train.steps").inc()    # lifetime total, sink or not
+            tel_on = _tel.active()
+            t0 = time.perf_counter()
+            with _tel.span("train.dispatch"), watched("sharded train step"):
+                out = fn(*args)
+                if getattr(self, "_numerics", False):
+                    loss, new_params, new_states, new_bufs, nstats = out
+                else:
+                    (loss, new_params, new_states, new_bufs), nstats = \
+                        out, None
+                if tel_on and _tel.config("sync_steps"):
+                    jax.block_until_ready(loss)
+            wall_ms = (time.perf_counter() - t0) * 1e3
+            with _tel.span("train.writeback"):
+                for n, v in zip(self._names, self._park_params(new_params)):
+                    sd[n]._value = v
+                for n, v in zip(self._buf_names, new_bufs):
+                    sd[n]._value = v
+                self._opt_states = self._park_states(new_states)
+                bad_layer = None
+                if nstats is not None:
+                    from ..telemetry import numerics as _numerics
+                    bad_layer = _numerics.record(
+                        "sharded", self.optimizer._step_count, 1,
+                        self._num_bundles, nstats,
+                        extra={"stage": self.stage})
+                self._guard_record(loss, layer=bad_layer)
+            if tel_on:
+                _tel.step_event(self, label="sharded", kind="step",
+                                step=self.optimizer._step_count, k=1,
+                                wall_ms=wall_ms, batch_vals=batch_vals,
+                                extra={"stage": self.stage}, span=call)
         return Tensor(loss)

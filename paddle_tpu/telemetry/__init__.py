@@ -6,44 +6,79 @@ exporter reads from:
 
   producers                         events
   ---------                         ------
-  jit.TrainStep / ShardedTrainStep  train.step (wall_ms, phases, k)
+  jit.TrainStep / ShardedTrainStep  train.step (wall_ms, step_ms, k,
+                                    tokens_per_sec)
   OffloadPipelineStep               train.step (trainer=offload)
   PipelineEngine.train_batch        pp.train_batch (schedule, micro)
   collective_schedule()             collective.schedule (kind counts)
-  ContinuousBatcher                 serve.chunk / serve.recompile /
-                                    serve.kv, and the robustness set
+  ContinuousBatcher                 serve.chunk (wall_ms, tokens and the
+                                    six <phase>_ms of its step()) /
+                                    serve.request (latencies and the
+                                    chunks it was admitted, first
+                                    answered and done in) /
+                                    serve.recompile / serve.kv /
+                                    serve.spec, and the robustness set
                                     (ISSUE 9): serve.shed /
                                     serve.deadline_miss /
                                     serve.requeue / serve.chunk_fault /
                                     serve.hung / serve.drain
+  ServeRouter                       router.route / router.handoff and
+                                    the fleet's router.* events
   io.prefetch_to_device             io.step (host_wait_ms)
   distributed.watchdog              watchdog.timeout
   distributed.fault                 fault.hit
   distributed.checkpoint            ckpt.commit / ckpt.gc
   compile cache (this package)      compile.program (hit/miss, ms)
+  cost / memory ledgers             cost.program / cost.measure /
+                                    perf.drift
 
-Cost contract: with no sink attached the whole plane is one truthiness
-check per would-be event, and arming/disarming sinks or
+  spans (`telemetry.span`: a profiler annotation always, and with a
+  sink a record with t0, dur_ms, span, parent, parent_span, the ids)
+  -----
+  ShardedTrainStep.__call__ /       train.step (step, k) > train.prepare,
+  run_steps                         train.dispatch, train.writeback; the
+                                    train.step event's fields ride the
+                                    span's record (one record a step)
+  the jitted train step             jax.named_scope: train.grad_reduce,
+                                    train.optimizer, train.guard (beside
+                                    the models' llama.layer{i}/attn/mlp)
+  ContinuousBatcher.step            serve.step (chunk) > serve.evict,
+                                    serve.admit (admitted),
+                                    serve.dispatch (kind, chunk),
+                                    serve.device_wait (kind, chunk),
+                                    serve.harvest (chunk) >
+                                    serve.deliver (tokens); instants
+                                    (`telemetry.mark`) serve.req.admit /
+                                    serve.req.first_token /
+                                    serve.req.done (req, chunk)
+  profiler.RecordEvent              the caller's name (kind=record_event)
+
+Cost contract: with no sink attached an event is one truthiness check
+and a span is one inactive profiler annotation (half a microsecond, no
+record, no clock read), and arming/disarming sinks or
 ``FLAGS_compile_cache_dir`` leaves every compiled program byte-identical
-(bench.py asserts both).  Exporters: `attach_jsonl` (step log),
-`attach_chrome_trace` (chrome://tracing / Perfetto), `dump()` (the
-snapshot bench.py embeds in its JSON lines).  `tools/telemetry_report.py`
-renders a JSONL log into per-phase medians/p99, MFU trend and cache hit
-rate.
+(tests/test_telemetry.py and tests/test_program_spans.py assert the
+host half and that the scopes are metadata alone; bench.py's
+`_assert_telemetry_zero_overhead` the HLO across an arming cycle).
+Exporters: `attach_jsonl` (step log), `attach_chrome_trace`
+(chrome://tracing / Perfetto), `dump()` (the snapshot bench.py embeds
+in its JSON lines).  `tools/telemetry_report.py` renders a JSONL log
+into step medians/p99, the spans' durations and self times, each serve
+chunk's time by phase and the cache hit rate.  On the device's clock the
+spans are read from a profiler trace: `benchmark/program_spans.py`.
 """
 from __future__ import annotations
 
 from .registry import (MetricsRegistry, Counter, Gauge, Histogram,  # noqa: F401
                        registry, counter, gauge, histogram,
                        add_sink, remove_sink, sinks, active, emit, span,
-                       configure, config, reset as _registry_reset,
+                       mark, configure, config, reset as _registry_reset,
                        set_rank, rank_info, percentile_of,
                        percentiles_of, summary_of)
 from .exporters import (JsonlSink, ChromeTraceSink, MemorySink,  # noqa: F401
                         attach_jsonl, attach_chrome_trace, chrome_event)
 from .compile_cache import (cache_dir, maybe_enable_persistent_cache,  # noqa: F401
                             aot_compile, compile_report, clear_report)
-from . import probe  # noqa: F401
 from . import memledger  # noqa: F401
 from .memledger import memory_report  # noqa: F401
 from . import costledger  # noqa: F401
@@ -56,13 +91,13 @@ from . import numerics  # noqa: F401
 __all__ = ["MetricsRegistry", "Counter", "Gauge", "Histogram",
            "registry", "counter", "gauge", "histogram",
            "add_sink", "remove_sink", "sinks", "active", "emit", "span",
-           "configure", "config", "reset",
+           "mark", "configure", "config", "reset",
            "set_rank", "rank_info", "percentile_of", "percentiles_of",
            "JsonlSink", "ChromeTraceSink", "MemorySink",
            "attach_jsonl", "attach_chrome_trace", "chrome_event",
            "cache_dir", "maybe_enable_persistent_cache",
            "aot_compile", "compile_report",
-           "clear_report", "probe", "memledger", "memory_report",
+           "clear_report", "memledger", "memory_report",
            "costledger", "cost_report",
            "fleet", "flightrec", "FlightRecorder", "numerics",
            "summary_of", "dump", "step_event"]
@@ -125,17 +160,22 @@ except Exception:                       # recorder must never break import
 
 
 def step_event(trainer, *, label: str, kind: str, step: int, k: int,
-               wall_ms: float, batch_vals=(), loss_fn=None, extra=None):
+               wall_ms: float, batch_vals=(), extra=None, span=None):
     """Publish one `train.step` event for a trainer's compiled call —
     the ONE implementation every trainer shares (jit/sharded/offload
     pass their label; schema changes land here once).
 
     Callers guard with `telemetry.active()` BEFORE assembling any of
-    these arguments, and call AFTER writing the new params back into
-    the model (the one-time phase probe reads live state_dict values;
-    the pre-call buffers were just donated).  `wall_ms` covers the
-    whole (possibly K-fused) call; per-step values are derived here.
-    `batch_vals` is ONE step's batch (phase probe + token count).
+    these arguments.  `wall_ms` covers the whole (possibly K-fused)
+    call; per-step values are derived here.  `batch_vals` is ONE
+    step's batch (token count).  A trainer whose call is a
+    `train.step` span passes it as `span`: the fields then ride that
+    span's record (ONE `train.step` record a step, with `t0`, `dur_ms`
+    and its children `train.prepare` / `train.dispatch` /
+    `train.writeback` beside it) and no second event is emitted.
+    Where the DEVICE's time went is the step program's scopes'
+    (`train.grad_reduce`, `train.optimizer`, `train.guard`) to say,
+    in a profiler trace.
     `kind` names the compiled program ("step"/"multi"); its first event
     per trainer is marked cold=True — that wall may include the XLA
     compile, so the report CLI excludes cold steps."""
@@ -154,15 +194,6 @@ def step_event(trainer, *, label: str, kind: str, step: int, k: int,
         fields["tokens"] = tokens
         if wall_ms > 0:
             fields["tokens_per_sec"] = round(tokens / (wall_ms / 1e3), 1)
-    phases = probe.trainer_phases(trainer, batch_vals, loss_fn=loss_fn) \
-        if batch_vals else None
-    if phases:
-        fields["phases"] = {
-            "fwd_ms": phases["fwd_ms"],
-            "bwd_ms": phases["bwd_ms"],
-            "opt_ms": round(max(per_step - phases["fwdbwd_ms"], 0.0), 3),
-            "n_params": phases["n_params"],
-        }
     if extra:
         fields.update(extra)
     # feed the cost ledger's measured-wall window (warm calls only —
@@ -182,7 +213,10 @@ def step_event(trainer, *, label: str, kind: str, step: int, k: int,
         costledger.observe(ml_label, wall_ms,
                            cold="cold" in fields or refreshed)
     histogram("train.step_ms").observe(per_step)
-    emit("train.step", fields)
+    if span is not None:
+        span.set(**fields)
+    else:
+        emit("train.step", fields)
     # NOTE: the train.steps counter is incremented by the trainers
     # UNCONDITIONALLY (sink or not) so dump() snapshots lifetime totals
     # — incrementing it here too would double-count

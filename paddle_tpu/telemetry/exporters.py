@@ -155,8 +155,10 @@ def _jsonable(x):
 def chrome_event(rec: dict, pid: Optional[int] = None,
                  tid: Optional[int] = None) -> dict:
     """One telemetry record → one chrome-trace event: ``dur_ms`` makes
-    a complete ('X') slice ending at the record's ts, anything else an
-    instant ('i').  THE conversion both the live ChromeTraceSink and
+    a complete ('X') slice from the record's ``t0`` (a span's own start,
+    so nested spans draw nested; without one the slice ends at ts),
+    anything else an instant ('i').  THE conversion both the live
+    ChromeTraceSink and
     the offline per-rank log merge (telemetry.fleet) share — the lane
     identity (pid) is the caller's choice: process id live, RANK in a
     merged fleet trace."""
@@ -164,11 +166,13 @@ def chrome_event(rec: dict, pid: Optional[int] = None,
     name = rec.get("event", "event")
     pid = os.getpid() if pid is None else pid
     tid = threading.get_ident() if tid is None else tid
-    args = {k: v for k, v in rec.items() if k not in ("ts", "event")}
+    args = {k: v for k, v in rec.items()
+            if k not in ("ts", "t0", "event")}
     if "dur_ms" in rec:
         dur_us = float(rec["dur_ms"]) * 1e3
+        start_us = rec["t0"] * 1e6 if "t0" in rec else ts_us - dur_us
         return {"name": name, "ph": "X", "pid": pid, "tid": tid,
-                "ts": ts_us - dur_us, "dur": dur_us, "args": args}
+                "ts": start_us, "dur": dur_us, "args": args}
     return {"name": name, "ph": "i", "s": "p", "pid": pid,
             "tid": tid, "ts": ts_us, "args": args}
 

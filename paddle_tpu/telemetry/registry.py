@@ -11,14 +11,18 @@ Cost contract (bench-asserted, like analysis/fault):
 
   * `emit()` with no sink attached is one module-global truthiness
     check and a return — no dict building, no timestamps, no locking.
-  * `span()` with no sink attached returns a shared no-op context
-    manager — no allocation.
+  * `span()` is always a `jax.profiler.TraceAnnotation`: while a
+    profiler session runs it lies in the session's trace, on the clock
+    of the device's operations; while none runs it costs what an
+    inactive `TraceMe` costs (under a microsecond).  With no sink
+    attached it builds no record and reads no clock of its own.
   * Counters/gauges always accumulate (a few ns: one dict lookup and an
     int add) so `telemetry.dump()` can snapshot lifetime totals even
     when no sink ever ran; histograms keep a bounded reservoir.
-  * Nothing here ever touches jax or the compiled step — the plane is
-    host-side only, so arming/disarming sinks cannot change a program
-    (bench asserts byte-identical HLO across an attach/detach cycle).
+  * Nothing here ever touches the compiled step — the plane is
+    host-side only (of jax it uses the profiler's annotation alone),
+    so arming/disarming sinks cannot change a program (bench asserts
+    byte-identical HLO across an attach/detach cycle).
 
 Sinks are objects with a ``record(rec: dict)`` method (and optionally
 ``flush()``/``close()``); see exporters.py.  A raising sink is detached
@@ -30,10 +34,12 @@ import threading
 import time
 from typing import Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 __all__ = ["Counter", "Gauge", "Histogram", "MetricsRegistry",
            "registry", "counter", "gauge", "histogram",
            "add_sink", "remove_sink", "sinks", "active", "emit", "span",
-           "configure", "config", "reset",
+           "mark", "configure", "config", "reset",
            "set_rank", "rank_info", "percentile_of", "percentiles_of",
            "summary_of"]
 
@@ -270,14 +276,11 @@ def rank_info() -> Optional[tuple]:
 
 # plane configuration — host-side behavior switches only (nothing here
 # may change a compiled program):
-#   step_phases: trainers attach the one-time fwd/bwd phase
-#     decomposition to their step events while a sink is live (costs two
-#     extra small compiles per trainer, once)
 #   sync_steps: trainers block_until_ready the loss inside the step
 #     span so wall_ms is exact step wall (default off: with donated
 #     buffers steady-state dispatch wall tracks step wall, and a forced
 #     sync stalls the host's dispatch-ahead every step)
-_CONFIG_DEFAULTS = {"step_phases": True, "sync_steps": False}
+_CONFIG_DEFAULTS = {"sync_steps": False}
 _CONFIG = dict(_CONFIG_DEFAULTS)
 
 
@@ -359,50 +362,90 @@ def emit(event: str, fields: Optional[dict] = None, **kw):
             remove_sink(s, close=True)
 
 
-class _Span:
-    __slots__ = ("event", "fields", "_t0")
+class _Open(threading.local):
+    """Per thread: the (name, number) of the spans open on it, for a
+    record's parent, and the running number the last one got.  Only
+    kept while a sink listens."""
 
-    def __init__(self, event: str, fields: dict):
+    def __init__(self):
+        self.stack = []
+        self.number = 0
+
+
+_OPEN = _Open()
+
+
+class _Span:
+    """One span: a profiler annotation for as long as the body runs and,
+    when a sink is attached at its start, one record at its end."""
+
+    __slots__ = ("event", "ids", "_ann", "_rec")
+
+    def __init__(self, event: str, ids: dict):
         self.event = event
-        self.fields = fields
-        self._t0 = 0.0
+        self.ids = ids
+        self._ann = TraceAnnotation(event, **ids)
+        self._rec = None
+
+    def set(self, **ids):
+        """Ids known only once the body has run (a count): they join the
+        annotation's stats and the record."""
+        self.ids.update(ids)
+        self._ann.set_metadata(**ids)
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        self._ann.__enter__()
+        if _SINKS:
+            stack = _OPEN.stack
+            number = _OPEN.number = _OPEN.number + 1
+            self._rec = (time.time(), time.perf_counter(), number,
+                         stack[-1] if stack else None)
+            stack.append((self.event, number))
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = (time.perf_counter() - self._t0) * 1e3
-        if exc_type is not None:
-            # a raising body must be distinguishable from a clean one
-            # in the trace (ISSUE 14): mark the span and RE-raise — an
-            # incident bundle's timeline then shows the failing phase
-            emit(self.event, self.fields, dur_ms=round(dur, 4),
-                 error=exc_type.__name__)
-        else:
-            emit(self.event, self.fields, dur_ms=round(dur, 4))
+        rec, self._rec = self._rec, None
+        if rec is not None:
+            t0, started, number, parent = rec
+            dur = (time.perf_counter() - started) * 1e3
+            stack = _OPEN.stack
+            while stack and stack.pop()[1] != number:
+                pass
+            fields = dict(self.ids, t0=t0, dur_ms=round(dur, 4),
+                          span=number)
+            if parent is not None:
+                fields["parent"], fields["parent_span"] = parent
+            if exc_type is not None:
+                # a raising body must be distinguishable from a clean
+                # one in the trace (ISSUE 14): mark the span and
+                # RE-raise — an incident bundle's timeline then shows
+                # the failing phase
+                fields["error"] = exc_type.__name__
+            emit(self.event, fields)
+        self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
-class _NoopSpan:
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP = _NoopSpan()
+def span(event: str, **ids):
+    """THE way to open a span: `with telemetry.span("serve.step",
+    chunk=7):`.  Always a profiler annotation named `event` with `ids`
+    as its stats (see the module docstring for what that costs).  With a
+    sink attached it also emits, at its end, one record: `ts` (end),
+    `t0` (start), `dur_ms`, `span` (its running number on this thread),
+    `parent` / `parent_span` (name and number of the span it lies in),
+    the ids, and `error=<type>` where the body raised."""
+    return _Span(event, ids)
 
 
-def span(event: str, **fields):
-    """Timed context manager: emits `event` with dur_ms on exit.  With
-    no sink attached returns a shared no-op (no allocation)."""
-    if not _SINKS:
-        return _NOOP
-    return _Span(event, fields)
+def mark(event: str, **ids):
+    """An instant at the place something happens (a request is admitted):
+    a zero-length annotation, and an event where a sink listens."""
+    with TraceAnnotation(event, **ids):
+        pass
+    if _SINKS:
+        if _OPEN.stack:
+            ids["parent"], ids["parent_span"] = _OPEN.stack[-1]
+        emit(event, ids)
 
 
 def reset():

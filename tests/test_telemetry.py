@@ -4,13 +4,16 @@ cache — ISSUE 6.
 The contracts under test:
 
   * a 3-step jit.TrainStep run with a JSONL sink attached emits
-    per-step events carrying phase timings (acceptance criterion);
+    per-step events (acceptance criterion); where the step's time goes
+    is the `train.step` span's and its children's to say, on the
+    profiler's clock (tests/test_program_spans.py);
   * a SECOND process pointed at the same FLAGS_compile_cache_dir
     reports a cache hit — no recompile — via telemetry.compile_report()
     (acceptance criterion);
   * with no sink attached the plane is free: emit() is a no-op, span()
-    allocates nothing, programs are byte-identical (bench.py asserts
-    the HLO half; here the host half);
+    is the profiler's annotation and nothing else (no record, no clock
+    read), programs are byte-identical (bench.py asserts the HLO half;
+    here the host half);
   * every producer (trainers, serving batcher, watchdog, fault
     registry, checkpoint runtime, io prefetcher) publishes its events;
   * ContinuousBatcher.stats() counters SURVIVE a forced program
@@ -94,13 +97,119 @@ class TestRegistry:
         assert h.count == 100
         assert len(h._window) == 8          # ring, not unbounded
 
-    def test_emit_without_sink_is_noop_and_span_singleton(self):
-        # no sink: emit returns without touching anything, span returns
-        # THE shared no-op (no allocation on the hot path)
-        telemetry.emit("x", a=1)
-        s1 = telemetry.span("x")
-        s2 = telemetry.span("y")
-        assert s1 is s2
+    def test_without_sink_span_builds_no_record(self, monkeypatch):
+        # no sink: emit returns without touching anything; a span is the
+        # profiler's annotation alone: no record built, no clock read,
+        # nothing pushed on the thread's stack, no sink called
+        import importlib
+        registry = importlib.import_module("paddle_tpu.telemetry.registry")
+
+        class _NoClock:
+            def __getattr__(self, name):
+                raise AssertionError(f"time.{name} read with no sink")
+
+        built, number = [], registry._OPEN.number
+        monkeypatch.setattr(registry, "emit",
+                            lambda *a, **k: built.append(a))
+        monkeypatch.setattr(registry, "time", _NoClock())
+        with telemetry.span("x", a=1) as outer:
+            with telemetry.span("y"):
+                pass
+            outer.set(b=2)
+            telemetry.mark("z", req=3)
+        assert built == [] and outer._rec is None
+        assert registry._OPEN.stack == [] \
+            and registry._OPEN.number == number
+
+    def test_span_records_start_parent_and_running_number(self):
+        sink = telemetry.add_sink(telemetry.MemorySink())
+        with telemetry.span("outer", chunk=7) as outer:
+            with telemetry.span("first"):
+                time.sleep(0.002)
+            telemetry.mark("instant", req=5)
+            with telemetry.span("second") as second:
+                second.set(count=3)
+                with telemetry.span("inner"):
+                    pass
+            outer.set(admitted=2)
+        with telemetry.span("sibling"):
+            pass
+        telemetry.remove_sink(sink)
+        recs = {r["event"]: r for r in sink.records}
+        assert [r["event"] for r in sink.records] == [
+            "first", "instant", "inner", "second", "outer", "sibling"]
+        out = recs["outer"]
+        assert out["chunk"] == 7 and out["admitted"] == 2
+        assert "parent" not in out and "parent" not in recs["sibling"]
+        for name in ("first", "second", "instant"):
+            assert recs[name]["parent"] == "outer"
+            assert recs[name]["parent_span"] == out["span"]
+        assert recs["inner"]["parent"] == "second"
+        assert recs["inner"]["parent_span"] == recs["second"]["span"]
+        assert recs["second"]["count"] == 3
+        # the running number rises in the order the spans were OPENED
+        order = ["outer", "first", "second", "inner", "sibling"]
+        numbers = [recs[n]["span"] for n in order]
+        assert numbers == sorted(numbers) and len(set(numbers)) == 5
+        for r in sink.records:
+            if "dur_ms" in r:
+                assert r["t0"] <= r["ts"]
+                assert r["ts"] - r["t0"] == pytest.approx(
+                    r["dur_ms"] / 1e3, abs=0.05)
+        assert recs["first"]["dur_ms"] >= 1.5
+        assert out["t0"] <= recs["first"]["t0"] \
+            <= recs["second"]["t0"] <= out["ts"]
+
+    def test_span_numbers_are_per_thread(self):
+        import threading
+        sink = telemetry.add_sink(telemetry.MemorySink())
+
+        def work():
+            with telemetry.span("t.outer"):
+                with telemetry.span("t.inner"):
+                    pass
+
+        with telemetry.span("main.outer"):
+            t = threading.Thread(target=work)
+            t.start()
+            t.join(timeout=30)
+            assert not t.is_alive()
+        telemetry.remove_sink(sink)
+        recs = {r["event"]: r for r in sink.records}
+        # the other thread's spans do not lie in this thread's span
+        assert "parent" not in recs["t.outer"]
+        assert recs["t.inner"]["parent"] == "t.outer"
+        assert recs["t.outer"]["span"] == 1
+
+    def test_raising_span_keeps_error_and_unwinds(self):
+        sink = telemetry.add_sink(telemetry.MemorySink())
+        with pytest.raises(KeyError):
+            with telemetry.span("outer"):
+                with telemetry.span("bad", tag="t"):
+                    raise KeyError("x")
+        with telemetry.span("after"):
+            pass
+        telemetry.remove_sink(sink)
+        recs = {r["event"]: r for r in sink.records}
+        assert recs["bad"]["error"] == "KeyError" \
+            and recs["bad"]["tag"] == "t"
+        assert recs["outer"]["error"] == "KeyError"
+        assert "parent" not in recs["after"] and "error" not in recs["after"]
+
+    def test_chrome_sink_draws_spans_from_their_start(self):
+        sink = telemetry.add_sink(telemetry.ChromeTraceSink())
+        with telemetry.span("outer"):
+            with telemetry.span("inner"):
+                time.sleep(0.002)
+            time.sleep(0.002)
+        telemetry.remove_sink(sink, close=False)
+        ev = {e["name"]: e for e in sink.trace_events}
+        o, i = ev["outer"], ev["inner"]
+        assert o["ph"] == i["ph"] == "X"
+        # nested, not stacked at their end
+        assert o["ts"] <= i["ts"] and \
+            i["ts"] + i["dur"] <= o["ts"] + o["dur"] + 1.0
+        assert "t0" not in i["args"] and i["args"]["parent"] == "outer"
 
     def test_sink_receives_and_broken_sink_detached(self):
         good = telemetry.add_sink(telemetry.MemorySink())
@@ -130,15 +239,16 @@ class TestRegistry:
             telemetry.configure(not_a_switch=True)
 
     def test_reset_restores_config_defaults(self):
-        telemetry.configure(sync_steps=True, step_phases=False)
+        telemetry.configure(sync_steps=True)
         telemetry.reset()
         assert telemetry.config("sync_steps") is False
-        assert telemetry.config("step_phases") is True
+        # the one switch left: the probe's went with the probe
+        assert telemetry.configure() == {"sync_steps": False}
 
 
 # ---------------------------------------------------------------------------
 # train-step events (acceptance: 3-step run + JSONL sink -> per-step
-# events with phase timings)
+# events; the phase split is the spans', not the event's)
 
 class TestStepEvents:
     def test_three_step_trainstep_jsonl(self, tmp_path):
@@ -156,10 +266,10 @@ class TestStepEvents:
         assert [e["step"] for e in steps] == [1, 2, 3]
         for e in steps:
             assert e["trainer"] == "jit" and e["k"] == 1
-            assert e["wall_ms"] >= 0
-            ph = e["phases"]
-            for k in ("fwd_ms", "bwd_ms", "opt_ms", "n_params"):
-                assert isinstance(ph[k], (int, float)), (k, e)
+            assert e["wall_ms"] >= 0 and e["step_ms"] >= 0
+            # no probe: nothing beside the program was compiled or
+            # timed to fill the event
+            assert "phases" not in e
         assert steps[0].get("cold") is True
         assert "cold" not in steps[1]
 
@@ -197,12 +307,35 @@ class TestStepEvents:
         assert all(e["trainer"] == "sharded" for e in evs)
         assert evs[1]["step"] == 3          # 1 single + 2 fused
 
-    def test_no_sink_no_phase_probe_state(self):
-        # without a sink the trainer must not even cache phase-probe
-        # state (the probe never ran)
-        step, x = _mlp_step()
+    def test_sharded_step_spans_split_the_call(self):
+        # what the probe's fwd/bwd/opt columns stood for on the host's
+        # side: the call's own spans, children of train.step, covering
+        # it in order (the device's side is the step program's scopes)
+        import jax
+        from paddle_tpu.parallel import ShardedTrainStep
+        from paddle_tpu.distributed.topology import build_mesh
+        paddle.seed(0)
+        m = paddle.nn.Linear(8, 8)
+        opt = paddle.optimizer.AdamW(1e-3, parameters=m.parameters())
+        step = ShardedTrainStep(
+            m, opt, build_mesh(devices=jax.devices()[:1]),
+            loss_fn=lambda o, y: paddle.nn.functional.mse_loss(o, y))
+        x = paddle.to_tensor(np.ones((4, 8), np.float32))
+        step(x, x)                          # no sink: nothing recorded
+        sink = telemetry.add_sink(telemetry.MemorySink())
         step(x, x)
-        assert not hasattr(step, "_tel_phases")
+        telemetry.remove_sink(sink)
+        spans = [r for r in sink.records if "span" in r]
+        assert [r["event"] for r in spans] == [
+            "train.prepare", "train.dispatch", "train.writeback",
+            "train.step"]
+        call = spans[-1]
+        assert call["step"] == 2 and call["k"] == 1
+        for child in spans[:-1]:
+            assert child["parent"] == "train.step"
+            assert child["parent_span"] == call["span"]
+            assert call["t0"] <= child["t0"]
+        assert sum(c["dur_ms"] for c in spans[:-1]) <= call["dur_ms"]
 
 
 # ---------------------------------------------------------------------------
@@ -760,8 +893,33 @@ class TestExportersAndFacade:
             telemetry.remove_sink(sink)
         rep = cli.analyze(cli.load_events(log))
         assert rep["train_steps"] == 4 and rep["cold_steps"] == 1
-        assert set(rep["phases"]) == {"fwd_ms", "bwd_ms", "opt_ms"}
+        assert "phases" not in rep and rep["step_ms"]["p50"] >= 0
         assert cli.render(rep)
+
+    def test_report_span_table(self):
+        # the sink-side reader of the program's spans: per name count,
+        # p50 / max of the duration and the SELF time (duration minus
+        # what the span's children cover)
+        sys.path.insert(0, os.path.join(REPO, "tools"))
+        try:
+            import telemetry_report as cli
+        finally:
+            sys.path.pop(0)
+        events = []
+        for k, (step_ms, wait_ms) in enumerate([(10.0, 6.0), (20.0, 8.0)]):
+            base = 100 * k
+            events += [
+                {"event": "serve.device_wait", "span": base + 2,
+                 "parent": "serve.step", "parent_span": base + 1,
+                 "dur_ms": wait_ms, "t0": 1.0, "ts": 2.0},
+                {"event": "serve.step", "span": base + 1,
+                 "dur_ms": step_ms, "t0": 0.5, "ts": 2.5, "chunk": k}]
+        rep = cli.analyze(events)
+        assert rep["spans"]["serve.step"] == {
+            "count": 2, "p50_ms": 10.0, "max_ms": 20.0,
+            "self_p50_ms": 4.0, "self_max_ms": 12.0}
+        assert rep["spans"]["serve.device_wait"]["self_max_ms"] == 8.0
+        assert "serve.step" in cli.render(rep)
 
     def test_dump_snapshot_and_bench_field(self, capsys):
         telemetry.counter("x").inc(5)

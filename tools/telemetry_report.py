@@ -2,18 +2,19 @@
 paddle_tpu.telemetry (JSON output option + --selftest wired into
 tier-1, like tools/verify_program.py).
 
-    python tools/telemetry_report.py steps.jsonl [--json] [--peak F]
+    python tools/telemetry_report.py steps.jsonl [--json]
         Read a JSONL step log (telemetry.attach_jsonl) and print:
-        per-phase medians/p99 over warm train.step events, tokens/s and
-        the MFU trend (first half vs second half of the run), serving
-        chunk stats, io host-wait stats, and the compile-cache hit
-        rate.
+        step-time medians/p99 over warm train.step events, tokens/s,
+        the program's spans (count, duration and self time per name:
+        train.step and its children, serve.step and its phases),
+        serving chunk stats with each chunk's time by phase, io
+        host-wait stats, and the compile-cache hit rate.
 
     python tools/telemetry_report.py --selftest
         CI canary: runs a 5-step toy train loop with a JSONL sink (and
         a compile cache dir) in a temp dir, validates the emitted
-        schema (every step event carries wall_ms + fwd/bwd/opt phase
-        timings; compile.program events carry hit/miss), THEN a tiny
+        schema (every step event carries wall_ms and step_ms;
+        compile.program events carry hit/miss), THEN a tiny
         serve workload that load-sheds (bounded queue) and misses a
         deadline, validating the serve-robustness events
         (serve.shed carries slo+reason, serve.deadline_miss fires)
@@ -57,10 +58,8 @@ def load_events(path):
     return events
 
 
-def analyze(events, peak=None):
+def analyze(events):
     """Aggregate a JSONL event list into the report dict."""
-    if peak is None:
-        peak = float(os.environ.get("PEAK_FLOPS", 0)) or None
     steps = [e for e in events if e.get("event") == "train.step"]
     warm = [e for e in steps if not e.get("cold")]
     out = {"events": len(events), "train_steps": len(steps),
@@ -72,12 +71,6 @@ def analyze(events, peak=None):
 
     if warm:
         walls = series("step_ms")
-        ph = {"fwd_ms": [], "bwd_ms": [], "opt_ms": []}
-        for e in warm:
-            for k in ph:
-                v = e.get("phases", {}).get(k)
-                if isinstance(v, (int, float)):
-                    ph[k].append(v)
         # the shared summary derivation (ISSUE 14) adds TRUE window
         # min/max beside the percentiles — the outliers a percentile
         # window samples away are what an incident hunt needs
@@ -87,27 +80,36 @@ def analyze(events, peak=None):
                           "p99": round(s["p99"], 3),
                           "min": round(s["min"], 3),
                           "max": round(s["max"], 3)}
-        out["phases"] = {k: {"p50": round(_pct(v, 50), 3),
-                             "p99": round(_pct(v, 99), 3)}
-                         for k, v in ph.items() if v}
         tps = series("tokens_per_sec")
         if tps:
             out["tokens_per_sec"] = {"p50": round(_pct(tps, 50), 1),
                                      "p99": round(_pct(tps, 99), 1)}
-            n_params = next((e["phases"]["n_params"] for e in warm
-                             if e.get("phases", {}).get("n_params")),
-                            None)
-            if n_params and peak:
-                mfus = [6.0 * n_params * t / peak for t in tps]
-                half = max(1, len(mfus) // 2)
-                out["mfu"] = {
-                    "p50": round(float(np.median(mfus)), 4),
-                    "first_half": round(float(np.median(mfus[:half])), 4),
-                    "second_half": round(float(np.median(mfus[half:])), 4),
-                }
-                out["mfu"]["trend"] = round(
-                    out["mfu"]["second_half"] - out["mfu"]["first_half"],
-                    4)
+
+    # the program's spans (telemetry.span records: `span` is the
+    # record's running number on its thread, `parent_span` that of the
+    # span it lay in): per name the duration and the SELF time, which
+    # is the duration minus what the span's children cover
+    spans = [e for e in events if "span" in e
+             and isinstance(e.get("dur_ms"), (int, float))]
+    if spans:
+        covered = {}
+        for e in spans:
+            if "parent_span" in e:
+                key = (e.get("rank"), e["parent_span"], e.get("parent"))
+                covered[key] = covered.get(key, 0.0) + e["dur_ms"]
+        by_name = {}
+        for e in spans:
+            own = e["dur_ms"] - covered.get(
+                (e.get("rank"), e["span"], e["event"]), 0.0)
+            by_name.setdefault(e["event"], []).append(
+                (e["dur_ms"], max(own, 0.0)))
+        out["spans"] = {
+            name: {"count": len(v),
+                   "p50_ms": round(_pct([d for d, _ in v], 50), 3),
+                   "max_ms": round(max(d for d, _ in v), 3),
+                   "self_p50_ms": round(_pct([o for _, o in v], 50), 3),
+                   "self_max_ms": round(max(o for _, o in v), 3)}
+            for name, v in sorted(by_name.items())}
 
     compiles = [e for e in events if e.get("event") == "compile.program"]
     if compiles:
@@ -137,6 +139,20 @@ def analyze(events, peak=None):
             "recompiles": sum(1 for e in events
                               if e.get("event") == "serve.recompile"),
         }
+        # each steady chunk's step() by phase (the six <phase>_ms of
+        # serve.chunk): was a slow stretch the host's or the device's
+        phases = {}
+        for e in chunks:
+            if e.get("first_use"):
+                continue
+            for k, v in e.items():
+                if k.endswith("_ms") and k != "wall_ms" \
+                        and isinstance(v, (int, float)):
+                    phases.setdefault(k[:-3], []).append(v)
+        if phases:
+            out["serve"]["phase_ms"] = {
+                k: {"p50": round(_pct(v, 50), 3), "max": round(max(v), 3)}
+                for k, v in phases.items()}
         # paged-KV pool trajectory (serve.kv rides every chunk): last
         # snapshot carries the lifetime counters, peak shows pressure
         kv = [e for e in events if e.get("event") == "serve.kv"]
@@ -304,6 +320,17 @@ def analyze(events, peak=None):
             if a["with_deadline"]:
                 a["attainment"] = round(
                     a["deadline_met"] / a["with_deadline"], 4)
+        # in chunks (the `chunk` ids of the serve.step spans that
+        # caused them): admission to first token, first token to done
+        for name, a, b in (("prefill_chunks", "admit_chunk",
+                            "first_token_chunk"),
+                           ("decode_chunks", "first_token_chunk",
+                            "done_chunk")):
+            vals = [e[b] - e[a] + 1 for e in reqs
+                    if e.get(a, -1) >= 0 and e.get(b, -1) >= 0]
+            if vals:
+                lat[name] = {"count": len(vals),
+                             "p50": _pct(vals, 50), "max": max(vals)}
         s = out.setdefault("serve", {})
         s["latency"] = lat
         s["slo"] = att
@@ -400,15 +427,12 @@ def render(rep):
         if n.get("first_nonfinite_layer"):
             line += f" (first: {n['first_nonfinite_layer']})"
         lines.append(line)
-    for k, v in rep.get("phases", {}).items():
-        lines.append(f"  {k:<9} p50={v['p50']:<10} p99={v['p99']}")
     if "tokens_per_sec" in rep:
         lines.append(f"tokens/s    p50={rep['tokens_per_sec']['p50']}")
-    if "mfu" in rep:
-        m = rep["mfu"]
-        lines.append(f"mfu         p50={m['p50']}  trend "
-                     f"{m['first_half']} -> {m['second_half']} "
-                     f"({'+' if m['trend'] >= 0 else ''}{m['trend']})")
+    for name, v in rep.get("spans", {}).items():
+        lines.append(f"  span {name:<20} n={v['count']:<5} "
+                     f"p50={v['p50_ms']}ms max={v['max_ms']}ms  self "
+                     f"p50={v['self_p50_ms']}ms max={v['self_max_ms']}ms")
     if "compile" in rep:
         c = rep["compile"]
         rate = "n/a" if c["hit_rate"] is None else c["hit_rate"]
@@ -424,6 +448,10 @@ def render(rep):
                          f"{s['recompiles']} recompiles")
         else:
             lines.append("serve       (no chunk events)")
+        if "phase_ms" in s:
+            lines.append("  phases    " + ", ".join(
+                f"{k} p50={v['p50']}/max={v['max']}ms"
+                for k, v in s["phase_ms"].items()))
         if "kv" in s:
             k = s["kv"]
             lines.append(
@@ -589,11 +617,6 @@ def _selftest():
                       "step_ms"):
                 if k not in e:
                     problems.append(f"step event {i} missing {k!r}")
-            ph = e.get("phases", {})
-            for k in ("fwd_ms", "bwd_ms", "opt_ms", "n_params"):
-                if not isinstance(ph.get(k), (int, float)):
-                    problems.append(f"step event {i} phases missing "
-                                    f"{k!r}")
             if e.get("wall_ms", -1) < 0:
                 problems.append(f"step event {i} negative wall_ms")
         if [e["step"] for e in steps] != sorted(e["step"] for e in steps):
@@ -632,8 +655,8 @@ def _selftest():
                 if key not in e:
                     problems.append(f"perf.drift missing {key!r}: {e}")
         rep = analyze(events)
-        if "phases" not in rep or "step_ms" not in rep:
-            problems.append(f"report missing phase stats: {rep}")
+        if "step_ms" not in rep:
+            problems.append(f"report missing step stats: {rep}")
         cost = rep.get("cost")
         if not cost or cost.get("drifts", 0) < 1 \
                 or "jit.TrainStep.step" not in cost.get("programs", {}):
@@ -700,6 +723,25 @@ def _selftest():
                 or rob["deadline_misses"] < 1 \
                 or "best_effort" not in rob["shed_by_class"]:
             problems.append(f"robustness section wrong: {rob}")
+        # the program's spans and the chunk's phases (ISSUE 25): every
+        # steady serve.chunk carries the six phase durations, and the
+        # span table names serve.step with its children
+        from paddle_tpu.inference.serving import PHASES
+        for e in sevents:
+            if e.get("event") == "serve.chunk":
+                for k in PHASES:
+                    if not isinstance(e.get(f"{k}_ms"), (int, float)):
+                        problems.append(f"serve.chunk missing {k}_ms: {e}")
+        want = {"serve.step"} | {f"serve.{k}" for k in PHASES}
+        if not want <= set(srep.get("spans", {})):
+            problems.append(f"span table lacks "
+                            f"{sorted(want - set(srep.get('spans', {})))}")
+        steady = [e for e in sevents if e.get("event") == "serve.chunk"
+                  and not e.get("first_use")]
+        if steady and set(srep["serve"].get("phase_ms", {})) \
+                != set(PHASES):
+            problems.append(f"report missing serve phase split: "
+                            f"{srep.get('serve')}")
         print(render(srep))
 
         # speculative-decoding leg (ISSUE 11): a self-speculating
@@ -839,9 +881,6 @@ def main(argv=None):
                     help="run a 5-step toy loop and validate the "
                          "emitted schema; exit 1 on any violation")
     ap.add_argument("--json", action="store_true")
-    ap.add_argument("--peak", type=float, default=None,
-                    help="chip peak FLOP/s for MFU (default: "
-                         "PEAK_FLOPS env, else omitted)")
     args = ap.parse_args(argv)
 
     if args.selftest:
@@ -855,7 +894,7 @@ def main(argv=None):
 
     if not args.log:
         ap.error("provide a JSONL log path or --selftest")
-    rep = analyze(load_events(args.log), peak=args.peak)
+    rep = analyze(load_events(args.log))
     if args.json:
         print(json.dumps(rep, indent=1))
     else:
