@@ -13,8 +13,10 @@ calls, on ONE TPU chip and in ONE process:
           chunked prefill) answers a handful of requests of mixed prompt
           lengths; the tokens are compared with plain generate() on the
           same model.  The paged-attention kernel is then held to its
-          XLA twin on one pool (bf16 and int8, decode and chunk widths),
-          and a second batcher answers two requests from an int8 pool.
+          XLA twin on one pool (bf16 and int8, decode and chunk widths)
+          at this geometry and at the benchmark's serve cell's, where
+          both sides' time a call is printed, and a second batcher
+          answers two requests from an int8 pool.
 
 For both, the compiled program's text must hold the Pallas kernels
 (`tpu_custom_call`): flash attention, rms norm, rope and fused AdamW in the
@@ -320,12 +322,35 @@ def require_equal_to_generate(model, prompts, served):
         left_generate_on_a_tie=ties)
 
 
+# The benchmark's serve cell (dsllm7b_serve_chat_c24 under the batcher's
+# defaults): 24 slots, 66 pages of 16 rows a slot, admission width 32.
+CELL_SLOTS, CELL_PAGES_PER_SLOT, CELL_CHUNK = 24, 66, 32
+TIMED_CALLS = 20
+
+
+def ms_a_call(fn, args):
+    """Wall time of one call in ms, over TIMED_CALLS calls dispatched one
+    behind the other after a warm one: the device's time a call where
+    that is longer than the host's dispatch (about 0.2 ms), an upper
+    bound on it either way."""
+    import jax
+    jax.block_until_ready(fn(*args))
+    t0 = time.perf_counter()
+    for _ in range(TIMED_CALLS):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    return (time.perf_counter() - t0) / TIMED_CALLS * 1e3
+
+
 def require_paged_kernel_equals_twin(cfg, page_size):
     """The Pallas paged-attention kernel against ops.xla_paged_attention
-    on the same pool and query, at the serve phase's geometry: a bf16 and
-    an int8 pool filled by the repo's own writer (ops.paged_kv_update,
-    which quantizes), scattered pages, decode (C = 1) and chunk widths,
-    depths on both sides of page boundaries."""
+    on the same pool and query: a bf16 and an int8 pool filled by the
+    repo's own writer (ops.paged_kv_update, which quantizes), scattered
+    pages, decode (C = 1) and chunk widths.  Two geometries: the serve
+    phase's, depths on both sides of page boundaries, and the
+    benchmark's serve cell's, 12 to 60 live pages of 66 a slot as its
+    traffic leaves them — there each line also carries both sides' time
+    a call (the smoke's own calls are shorter than their dispatch)."""
     import numpy as np
     import jax
     import jax.numpy as jnp
@@ -333,57 +358,70 @@ def require_paged_kernel_equals_twin(cfg, page_size):
     from paddle_tpu.ops.pallas import paged_attention as kernel
     n_kv, heads, hd = (cfg.num_key_value_heads, cfg.num_attention_heads,
                        cfg.head_dim)
-    per_slot = SERVE_MAX_LEN // page_size
-    n_pages = 1 + SERVE_SLOTS * per_slot           # page 0: the null page
     rng = np.random.RandomState(SEED + 2)
-    table = jnp.asarray(1 + rng.permutation(n_pages - 1).reshape(
-        SERVE_SLOTS, per_slot), jnp.int32)
 
     def normal(*shape):
         return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
-    k_rows = normal(SERVE_SLOTS, SERVE_MAX_LEN, n_kv, hd)
-    v_rows = normal(SERVE_SLOTS, SERVE_MAX_LEN, n_kv, hd)
+    cell_depths = page_size * rng.randint(12, 61, CELL_SLOTS) \
+        - rng.randint(1, page_size + 1, CELL_SLOTS)
+    geometries = (
+        ("smoke", SERVE_SLOTS, SERVE_MAX_LEN // page_size, PREFILL_CHUNK,
+         ((1, (15, 16, 17, SERVE_MAX_LEN - 1)),
+          (PREFILL_CHUNK, (0, 16, 100, SERVE_MAX_LEN - PREFILL_CHUNK)))),
+        ("cell", CELL_SLOTS, CELL_PAGES_PER_SLOT, CELL_CHUNK,
+         ((1, cell_depths), (CELL_CHUNK, cell_depths - CELL_CHUNK))))
     write = jax.jit(ops.paged_kv_update, static_argnums=(8,))
-    for quant in (False, True):
-        shape = (n_pages, 1, n_kv, page_size, hd)
-        if not kernel.supports(shape):
-            fail(f"serve: paged_attention.supports refuses the pool {shape}")
-        pool = [jnp.zeros(shape, jnp.int8 if quant else jnp.bfloat16)] * 2
-        scales = [jnp.ones(shape[:3], jnp.float32)] * 2 if quant \
-            else [None, None]
-        for r0 in range(0, SERVE_MAX_LEN, PREFILL_CHUNK):
-            rows = slice(r0, r0 + PREFILL_CHUNK)
-            *pool, ks, vs = write(
-                *pool, *scales, table,
-                jnp.full((SERVE_SLOTS,), r0, jnp.int32),
-                k_rows[:, rows], v_rows[:, rows], 0)
-            scales = [ks, vs]
-        for width, depths in ((1, (15, 16, 17, SERVE_MAX_LEN - 1)),
-                              (PREFILL_CHUNK, (0, 16, 100,
-                                               SERVE_MAX_LEN
-                                               - PREFILL_CHUNK))):
-            q = normal(SERVE_SLOTS, width, heads, hd)
-            pos = jnp.asarray(depths, jnp.int32)
-            args = (q, *pool, table, pos, 0, *scales)
-            fn = jax.jit(ops.paged_attention, static_argnums=(5,))
-            if "paged_attention" not in kernels_in(
-                    fn.lower(*args).compile().as_text()):
-                fail("serve: ops.paged_attention compiled without its "
-                     "kernel")
-            got = np.asarray(fn(*args), np.float32)
-            want = np.asarray(jax.jit(ops.xla_paged_attention,
-                                      static_argnums=(5,))(*args),
-                              np.float32)
-            tol = PAGED_TOL_STEPS * float(bf16_step(np.abs(want).max()))
-            err = float(np.abs(got - want).max())
-            say(phase="serve", paged_kernel_vs_twin=dict(
-                pool="int8" if quant else "bf16", width=width,
-                max_abs_difference=err, largest_output=float(
-                    np.abs(want).max()), tolerance=tol))
-            if not np.isfinite(got).all() or not np.allclose(
-                    got, want, rtol=PAGED_TOL_STEPS * 2.0 ** -8, atol=tol):
-                fail(f"serve: the paged kernel leaves its twin by {err} "
-                     f"(pool int8={quant}, width {width}, tolerance {tol})")
+    fn = jax.jit(ops.paged_attention, static_argnums=(5,))
+    twin = jax.jit(ops.xla_paged_attention, static_argnums=(5,))
+    for name, slots, per_slot, chunk, cases in geometries:
+        n_pages = 1 + slots * per_slot             # page 0: the null page
+        n_rows = per_slot * page_size // chunk * chunk
+        table = jnp.asarray(1 + rng.permutation(n_pages - 1).reshape(
+            slots, per_slot), jnp.int32)
+        k_rows = normal(slots, n_rows, n_kv, hd)
+        v_rows = normal(slots, n_rows, n_kv, hd)
+        for quant in (False, True):
+            shape = (n_pages, 1, n_kv, page_size, hd)
+            if not kernel.supports(shape):
+                fail(f"serve: paged_attention.supports refuses the pool "
+                     f"{shape}")
+            pool = [jnp.zeros(shape, jnp.int8 if quant else jnp.bfloat16)] * 2
+            scales = [jnp.ones(shape[:3], jnp.float32)] * 2 if quant \
+                else [None, None]
+            for r0 in range(0, n_rows, chunk):
+                rows = slice(r0, r0 + chunk)
+                *pool, ks, vs = write(
+                    *pool, *scales, table,
+                    jnp.full((slots,), r0, jnp.int32),
+                    k_rows[:, rows], v_rows[:, rows], 0)
+                scales = [ks, vs]
+            for width, depths in cases:
+                q = normal(slots, width, heads, hd)
+                pos = jnp.asarray(depths, jnp.int32)
+                args = (q, *pool, table, pos, 0, *scales)
+                if "paged_attention" not in kernels_in(
+                        fn.lower(*args).compile().as_text()):
+                    fail("serve: ops.paged_attention compiled without its "
+                         "kernel")
+                got = np.asarray(fn(*args), np.float32)
+                want = np.asarray(twin(*args), np.float32)
+                tol = PAGED_TOL_STEPS * float(bf16_step(np.abs(want).max()))
+                err = float(np.abs(got - want).max())
+                times = dict(kernel_ms_a_call=ms_a_call(fn, args),
+                             twin_ms_a_call=ms_a_call(twin, args)) \
+                    if name == "cell" else {}
+                say(phase="serve", paged_kernel_vs_twin=dict(
+                    geometry=name, pool="int8" if quant else "bf16",
+                    width=width, live_pages=int(np.sum(kernel.pages_walked(
+                        np.asarray(depths), width, page_size, per_slot))),
+                    max_abs_difference=err, largest_output=float(
+                        np.abs(want).max()), tolerance=tol, **times))
+                if not np.isfinite(got).all() or not np.allclose(
+                        got, want, rtol=PAGED_TOL_STEPS * 2.0 ** -8,
+                        atol=tol):
+                    fail(f"serve: the paged kernel leaves its twin by {err} "
+                         f"({name} geometry, pool int8={quant}, width "
+                         f"{width}, tolerance {tol})")
 
 
 def phase_serve():

@@ -18,10 +18,10 @@ XLA program):
     slot, addressed through a per-slot page table `[B, pages_per_slot]`
     carried through the scan; page 0 is a reserved null page;
   * attention gathers by page table INSIDE the kernel
-    (ops.paged_attention: Pallas scalar-prefetch kernel on TPU, a
-    `take`-gather jnp twin elsewhere — bit-identical to the dense path
-    off-TPU); writes touch only the page window overlapping the step's
-    rows (ops.paged_kv_update);
+    (ops.paged_attention: a Pallas kernel on TPU that walks each
+    slot's live pages, a `take`-gather jnp twin elsewhere —
+    bit-identical to the dense path off-TPU); writes touch only the
+    page window overlapping the step's rows (ops.paged_kv_update);
   * PREFIX SHARING (inference/paged_kv.py): a host-side token-exact
     trie over page-sized prompt chunks maps admissions onto already-
     resident pages with refcounts — matched tokens SKIP their prefill
@@ -498,6 +498,11 @@ class ContinuousBatcher:
         self._phase_times: deque = deque(maxlen=1024)
         self._phase_ms = dict.fromkeys(PHASES, 0.0)
         self._chunk_no = 0              # the chunk step() is working on
+        # paged attention's page walk, summed over every chunk's scan
+        # steps (_kv_page_counts): what occupied slots hold / what the
+        # kernel walks
+        self._kv_pages_live = 0
+        self._kv_pages_walked = 0
         self._chunk_event = None        # serve.chunk's fields
         # per-request latency windows (bounded, same discipline as the
         # chunk times) + per-SLO-class deadline attainment — host
@@ -916,6 +921,10 @@ class ContinuousBatcher:
         self._mode = self._mode.at[i].set(False)
         self._mode_host[i] = False
         self._done_host[i] = True
+        # a free slot rests at depth 0: the paged kernel's walk follows
+        # pos, and a stale depth would walk the null page that many times
+        self._pos = self._pos.at[i].set(0)
+        self._pos_host[i] = 0
         if self.kv_layout == "paged" and self._plans[i] is not None:
             self._alloc.release_plan(self._plans[i])
             self._plans[i] = None
@@ -1209,6 +1218,12 @@ class ContinuousBatcher:
             "chunk_time_p50": times[len(times) // 2] if times else 0.0,
             "chunk_time_max": self._chunk_time_max,
             "phase_ms": self._phase_summary(),
+            # pages one paged-attention call covers, summed over every
+            # chunk's scan steps: held by occupied slots / walked by
+            # the kernel.  Equal while every slot is occupied (the walk
+            # is ragged); a free slot walks its one or two pages
+            "kv_pages_live": self._kv_pages_live,
+            "kv_pages_walked": self._kv_pages_walked,
             "compiled_programs": self.compiled_programs,
             "kv_layout": self.kv_layout,
             "kv_bytes": self.kv_cache_bytes(),
@@ -2176,6 +2191,36 @@ class ContinuousBatcher:
                             *self._carry_args())
         return fn.lower(self._param_vals(), *self._carry_args())
 
+    def _kv_page_counts(self, width: int, steps: int):
+        """(live, walked): the pages one paged-attention call covers,
+        summed over the `steps` scan steps of the chunk about to be
+        dispatched — `live` of the occupied slots, `walked` of all B,
+        which is what the kernel walks (ops.pallas.paged_attention.
+        pages_walked, the kernel's own bound).  Replayed on the host
+        from `_pos_host` and the slots' prompts as step_core advances
+        them, no device read; a speculative decode step counts the one
+        token it is sure to advance.  (0, 0) for the dense layout."""
+        if self.kv_layout != "paged":
+            return 0, 0
+        from ..ops.pallas.paged_attention import pages_walked
+        pos = self._pos_host.astype(np.int64)
+        done = self._done_host.copy()
+        mode = self._mode_host.copy()
+        occupied = np.array([r is not None for r in self._slots])
+        plen = np.array([len(r.prompt) if r is not None else 0
+                         for r in self._slots], np.int64)
+        live = walked = 0
+        for _ in range(steps):
+            n = pages_walked(pos, width, self.page_size,
+                             self.pages_per_slot)
+            walked += int(n.sum())
+            live += int(n[occupied].sum())
+            filling = mode & ~done
+            pos += np.where(filling, np.minimum(width, plen - pos), ~done)
+            mode &= ~(filling & (pos >= plen))
+            done |= pos >= self.max_len - 1
+        return live, walked
+
     def _run_chunk(self, mixed: bool) -> bool:
         """One scan chunk, in the phases `serve.dispatch`,
         `serve.device_wait` and `serve.harvest` (the `on_token`
@@ -2188,14 +2233,19 @@ class ContinuousBatcher:
         kind = "admit" if mixed else "decode"
         ck = self._chunk_no
         n_emit = n_acc = None
+        # the chunk's program by its width and scan length (_spec_w is
+        # 1 without speculation)
+        width, steps = (self.prefill_chunk, self.admit_steps) if mixed \
+            else (self._spec_w, self.chunk)
+        pages = self._kv_page_counts(width, steps)
         try:
-            with self._phase("dispatch", kind=kind, chunk=ck):
-                if mixed:
-                    fn = self._step_fn(self.prefill_chunk, self.admit_steps)
-                elif self.spec_k:
+            with self._phase("dispatch", kind=kind, chunk=ck,
+                             kv_pages_live=pages[0],
+                             kv_pages_walked=pages[1]):
+                if self.spec_k and not mixed:
                     fn = self._spec_step_fn()
                 else:
-                    fn = self._step_fn(1, self.chunk)
+                    fn = self._step_fn(width, steps)
                 # the chunk dispatch runs under the serve watchdog
                 # (FLAGS_stop_check_timeout): a hang dumps thread stacks
                 # / aborts per the r9 contract, and a delay-injected
@@ -2242,6 +2292,8 @@ class ContinuousBatcher:
                 raise
             return False
         self._consecutive_chunk_faults = 0
+        self._kv_pages_live += pages[0]
+        self._kv_pages_walked += pages[1]
         if self._watch.last_reported:
             self._hung_chunks += 1
             _tel.counter("serve.hung_chunks").inc()

@@ -191,10 +191,14 @@ def cached_attention(q, k_cache, v_cache, q_pos0, scale=None):
     Reference: `python/paddle/incubate/nn/functional/
     block_multihead_attention.py` (paged-KV decode).  TPU-native
     design: a ring buffer with STATIC S_max (XLA needs static shapes)
-    and one batched masked matmul — at q_len==1 a Pallas kernel would
-    be per-instance-overhead-bound (the measured failure mode of small
-    grids on v5e; see flash_attention._fwd_1b notes), while XLA lowers
-    this to a single large batched GEMV at full HBM rate."""
+    and one batched masked matmul over every row the buffer could
+    hold.  That is the right trade for the DENSE cache only: a kernel
+    with one grid step per (slot, head, block) is bound by its step
+    count at q_len==1 (measured on v5e: the first paged kernel, 50,688
+    steps a call, took 11.9 ms where XLA's gather twin took 5-6), but
+    one that walks a slot's live pages inside a grid step, all heads a
+    transfer, is not — ops.paged_attention at the same shapes takes
+    0.3 ms a call (ISSUE 26, PERF.md section 6)."""
     b, sq, h, d = q.shape
     sk = k_cache.shape[1]
     s = scale if scale is not None else 1.0 / (d ** 0.5)
@@ -338,11 +342,13 @@ def xla_paged_attention(q, k_pool, v_pool, page_table, pos, layer,
 def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                     k_scale=None, v_scale=None, scale=None):
     """Decode attention against the paged KV pool: Pallas kernel on TPU
-    (gather-by-page-table in the DMA index map, int8 dequant fused —
-    see ops/pallas/paged_attention.py), `take`-gather twin elsewhere
-    and for shapes the kernel's `supports` predicate refuses.  The
-    choice is made from the shapes alone: whatever the kernel raises —
-    a lowering or compiler refusal included — reaches the caller."""
+    (it copies each slot's LIVE pages of this layer itself, all kv
+    heads of a page a transfer, and applies an int8 pool's scales
+    inside — see ops/pallas/paged_attention.py), `take`-gather twin
+    elsewhere and for shapes the kernel's `supports` predicate refuses.
+    The choice is made from the shapes alone: whatever the kernel
+    raises — a lowering or compiler refusal included — reaches the
+    caller."""
     _check_paged_args(q, k_pool, k_scale, v_scale)
     if _on_tpu():
         from .pallas import paged_attention as _k

@@ -534,3 +534,86 @@ def test_cow_admissions_interleave_pressure_bitexact(model):
         np.testing.assert_array_equal(outs[rid],
                                       _isolated(model, p, 6))
     assert st["requests_submitted"] == st["requests_completed"] == 3
+
+
+# ---------------------------------------------------------------------------
+# the page walk's counters (ISSUE 26)
+
+
+def _walk_oracle(slots, width, steps, ps, per_slot, max_len):
+    """One chunk's pages, slot by slot and step by step, in plain python:
+    `slots` is [(pos, prompt_len, prefilling)] — what step_core does to a
+    live slot's depth, and ceil((pos + width) / ps) pages a step."""
+    total = 0
+    for pos, plen, filling in slots:
+        for _ in range(steps):
+            total += min(-(-(pos + width) // ps), per_slot)
+            if pos >= max_len - 1:
+                continue
+            if filling:
+                pos += min(width, plen - pos)
+                filling = pos < plen
+            else:
+                pos += 1
+    return total
+
+
+@pytest.mark.parametrize("spans", [False, True], ids=["stats", "span_ids"])
+def test_kv_page_counters_follow_the_walk(model, spans):
+    """stats()["kv_pages_walked"] is the kernel's own bound
+    (pages_walked) summed over every scan step of every chunk, replayed
+    on the host: equal to a slot-by-slot oracle for an admission chunk
+    and for a decode chunk, equal to kv_pages_live while every slot is
+    occupied, and a free slot at depth 0 costs one page a step.  The
+    `serve.dispatch` span carries each chunk's pair as ids."""
+    from paddle_tpu import telemetry
+    rng = np.random.RandomState(5)
+    ps, C, A, K, max_len = 8, 4, 2, 4, 64
+    bat = ContinuousBatcher(model, max_batch_size=2, max_len=max_len,
+                            chunk=K, prefill_chunk=C, admit_steps=A,
+                            kv_layout="paged", page_size=ps)
+    lens = (11, 6)
+    for n, new in zip(lens, (5, 16)):   # slot 0 frees long before slot 1
+        bat.submit(rng.randint(1, 128, n).astype(np.int32), new)
+    sink = telemetry.add_sink(telemetry.MemorySink()) if spans else None
+    try:
+        want = 0
+        while True:
+            before = bat.stats()
+            pos = [int(p) for p in bat._pos_host]
+            filling = [bool(m) for m in bat._mode_host]
+            free = [r is None for r in bat._slots]
+            bat.step()
+            after = bat.stats()
+            if after["chunks"] == before["chunks"]:
+                break
+            if before["chunks"] == 0:
+                # the first chunk admits both requests at depth 0
+                pos, filling, free = [0, 0], [True, True], [False, False]
+            elif any(free):
+                break       # a request finished: the walk below is over
+            mixed = after["admit_chunks"] > before["admit_chunks"]
+            got = after["kv_pages_walked"] - before["kv_pages_walked"]
+            assert got == _walk_oracle(
+                list(zip(pos, lens, filling)), C if mixed else 1,
+                A if mixed else K, ps, bat.pages_per_slot, max_len)
+            want += got
+            assert after["kv_pages_live"] == after["kv_pages_walked"] \
+                == want
+        assert want > 0 and before["decode_chunks"] > 0
+        # one slot free at depth 0: it walks one page a decode step,
+        # and holds nothing live
+        bat.run()
+        st = bat.stats()
+        assert st["kv_pages_walked"] > st["kv_pages_live"] > want
+        if spans:
+            ids = [r for r in sink.records
+                   if r.get("event") == "serve.dispatch"]
+            assert len(ids) == st["chunks"]
+            assert sum(r["kv_pages_walked"] for r in ids) \
+                == st["kv_pages_walked"]
+            assert sum(r["kv_pages_live"] for r in ids) \
+                == st["kv_pages_live"]
+    finally:
+        if sink is not None:
+            telemetry.remove_sink(sink)

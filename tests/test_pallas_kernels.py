@@ -339,8 +339,40 @@ class TestRope:
             rope_apply(q, k, cos, sin)
 
 
+# One walk of the paged kernel against its twin.  ps 8 makes a block 16
+# pages, so a table of 40 pages is walked in up to three blocks.
+#   heads = (h, n_kv); pos: one depth a slot; table: "scattered" (every
+#   slot its own pages), "dead0" (slot 0's row all null page) or
+#   "shared" (slots 0 and 1 map the same pages); budget: the VMEM the
+#   head blocking may take (None: the module's)
+_PAGED_CASES = {
+    # the three cases the kernel has always been held to
+    "decode_gqa2": dict(C=1, heads=(4, 2), P_slot=3, pos=(0, 5, 13)),
+    "chunk_gqa4": dict(C=4, heads=(8, 2), P_slot=3, pos=(0, 5, 13)),
+    "chunk_mha": dict(C=4, heads=(2, 2), P_slot=3, pos=(0, 5, 13)),
+    # ragged depths in one batch: depth 0; lanes ending exactly on a page
+    # (and block) boundary; lanes straddling it; the last page of the
+    # table; one page deep into the third block
+    "ragged": dict(C=4, heads=(4, 2), P_slot=40,
+                   pos=(0, 124, 126, 316, 257)),
+    "dead_slot": dict(C=4, heads=(4, 2), P_slot=40, pos=(0, 150, 37),
+                      table="dead0"),
+    "chunk32_group1": dict(C=32, heads=(2, 2), P_slot=40,
+                           pos=(0, 97, 288, 120)),
+    "decode_group4": dict(C=1, heads=(8, 2), P_slot=40,
+                          pos=(0, 127, 128, 319)),
+    # 4 kv heads that a small budget cuts into blocks of 2 and of 1
+    "head_blocks_of_2": dict(C=4, heads=(8, 4), P_slot=40,
+                             pos=(3, 200, 129), budget=100_000, hb=2),
+    "head_blocks_of_1": dict(C=1, heads=(4, 4), P_slot=40,
+                             pos=(3, 200, 129), budget=1, hb=1),
+    "shared_pages": dict(C=4, heads=(4, 2), P_slot=40,
+                         pos=(140, 131, 20), table="shared"),
+}
+
+
 class TestPagedAttention:
-    """Paged-attention kernel (scalar-prefetch page gather) vs the
+    """Paged-attention kernel (its own copies of the live pages) vs the
     take-gather jnp twin (ops.xla_paged_attention)."""
 
     def _pool(self, P=10, ps=8, L=2, n_kv=2, d=16, quant=False):
@@ -349,6 +381,8 @@ class TestPagedAttention:
                                           (P, L, n_kv, ps, d)), jnp.int8)
             vp = jnp.asarray(_rng.randint(-127, 128,
                                           (P, L, n_kv, ps, d)), jnp.int8)
+            # every (page, layer, head) its own scale, a factor of six
+            # apart: a scale read from the wrong page or head shows
             ks = jnp.asarray(_rng.rand(P, L, n_kv) * 0.05 + 0.01,
                              jnp.float32)
             vs = jnp.asarray(_rng.rand(P, L, n_kv) * 0.05 + 0.01,
@@ -356,37 +390,63 @@ class TestPagedAttention:
             return kp, vp, ks, vs
         return r(P, L, n_kv, ps, d), r(P, L, n_kv, ps, d), None, None
 
-    @pytest.mark.parametrize("C,h", [(1, 4), (4, 8), (4, 2)])
-    def test_forward_vs_twin(self, C, h):
-        from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    def _table(self, kind, B, P_slot):
+        P = 1 + B * P_slot
+        pt = _rng.permutation(P - 1)[:B * P_slot].reshape(B, P_slot) + 1
+        if kind == "dead0":
+            pt[0] = 0
+        elif kind == "shared":
+            pt[1] = pt[0]
+        return P, jnp.asarray(pt, jnp.int32)
+
+    def _check(self, case, quant):
+        from paddle_tpu.ops.pallas import paged_attention as kernel
         from paddle_tpu.ops import xla_paged_attention
-        B, P, ps, P_slot, L, n_kv, d = 3, 10, 8, 3, 2, 2, 16
-        kp, vp, _, _ = self._pool(P, ps, L, n_kv, d)
+        ps, L, d = 8, 2, 16
+        C, (h, n_kv), P_slot = case["C"], case["heads"], case["P_slot"]
+        B = len(case["pos"])
+        P, pt = self._table(case.get("table", "scattered"), B, P_slot)
+        kp, vp, ks, vs = self._pool(P, ps, L, n_kv, d, quant=quant)
         q = r(B, C, h, d)
-        pt = jnp.asarray(_rng.permutation(P - 1)[:B * P_slot]
-                         .reshape(B, P_slot) + 1, jnp.int32)
-        pos = jnp.asarray([0, 5, 13], jnp.int32)
+        pos = jnp.asarray(case["pos"], jnp.int32)
+        extra = {}
+        if "budget" in case:
+            extra["vmem_budget"] = case["budget"]
+            rows = -(-C * (h // n_kv) // 8) * 8
+            assert kernel._blocking(
+                n_kv, rows, ps, d, kp.dtype.itemsize, 4, P_slot,
+                case["budget"])[0] == case["hb"]
         for li in range(L):
-            out = paged_attention(q, kp, vp, pt, pos, li,
-                                  interpret=True)
-            ref = xla_paged_attention(q, kp, vp, pt, pos, li)
+            out = kernel.paged_attention(q, kp, vp, pt, pos, li, ks, vs,
+                                         interpret=True, **extra)
+            ref = xla_paged_attention(q, kp, vp, pt, pos, li, ks, vs)
             np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                        atol=2e-5, rtol=2e-5)
 
-    def test_int8_dequant_fused(self):
-        from paddle_tpu.ops.pallas.paged_attention import paged_attention
-        from paddle_tpu.ops import xla_paged_attention
-        B, P, ps, P_slot, L, n_kv, d = 2, 8, 8, 3, 2, 2, 16
-        kp, vp, ks, vs = self._pool(P, ps, L, n_kv, d, quant=True)
-        q = r(B, 4, 4, d)
-        pt = jnp.asarray(_rng.permutation(P - 1)[:B * P_slot]
-                         .reshape(B, P_slot) + 1, jnp.int32)
-        pos = jnp.asarray([3, 11], jnp.int32)
-        out = paged_attention(q, kp, vp, pt, pos, 1, ks, vs,
-                              interpret=True)
-        ref = xla_paged_attention(q, kp, vp, pt, pos, 1, ks, vs)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=2e-5, rtol=2e-5)
+    @pytest.mark.parametrize("name", list(_PAGED_CASES))
+    def test_forward_vs_twin(self, name):
+        self._check(_PAGED_CASES[name], quant=False)
+
+    @pytest.mark.parametrize("name", ["chunk_gqa4", "ragged", "dead_slot",
+                                      "decode_group4", "head_blocks_of_1",
+                                      "shared_pages"])
+    def test_int8_dequant_fused(self, name):
+        self._check(_PAGED_CASES[name], quant=True)
+
+    @pytest.mark.parametrize("C", [1, 4, 32])
+    def test_pages_walked_are_the_live_pages(self, C):
+        """The walk's bound: ceil((pos + C) / page_size) pages a slot,
+        at least one (a walk of none would leave the next slot's first
+        block unstarted) and never past its table — for a numpy vector
+        as for a scalar."""
+        from paddle_tpu.ops.pallas.paged_attention import pages_walked
+        ps, P_slot = 16, 66
+        pos = np.array([-40, 0, 1, 15, 16, 17, 100, 511, 1023, 1055])
+        want = np.clip(-(-(pos + C) // ps), 1, P_slot)
+        np.testing.assert_array_equal(pages_walked(pos, C, ps, P_slot),
+                                      want)
+        assert [int(pages_walked(int(p), C, ps, P_slot)) for p in pos] \
+            == list(want)
 
     def test_int8_needs_scales(self):
         from paddle_tpu.ops.pallas.paged_attention import paged_attention
