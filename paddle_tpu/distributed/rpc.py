@@ -65,9 +65,16 @@ def _serve_loop():
             time.sleep(0.1)
             continue
         for key, raw in sorted(calls.items()):
-            kv.delete(key)
             try:
                 req = _dec(raw)
+            except AttributeError:
+                # a function the caller pickled by name is not defined
+                # here YET: a peer's call can arrive between init_rpc()
+                # and the script's own `def`s.  Left for the next poll;
+                # the caller's timeout bounds the wait
+                continue
+            kv.delete(key)
+            try:
                 fn = req["fn"]
                 out = fn(*req.get("args", ()), **(req.get("kwargs") or {}))
                 payload = {"ok": True, "value": out}

@@ -13,12 +13,25 @@ the stacked expert weights sharded on the expert dim over the `ep` axis,
 GSPMD lowers the dispatch einsum to exactly the reference's all_to_all.
 Gates:
 
-  naive  — top-k softmax, no capacity, no aux loss
-  switch — top-1, capacity-bounded, load-balance aux loss (Fedus et al.)
-  gshard — top-2, capacity-bounded, aux loss (Lepikhin et al.)
+  naive   — top-k softmax, no capacity, no aux loss
+  switch  — top-1, capacity-bounded, load-balance aux loss (Fedus et al.)
+  gshard  — top-2, capacity-bounded, aux loss (Lepikhin et al.)
+  sigmoid — top-k of fp32 sigmoid scores plus a choice-only bias, weights
+            renormalised over the chosen and scaled (DeepSeek-V3's
+            auxiliary-loss-free router), no capacity, no aux loss
 
 Tokens over capacity are dropped (combine weight 0 → residual passthrough
-is the caller's choice, as in the reference).
+is the caller's choice, as in the reference).  The gates WITHOUT a capacity
+(naive, sigmoid) drop nothing: their assignments are sorted by expert and
+the experts run as one grouped product over the sorted rows
+(`dropless_experts`), so the work grows with the assignments, not with
+experts x tokens.
+
+A layer may hold only ITS SHARE of the experts (`experts_held=(first,
+count)` of a router `router_width` wide): it routes over all of them,
+normalises over all the chosen, and computes the part of the result its own
+experts give.  What the absent experts would add is the other chips' part;
+on one chip the layer runs without the exchange.
 """
 from __future__ import annotations
 
@@ -37,7 +50,92 @@ from .....framework.dispatch import run, to_tensor_args
 from .....framework.tensor import Tensor
 
 __all__ = ["MoELayer", "NaiveGate", "SwitchGate", "GShardGate",
-           "ExpertMLP"]
+           "SigmoidGate", "ExpertMLP", "StepCounters", "dropless_experts",
+           "COUNTER_NAMES"]
+
+# what a dropless layer counts a step, on the device (StepCounters)
+COUNTER_NAMES = ("moe_assignments", "moe_assignments_held",
+                 "moe_expert_steps_hit", "moe_tokens_per_expert_max")
+
+
+class StepCounters:
+    """Routing counts of one step program, made by whoever traces the
+    step and handed down to the expert layers, which add to it while
+    they are traced: `valid` [tokens] marks the lanes the step keeps
+    (the others are routed nowhere and cost no expert work).  `vector()`
+    is int32 [len(COUNTER_NAMES)]: assignments of valid tokens,
+    those that chose an expert held here, (layer, held expert) pairs
+    with at least one token, and the largest single (layer, expert)
+    load — the first three add over layers and steps, the last is a
+    maximum."""
+
+    def __init__(self, valid=None):
+        self.valid = None if valid is None else valid.reshape(-1)
+        self._sums = jnp.zeros((3,), jnp.int32)
+        self._max = jnp.zeros((), jnp.int32)
+
+    def add(self, n_valid, top_k, sizes):
+        """sizes [count]: valid tokens each held expert got."""
+        self._sums = self._sums + jnp.stack(
+            [n_valid * top_k, jnp.sum(sizes),
+             jnp.sum((sizes > 0).astype(jnp.int32))]).astype(jnp.int32)
+        self._max = jnp.maximum(self._max, jnp.max(sizes))
+
+    def vector(self):
+        return jnp.concatenate([self._sums, self._max[None]])
+
+    @staticmethod
+    def merge(steps):
+        """[steps, 4] of a scan -> [4]."""
+        return jnp.concatenate([jnp.sum(steps[:, :3], axis=0),
+                                jnp.max(steps[:, 3:], axis=0)])
+
+
+def dropless_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
+                     b1=None, b2=None, counters=None):
+    """sum_j topw[s, j] * expert_{topi[s, j]}(tokens[s]) over the chosen
+    experts HELD here, without a capacity: tokens [S, d]; topi [S, k]
+    ids over the router's whole width; topw [S, k] fp32; w1
+    [count, d, *], w2 [count, *, d] the held experts first..first+count
+    (optional biases [count, 1, *]).  The S*k assignments are sorted by
+    held expert (absent experts' and invalid tokens' last), the rows
+    gathered in that order and both products run grouped
+    (`jax.lax.ragged_dot`: a grouped-matmul kernel on TPU that stops at
+    the last group's end), then each token sums its k rows back in
+    fp32.  Returns [S, d] fp32."""
+    S, k = topi.shape
+    count = w1.shape[0]
+    local = topi.astype(jnp.int32) - first
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held = held & valid[:, None]
+    with jax.named_scope("moe.dispatch"):
+        key = jnp.where(held, local, count).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        # int32 whatever jax_enable_x64 says: the grouped product's
+        # TPU lowering takes no 64-bit group sizes
+        sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0,
+                        promote_integers=False)
+        rows = jnp.take(tokens, order // k, axis=0)          # [S*k, d]
+    if counters is not None:
+        n_valid = S if valid is None else jnp.sum(valid.astype(jnp.int32))
+        counters.add(n_valid, k, sizes)
+    with jax.named_scope("moe.experts"):
+        expert = jnp.take(key, order)     # of each sorted row
+        h = jax.lax.ragged_dot(rows, w1, sizes)
+        if b1 is not None:
+            h = h + jnp.take(b1[:, 0], expert, axis=0, mode="clip")
+        out = jax.lax.ragged_dot(_expert_act(h, act).astype(rows.dtype),
+                                 w2, sizes)
+        if b2 is not None:
+            out = out + jnp.take(b2[:, 0], expert, axis=0, mode="clip")
+    with jax.named_scope("moe.combine"):
+        back = jnp.zeros((S * k,), jnp.int32).at[order].set(
+            jnp.arange(S * k, dtype=jnp.int32))
+        mine = jnp.take(out, back, axis=0).reshape(S, k, -1)
+        # rows past the last group are whatever the kernel left there
+        mine = jnp.where(held[..., None], mine.astype(jnp.float32), 0.0)
+        return jnp.sum(mine * topw[..., None].astype(jnp.float32), axis=1)
 
 
 def _topk_dispatch(gates, k, capacity):
@@ -83,8 +181,9 @@ class _GateBase(Layer):
     use_capacity = True
     use_aux = True
 
-    def __init__(self, d_model, num_experts, capacity_factor=None):
-        super().__init__()
+    def __init__(self, d_model, num_experts, capacity_factor=None,
+                 dtype=None):
+        super().__init__(dtype=dtype)
         self.d_model = d_model
         self.num_experts = num_experts
         self.capacity_factor = capacity_factor
@@ -123,6 +222,37 @@ class GShardGate(_GateBase):
     top_k = 2
 
 
+class SigmoidGate(_GateBase):
+    """DeepSeek-V3's router: s = sigmoid(x W) in fp32 over the whole
+    width; the top_k largest of s + bias are chosen (`bias` is a buffer
+    the training balances, used for the CHOICE only); their weights are
+    scaling * s / sum of the chosen s.  One routing group, no capacity,
+    no auxiliary loss."""
+    top_k = 8
+    use_capacity = False
+    use_aux = False
+
+    def __init__(self, d_model, num_experts, top_k=8, scaling=1.0,
+                 bias=True, dtype=None, **kw):
+        super().__init__(d_model, num_experts, dtype=dtype)
+        self.top_k = top_k
+        self.scaling = float(scaling)
+        self.bias = self.register_buffer(
+            "bias", Tensor(jnp.zeros((num_experts,), jnp.float32))) \
+            if bias else None
+
+    def route(self, tokens, weight, bias):
+        """(topi [S, k], topw [S, k] fp32, scores [S, E] fp32)."""
+        s = jax.nn.sigmoid(jnp.matmul(
+            tokens.astype(jnp.float32), weight.astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST))
+        pick = s if bias is None else s + bias.astype(jnp.float32)
+        _, topi = jax.lax.top_k(pick, self.top_k)
+        chosen = jnp.take_along_axis(s, topi, axis=-1)
+        topw = self.scaling * chosen / jnp.sum(chosen, -1, keepdims=True)
+        return topi, topw, s
+
+
 class ExpertMLP(Layer):
     """One expert: Linear → activation → Linear (the reference's
     ExpertLayer shape)."""
@@ -151,14 +281,36 @@ class MoELayer(Layer):
 
     The load-balance aux loss of the last forward is on `self.l_aux`
     (reference keeps it the same way).
+
+    A share of an expert-parallel layer: `num_experts` experts are HELD,
+    `experts_held=(first, count)` says which of the router's
+    `router_width` they are (stacked style only; count must equal
+    num_experts).  gate="sigmoid" takes `routed_scaling`, `router_bias`
+    and `shared_hidden` (one always-on swiglu expert that wide, computed
+    whole on every chip) and has no expert biases.  `dtype`: what the
+    leaves are held in (the default float32, as every Layer).
     """
 
     def __init__(self, d_model=None, d_hidden=None, num_experts=None,
                  gate="gshard", experts: Optional[List[Layer]] = None,
                  top_k=None, capacity_factor=None, ep_axis="dp",
                  moe_group=None, recompute_interval=0,
-                 activation="gelu", **kw):
-        super().__init__()
+                 activation="gelu", experts_held=None, router_width=None,
+                 routed_scaling=1.0, router_bias=False, shared_hidden=0,
+                 dtype=None, **kw):
+        super().__init__(dtype=dtype)
+        held_n = num_experts if num_experts else len(experts or ())
+        self.first_expert, count = experts_held or (0, held_n)
+        width = router_width or held_n
+        if count != held_n or self.first_expert < 0 \
+                or self.first_expert + count > width:
+            raise ValueError(
+                f"experts_held {experts_held} does not name {held_n} "
+                f"experts of a router {width} wide")
+        if width != held_n and (experts is not None or not isinstance(
+                gate, str) or gate not in ("naive", "sigmoid")):
+            raise ValueError("a share of the experts needs a dropless "
+                             "gate (naive|sigmoid) and stacked experts")
         # "swiglu": llama/Mixtral-style experts — w1 holds gate+up
         # halves ([E, d, 2*dh]); "gelu": the reference ExpertLayer MLP
         self.activation = activation
@@ -166,12 +318,14 @@ class MoELayer(Layer):
             if experts is not None and d_model is None:
                 d_model = experts[0].fc1.weight.shape[0]
             cls = {"naive": NaiveGate, "switch": SwitchGate,
-                   "gshard": GShardGate}[gate]
+                   "gshard": GShardGate, "sigmoid": SigmoidGate}[gate]
             kwargs = {}
-            if top_k is not None and cls is NaiveGate:
+            if top_k is not None and cls in (NaiveGate, SigmoidGate):
                 kwargs["top_k"] = top_k
-            self.gate = cls(d_model,
-                            num_experts if num_experts else len(experts),
+            if cls is SigmoidGate:
+                kwargs.update(scaling=routed_scaling, bias=router_bias,
+                              dtype=dtype)
+            self.gate = cls(d_model, width,
                             **({"capacity_factor": capacity_factor}
                                | kwargs))
             if top_k is not None:
@@ -192,13 +346,23 @@ class MoELayer(Layer):
             self.w1 = self.create_parameter(
                 shape=[num_experts, d_model, w1_h],
                 default_initializer=I.XavierUniform())
-            self.b1 = self.create_parameter(
-                shape=[num_experts, 1, w1_h], is_bias=True)
             self.w2 = self.create_parameter(
                 shape=[num_experts, d_hidden, d_model],
                 default_initializer=I.XavierUniform())
-            self.b2 = self.create_parameter(
-                shape=[num_experts, 1, d_model], is_bias=True)
+            self.b1 = self.b2 = None
+            if gate != "sigmoid":
+                self.b1 = self.create_parameter(
+                    shape=[num_experts, 1, w1_h], is_bias=True)
+                self.b2 = self.create_parameter(
+                    shape=[num_experts, 1, d_model], is_bias=True)
+            self.shared_w1 = self.shared_w2 = None
+            if shared_hidden:
+                self.shared_w1 = self.create_parameter(
+                    shape=[d_model, 2 * shared_hidden],
+                    default_initializer=I.XavierUniform())
+                self.shared_w2 = self.create_parameter(
+                    shape=[shared_hidden, d_model],
+                    default_initializer=I.XavierUniform())
             self._shard_experts()
         self.l_aux = None
 
@@ -212,6 +376,8 @@ class MoELayer(Layer):
             return
         for w, nd in ((self.w1, 3), (self.b1, 3), (self.w2, 3),
                       (self.b2, 3)):
+            if w is None:
+                continue
             spec = [self.ep_axis] + [None] * (nd - 1)
             try:
                 w._value = jax.device_put(
@@ -219,10 +385,61 @@ class MoELayer(Layer):
             except Exception:
                 pass
 
-    def forward(self, x):
+    def _dropless(self, xv, vals, valid=None, counters=None):
+        """The gates without a capacity, on raw values: route (fp32),
+        sorted dispatch, grouped experts, the shared expert beside
+        them.  vals: {name: value} of this layer's leaves."""
+        gate, act = self.gate, self.activation
+        cd, shape = xv.dtype, xv.shape
+        tokens = xv.reshape(-1, shape[-1])
+        with jax.named_scope("moe.route"):
+            if isinstance(gate, SigmoidGate):
+                topi, topw, _ = gate.route(tokens, vals["gate"],
+                                           vals.get("bias"))
+            else:
+                probs = jax.nn.softmax(
+                    tokens.astype(jnp.float32)
+                    @ vals["gate"].astype(jnp.float32), axis=-1)
+                topv, topi = jax.lax.top_k(probs, gate.top_k)
+                topw = topv / jnp.maximum(
+                    jnp.sum(topv, -1, keepdims=True), 1e-9)
+
+        def cast(name):
+            return None if vals.get(name) is None else vals[name].astype(cd)
+        y = dropless_experts(tokens, topi, topw, cast("w1"), cast("w2"),
+                             act, self.first_expert, valid, cast("b1"),
+                             cast("b2"), counters)
+        if vals.get("shared_w1") is not None:
+            with jax.named_scope("moe.shared"):
+                h = _expert_act(tokens @ cast("shared_w1"), "swiglu")
+                y = y + (h @ cast("shared_w2")).astype(jnp.float32)
+        return y.astype(cd).reshape(shape)
+
+    def _dropless_leaves(self):
+        leaves = {"gate": self.gate.weight, "w1": self.w1, "w2": self.w2,
+                  "b1": self.b1, "b2": self.b2,
+                  "bias": getattr(self.gate, "bias", None),
+                  "shared_w1": self.shared_w1, "shared_w2": self.shared_w2}
+        return {k: v for k, v in leaves.items() if v is not None}
+
+    def forward(self, x, valid=None, counters=None):
+        """valid [tokens] bool and counters (a StepCounters): the step
+        programs' handles on a dropless layer — invalid lanes are routed
+        nowhere, the routing is counted."""
         (x,) = to_tensor_args(x)
         gate = self.gate
         gw = gate.weight
+        if self.experts_list is None and not gate.use_capacity:
+            leaves = self._dropless_leaves()
+            names = list(leaves)
+
+            def fn(xv, *ws):
+                y = self._dropless(xv, dict(zip(names, ws)), valid,
+                                   counters)
+                return y, jnp.zeros((), jnp.float32)
+            out, aux = run(fn, x, *leaves.values(), name="moe")
+            self.l_aux = aux
+            return out
         if self.experts_list is None:
             act = self.activation
             params = [gw, self.w1, self.b1, self.w2, self.b2]
@@ -239,26 +456,6 @@ class MoELayer(Layer):
                 logits = tokens.astype(jnp.float32) @ gwv.astype(
                     jnp.float32)
                 gates = jax.nn.softmax(logits, axis=-1)
-                if not gate.use_capacity:
-                    # no-drop top-k: run every expert on every token and
-                    # combine with the [S, E] top-k weights — avoids the
-                    # [S, E, S] dispatch tensor an uncapped capacity
-                    # formulation would need (O(S²E) memory)
-                    topv, topi = jax.lax.top_k(gates, gate.top_k)
-                    normv = topv / jnp.maximum(
-                        jnp.sum(topv, -1, keepdims=True), 1e-9)
-                    cmb = jnp.zeros_like(gates)
-                    for j in range(gate.top_k):
-                        cmb = cmb + normv[:, j, None] * jax.nn.one_hot(
-                            topi[:, j], gates.shape[-1])
-                    h = _expert_act(
-                        jnp.einsum("sm,emh->esh", tokens, w1) + b1,
-                        act)
-                    expert_out = jnp.einsum("esh,ehm->esm", h, w2) + b2
-                    y = jnp.einsum("se,esm->sm",
-                                   cmb.astype(xv.dtype), expert_out)
-                    aux = jnp.zeros((), jnp.float32)
-                    return y.reshape(shape), aux
                 cap = gate.capacity(tokens.shape[0])
                 dispatch, combine, aux = _topk_dispatch(
                     gates, gate.top_k, cap)

@@ -16,7 +16,11 @@ XLA program):
   * ONE device page pool `[num_pages, layers, kv_heads, page_size,
     head_dim]` per K and V (models.llama.init_paged_cache) backs every
     slot, addressed through a per-slot page table `[B, pages_per_slot]`
-    carried through the scan; page 0 is a reserved null page;
+    carried through the scan; page 0 is a reserved null page.  What a
+    token's row IS the model says (`kv_row_spec`): a latent-attention
+    model keeps one pool of `[num_pages, layers, page_size, width]`
+    rows, and page copy, prefix sharing and hand-off move its pages by
+    the same programs (they index pages, whatever a page holds);
   * attention gathers by page table INSIDE the kernel
     (ops.paged_attention: a Pallas kernel on TPU that walks each
     slot's live pages, a `take`-gather jnp twin elsewhere —
@@ -465,7 +469,9 @@ class ContinuousBatcher:
             self._cache = model.init_paged_cache(self.num_pages,
                                                  self.page_size,
                                                  kv_dtype)
-            self._kv_dtype = str(np.dtype(self._cache["k"].dtype))
+            spec = model.kv_row_spec(kv_dtype)
+            self._kv_dtype = str(np.dtype(spec["dtype"]))
+            self._pages_walked = spec["pages_walked"]
             self._page_table = jnp.zeros((self.B, self.pages_per_slot),
                                          jnp.int32)
         else:
@@ -503,6 +509,14 @@ class ContinuousBatcher:
         # kernel walks
         self._kv_pages_live = 0
         self._kv_pages_walked = 0
+        # what the MODEL counts a step on the device (a dropless expert
+        # layer's routing, models.llama.step_counter_names): summed in
+        # the scan, read with the chunk's tokens, never under
+        # speculation (its decode program is a different scan)
+        names = getattr(model, "step_counter_names", tuple)()
+        self._counter_names = () if self.spec_k or kv_layout != "paged" \
+            else tuple(names)
+        self._model_counts = dict.fromkeys(self._counter_names, 0)
         self._chunk_event = None        # serve.chunk's fields
         # per-request latency windows (bounded, same discipline as the
         # chunk times) + per-SLO-class deadline attainment — host
@@ -594,18 +608,17 @@ class ContinuousBatcher:
         allocation (the bench's int8-vs-bf16 sizing comparison must
         not burn two throwaway pools of HBM).  Matches
         kv_cache_bytes() of a real instance (test-pinned)."""
-        from ..models.llama import _resolve_kv_dtype
-        cfg = model.config
         B = int(max_batch_size)
         prefill_chunk = max(1, min(int(prefill_chunk), int(max_len)))
         ps, p_slot, n_pages = cls._paged_geometry(
             B, int(max_len), prefill_chunk, page_size, num_pages)
-        dt, quant = _resolve_kv_dtype(cfg, kv_dtype)
-        pool = 2 * n_pages * ps * cfg.num_hidden_layers \
-            * cfg.num_key_value_heads * cfg.head_dim \
-            * jnp.dtype(dt).itemsize
-        scales = (2 * n_pages * cfg.num_hidden_layers
-                  * cfg.num_key_value_heads * 4) if quant else 0
+        # the row is the MODEL's to state (K and V heads, or one latent)
+        spec = model.kv_row_spec(kv_dtype)
+        layers = model.config.num_hidden_layers
+        rows = sum(int(np.prod(row)) for row in spec["pools"].values())
+        pool = n_pages * ps * layers * rows \
+            * jnp.dtype(spec["dtype"]).itemsize
+        scales = len(spec["pools"]) * n_pages * layers * spec["scales"] * 4
         table = B * p_slot * 4
         return pool + scales + table
 
@@ -1224,6 +1237,7 @@ class ContinuousBatcher:
             # is ragged); a free slot walks its one or two pages
             "kv_pages_live": self._kv_pages_live,
             "kv_pages_walked": self._kv_pages_walked,
+            **self._model_counts,
             "compiled_programs": self.compiled_programs,
             "kv_layout": self.kv_layout,
             "kv_bytes": self.kv_cache_bytes(),
@@ -1889,6 +1903,7 @@ class ContinuousBatcher:
         spec = self.spec_k > 0
         draft = self._draft
         draft_names = self._draft_names
+        counted = bool(self._counter_names)
         from ..jit import _swapped_state
 
         def build():
@@ -1914,7 +1929,16 @@ class ContinuousBatcher:
                     prefilling,
                     jnp.minimum(C, plen - pos),
                     jnp.where(done, 0, 1)).astype(jnp.int32)
-                if paged:
+                counts = None
+                if counted:
+                    # the expert layers route the lanes the step keeps
+                    # and count their routing as they are traced
+                    from ..incubate.distributed.models.moe import \
+                        StepCounters
+                    counts = StepCounters(lanes[None] < n_valid[:, None])
+                    lg, cache = model.forward_cached_paged(
+                        x, cache, page_table, pos, counts)
+                elif paged:
                     lg, cache = model.forward_cached_paged(
                         x, cache, page_table, pos)
                 else:
@@ -1944,6 +1968,9 @@ class ContinuousBatcher:
                      & (n_valid > 0)).astype(jnp.int32))
                 carry = (cache, dcache, page_table, tok, pos, mode,
                          plen, prompts, done)
+                if counted:
+                    return carry, (out_tok, n_pref, n_dec,
+                                   counts.vector())
                 return carry, (out_tok, n_pref, n_dec)
 
             def run_scan(cache, dcache, page_table, tok, pos, mode,
@@ -1952,9 +1979,16 @@ class ContinuousBatcher:
                     return step_core(carry)
                 carry = (cache, dcache, page_table, tok, pos, mode,
                          plen, prompts, done)
-                carry, (toks, n_pref, n_dec) = jax.lax.scan(
-                    body, carry, None, length=K)
-                return carry, toks.T, jnp.sum(n_pref), jnp.sum(n_dec)
+                carry, ys = jax.lax.scan(body, carry, None, length=K)
+                toks, n_pref, n_dec = ys[:3]
+                counts = ()
+                if counted:
+                    # a counting model's program has one more output
+                    from ..incubate.distributed.models.moe import \
+                        StepCounters
+                    counts = (StepCounters.merge(ys[3]),)
+                return (carry, toks.T, jnp.sum(n_pref),
+                        jnp.sum(n_dec)) + counts
 
             if spec:
                 def serve_step(param_vals, draft_vals, cache, dcache,
@@ -1983,13 +2017,13 @@ class ContinuousBatcher:
             def serve_step(param_vals, cache, page_table, tok, pos,
                            mode, plen, prompts, done):
                 with _swapped_state(model, names, list(param_vals)):
-                    carry, toks, n_pref, n_dec = run_scan(
+                    carry, toks, n_pref, n_dec, *counts = run_scan(
                         cache, None, page_table, tok, pos, mode, plen,
                         prompts, done)
                 (cache, _, page_table, tok, pos, mode, plen, prompts,
                  done) = carry
                 return (cache, page_table, tok, pos, mode, plen,
-                        prompts, done, toks, n_pref, n_dec)
+                        prompts, done, toks, n_pref, n_dec, *counts)
             # donate every carry buffer: the KV pool dominates — a
             # non-donated chunk pays a pool-sized HBM copy per call
             return jax.jit(serve_step,
@@ -2194,15 +2228,17 @@ class ContinuousBatcher:
     def _kv_page_counts(self, width: int, steps: int):
         """(live, walked): the pages one paged-attention call covers,
         summed over the `steps` scan steps of the chunk about to be
-        dispatched — `live` of the occupied slots, `walked` of all B,
-        which is what the kernel walks (ops.pallas.paged_attention.
-        pages_walked, the kernel's own bound).  Replayed on the host
-        from `_pos_host` and the slots' prompts as step_core advances
+        dispatched — `live`: up to the frontier of each occupied slot;
+        `walked`: what the attention walks of all B (the bound its own
+        module states, handed over in the model's kv_row_spec: the K/V
+        kernel's IS the frontier, the latent XLA walk's is the deepest
+        slot's block).  Replayed on the host from `_pos_host` and the slots' prompts as step_core advances
         them, no device read; a speculative decode step counts the one
         token it is sure to advance.  (0, 0) for the dense layout."""
         if self.kv_layout != "paged":
             return 0, 0
-        from ..ops.pallas.paged_attention import pages_walked
+        from ..ops.pallas.paged_attention import pages_walked as to_frontier
+        pages_walked = self._pages_walked
         pos = self._pos_host.astype(np.int64)
         done = self._done_host.copy()
         mode = self._mode_host.copy()
@@ -2211,10 +2247,13 @@ class ContinuousBatcher:
                          for r in self._slots], np.int64)
         live = walked = 0
         for _ in range(steps):
-            n = pages_walked(pos, width, self.page_size,
-                             self.pages_per_slot)
+            n = held = pages_walked(pos, width, self.page_size,
+                                    self.pages_per_slot)
+            if pages_walked is not to_frontier:
+                held = to_frontier(pos, width, self.page_size,
+                                   self.pages_per_slot)
             walked += int(n.sum())
-            live += int(n[occupied].sum())
+            live += int(held[occupied].sum())
             filling = mode & ~done
             pos += np.where(filling, np.minimum(width, plen - pos), ~done)
             mode &= ~(filling & (pos >= plen))
@@ -2233,6 +2272,7 @@ class ContinuousBatcher:
         kind = "admit" if mixed else "decode"
         ck = self._chunk_no
         n_emit = n_acc = None
+        counted = []        # a counting model's one more output
         # the chunk's program by its width and scan length (_spec_w is
         # 1 without speculation)
         width, steps = (self.prefill_chunk, self.admit_steps) if mixed \
@@ -2275,7 +2315,7 @@ class ContinuousBatcher:
                     else:
                         (self._cache, page_table, self._tok, self._pos,
                          self._mode, self._plen, self._prompts,
-                         self._done, toks, n_pref, n_dec) = fn(
+                         self._done, toks, n_pref, n_dec, *counted) = fn(
                             self._param_vals(), *self._carry_args())
         except fault.FaultError:
             self._chunk_retries += 1
@@ -2308,13 +2348,24 @@ class ContinuousBatcher:
         # separately would pay it six times per boundary
         with self._phase("device_wait", kind=kind, chunk=ck):
             (toks, mode_h, done_h, pos_h, n_pref, n_dec, n_emit,
-             n_acc) = jax.device_get(
+             n_acc, counted) = jax.device_get(
                 (toks, self._mode, self._done, self._pos, n_pref, n_dec,
-                 n_emit, n_acc))
-        with self._phase("harvest", chunk=ck):
+                 n_emit, n_acc, counted))
+        counts = dict(zip(self._counter_names,
+                          (int(v) for vec in counted for v in vec)))
+        with self._phase("harvest", chunk=ck, **counts):
+            self._count_model(counts)
             self._harvest(kind, t0, np.asarray(toks), mode_h, done_h,
                           pos_h, int(n_pref), int(n_dec), n_emit, n_acc)
         return True
+
+    def _count_model(self, counts):
+        """A chunk's model counts into stats(): sums, but a `_max`
+        count keeps the largest chunk's."""
+        for name, v in counts.items():
+            old = self._model_counts[name]
+            self._model_counts[name] = max(old, v) \
+                if name.endswith("_max") else old + v
 
     def _harvest(self, kind, t0, toks, mode_h, done_h, pos_h, n_pref,
                  n_dec, n_emit, n_acc):

@@ -94,10 +94,46 @@ class LlamaConfig:
     moe_top_k: int = 2
     moe_gate: str = "gshard"
     moe_aux_weight: float = 0.01
+    # moe_gate "sigmoid" (DeepSeek-V3 style): fp32 sigmoid scores over
+    # `moe_router_width` experts, of which this model HOLDS
+    # moe_num_experts starting at moe_first_expert (its share of an
+    # expert-parallel deployment; 0 width = all held), top-k of score +
+    # bias, weights moe_routed_scaling * s / sum of the chosen s, beside
+    # moe_shared_experts always-on experts; dropless.  Experts are
+    # moe_intermediate_size wide (0 = intermediate_size); the first
+    # first_k_dense_replace layers keep the dense MLP.
+    moe_router_width: int = 0
+    moe_first_expert: int = 0
+    moe_intermediate_size: int = 0
+    moe_shared_experts: int = 0
+    moe_routed_scaling: float = 1.0
+    moe_router_bias: bool = False
+    first_k_dense_replace: int = 0
+    # latent attention (MLA, DeepSeek-V2): kv_lora_rank > 0 swaps the
+    # attention class.  The cache holds ONE row of kv_lora_rank +
+    # qk_rope_head_dim a token a layer; heads are qk_nope + qk_rope wide
+    # on the query side and v_head_dim on the value side.  use_qk_norm:
+    # a learned rmsnorm over each query head before the rotary (the key
+    # side's norm is the latent's own).
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    use_qk_norm: bool = False
+    # a config's rope_scaling group ({"type": "deepseek_yarn", ...})
+    rope_scaling: dict | None = None
 
     @property
     def head_dim(self):
         return self.hidden_size // self.num_attention_heads
+
+    @property
+    def latent_attention(self):
+        return self.kv_lora_rank > 0
+
+    def expert_layer(self, layer_idx: int) -> bool:
+        return self.moe_num_experts > 0 \
+            and layer_idx >= self.first_k_dense_replace
 
     @property
     def compute_dtype(self):
@@ -347,6 +383,132 @@ class LlamaAttention(nn.Layer):
         return run(_fn, attn, self.o_proj, name="attn_out_proj")
 
 
+class LlamaMLAttention(nn.Layer):
+    """Multi-head latent attention (DeepSeek-V2).  With x the block's
+    normed input, per head h of nh:
+
+      q = x Wq -> [q_nope | q_rope]   (q_norm over the whole head first,
+                                       where use_qk_norm)
+      [c | k_r] = x Wkv_a;  c = rmsnorm(c; kv_norm);  k_r, q_rope rotated
+      [k_nope_h | v_h] = c Wkv_b[h]
+      score_h = (q_nope_h . k_nope_h + q_rope_h . k_r) * scale
+
+    scale = (nope + rope)^-1/2 * yarn_mscale(mscale_all_dim)^2.  A
+    token's cached row is [c | k_r], c after its norm and k_r after its
+    rotation.  `forward` (a whole sequence) expands k and v per head;
+    the paged path ABSORBS Wkv_b into the query and the output
+    (qt_h = q_nope_h Wuk[h]^T, u_h = sum p c, o_h = u_h Wuv[h]): the
+    same numbers, and per (query, cached row) pair nh * (2R + r) products
+    where expanding a cached row would cost R * nh * (nope + v) a step.
+    Rotary layout: rotate-half (a column permutation of Wq / Wkv_a away
+    from the interleaved one)."""
+
+    def __init__(self, config: LlamaConfig):
+        super().__init__(dtype=config.dtype)
+        from ..framework.tensor import Parameter
+        self.config = config
+        h, nh = config.hidden_size, config.num_attention_heads
+        R, nope = config.kv_lora_rank, config.qk_nope_head_dim
+        r, vd = config.qk_rope_head_dim, config.v_head_dim
+        pd = config.param_dtype or config.dtype
+        self.q_proj = Parameter(_init_weight([h, nh * (nope + r)],
+                                             h ** -0.5, pd))
+        self.kv_a_proj = Parameter(_init_weight([h, R + r], h ** -0.5, pd))
+        self.kv_norm = Parameter(jnp.ones([R], config.storage_dtype))
+        if config.use_qk_norm:
+            self.q_norm = Parameter(jnp.ones([nope + r],
+                                             config.storage_dtype))
+        self.kv_b_proj = Parameter(_init_weight([R, nh * (nope + vd)],
+                                                R ** -0.5, pd))
+        self.o_proj = Parameter(_init_weight([nh * vd, h],
+                                             (nh * vd) ** -0.5, pd))
+        m = 1.0
+        if config.rope_scaling:
+            m = tpu_ops.yarn_mscale(
+                config.rope_scaling["factor"],
+                config.rope_scaling.get("mscale_all_dim", 0.0))
+        self.scale = (nope + r) ** -0.5 * m * m
+
+    def _project(self, x, cos, sin):
+        """(q_nope [b,s,nh,nope], q_rope [b,s,nh,r] rotated, latent rows
+        [b,s,R+r]: c normed | k_r rotated) of raw jax x [b,s,h]."""
+        cfg = self.config
+        b, s, _ = x.shape
+        nh, nope, r = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                       cfg.qk_rope_head_dim)
+        R, eps, cd = cfg.kv_lora_rank, cfg.rms_norm_eps, x.dtype
+        q = (x @ self.q_proj.value.astype(cd)).reshape(b, s, nh, nope + r)
+        if cfg.use_qk_norm:
+            q = tpu_ops.xla_rms_norm(q, self.q_norm.value.astype(cd), eps)
+        kv = x @ self.kv_a_proj.value.astype(cd)
+        c = tpu_ops.xla_rms_norm(kv[..., :R],
+                                 self.kv_norm.value.astype(cd), eps)
+        q_rope, k_r = tpu_ops.xla_apply_rope(
+            q[..., nope:], kv[..., None, R:], cos, sin)
+        return q[..., :nope], q_rope, jnp.concatenate(
+            [c, k_r[:, :, 0]], axis=-1)
+
+    def _wkv_b(self, dtype):
+        cfg = self.config
+        return self.kv_b_proj.value.astype(dtype).reshape(
+            cfg.kv_lora_rank, cfg.num_attention_heads,
+            cfg.qk_nope_head_dim + cfg.v_head_dim)
+
+    def _expanded(self, x, cos, sin):
+        cfg = self.config
+        b, s, _ = x.shape
+        nh, nope, R = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
+                       cfg.kv_lora_rank)
+        q_nope, q_rope, rows = self._project(x, cos, sin)
+        kv = jnp.einsum("bsc,chd->bshd", rows[..., :R],
+                        self._wkv_b(x.dtype))
+        k_r = jnp.broadcast_to(rows[:, :, None, R:],
+                               (b, s, nh, rows.shape[-1] - R))
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        k = jnp.concatenate([kv[..., :nope], k_r], axis=-1)
+        # unequal q and v head sizes: the flash kernel takes one size
+        out = tpu_ops.xla_attention(q, k, kv[..., nope:], causal=True,
+                                    scale=self.scale)
+        return out.reshape(b, s, -1) @ self.o_proj.value.astype(x.dtype)
+
+    def forward(self, x, cos, sin):
+        (x,) = to_tensor_args(x)
+        cos_a = cos.value if isinstance(cos, Tensor) else cos
+        sin_a = sin.value if isinstance(sin, Tensor) else sin
+        names = ["q_proj", "kv_a_proj", "kv_norm", "kv_b_proj", "o_proj"] \
+            + (["q_norm"] if self.config.use_qk_norm else [])
+        from ..jit import _swapped_state
+
+        def _fn(v, *ws):
+            with _swapped_state(self, names, list(ws)):
+                return self._expanded(v, cos_a, sin_a)
+        return run(_fn, x, *[getattr(self, n) for n in names],
+                   name="mla_attention")
+
+    def forward_cached_paged(self, x, cos, sin, cache, page_table, pos,
+                             layer):
+        """Paged decode / chunked-prefill attention on raw jax values:
+        this step's rows go into the latent pool, every lane attends in
+        latent space (absorbed).  Returns (out, cache)."""
+        cfg = self.config
+        b, s, _ = x.shape
+        nope = cfg.qk_nope_head_dim
+        with jax.named_scope("mla.project"):
+            q_nope, q_rope, rows = self._project(x, cos, sin)
+            w = self._wkv_b(x.dtype)
+            q_lat = jnp.einsum("bshd,chd->bshc", q_nope, w[..., :nope])
+        with jax.named_scope("mla.cache_write"):
+            pool = tpu_ops.latent_kv_update(cache["kv"], page_table, pos,
+                                            rows, layer)
+        with jax.named_scope("mla.attend"):
+            u = tpu_ops.latent_paged_attention(
+                q_lat, q_rope, pool, page_table, pos, layer, self.scale)
+        with jax.named_scope("mla.project"):
+            out = jnp.einsum("bshc,chd->bshd", u, w[..., nope:])
+            out = out.reshape(b, s, -1) @ self.o_proj.value.astype(x.dtype)
+        return out, dict(cache, kv=pool)
+
+
 class LlamaMLP(nn.Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__(dtype=config.dtype)
@@ -377,15 +539,32 @@ class LlamaDecoderLayer(nn.Layer):
         self._recompute = config.recompute and (
             config.recompute_layers is None
             or layer_idx < config.recompute_layers)
-        self.self_attn = LlamaAttention(config)
-        if config.moe_num_experts > 0:
+        # the block's two halves are chosen from the config, per layer
+        self.self_attn = LlamaMLAttention(config) \
+            if config.latent_attention else LlamaAttention(config)
+        self.expert_layer = config.expert_layer(layer_idx)
+        if self.expert_layer:
             from ..incubate.distributed.models.moe import MoELayer
+            share = {}
+            if config.moe_gate == "sigmoid":
+                share = dict(
+                    dtype=config.param_dtype or config.dtype,
+                    experts_held=(config.moe_first_expert,
+                                  config.moe_num_experts),
+                    router_width=config.moe_router_width
+                    or config.moe_num_experts,
+                    routed_scaling=config.moe_routed_scaling,
+                    router_bias=config.moe_router_bias,
+                    shared_hidden=config.moe_shared_experts
+                    * (config.moe_intermediate_size
+                       or config.intermediate_size))
             self.mlp = MoELayer(
                 d_model=config.hidden_size,
-                d_hidden=config.intermediate_size,
+                d_hidden=config.moe_intermediate_size
+                or config.intermediate_size,
                 num_experts=config.moe_num_experts,
                 gate=config.moe_gate, top_k=config.moe_top_k,
-                activation="swiglu")
+                activation="swiglu", **share)
         else:
             self.mlp = LlamaMLP(config)
         self.input_layernorm = LlamaRMSNorm(config)
@@ -472,11 +651,13 @@ class LlamaDecoderLayer(nn.Layer):
             x = x + self.mlp(h)
         return run(constrain_activation, x, name="constrain_resid")
 
-    def _block_cached(self, x, cos, sin, attend):
+    def _block_cached(self, x, cos, sin, attend, counters=None):
         """Shared decode-block skeleton for both KV layouts: norm →
         attend(h) → residual → norm → MLP → residual.  `attend(h)`
         returns (attn_out, new_kv_state) — the ONLY point where the
-        dense ring buffer and the paged pool differ."""
+        dense ring buffer and the paged pool differ.  `counters`: the
+        step's moe StepCounters (its `valid` lanes are the only ones a
+        dropless expert layer routes)."""
         cfg = self.config
         ln1 = self.input_layernorm.weight.value
         ln2 = self.post_attention_layernorm.weight.value
@@ -484,7 +665,10 @@ class LlamaDecoderLayer(nn.Layer):
         attn, kv_state = attend(h)
         x = x + attn
         h = tpu_ops.rms_norm(x, ln2.astype(x.dtype), cfg.rms_norm_eps)
-        if cfg.moe_num_experts > 0:
+        if self.expert_layer and counters is not None:
+            x = x + self.mlp(h, valid=counters.valid,
+                             counters=counters).value
+        elif self.expert_layer:
             # MoE decode: route through the expert layer (dispatch
             # handles raw jax values; aux loss is irrelevant at decode)
             x = x + self.mlp(h).value
@@ -506,13 +690,13 @@ class LlamaDecoderLayer(nn.Layer):
         return x, k_cache, v_cache
 
     def forward_cached_paged(self, x, cos, sin, cache, page_table, pos,
-                             layer):
+                             layer, counters=None):
         """Raw-jax paged decode block (see
         LlamaAttention.forward_cached_paged)."""
         def attend(h):
             return self.self_attn.forward_cached_paged(
                 h, cos, sin, cache, page_table, pos, layer)
-        return self._block_cached(x, cos, sin, attend)
+        return self._block_cached(x, cos, sin, attend, counters)
 
 
 class LlamaModel(nn.Layer):
@@ -533,8 +717,7 @@ class LlamaModel(nn.Layer):
         cfg = self.config
         (input_ids,) = to_tensor_args(input_ids)
         seq_len = input_ids.shape[1]
-        cos, sin = tpu_ops.rope_cos_sin(seq_len, cfg.head_dim,
-                                        cfg.rope_theta, jnp.float32)
+        cos, sin = self._rope_tables(seq_len)
         from ..parallel.sharded_trainer import constrain_activation
         # named_scope threads model-structure names into the HLO op
         # metadata and device traces (ISSUE 12): the cost ledger's
@@ -551,10 +734,52 @@ class LlamaModel(nn.Layer):
         with jax.named_scope("llama.norm"):
             return self.norm(x)
 
+    def _rope_tables(self, seq_len, position_ids=None):
+        """cos/sin over the rotated dims: the whole head, or an MLA
+        head's rope part under the config's rope_scaling."""
+        cfg = self.config
+        if cfg.latent_attention:
+            return tpu_ops.rope_cos_sin(
+                seq_len, cfg.qk_rope_head_dim, cfg.rope_theta, jnp.float32,
+                position_ids=position_ids, scaling=cfg.rope_scaling)
+        return tpu_ops.rope_cos_sin(seq_len, cfg.head_dim, cfg.rope_theta,
+                                    jnp.float32, position_ids=position_ids)
+
+    def kv_row_spec(self, kv_dtype=None):
+        """What ONE token holds in ONE layer of the paged pool, for
+        whoever sizes or names the pool without reading head counts:
+        {"pools": {name: row shape}, "dtype", "scales": per-page scale
+        entries a pool carries (0 unless int8), "pages_walked": the
+        bound of the attention's walk over a slot's table, f(pos, q_len,
+        page_size, pages_per_slot)}.  A page of a pool is
+        [layers, *row[:-1], page_size, row[-1]]."""
+        cfg = self.config
+        dt, quant = _resolve_kv_dtype(cfg, kv_dtype)
+        if cfg.latent_attention:
+            if quant:
+                raise ValueError(
+                    "int8 KV is not implemented for latent (MLA) rows: a "
+                    "row is [normed latent | rotated key] and one scale a "
+                    "page would quantize the two against each other; use "
+                    "kv_dtype auto|bfloat16|float32")
+            row = (cfg.kv_lora_rank + cfg.qk_rope_head_dim,)
+            return {"pools": {"kv": row}, "dtype": dt, "scales": 0,
+                    "pages_walked": tpu_ops.latent_pages_walked}
+        from ..ops.pallas.paged_attention import pages_walked
+        row = (cfg.num_key_value_heads, cfg.head_dim)
+        return {"pools": {"k": row, "v": row}, "dtype": dt,
+                "scales": cfg.num_key_value_heads if quant else 0,
+                "pages_walked": pages_walked}
+
     def init_cache(self, batch: int, max_len: int):
         """Per-layer KV ring buffers [b, max_len, n_kv, hd] in the
         compute dtype (static shapes — XLA requirement)."""
         cfg = self.config
+        if cfg.latent_attention:
+            raise NotImplementedError(
+                "latent (MLA) attention serves through the paged pool "
+                "only (init_paged_cache / forward_cached_paged); the dense "
+                "ring-buffer layout has no latent form")
         shape = (batch, max_len, cfg.num_key_value_heads, cfg.head_dim)
         dt = cfg.compute_dtype
         return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
@@ -573,6 +798,12 @@ class LlamaModel(nn.Layer):
         per-page per-head fp32 scales alongside the pool)."""
         cfg = self.config
         dt, quant = _resolve_kv_dtype(cfg, kv_dtype)
+        if cfg.latent_attention:
+            # ONE pool of latent rows [pages, layers, page_size, R + r]
+            # (kv_row_spec refuses int8 in so many words)
+            (width,) = self.kv_row_spec(kv_dtype)["pools"]["kv"]
+            return {"kv": jnp.zeros((num_pages, len(self.layers),
+                                     page_size, width), dt)}
         shape = (num_pages, len(self.layers), cfg.num_key_value_heads,
                  page_size, cfg.head_dim)
         cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -584,24 +815,25 @@ class LlamaModel(nn.Layer):
             cache["v_scale"] = jnp.ones(sshape, jnp.float32)
         return cache
 
-    def forward_cached_paged(self, input_ids, cache, page_table, pos):
+    def forward_cached_paged(self, input_ids, cache, page_table, pos,
+                             counters=None):
         """Paged twin of forward_cached: input_ids [b, s_new]; cache:
         init_paged_cache pytree; page_table [b, pages_per_slot] int32;
-        pos [b] int32 per-slot depths.  Returns (hidden, new_cache)."""
+        pos [b] int32 per-slot depths; counters: a moe StepCounters
+        for a model with dropless expert layers (step_counter_names).
+        Returns (hidden, new_cache)."""
         cfg = self.config
         s = input_ids.shape[1]
         positions = jnp.asarray(pos, jnp.int32)[..., None] \
             + jnp.arange(s, dtype=jnp.int32)
-        cos, sin = tpu_ops.rope_cos_sin(s, cfg.head_dim, cfg.rope_theta,
-                                        jnp.float32,
-                                        position_ids=positions)
+        cos, sin = self._rope_tables(s, positions)
         x = jnp.take(self.embed_tokens.value,
                      input_ids.astype(jnp.int32),
                      axis=0).astype(cfg.compute_dtype)
         for li, layer in enumerate(self.layers):
             with jax.named_scope(f"llama.layer{li}"):
                 x, cache = layer.forward_cached_paged(
-                    x, cos, sin, cache, page_table, pos, li)
+                    x, cos, sin, cache, page_table, pos, li, counters)
         w = self.norm.weight.value
         with jax.named_scope("llama.norm"):
             return tpu_ops.rms_norm(x, w.astype(x.dtype),
@@ -672,6 +904,19 @@ class LlamaForCausalLM(nn.Layer):
         return self.llama.init_paged_cache(num_pages, page_size,
                                            kv_dtype)
 
+    def kv_row_spec(self, kv_dtype=None):
+        return self.llama.kv_row_spec(kv_dtype)
+
+    def step_counter_names(self):
+        """Names of the int32 counts a serve step of this model
+        accumulates on the device (incubate...moe.StepCounters): those
+        of its dropless expert layers, () without any."""
+        cfg = self.config
+        if cfg.moe_num_experts > 0 and cfg.moe_gate in ("naive", "sigmoid"):
+            from ..incubate.distributed.models.moe import COUNTER_NAMES
+            return COUNTER_NAMES
+        return ()
+
     def _lm_logits(self, x):
         """Decode-path lm head: tied embeddings stay unquantized (the
         embedding is gathered elsewhere); an untied head rides the
@@ -681,10 +926,12 @@ class LlamaForCausalLM(nn.Layer):
             return x @ w.T.astype(x.dtype)
         return _wo_mm(self, "lm_head", x)
 
-    def forward_cached_paged(self, input_ids, cache, page_table, pos):
+    def forward_cached_paged(self, input_ids, cache, page_table, pos,
+                             counters=None):
         """Paged twin of forward_cached: returns (logits, new_cache)."""
         x, cache = self.llama.forward_cached_paged(input_ids, cache,
-                                                   page_table, pos)
+                                                   page_table, pos,
+                                                   counters)
         return self._lm_logits(x), cache
 
     def forward_cached(self, input_ids, cache, pos):

@@ -23,6 +23,9 @@ __all__ = ["attention", "cached_attention", "rms_norm", "layer_norm",
            "fused_add_rms_norm", "xla_fused_add_rms_norm",
            "rope", "apply_rope",
            "paged_attention", "xla_paged_attention", "paged_kv_update",
+           "latent_kv_update", "latent_paged_attention",
+           "latent_pages_walked", "yarn_inv_freq", "yarn_mscale",
+           "xla_apply_rope",
            "swiglu", "get_attention_backend", "set_attention_backend",
            "kernel_mesh_scope",
            "gqa_scores", "gqa_weighted_v",
@@ -359,6 +362,103 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                                layer, k_scale, v_scale, scale)
 
 
+# ---------------------------------------------------------------------------
+# latent rows in the paged pool (MLA): ONE pool, no V pool
+# ---------------------------------------------------------------------------
+# A token's row in a layer is [c | k_r]: the normed kv latent and the
+# rotated shared key, `rank + rope` wide.  The pool is page-major like
+# the K/V pools ([P, L, ps, W]; page 0 the null page), so the batcher's
+# page copy / export / import programs serve it unchanged.
+LATENT_BLOCK_ROWS = 512     # key rows one step of the attention walk takes
+
+
+def latent_kv_update(pool, page_table, pos, rows, layer):
+    """Write one step's latent rows: pool [P, L, ps, W]; page_table
+    [B, P_slot]; pos [B]; rows [B, C, W]; layer a python int.  Lane c of
+    slot b lands at logical row pos[b] + c of its table — ONE scatter of
+    B*C rows straight into the carried pool (no window of pages is read
+    back, nothing pool-sized is copied).  Lanes of free slots meet in
+    the null page, whose content is junk by contract."""
+    ps = pool.shape[2]
+    B, C = rows.shape[:2]
+    at = jnp.asarray(pos, jnp.int32)[:, None] \
+        + jnp.arange(C, dtype=jnp.int32)[None]                   # [B, C]
+    slot_page = jnp.clip(at // ps, 0, page_table.shape[1] - 1)
+    page = jnp.take_along_axis(page_table, slot_page, axis=1)    # [B, C]
+    return pool.at[page, layer, at % ps].set(rows.astype(pool.dtype))
+
+
+def latent_pages_walked(pos, q_len, page_size, pages_per_slot):
+    """Pages of ITS table each slot's attention reads in one
+    latent_paged_attention call (numpy, on the host: the batcher's
+    `kv_pages_walked`): every slot walks whole blocks of
+    LATENT_BLOCK_ROWS key rows up to the DEEPEST slot's frontier — the
+    XLA walk cannot stop early for a shallow slot, which is what a
+    kernel with one grid step a slot would add."""
+    import numpy as np
+    pb = max(1, LATENT_BLOCK_ROWS // page_size)
+    frontier = int(np.max(pos)) + q_len - 1
+    blocks = min(frontier // (pb * page_size) + 1, -(-pages_per_slot // pb))
+    return np.full(np.shape(pos), min(blocks * pb, pages_per_slot), np.int64)
+
+
+def latent_paged_attention(q_lat, q_rope, pool, page_table, pos, layer,
+                           scale):
+    """Absorbed MLA attention against the latent pool.
+
+    q_lat [B, C, h, R]: each head's no-rope query carried into latent
+    space (q_nope Wuk^T); q_rope [B, C, h, r] rotated; pool
+    [P, L, ps, R + r]; query lane c of slot b sees rows j <= pos[b] + c.
+    Returns u [B, C, h, R] fp32-accumulated in q_lat.dtype: the
+    probability-weighted sum of the latents, which the caller carries
+    back out through Wuv.  All heads share a row, so a slot's C*h
+    queries are one [C*h, R + r] tile against its rows.
+
+    XLA ops: a walk over blocks of LATENT_BLOCK_ROWS rows (gathered by
+    page table) with an fp32 running softmax, as many blocks as the
+    deepest slot needs.  Scores of a whole 4 k-row table at once would
+    be [B, C*h, rows] fp32: 2 GB at 64 slots x 32 lanes."""
+    B, C, h, R = q_lat.shape
+    P, L, ps, W = pool.shape
+    P_slot = page_table.shape[1]
+    pb = max(1, min(P_slot, LATENT_BLOCK_ROWS // ps))
+    n_blocks = -(-P_slot // pb)
+    rows_blk = pb * ps
+    pos = jnp.asarray(pos, jnp.int32)
+    q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype)],
+                        axis=-1).reshape(B, C * h, W)
+    # the query lane of each of the C*h rows of the tile
+    q_pos = pos[:, None] + jnp.repeat(jnp.arange(C, dtype=jnp.int32), h)[None]
+    # table padded to whole blocks with the null page
+    table = jnp.pad(page_table, ((0, 0), (0, n_blocks * pb - P_slot)))
+    need = jnp.minimum((jnp.max(pos) + C - 1) // rows_blk + 1, n_blocks)
+
+    def block(i, carry):
+        m, l, acc = carry
+        ids = jax.lax.dynamic_slice_in_dim(table, i * pb, pb, axis=1)
+        rows = pool[ids, layer].reshape(B, rows_blk, W)
+        s = jnp.einsum("bqw,bkw->bqk", q, rows.astype(q.dtype),
+                       preferred_element_type=jnp.float32) * scale
+        k_pos = i * rows_blk + jnp.arange(rows_blk, dtype=jnp.int32)
+        s = jnp.where(k_pos[None, None, :] <= q_pos[:, :, None], s, -1e30)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        fix = jnp.exp(m - m_new)
+        l = l * fix + jnp.sum(p, axis=-1)
+        acc = acc * fix[..., None] + jnp.einsum(
+            "bqk,bkr->bqr", p.astype(q.dtype),
+            rows[..., :R].astype(q.dtype),
+            preferred_element_type=jnp.float32)
+        return m_new, l, acc
+
+    m0 = jnp.full((B, C * h), -1e30, jnp.float32)
+    l0 = jnp.zeros((B, C * h), jnp.float32)
+    acc0 = jnp.zeros((B, C * h, R), jnp.float32)
+    # block 0 always holds row 0 <= every query's position, so l > 0
+    _, l, acc = jax.lax.fori_loop(0, need, block, (m0, l0, acc0))
+    return (acc / l[..., None]).astype(q_lat.dtype).reshape(B, C, h, R)
+
+
 def attention(q, k, v, mask=None, causal=False, scale=None, dropout_p=0.0):
     """Flash kernel or XLA, chosen from the backend setting and the
     shapes (flash_attention.supports) — never from an exception: what
@@ -448,14 +548,59 @@ def layer_norm(x, weight=None, bias=None, epsilon=1e-5):
 # ---------------------------------------------------------------------------
 # rotary position embedding
 # ---------------------------------------------------------------------------
+def yarn_inv_freq(dim, base, factor, original_max_position,
+                  beta_fast=32, beta_slow=1):
+    """`deepseek_yarn` rotary frequencies (DeepSeek-V2's YaRN): each of
+    the dim/2 frequencies 1/base^(2i/dim) is blended with itself over
+    `factor` by a linear ramp between the correction dims of `beta_fast`
+    and `beta_slow` rotations over `original_max_position` positions —
+    fast dims keep their frequency, slow ones are interpolated."""
+    def correction_dim(rotations):
+        return dim * math.log(original_max_position
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+    low = max(math.floor(correction_dim(beta_fast)), 0)
+    high = min(math.ceil(correction_dim(beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001       # the reference's guard against a 0 divide
+    plain = 1.0 / (base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return plain / factor * ramp + plain * (1.0 - ramp)
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention temperature 0.1 * mscale * ln(factor) + 1 (1 for
+    factor <= 1).  With `mscale_all_dim` it enters the softmax scale
+    SQUARED; cos and sin carry mscale / mscale_all_dim."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
 def rope_cos_sin(seq_len, head_dim, base=10000.0, dtype=jnp.float32,
-                 position_ids=None):
-    inv_freq = 1.0 / (base ** (jnp.arange(0, head_dim, 2,
-                                          dtype=jnp.float32) / head_dim))
+                 position_ids=None, scaling=None):
+    """cos/sin tables [.., s, head_dim] (rotate-half layout).  `scaling`:
+    a config's `rope_scaling` group; only `deepseek_yarn` is known."""
+    amp = 1.0
+    if scaling is None:
+        inv_freq = 1.0 / (base ** (jnp.arange(0, head_dim, 2,
+                                              dtype=jnp.float32) / head_dim))
+    elif scaling.get("type") == "deepseek_yarn":
+        inv_freq = yarn_inv_freq(
+            head_dim, base, scaling["factor"],
+            scaling["original_max_position_embeddings"],
+            scaling.get("beta_fast", 32), scaling.get("beta_slow", 1))
+        amp = yarn_mscale(scaling["factor"], scaling.get("mscale", 1.0)) \
+            / yarn_mscale(scaling["factor"],
+                          scaling.get("mscale_all_dim", 0.0))
+    else:
+        raise ValueError(f"unknown rope_scaling {scaling!r}")
     pos = (jnp.arange(seq_len, dtype=jnp.float32) if position_ids is None
            else position_ids.astype(jnp.float32))
     freqs = jnp.einsum("...s,d->...sd", pos, inv_freq)
     emb = jnp.concatenate([freqs, freqs], axis=-1)
+    if amp != 1.0:
+        return (jnp.cos(emb) * amp).astype(dtype), \
+            (jnp.sin(emb) * amp).astype(dtype)
     return jnp.cos(emb).astype(dtype), jnp.sin(emb).astype(dtype)
 
 
@@ -484,6 +629,11 @@ def apply_rope(q, k, cos, sin):
             ("b.h.", "b.h."))
         if out is not None:
             return out
+    return xla_apply_rope(q, k, cos, sin)
+
+
+def xla_apply_rope(q, k, cos, sin):
+    """apply_rope's jnp math (fp32 rotate-half), whatever the backend."""
     if cos.ndim == 2:      # [s, d] → [1, s, 1, d]
         cos, sin = cos[None, :, None, :], sin[None, :, None, :]
     elif cos.ndim == 3:    # [b, s, d] → [b, s, 1, d]

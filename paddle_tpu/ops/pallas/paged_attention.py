@@ -236,7 +236,12 @@ def supports(pool_shape, interpret=None) -> bool:
     a transfer is [hb, page_size, head_dim] of the pool, whole in its
     two minor dims, so Mosaic needs head_dim on whole 128-lane tiles and
     page_size on whole 8-row sublane tiles.  Interpret mode (CPU tests)
-    has no tiling."""
+    has no tiling.  No for latent (MLA) rows, a pool [P, L, ps, width]
+    without a head axis and without a V pool: this kernel reads K and V
+    of equal head size, a latent row is key (all of it) and value (its
+    first kv_lora_rank columns) at once — ops.latent_paged_attention."""
+    if len(pool_shape) != 5:
+        return False
     interp = _interpret() if interpret is None else interpret
     ps, d = pool_shape[3], pool_shape[4]
     return interp or (d % 128 == 0 and ps % 8 == 0)

@@ -205,3 +205,44 @@ def test_paged_attention(for_chip, pool, width, kv_heads):
                     pool_s, pool_s, ((SLOTS, PAGES_PER_SLOT), jnp.int32),
                     ((SLOTS,), jnp.int32), scale_s, scale_s)
     assert "paged_attention" in _kernels(text)
+
+
+@pytest.mark.parametrize("width", [1, 32], ids=["decode", "admit"])
+def test_latent_pool_write_and_attention(for_chip, width):
+    """The latent (MLA) pool's XLA path at the sarvam-105b cell's widths (64
+    heads, rank 512 + rope 64, 16 slots here): the scatter of the step's
+    rows and the absorbed attention's block walk (a while loop with a
+    traced trip count) compile for the chip."""
+    from paddle_tpu import ops
+    heads, rank, rope, slots, p_slot = 64, 512, 64, 16, 40
+    pages = 1 + slots * p_slot
+
+    def f(pool, table, pos, rows, q_lat, q_rope):
+        pool = ops.latent_kv_update(pool, table, pos, rows, 1)
+        return pool, ops.latent_paged_attention(q_lat, q_rope, pool, table,
+                                                pos, 1, 0.1)
+    bf = jnp.bfloat16
+    text = for_chip(f, ((pages, 2, PAGE_SIZE, rank + rope), bf),
+                    ((slots, p_slot), jnp.int32), ((slots,), jnp.int32),
+                    ((slots, width, rank + rope), bf),
+                    ((slots, width, heads, rank), bf),
+                    ((slots, width, heads, rope), bf))
+    assert "while" in text and "scatter" in text
+
+
+def test_dropless_experts_grouped_product(for_chip):
+    """The share-aware expert layer's dispatch at the cell's widths (hidden
+    4096, experts 2048 wide, 8 of 128 a token, 8 held here): the grouped
+    product is the TPU's own ragged-dot kernel, and its group sizes are
+    32-bit although the package turns jax_enable_x64 on."""
+    import paddle_tpu  # noqa: F401  (x64 on, as every program of the repo)
+    from paddle_tpu.incubate.distributed.models.moe import dropless_experts
+    held, d, f_, k, tokens = 8, 4096, 2048, 8, 256
+
+    def f(x, logits, w1, w2):
+        topv, topi = jax.lax.top_k(logits, k)
+        return dropless_experts(x, topi, topv, w1, w2, "swiglu", first=16)
+    bf = jnp.bfloat16
+    text = for_chip(f, ((tokens, d), bf), ((tokens, 128), jnp.float32),
+                    ((held, d, 2 * f_), bf), ((held, f_, d), bf))
+    assert text.count("ragged-dot") >= 2

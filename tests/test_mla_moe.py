@@ -1,0 +1,520 @@
+"""Latent attention (MLA), YaRN and the share-aware dropless expert layer,
+against the benchmark's plain float32 reference
+(benchmark/reference/mla_moe_f32.py) at a tiny size on the CPU: the decoder
+`paddle_tpu.models.llama` builds from such a configuration, its paged
+(latent-pool) path, `ContinuousBatcher` over it, and the pieces alone."""
+import math
+import os
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import paddle_tpu as paddle
+from paddle_tpu import ops as tpu_ops
+from paddle_tpu.inference import ContinuousBatcher
+from paddle_tpu.incubate.distributed.models.moe import (
+    MoELayer, SigmoidGate, StepCounters, dropless_experts)
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+import weights_mla_moe                               # noqa: E402
+from drivers import mla_moe_program                  # noqa: E402
+from reference import mla_moe_f32 as ref             # noqa: E402
+
+SEED = 7
+
+
+def tiny_cfg(**over):
+    """The published configuration's keys at a tiny size: 1 dense layer and
+    2 expert layers, 16 routed experts of which [4, 8) are held, top 3."""
+    cfg = {"model_class": "paddle_tpu.models.llama",
+           "model_type": "sarvam_mla", "torch_dtype": "float32",
+           "hidden_size": 64, "intermediate_size": 128, "vocab_size": 256,
+           "num_hidden_layers": 3, "first_k_dense_replace": 1,
+           "num_attention_heads": 4, "kv_lora_rank": 32,
+           "qk_nope_head_dim": 16, "qk_rope_head_dim": 8, "v_head_dim": 16,
+           "use_qk_norm": True, "rms_norm_eps": 1e-6, "rope_theta": 10000,
+           "max_position_embeddings": 512,
+           "rope_scaling": {"type": "deepseek_yarn", "factor": 40,
+                            "beta_fast": 32, "beta_slow": 1, "mscale": 1,
+                            "mscale_all_dim": 1,
+                            "original_max_position_embeddings": 64},
+           "moe_intermediate_size": 32, "num_experts": 4,
+           "router_width": 16, "experts_held": [4, 8],
+           "num_experts_per_tok": 3, "num_shared_experts": 1,
+           "routed_scaling_factor": 2.5,
+           "moe_router_enable_expert_bias": True,
+           "tie_word_embeddings": False}
+    cfg.update(over)
+    return cfg
+
+
+def ref_params(cfg, seed=SEED):
+    return {n: v.astype(jnp.float32)
+            for n, v in weights_mla_moe.leaves(seed, cfg, "float32")}
+
+
+@pytest.fixture(scope="module")
+def model():
+    m = mla_moe_program.build_model(tiny_cfg(), SEED, "float32")
+    m.eval()
+    return m
+
+
+@pytest.fixture(scope="module")
+def ids():
+    return np.random.RandomState(0).randint(0, 256, (2, 40)).astype(np.int32)
+
+
+@pytest.fixture(scope="module")
+def ref_logits(ids):
+    cfg = tiny_cfg()
+    return np.asarray(ref.forward_logits(ref_params(cfg), ids, cfg))
+
+
+# -- (1) the full forward ---------------------------------------------------
+
+def test_full_forward_logits_match_reference(model, ids, ref_logits):
+    got = np.asarray(model(paddle.to_tensor(ids)).value)
+    np.testing.assert_allclose(got, ref_logits, rtol=2e-4, atol=2e-4)
+
+
+# -- (2) prefill then decode through the latent pool ------------------------
+
+@pytest.mark.parametrize("split", [(16, 16, 8), (37, 1, 1, 1)])
+def test_paged_prefill_then_decode_match_reference(model, ids, ref_logits,
+                                                   split):
+    """Chunks of a prompt and then single tokens through
+    forward_cached_paged: every lane's logits are the full causal
+    forward's (absorbed attention against the latent pool)."""
+    cache = model.init_paged_cache(16, 8)
+    assert set(cache) == {"kv"} and cache["kv"].shape == (16, 3, 8, 40)
+    table = jnp.asarray(np.arange(1, 13, dtype=np.int32).reshape(2, 6))
+    got, at = [], 0
+    for n in split:
+        lg, cache = model.forward_cached_paged(
+            jnp.asarray(ids[:, at:at + n]), cache, table,
+            jnp.full((2,), at, jnp.int32))
+        got.append(np.asarray(lg))
+        at += n
+    np.testing.assert_allclose(np.concatenate(got, 1), ref_logits[:, :at],
+                               rtol=2e-4, atol=2e-4)
+
+
+def _served(model, prompts, want, slots=3, **knobs):
+    bat = ContinuousBatcher(model, max_batch_size=slots, max_len=64, chunk=4,
+                            prefill_chunk=8, page_size=8, **knobs)
+    rids = [bat.submit(p, max_new_tokens=want) for p in prompts]
+    out = bat.run()
+    return bat, [np.asarray(out[r]) for r in rids]
+
+
+def test_batcher_serves_reference_tokens(model, ids):
+    """served_token_gap at tiny size: each served token's reference logit
+    lies at most rounding below the reference's best (logits compared, not
+    tokens), more requests than slots so that slots are reused."""
+    cfg = tiny_cfg()
+    prompts = [ids[i % 2, :9 + 3 * i] for i in range(5)]
+    bat, served = _served(model, prompts, 7)
+    assert bat.kv_layout == "paged" and set(bat._cache) == {"kv"}
+    params = ref_params(cfg)
+    for prompt, tokens in zip(prompts, served):
+        assert len(tokens) == 7
+        seq = np.concatenate([prompt, tokens])[None]
+        rows = np.asarray(ref.forward_logits(params, seq, cfg))[0]
+        rows = rows[len(prompt) - 1:len(prompt) - 1 + len(tokens)]
+        gap = rows.max(-1) - rows[np.arange(len(tokens)), tokens]
+        assert gap.max() < 1e-3, gap
+
+
+def test_prefix_sharing_and_page_copy_serve_the_latent_pool(model, ids):
+    """The page programs index pages whatever a page holds: a shared prefix
+    maps resident latent pages and the outputs stay those of isolation."""
+    sys_p = ids[0, :24]
+    prompts = [np.concatenate([sys_p, ids[1, :n]]) for n in (5, 9, 3)]
+    alone = [_served(model, [p], 5, prefix_sharing=False)[1][0]
+             for p in prompts]
+    # one slot: each request finds its predecessor's full pages resident
+    bat, shared = _served(model, prompts, 5, slots=1, prefix_sharing=True)
+    assert bat.stats()["prefix_hit_tokens"] >= 2 * 24
+    for a, b in zip(alone, shared):
+        np.testing.assert_array_equal(a, b)
+
+
+# -- (3) absorbed and expanded attention ------------------------------------
+
+def test_absorbed_attention_equals_expanded(model, ids):
+    """One layer's attention alone: the whole-sequence expanded form
+    (k and v of every head built from the latent) against the paged
+    absorbed form (queries carried into latent space), same numbers."""
+    attn = model.llama.layers[1].self_attn
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 24, 64), jnp.float32)
+    cos, sin = model.llama._rope_tables(24)
+    expanded = np.asarray(attn._expanded(x, cos, sin))
+    cache = {"kv": jnp.zeros((8, 3, 8, 40), jnp.float32)}
+    table = jnp.asarray(np.arange(1, 7, dtype=np.int32).reshape(2, 3))
+    pos = jnp.zeros((2,), jnp.int32)
+    cos_b, sin_b = model.llama._rope_tables(
+        24, pos[:, None] + jnp.arange(24)[None])
+    absorbed, _ = attn.forward_cached_paged(x, cos_b, sin_b, cache, table,
+                                            pos, 1)
+    np.testing.assert_allclose(np.asarray(absorbed), expanded, rtol=1e-4,
+                               atol=1e-5)
+
+
+def test_latent_attention_walks_blocks(monkeypatch):
+    """More than one block of the walk, ragged depths: the running softmax
+    over blocks equals one softmax over each slot's rows."""
+    monkeypatch.setattr(tpu_ops, "LATENT_BLOCK_ROWS", 16)
+    rng = np.random.RandomState(5)
+    B, C, h, R, r, ps = 3, 2, 4, 16, 8, 8
+    pool = jnp.asarray(rng.randn(20, 2, ps, R + r), jnp.float32)
+    table = jnp.asarray(rng.permutation(np.arange(1, 19)).reshape(3, 6)
+                        .astype(np.int32))
+    pos = jnp.asarray([37, 0, 20], jnp.int32)
+    q_lat = jnp.asarray(rng.randn(B, C, h, R), jnp.float32)
+    q_rope = jnp.asarray(rng.randn(B, C, h, r), jnp.float32)
+    got = np.asarray(tpu_ops.latent_paged_attention(
+        q_lat, q_rope, pool, table, pos, 1, 0.3))
+    rows = np.asarray(pool)[np.asarray(table), 1].reshape(B, 6 * ps, R + r)
+    q = np.concatenate([np.asarray(q_lat), np.asarray(q_rope)], -1)
+    for b in range(B):
+        for c in range(C):
+            n = int(pos[b]) + c + 1
+            s = np.einsum("hw,kw->hk", q[b, c], rows[b, :n]) * 0.3
+            p = np.exp(s - s.max(-1, keepdims=True))
+            p /= p.sum(-1, keepdims=True)
+            np.testing.assert_allclose(got[b, c], p @ rows[b, :n, :R],
+                                       rtol=1e-4, atol=1e-5)
+    walked = tpu_ops.latent_pages_walked(np.asarray(pos), C, ps, 6)
+    assert walked.tolist() == [6, 6, 6]         # 3 blocks of 2 pages, all
+    assert tpu_ops.latent_pages_walked(np.array([3, 0]), 1, ps, 6) \
+        .tolist() == [2, 2]
+
+
+# -- (4) the shares add up --------------------------------------------------
+
+def test_four_shares_add_up_to_the_uncut_layer():
+    """8 experts over 4 chips: each share routes over all 8, normalises
+    over all the chosen and computes its 2 experts' part; the parts summed,
+    the shared expert counted once, are the uncut reference layer."""
+    d, f, E, k = 32, 16, 8, 3
+    base = tiny_cfg(hidden_size=d, moe_intermediate_size=f, router_width=E,
+                    num_experts_per_tok=k)
+    rng = np.random.RandomState(11)
+    whole = {"router": rng.randn(d, E) * d ** -0.5,
+             "router_bias": rng.randn(E) * 0.05,
+             "experts_w1": rng.randn(E, d, 2 * f) * d ** -0.5,
+             "experts_w2": rng.randn(E, f, d) * f ** -0.5,
+             "shared_w1": rng.randn(d, 2 * f) * d ** -0.5,
+             "shared_w2": rng.randn(f, d) * f ** -0.5}
+    whole = {n: jnp.asarray(v, jnp.float32) for n, v in whole.items()}
+    x = jnp.asarray(rng.randn(2, 9, d), jnp.float32)
+    mm = ref.weight_matmul("float32")
+    uncut = ref.expert_layer(
+        whole, x, dict(base, num_experts=E, experts_held=[0, E]), mm)
+    shared = ref.swiglu(x, whole["shared_w1"], whole["shared_w2"], mm)
+    total = jnp.zeros_like(x)
+    for first in range(0, E, 2):
+        layer = MoELayer(d_model=d, d_hidden=f, num_experts=2,
+                         gate="sigmoid", top_k=k, activation="swiglu",
+                         experts_held=(first, 2), router_width=E,
+                         routed_scaling=2.5, router_bias=True,
+                         shared_hidden=f)
+        vals = {"gate": whole["router"], "bias": whole["router_bias"],
+                "w1": whole["experts_w1"][first:first + 2],
+                "w2": whole["experts_w2"][first:first + 2],
+                "shared_w1": whole["shared_w1"],
+                "shared_w2": whole["shared_w2"]}
+        part = layer._dropless(x, vals)
+        np.testing.assert_allclose(            # the share against ITS reference
+            np.asarray(part), np.asarray(ref.expert_layer(
+                dict(whole, experts_w1=vals["w1"], experts_w2=vals["w2"]), x,
+                dict(base, num_experts=2, experts_held=[first, first + 2]),
+                mm)), rtol=1e-4, atol=1e-5)
+        total = total + (part - shared)
+    np.testing.assert_allclose(np.asarray(total + shared), np.asarray(uncut),
+                               rtol=1e-4, atol=1e-5)
+
+
+# -- (5) the router ---------------------------------------------------------
+
+def test_router_bias_moves_the_choice_not_the_weight():
+    gate = SigmoidGate(8, 6, top_k=2, scaling=2.5, bias=True)
+    w = jnp.asarray(np.random.RandomState(2).randn(8, 6), jnp.float32)
+    x = jnp.asarray(np.random.RandomState(3).randn(5, 8), jnp.bfloat16)
+    plain_i, plain_w, s = gate.route(x, w, jnp.zeros((6,)))
+    assert plain_w.dtype == s.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(plain_w.sum(-1)), 2.5, rtol=1e-6)
+    bias = jnp.zeros((6,)).at[4].set(10.0)       # expert 4 wins every choice
+    top_i, top_w, s2 = gate.route(x, w, bias)
+    assert (np.asarray(top_i)[:, 0] == 4).all()
+    assert not np.array_equal(np.asarray(top_i), np.asarray(plain_i))
+    np.testing.assert_array_equal(np.asarray(s), np.asarray(s2))
+    chosen = np.take_along_axis(np.asarray(s), np.asarray(top_i), -1)
+    np.testing.assert_allclose(                   # weights are of s, not s + b
+        np.asarray(top_w), 2.5 * chosen / chosen.sum(-1, keepdims=True),
+        rtol=1e-6)
+    np.testing.assert_allclose(np.asarray(top_w.sum(-1)), 2.5, rtol=1e-6)
+
+
+# -- (6) dropless under skew ------------------------------------------------
+
+@pytest.mark.parametrize("target, held_rows", [(5, 12), (1, 0)])
+def test_dropless_under_skew(target, held_rows):
+    """Every token to ONE held expert (no capacity drops a row), and every
+    token to absent experts (the share adds nothing)."""
+    rng = np.random.RandomState(4)
+    S, d, f, first = 12, 16, 8, 4
+    w1 = jnp.asarray(rng.randn(4, d, 2 * f), jnp.float32)
+    w2 = jnp.asarray(rng.randn(4, f, d), jnp.float32)
+    x = jnp.asarray(rng.randn(S, d), jnp.float32)
+    topi = jnp.stack([jnp.full((S,), target), jnp.full((S,), 12)], 1)
+    topw = jnp.asarray(rng.rand(S, 2), jnp.float32)
+    counters = StepCounters()
+    y = np.asarray(dropless_experts(x, topi, topw, w1, w2, "swiglu", first,
+                                    counters=counters))
+    want = np.zeros((S, d), np.float32)
+    if held_rows:
+        gu = np.asarray(x) @ np.asarray(w1[target - first])
+        act = gu[:, :f] / (1 + np.exp(-gu[:, :f])) * gu[:, f:]
+        want = np.asarray(topw)[:, :1] * (act @ np.asarray(w2[target - first]))
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+    assert counters.vector().tolist() == [
+        2 * S, held_rows, int(held_rows > 0), held_rows]
+
+
+def test_naive_gate_takes_the_sorted_dispatch():
+    """The gate without a capacity no longer runs every expert on every
+    token: its program is the grouped product over sorted rows, and it
+    gives what a loop over the chosen experts gives."""
+    paddle.seed(1)
+    moe = MoELayer(d_model=8, d_hidden=16, num_experts=4, gate="naive",
+                   top_k=2)
+    x = np.random.RandomState(0).randn(2, 5, 8).astype(np.float32)
+    out = np.asarray(moe(paddle.to_tensor(x)).value).reshape(10, 8)
+    vals = {k: np.asarray(t.value) for k, t in moe._dropless_leaves().items()}
+    tokens = x.reshape(10, 8)
+    logits = tokens @ vals["gate"]
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    want = np.zeros_like(tokens)
+    for s_, row in enumerate(tokens):
+        top = np.argsort(-probs[s_])[:2]
+        for e in top:
+            h = np.asarray(jax.nn.gelu(row @ vals["w1"][e] + vals["b1"][e, 0]))
+            want[s_] += probs[s_, e] / probs[s_, top].sum() \
+                * (h @ vals["w2"][e] + vals["b2"][e, 0])
+    np.testing.assert_allclose(out, want, rtol=1e-4, atol=1e-5)
+    text = str(jax.make_jaxpr(lambda v: moe._dropless(
+        v, {k: t.value for k, t in moe._dropless_leaves().items()}))(
+            jnp.asarray(x)))
+    assert "ragged_dot" in text
+
+
+# -- (7) YaRN ---------------------------------------------------------------
+
+def test_yarn_frequencies_and_mscale_closed_form():
+    dim, base, factor, span = 64, 10000.0, 40.0, 4096
+    got = np.asarray(tpu_ops.yarn_inv_freq(dim, base, factor, span, 32, 1))
+
+    def corr(rot):
+        return dim * math.log(span / (rot * 2 * math.pi)) / (2 * math.log(base))
+    low, high = math.floor(corr(32)), math.ceil(corr(1))
+    assert (low, high) == (10, 23)
+    for i in range(dim // 2):
+        plain = base ** (-2 * i / dim)
+        ramp = min(max((i - low) / (high - low), 0.0), 1.0)
+        np.testing.assert_allclose(got[i], plain * (1 - ramp)
+                                   + plain / factor * ramp, rtol=1e-6)
+    np.testing.assert_allclose(got[:11], [base ** (-2 * i / dim)
+                                          for i in range(11)], rtol=1e-6)
+    np.testing.assert_allclose(got[23:] * factor,
+                               [base ** (-2 * i / dim)
+                                for i in range(23, 32)], rtol=1e-6)
+    m = tpu_ops.yarn_mscale(factor, 1.0)
+    assert abs(m - (0.1 * math.log(40.0) + 1)) < 1e-12 and round(m, 4) == 1.3689
+    assert tpu_ops.yarn_mscale(1.0) == 1.0
+    # the reference's own (numpy, float64) and the attention's scale
+    np.testing.assert_allclose(got, np.asarray(ref.yarn_inv_freq(
+        dim, base, {"factor": factor, "beta_fast": 32, "beta_slow": 1,
+                    "original_max_position_embeddings": span})), rtol=1e-6)
+    scaling = {"type": "deepseek_yarn", "factor": factor, "beta_fast": 32,
+               "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+               "original_max_position_embeddings": span}
+    cos, sin = tpu_ops.rope_cos_sin(4, dim, base, scaling=scaling)
+    np.testing.assert_allclose(np.asarray(cos)[3, :32], np.cos(3 * got),
+                               rtol=1e-5, atol=1e-6)    # unscaled: ratio 1
+    with pytest.raises(ValueError, match="rope_scaling"):
+        tpu_ops.rope_cos_sin(4, dim, base, scaling={"type": "linear"})
+
+
+def test_attention_scale_carries_mscale_squared(model):
+    m = 0.1 * math.log(40.0) + 1
+    assert abs(model.llama.layers[0].self_attn.scale
+               - 24 ** -0.5 * m * m) < 1e-9
+
+
+# -- (8) the counters -------------------------------------------------------
+
+def test_step_counters_equal_a_host_recount(model, ids):
+    """moe_assignments_held / moe_expert_steps_hit / the largest load of one
+    paged step with junk lanes, against numpy over the reference's routing
+    of the same hidden states (the program's own, layer by layer)."""
+    cfg = tiny_cfg()
+    x = jnp.asarray(ids[:, :8])
+    n_valid = jnp.asarray([8, 3])
+    valid = jnp.arange(8)[None] < n_valid[:, None]
+    cache = model.init_paged_cache(8, 8)
+    table = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+    counters = StepCounters(valid)
+    with paddle.no_grad():
+        model.forward_cached_paged(x, cache, table,
+                                   jnp.zeros((2,), jnp.int32), counters)
+    # the layers' inputs, from the same model without counting
+    seen = []
+    for li in (1, 2):
+        mlp = model.llama.layers[li].mlp
+        orig = mlp._dropless
+
+        def spy(xv, vals, valid=None, counters=None, orig=orig):
+            seen.append((np.asarray(xv), {k: np.asarray(v)
+                                          for k, v in vals.items()}))
+            return orig(xv, vals, valid, counters)
+        mlp._dropless = spy
+    try:
+        with paddle.no_grad():
+            model.forward_cached_paged(x, cache, table,
+                                       jnp.zeros((2,), jnp.int32))
+    finally:
+        for li in (1, 2):
+            del model.llama.layers[li].mlp._dropless
+    keep = np.asarray(valid).reshape(-1)
+    held = hit = biggest = 0
+    for xv, vals in seen:
+        chosen, _ = ref.routing(jnp.asarray(xv.reshape(-1, 64)),
+                                jnp.asarray(vals["gate"]),
+                                jnp.asarray(vals["bias"]), cfg)
+        chosen = np.asarray(chosen)[keep]
+        loads = np.bincount(chosen.reshape(-1), minlength=16)[4:8]
+        held, hit = held + loads.sum(), hit + (loads > 0).sum()
+        biggest = max(biggest, loads.max())
+    assert counters.vector().tolist() == [11 * 3 * 2, held, hit, biggest]
+    assert 0 < held < 11 * 3 * 2
+
+
+def test_batcher_counts_on_the_device_and_reports_in_stats(model, ids):
+    from paddle_tpu import telemetry
+    sink = telemetry.MemorySink()
+    telemetry.add_sink(sink)
+    try:
+        bat, served = _served(model, [ids[0, :11], ids[1, :20]], 6)
+    finally:
+        telemetry.remove_sink(sink)
+    st = bat.stats()
+    # every valid token of every expert layer chose 3: prompt tokens once,
+    # and each decode step's one token a slot
+    work = st["prefill_tokens"] + st["decode_tokens"]
+    assert st["moe_assignments"] == work * 3 * 2
+    assert 0 < st["moe_assignments_held"] < st["moe_assignments"]
+    assert 0 < st["moe_expert_steps_hit"] <= st["chunks"] * 4 * 2 * 4
+    assert 0 < st["moe_tokens_per_expert_max"] <= 2 * 8
+    assert model.step_counter_names() == tuple(
+        k for k in st if k.startswith("moe_"))
+    # a dense llama counts nothing and its program has no such output
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
+    dense = LlamaForCausalLM(llama_tiny_config())
+    assert dense.step_counter_names() == ()
+    assert not any(k.startswith("moe_") for k in ContinuousBatcher(
+        dense, max_batch_size=1, max_len=16).stats())
+
+
+# -- (9) what latent rows refuse, and what the batcher asks the model --------
+
+def test_int8_kv_on_latent_rows_refuses(model):
+    with pytest.raises(ValueError, match="int8 KV is not implemented for "
+                                         "latent"):
+        ContinuousBatcher(model, max_batch_size=1, max_len=16,
+                          kv_dtype="int8")
+    with pytest.raises(NotImplementedError, match="paged pool only"):
+        model.init_cache(1, 16)
+    from paddle_tpu.ops.pallas import paged_attention as kernel
+    assert not kernel.supports((16, 3, 8, 40), interpret=True)
+    assert kernel.supports((16, 3, 4, 8, 128), interpret=False)
+
+
+@pytest.mark.parametrize("latent", [True, False])
+def test_paged_kv_bytes_takes_the_row_from_the_model(model, latent):
+    """The allocation-free estimate equals a real instance for both row
+    layouts, and the spec says what a row is."""
+    from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
+    m = model if latent else LlamaForCausalLM(llama_tiny_config())
+    spec = m.kv_row_spec()
+    assert spec["pools"] == ({"kv": (40,)} if latent
+                             else {"k": (4, 32), "v": (4, 32)})
+    for dt in ("float32",) if latent else ("float32", "int8"):
+        bat = ContinuousBatcher(m, max_batch_size=2, max_len=32,
+                                prefill_chunk=4, page_size=8, kv_dtype=dt)
+        assert ContinuousBatcher.paged_kv_bytes(
+            m, max_batch_size=2, max_len=32, prefill_chunk=4, page_size=8,
+            kv_dtype=dt) == bat.kv_cache_bytes() == bat.stats()["kv_bytes"]
+        assert bat.stats()["kv_dtype"] == dt
+
+
+def test_kv_pages_live_and_walked_keep_their_meaning(model, ids):
+    """live: pages up to each occupied slot's frontier; walked: what the
+    latent walk reads — every slot to the deepest one's block."""
+    bat = ContinuousBatcher(model, max_batch_size=3, max_len=64, chunk=4,
+                            prefill_chunk=8, page_size=8)
+    bat.submit(ids[0, :30], max_new_tokens=2)
+    bat.submit(ids[1, :5], max_new_tokens=2)
+    bat.step()                    # one admission chunk: 1 step of 8 lanes
+    assert bat.admit_steps == 1
+    st = bat.stats()
+    # frontiers (pos + 7) // 8 + 1 with both slots at depth 0
+    assert st["kv_pages_live"] == 1 + 1
+    # one block of 512 rows covers the whole table: all 3 slots walk it
+    assert st["kv_pages_walked"] == 3 * bat.pages_per_slot
+    bat.step()       # slot 0 at depth 8; slot 1 at 5 rides the 8 lanes too
+    st = bat.stats()
+    assert st["kv_pages_live"] == 2 + (2 + 2)
+
+
+def test_hand_off_moves_latent_pages(model, ids):
+    """A prefill replica's finished prompt leaves as latent pages and a
+    decode replica resumes it without recomputing prefill: the tokens are
+    those of one unified batcher."""
+    from paddle_tpu.inference import pack_handoff, unpack_handoff
+    prompt = ids[0, :21]
+    _, (alone,) = _served(model, [prompt], 6)
+    knobs = dict(max_batch_size=2, max_len=64, chunk=4, prefill_chunk=8,
+                 page_size=8)
+    pre = ContinuousBatcher(model, role="prefill", **knobs)
+    dec = ContinuousBatcher(model, role="decode", **knobs)
+    rid = pre.submit(prompt, max_new_tokens=6)
+    for _ in range(32):
+        pre.step()
+        if rid in pre._handoff_ready:
+            break
+    meta, data = unpack_handoff(pack_handoff(*pre.export_handoff(rid)))
+    assert set(data) == {"kv"}
+    lid = dec.import_handoff(meta, data)
+    out = dec.run()
+    np.testing.assert_array_equal(np.asarray(out[lid]), alone)
+    assert dec.stats()["prefill_tokens"] == 0
+
+
+def test_latent_pool_is_donated_through_both_step_programs(model):
+    """The static sentinel's donation lint over the serve programs: the
+    latent pool and every other carry alias their outputs (an unaliased
+    pool would double its HBM), with the counters as one more output."""
+    bat = ContinuousBatcher(model, max_batch_size=2, max_len=64, chunk=4,
+                            prefill_chunk=8, page_size=8)
+    report = bat.preflight()
+    assert not report.errors and not report.warnings, report
