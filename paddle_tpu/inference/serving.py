@@ -25,7 +25,11 @@ XLA program):
     (ops.paged_attention: a Pallas kernel on TPU that walks each
     slot's live pages, a `take`-gather jnp twin elsewhere —
     bit-identical to the dense path off-TPU); writes touch only the
-    page window overlapping the step's rows (ops.paged_kv_update);
+    page window overlapping the step's rows (ops.paged_kv_update),
+    gathered from and scattered into the WHOLE pool by (page, layer):
+    the carried pools then keep the default dimension order, the only
+    one the kernel takes — a write that slices the layer out first
+    costs a pool-sized layout copy in front of every kernel call;
   * PREFIX SHARING (inference/paged_kv.py): a host-side token-exact
     trie over page-sized prompt chunks maps admissions onto already-
     resident pages with refcounts — matched tokens SKIP their prefill

@@ -250,7 +250,21 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
     re-encoded (int8 requant drift stays confined to pages actually
     being written).  int8 pages requantize against the page's new
     running amax, so a page's scale is always consistent with every
-    row it holds."""
+    row it holds.
+
+    The pools (and scales) are indexed WHOLE, by (page, layer): gather
+    `pool[ids, layer]`, scatter `pool.at[ids, layer].set`.  Nothing
+    slices or writes back along the layer axis, because the layout XLA
+    gives the carried pools follows from how they are indexed, and the
+    Pallas kernel (ops/pallas/paged_attention.py) takes its `pl.ANY`
+    pools in the default dimension order only.  With `pool[:, layer]`
+    and `.at[:, layer].set` XLA carries the pools LAYER-major and
+    re-lays both out in front of every layer's kernel call (a 1.65 GB
+    copy a pool a layer in the 7B serve cell, 65 % of its busy time);
+    with the rows scattered one by one (`pool.at[page, layer, :, row]`,
+    as ops.latent_kv_update writes its kernel-less pool) it prefers
+    page rows above kv heads, and copies as much (ISSUE 28;
+    tests/test_chip_compile.py holds the compiled program to this)."""
     P, L, n_kv, ps, hd = k_pool.shape
     B, C = k_new.shape[0], k_new.shape[1]
     P_slot = page_table.shape[1]
@@ -267,10 +281,9 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
         & ((start + ps) > pos[:, None])                      # [B, n_t]
 
     def upd(pool, scales, rows):
-        layer_pool = pool[:, layer]                   # [P, n_kv, ps, hd]
-        raw = jnp.take(layer_pool, ids, axis=0)   # [B, n_t, n_kv, ps, hd]
+        raw = pool[ids, layer]                    # [B, n_t, n_kv, ps, hd]
         if quant:
-            sc = jnp.take(scales[:, layer], ids, axis=0)  # [B, n_t, n_kv]
+            sc = scales[ids, layer]                       # [B, n_t, n_kv]
             w = _dequant_pages(raw, sc).astype(rows.dtype)
         else:
             w = raw
@@ -292,12 +305,10 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
                 -127, 127).astype(jnp.int8)
             pages_out = jnp.where(m, q8, raw)
             sc_out = jnp.where(touched[..., None], sc_new, sc)
-            sl = scales[:, layer].at[ids].set(sc_out)
-            scales = scales.at[:, layer].set(sl)
+            scales = scales.at[ids, layer].set(sc_out)
         else:
             pages_out = jnp.where(m, w.astype(pool.dtype), raw)
-        layer_pool = layer_pool.at[ids].set(pages_out)
-        return pool.at[:, layer].set(layer_pool), scales
+        return pool.at[ids, layer].set(pages_out), scales
 
     k_pool, k_scale = upd(k_pool, k_scale, k_new)
     v_pool, v_scale = upd(v_pool, v_scale, v_new)

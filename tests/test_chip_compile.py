@@ -12,7 +12,9 @@ Only one process may load the TPU's library, so the topology is described
 inside a module-scoped fixture (never at import), every compile happens in
 this process, and all of these tests live in this ONE file.
 """
+import math
 import os
+import re
 import sys
 
 import jax
@@ -43,9 +45,11 @@ def one_chip(topo):
 
 @pytest.fixture()
 def for_chip(one_chip, monkeypatch):
-    """compile(fn, *shapes) -> optimized HLO text of `fn` compiled for one
-    described v5e chip.  The kernels pick interpret mode from the backend
-    being the CPU; for the length of one test they are told it is a TPU.
+    """compile(fn, *shapes, donate=()) -> optimized HLO text of `fn`
+    compiled for one described v5e chip (a shape is (dims, dtype), or None
+    for an argument the call leaves out; `donate` as jit's donate_argnums).
+    The kernels pick interpret mode from the backend being the CPU; for
+    the length of one test they are told it is a TPU.
     The persistent compilation cache is off around the compile: an entry
     written for a described chip cannot be read back without one."""
     from jax.experimental.compilation_cache import compilation_cache as cc
@@ -54,10 +58,11 @@ def for_chip(one_chip, monkeypatch):
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
 
-    def compile_(fn, *shapes):
-        args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
-                for s, d in shapes]
-        return jax.jit(fn).lower(*args).compile().as_text()
+    def compile_(fn, *shapes, donate=()):
+        args = [s and jax.ShapeDtypeStruct(*s, sharding=one_chip)
+                for s in shapes]
+        return jax.jit(fn, donate_argnums=donate).lower(*args) \
+            .compile().as_text()
 
     yield compile_
     jax.config.update("jax_enable_compilation_cache", was)
@@ -205,6 +210,70 @@ def test_paged_attention(for_chip, pool, width, kv_heads):
                     pool_s, pool_s, ((SLOTS, PAGES_PER_SLOT), jnp.int32),
                     ((SLOTS,), jnp.int32), scale_s, scale_s)
     assert "paged_attention" in _kernels(text)
+
+
+def _results(text, op):
+    """(dims, layout) of every `op` instruction's result in HLO text,
+    fused computations included; the layout as written, tiling cut."""
+    pat = re.compile(r" = \w+\[([\d,]+)\]\{([\d,]+)[:}][^ ]* "
+                     + re.escape(op) + r"\(")
+    return [(tuple(map(int, m.group(1).split(","))), m.group(2))
+            for m in map(pat.search, text.splitlines()) if m]
+
+
+@pytest.mark.parametrize("width", [1, 32], ids=["decode", "admit"])
+@pytest.mark.parametrize("pool", ["bf16", "int8"])
+def test_pool_write_keeps_the_kernels_layout(for_chip, pool, width):
+    """The dense pool write inside the serve scan (ISSUE 28), at
+    dsllm7b_serve_chat_c24's widths (24 slots x 66 pages of 16 rows,
+    32 kv heads of 128): 2 scan steps over 2 layers of
+    ops.paged_kv_update then ops.paged_attention, each layer's rows
+    made from the layer before, the pools donated.  The kernel takes
+    its `pl.ANY` pools in the default dimension order only, so a write
+    that makes XLA carry them in another order costs a pool-sized
+    layout copy in front of every kernel call (1.65 GB a pool a layer
+    at the cell's depth: 65 % of the cell's busy time up to PR 27).
+    Held here: nothing pool-sized or layer-slice-sized is copied,
+    sliced or written back along the layer axis; the only pool-sized
+    work is the in-place scatter, on the default order."""
+    from paddle_tpu import ops
+    slots, p_slot, layers, steps = 24, 66, 2, 2
+    pages = 1 + slots * p_slot
+    quant = pool == "int8"
+
+    def f(kp, vp, ks, vs, table, pos, x):
+        def body(carry, _):
+            kp, vp, ks, vs, pos, x = carry
+            for layer in range(layers):
+                k, v = x * 0.5, x * 2.0
+                kp, vp, ks, vs = ops.paged_kv_update(
+                    kp, vp, ks, vs, table, pos, k, v, layer)
+                x = ops.paged_attention(x, kp, vp, table, pos, layer,
+                                        ks, vs)
+            return (kp, vp, ks, vs, pos + width, x), _sum32(x)
+        (kp, vp, ks, vs, _, _), ys = jax.lax.scan(
+            body, (kp, vp, ks, vs, pos, x), None, length=steps)
+        return kp, vp, ks, vs, ys
+
+    pool_s = ((pages, layers, HEADS, PAGE_SIZE, HEAD_DIM),
+              jnp.int8 if quant else jnp.bfloat16)
+    scale_s = ((pages, layers, HEADS), jnp.float32) if quant else None
+    text = for_chip(f, pool_s, pool_s, scale_s, scale_s,
+                    ((slots, p_slot), jnp.int32), ((slots,), jnp.int32),
+                    ((slots, width, HEADS, HEAD_DIM), jnp.bfloat16),
+                    donate=(0, 1, 2, 3))
+    assert "paged_attention" in _kernels(text)
+    layer_elems = pages * HEADS * PAGE_SIZE * HEAD_DIM
+    big = {layer_elems, layer_elems * layers}
+    for op in ("copy", "slice", "dynamic-update-slice", "transpose"):
+        found = [r for r in _results(text, op)
+                 if math.prod(r[0]) in big]
+        assert not found, f"pool-sized {op}: {found}"
+    scatters = [r for r in _results(text, "scatter")
+                if math.prod(r[0]) in big]
+    # K and V, a layer each, in the scan body
+    assert len(scatters) == 2 * layers and all(
+        r == (pool_s[0], "4,3,2,1,0") for r in scatters), scatters
 
 
 @pytest.mark.parametrize("width", [1, 32], ids=["decode", "admit"])

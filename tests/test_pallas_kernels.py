@@ -529,6 +529,210 @@ class TestPagedKVUpdate:
                                    atol=0.03, rtol=0.05)
 
 
+def _layer_sliced_kv_update(k_pool, v_pool, k_scale, v_scale, page_table,
+                             pos, k_new, v_new, layer):
+    """ops.paged_kv_update as it indexed the pools up to PR 27: slice
+    the layer out, scatter the window into the slice, write the slice
+    back.  The same bytes, by a path that cost a pool-sized layout copy
+    a layer on the chip (ISSUE 28) — kept HERE only, as what the
+    whole-pool indexing is held bit-identical to."""
+    P, L, n_kv, ps, hd = k_pool.shape
+    B, C = k_new.shape[0], k_new.shape[1]
+    P_slot = page_table.shape[1]
+    n_t = -(-C // ps) + 1
+    quant = k_pool.dtype == jnp.int8
+    p0 = jnp.clip(pos // ps, 0, max(P_slot - n_t, 0))
+    win = jnp.clip(p0[:, None] + jnp.arange(n_t, dtype=jnp.int32)[None],
+                   0, P_slot - 1)
+    ids = jnp.take_along_axis(page_table, win, axis=1)
+    rel0 = pos - p0 * ps
+    start = win * ps
+    touched = (start < (pos + C)[:, None]) & ((start + ps) > pos[:, None])
+
+    def upd(pool, scales, rows):
+        layer_pool = pool[:, layer]
+        raw = jnp.take(layer_pool, ids, axis=0)
+        if quant:
+            sc = jnp.take(scales[:, layer], ids, axis=0)
+            w = (raw.astype(jnp.float32)
+                 * sc[..., None, None]).astype(rows.dtype)
+        else:
+            w = raw
+        w = w.transpose(0, 2, 1, 3, 4).reshape(B, n_kv, n_t * ps, hd)
+        z = jnp.zeros((), jnp.int32)
+        w = jax.vmap(lambda buf, r_, r0: jax.lax.dynamic_update_slice(
+            buf, r_.astype(buf.dtype), (z, r0, z)))(
+                w, rows.transpose(0, 2, 1, 3), rel0)
+        w = w.reshape(B, n_kv, n_t, ps, hd).transpose(0, 2, 1, 3, 4)
+        m = touched[:, :, None, None, None]
+        if quant:
+            amax = jnp.max(jnp.abs(w.astype(jnp.float32)), axis=(3, 4))
+            sc_new = jnp.maximum(amax, 1e-8) / 127.0
+            q8 = jnp.clip(jnp.round(
+                w.astype(jnp.float32) / sc_new[..., None, None]),
+                -127, 127).astype(jnp.int8)
+            pages_out = jnp.where(m, q8, raw)
+            sc_out = jnp.where(touched[..., None], sc_new, sc)
+            scales = scales.at[:, layer].set(
+                scales[:, layer].at[ids].set(sc_out))
+        else:
+            pages_out = jnp.where(m, w.astype(pool.dtype), raw)
+        return pool.at[:, layer].set(layer_pool.at[ids].set(pages_out)), \
+            scales
+
+    k_pool, k_scale = upd(k_pool, k_scale, k_new)
+    v_pool, v_scale = upd(v_pool, v_scale, v_new)
+    return k_pool, v_pool, k_scale, v_scale
+
+
+class TestPagedKVUpdateWholePool:
+    """ops.paged_kv_update gathers and scatters the window's pages on
+    the WHOLE pool by (page, layer) (ISSUE 28: the carried pools keep
+    the layout the paged kernel takes).  Same rows, same pages, same
+    precision as the layer-sliced write it replaced: every pool and
+    scale bit-identical on the same inputs, for the off-benchmark users
+    too (GQA, int8, a C that straddles three pages, shared pages inside
+    a window, free slots on the null page, a window clamped at the end
+    of its table)."""
+    PS, P_SLOT, L, HD, LAYER = 16, 6, 3, 8, 1
+    # slots: 0 mid-page; 1 on a page boundary (its window's last page
+    # untouched); 2, 3 free (null page, duplicate indices); 4 at the
+    # end of its table (window start clamped)
+    WIDTHS = {"decode": (1, [21, 32, 0, 0, 95]),
+              "chunk": (32, [21, 32, 0, 0, 64]),
+              "three_pages": (20, [14, 32, 0, 0, 76])}
+
+    def _case(self, pool, n_kv, width):
+        rng = np.random.RandomState(0)
+        ps, P_slot, L, hd = self.PS, self.P_SLOT, self.L, self.HD
+        C, pos = self.WIDTHS[width]
+        B, P = len(pos), 1 + 3 * P_slot + 4
+        shape = (P, L, n_kv, ps, hd)
+        table = np.zeros((B, P_slot), np.int32)
+        table[[0, 1, 4]] = (rng.permutation(P - 1)[:3 * P_slot] + 1) \
+            .reshape(3, P_slot)
+        if pool == "int8":
+            kp, vp = (jnp.asarray(rng.randint(-127, 128, shape), jnp.int8)
+                      for _ in range(2))
+            ks, vs = (jnp.asarray(rng.uniform(0.01, 0.05, shape[:3]),
+                                  jnp.float32) for _ in range(2))
+        else:
+            kp, vp = (jnp.asarray(rng.randn(*shape), jnp.bfloat16)
+                      for _ in range(2))
+            ks = vs = None
+        kn, vn = (jnp.asarray(rng.randn(B, C, n_kv, hd), jnp.bfloat16)
+                  for _ in range(2))
+        return (kp, vp, ks, vs, jnp.asarray(table),
+                jnp.asarray(pos, jnp.int32), kn, vn)
+
+    @staticmethod
+    def _run(fn, args, layer):
+        out = jax.jit(fn, static_argnums=(8,))(*args, layer)
+        return [None if o is None else np.asarray(o) for o in out]
+
+    def _both(self, pool, n_kv, width):
+        from paddle_tpu.ops import paged_kv_update
+        args = self._case(pool, n_kv, width)
+        return (args, self._run(paged_kv_update, args, self.LAYER),
+                self._run(_layer_sliced_kv_update, args, self.LAYER))
+
+    @pytest.mark.parametrize("width", list(WIDTHS))
+    @pytest.mark.parametrize("n_kv", [32, 8], ids=["mha", "gqa"])
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    def test_pools_bit_identical_and_rows_land(self, pool, n_kv, width):
+        args, got, want = self._both(pool, n_kv, width)
+        for g, w in zip(got, want):
+            if w is None:
+                assert g is None
+            else:
+                assert g.dtype == w.dtype
+                np.testing.assert_array_equal(g, w)
+        # against the dense view: the occupied slots' logical rows
+        # [pos, pos + C) of the written layer hold the step's rows
+        kp, vp, ks, vs, table, pos, kn, vn = args
+        C = kn.shape[1]
+        for new, rows, scales in ((got[0], kn, got[2]), (got[1], vn, got[3])):
+            lg = new[:, self.LAYER][np.asarray(table)].astype(np.float32)
+            if scales is not None:
+                lg = lg * scales[:, self.LAYER][np.asarray(table)][
+                    ..., None, None]
+            lg = lg.transpose(0, 1, 3, 2, 4).reshape(
+                len(pos), -1, n_kv, self.HD)
+            for b in (0, 1, 4):
+                p = int(pos[b])
+                want_rows = np.asarray(rows[b].astype(jnp.float32))
+                if scales is None:
+                    np.testing.assert_array_equal(lg[b, p:p + C], want_rows)
+                else:
+                    np.testing.assert_allclose(lg[b, p:p + C], want_rows,
+                                               atol=0.05)
+        # the other layers are never touched
+        for new, old in ((got[0], kp), (got[1], vp)):
+            for layer in (0, 2):
+                np.testing.assert_array_equal(
+                    new[:, layer], np.asarray(old)[:, layer])
+
+    @pytest.mark.parametrize("width", list(WIDTHS))
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    def test_read_only_page_in_window_keeps_bytes_and_scale(self, pool,
+                                                            width):
+        """Slot 1 writes from a page boundary, so the last page of its
+        window (a shared prefix page could stand there) holds none of
+        the step's rows: its bytes and its scale come back as they
+        were — no re-encoding of a page nobody wrote."""
+        from paddle_tpu.ops import paged_kv_update
+        args = self._case(pool, 8, width)
+        kp, vp, ks, vs, table, pos, kn, vn = args
+        got = self._run(paged_kv_update, args, self.LAYER)
+        C = kn.shape[1]
+        first = int(pos[1]) // self.PS
+        n_t = -(-C // self.PS) + 1
+        untouched = int(table[1, first + n_t - 1])
+        assert (first + n_t - 1) * self.PS >= int(pos[1]) + C
+        for new, old in ((got[0], kp), (got[1], vp)):
+            np.testing.assert_array_equal(new[untouched],
+                                          np.asarray(old)[untouched])
+        if pool == "int8":
+            for new, old in ((got[2], ks), (got[3], vs)):
+                np.testing.assert_array_equal(new[untouched],
+                                              np.asarray(old)[untouched])
+
+    @pytest.mark.parametrize("width", list(WIDTHS))
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    def test_free_slots_meet_in_the_null_page_only(self, pool, width):
+        """Every slot free: all B windows are the null page, n_t times
+        each (duplicate scatter indices).  Whatever lands there, every
+        other page and every other scale is untouched."""
+        from paddle_tpu.ops import paged_kv_update
+        kp, vp, ks, vs, table, pos, kn, vn = self._case(pool, 8, width)
+        args = (kp, vp, ks, vs, jnp.zeros_like(table), jnp.zeros_like(pos),
+                kn, vn)
+        got = self._run(paged_kv_update, args, self.LAYER)
+        for new, old in zip(got, (kp, vp, ks, vs)):
+            if old is not None:
+                np.testing.assert_array_equal(new[1:], np.asarray(old)[1:])
+
+    @pytest.mark.parametrize("width", list(WIDTHS))
+    @pytest.mark.parametrize("pool", ["bf16", "int8"])
+    def test_window_clamped_at_the_table_end(self, pool, width):
+        """Slot 4's rows end at its table's last row, so its window
+        starts before its first row's page (p0 clamped): the rows land
+        where the layer-sliced write put them and the pages of its
+        table before the window keep their bytes."""
+        args, got, want = self._both(pool, 8, width)
+        kp, _, _, _, table, pos, kn, _ = args
+        C = kn.shape[1]
+        n_t = -(-C // self.PS) + 1
+        assert int(pos[4]) // self.PS > self.P_SLOT - n_t   # clamped
+        assert int(pos[4]) + C == self.P_SLOT * self.PS
+        mine = np.asarray(table[4])
+        np.testing.assert_array_equal(got[0][mine], want[0][mine])
+        np.testing.assert_array_equal(got[1][mine], want[1][mine])
+        before = mine[:self.P_SLOT - n_t]
+        np.testing.assert_array_equal(got[0][before],
+                                      np.asarray(kp)[before])
+
+
 class TestDispatchDoesNotHideTheKernel:
     """paddle_tpu.ops picks kernel-or-twin from the backend and the shapes
     (each kernel module's `supports` predicate) and calls the kernel
