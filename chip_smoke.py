@@ -48,9 +48,9 @@ SEED = 0
 SEQ = 2048
 BATCH = 2                # the four-chip mesh shards the batch two ways
 STEPS = 4
-# bench.py trains at 3e-4; with no warm-up on one repeated batch that
-# overshoots by the fourth step at these widths (losses 10.88, 9.14, 7.31,
-# 10.62 — my chip run, PR 21).  A smoke wants a loss that simply falls.
+# At 3e-4, with no warm-up on one repeated batch, training overshoots
+# by the fourth step at these widths (losses 10.88, 9.14, 7.31, 10.62 —
+# my chip run, PR 21).  A smoke wants a loss that simply falls.
 LEARNING_RATE = 1e-4
 # Depth: compiled.memory_analysis() for a described v5e (rehearsal 3, PR 21)
 # puts this train step at 13.09 GiB with 5 layers, 14.75 GiB with 6 and

@@ -44,9 +44,9 @@ CLI: `python tools/verify_program.py` (JSON mode + non-zero exit on
 findings, like tools/op_audit.py) and `python tools/static_check.py`
 (the sentinel catalog over the standard program zoo, diffed against
 tools/static_baseline.json).  All checks are cold-path: with the
-flags off the replay hot path pays one dict lookup, and bench.py
-asserts the replay-cache keys are byte-identical with the subsystem
-loaded.
+flags off the replay hot path pays one dict lookup and keeps its
+replay-cache keys (tests/test_program_verifier.py
+`test_hot_path_runs_zero_verifications_with_flag_off`).
 """
 from __future__ import annotations
 

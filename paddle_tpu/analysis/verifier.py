@@ -43,8 +43,9 @@ from .base import Finding, ProgramVerifyError
 
 __all__ = ["verify_program", "check_program", "VERIFY_CALLS"]
 
-# invocation counter — bench.py asserts this does NOT move on the
-# flags-off replay hot path (the zero-overhead contract)
+# invocation counter: it does NOT move on the flags-off replay hot path
+# (tests/test_program_verifier.py
+# `test_hot_path_runs_zero_verifications_with_flag_off`)
 VERIFY_CALLS = 0
 
 

@@ -112,8 +112,8 @@ _pending_lock = threading.Lock()
 # comes first (then cleared)
 _writer_error: list = []
 
-# write-activity counter: bench.py asserts the flags-off train hot path
-# performs zero checkpoint IO
+# write-activity counter: tests/test_fault_tolerance.py `TestZeroOverhead`
+# holds the flags-off train hot path to zero checkpoint IO
 WRITE_CALLS = 0
 
 

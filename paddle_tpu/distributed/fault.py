@@ -39,7 +39,8 @@ Call sites thread a *point* through their failure-prone operation::
 returns the :class:`Fault` for data modes (truncate/corrupt/nan/skip)
 the call site must implement.  When ``FLAGS_fault_injection`` is unset
 the whole machinery is a single cached-string comparison — no parsing,
-no counters, no syscalls (`bench.py` asserts this stays true).
+no counters, no syscalls (tests/test_fault_tolerance.py
+`TestZeroOverhead` holds it).
 
 Determinism: hits are counted per point, only while a spec is armed,
 and `reset()` (or re-arming a different spec) zeroes the counters —

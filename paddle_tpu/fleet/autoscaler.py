@@ -43,8 +43,9 @@ Three layers, strictly separated so each is testable alone:
 
 With ``FLAGS_autoscale`` off (the single-replica default) ``tick()``
 returns on one flag read — no KV traffic, no view aggregation, and the
-serve-step HLO / program-cache keys are byte-identical (bench.py's
-zero-overhead battery asserts all three).
+serve-step HLO / program-cache keys are byte-identical
+(tests/test_program_contracts.py: `test_autoscaler_off_is_one_flag_read`
+and `test_autoscale_flag_leaves_the_serve_programs_identical`).
 
 :class:`DiurnalLoadSim` generates the deterministic load curve the
 tier-1 end-to-end tests, ``chaos_check --autoscale`` and the

@@ -381,7 +381,8 @@ class ContinuousBatcher:
         # recomputed.  "unified" is the classic symmetric replica and
         # the default: with no prefill/decode batchers in the fleet,
         # every code path below is dormant and the serve-step programs
-        # are byte-identical (zero-overhead pin in bench.py).
+        # are byte-identical (tests/test_program_contracts.py:
+        # test_disagg_flags_leave_the_serve_programs_identical).
         if role not in ("unified", "prefill", "decode"):
             raise ValueError(f"role {role!r}: unified|prefill|decode")
         if role != "unified" and kv_layout != "paged":

@@ -203,8 +203,8 @@ def ignore_module(modules):
 
 
 # ---------------------------------------------------------------------------
-# TrainStep — whole-step compilation (the perf path used by Model.fit,
-# bench.py and the distributed trainer).
+# TrainStep — whole-step compilation (the perf path used by Model.fit
+# and the distributed trainer).
 # ---------------------------------------------------------------------------
 def per_step_lrs(optimizer, k: int, advance: bool = True):
     """Per-step LR array [k] for a fused run_steps window, plus a

@@ -1,5 +1,5 @@
 """BERT family — baseline config 2 (BERT-base pretraining, DP +
-sharding stage-1; BASELINE.md).
+sharding stage-1; BASELINE.json).
 
 Reference capability: PaddleNLP-style BERT built on the reference's nn
 stack (`python/paddle/nn/` MultiHeadAttention/TransformerEncoder) and

@@ -1,5 +1,5 @@
 """GPT family — baseline config 4 (GPT-3-style hybrid TP+PP+sharding
-pretraining; BASELINE.md).
+pretraining; BASELINE.json).
 
 Reference capability: PaddleNLP-style GPT trained by the Fleet hybrid
 engine (the reference's flagship static hybrid config).

@@ -1,5 +1,5 @@
 """Llama family — the flagship model (baseline config 3: Llama-2 7B/13B
-sharding-stage3 pretraining, SURVEY §6 / BASELINE.md).
+sharding-stage3 pretraining, SURVEY §6 / BASELINE.json).
 
 Reference capability: PaddleNLP-style llama built on the reference's fused
 ops (fused_rms_norm, fused_rotary_position_embedding, swiglu,

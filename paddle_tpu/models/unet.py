@@ -1,5 +1,5 @@
 """Diffusion UNet — baseline config 5 (Stable-Diffusion-style UNet,
-samples/sec; BASELINE.md).
+samples/sec; BASELINE.json).
 
 Reference capability: the reference trains SD/ERNIE-ViL-class multimodal
 models through its Fleet engine (paddle's diffusers port builds on

@@ -1,9 +1,8 @@
 """Weight-only quantized matmul kernel — dequant-in-VMEM fused into
 the decode matmul (ISSUE 11 tentpole).
 
-Decode sits at 0.79x of the HBM roofline (BENCH_r05): per generated
-token every weight byte crosses HBM once, so tokens/s is bytes/token-
-bound.  This kernel reads the weight at its PACKED width — 1 byte per
+Decode streams the weights: per generated token every weight byte
+crosses HBM once, so tokens/s is bytes/token-bound.  This kernel reads the weight at its PACKED width — 1 byte per
 element (int8) or half a byte (int4, two nibbles per byte) — and
 dequantizes in VMEM right after the DMA, so the HBM traffic the matmul
 pays is the packed traffic.  The activation [M, K] is tiny at decode

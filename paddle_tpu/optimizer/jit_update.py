@@ -34,11 +34,10 @@ from ..framework.flags import get_flag, define_flag
 __all__ = ["apply_update", "apply_updates", "maybe_master_state",
            "wants_master"]
 
-# r5 measurement note (tools/profile_mfu.py): STANDALONE the XLA
-# elementwise update beats the Pallas kernel 775 vs ~200 GB/s, but
-# IN-STEP the full llama train step is 5.4% faster with the kernel
-# (17,559 vs 16,607 tok/s) — XLA schedules its own update fusion worse
-# inside the big program.  The in-step number is the one that matters.
+# The kernel is chosen for what it does IN the step program, where XLA
+# schedules its own update fusion worse than it does standalone (round
+# 5, pre-ledger; not measured since.  The train cell reads the kernel
+# as `fused_adamw_roofline`, PERF.md section 5).
 define_flag("use_fused_adamw", True,
             "dispatch jitted Adam/AdamW updates to the fused Pallas kernel "
             "on TPU (measured faster in-step; off = XLA's own fusion)")

@@ -12,8 +12,8 @@ running it as a serial epilogue.
 The previous offload path (param_stream.py) placed a `device_put` inside
 each block's remat region and relied on XLA's latency-hiding scheduler;
 the backward *replayed* every region and re-streamed params serially —
-host-bandwidth-bound with near-zero overlap (BENCH_r05: 0.188× baseline,
-MFU 0.075).  This module replaces scheduler luck with structure:
+host-bandwidth-bound with near-zero overlap (round 5, pre-ledger; not
+measured since).  This module replaces scheduler luck with structure:
 
   forward   h_{i+1} = block(w_i, h_i) as ONE `lax.scan` over layers.
             The carry holds a (prefetch_depth+1)-deep window of

@@ -20,8 +20,8 @@ the transfer sits INSIDE the rematerialized region:
     the current block's compute (the double-buffered prefetch the
     reference implements by hand with CUDA streams).
 
-NOTE: the scheduler-dependent overlap above measured poorly (BENCH_r05
-offload at 0.188× baseline) — `parallel/offload_pipeline.py` is the
+NOTE: the scheduler-dependent overlap above measured poorly in round 5
+(pre-ledger; not measured since) — `parallel/offload_pipeline.py` is the
 explicit double-buffered replacement for block-stacked models; this
 scope remains the mechanism for irregular models.
 

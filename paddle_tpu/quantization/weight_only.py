@@ -6,8 +6,8 @@ group-wise packed weights with fp16/bf16 scales, dequant fused into
 the serving matmul) — the `quantization` layer SURVEY.md names as
 in-scope Paddle capability surface.
 
-TPU-native: decode is HBM-bandwidth-bound (0.79x of roofline,
-BENCH_r05) — every weight byte crosses HBM once per generated token,
+TPU-native: decode is HBM-bandwidth-bound — every weight byte
+crosses HBM once per generated token,
 so storing the linear weights at 1 byte (int8) or half a byte (int4)
 per element is a direct tokens/s multiplier.  `quantize_model` packs a
 llama/gpt model's linear weights IN PLACE: each target Parameter's

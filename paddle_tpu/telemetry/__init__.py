@@ -58,11 +58,11 @@ and a span is one inactive profiler annotation (half a microsecond, no
 record, no clock read), and arming/disarming sinks or
 ``FLAGS_compile_cache_dir`` leaves every compiled program byte-identical
 (tests/test_telemetry.py and tests/test_program_spans.py assert the
-host half and that the scopes are metadata alone; bench.py's
-`_assert_telemetry_zero_overhead` the HLO across an arming cycle).
+host half and that the scopes are metadata alone;
+tests/test_program_contracts.py the HLO across an arming cycle).
 Exporters: `attach_jsonl` (step log), `attach_chrome_trace`
-(chrome://tracing / Perfetto), `dump()` (the snapshot bench.py embeds
-in its JSON lines).  `tools/telemetry_report.py` renders a JSONL log
+(chrome://tracing / Perfetto), `dump()` (one snapshot of the whole
+plane).  `tools/telemetry_report.py` renders a JSONL log
 into step medians/p99, the spans' durations and self times, each serve
 chunk's time by phase and the cache hit rate.  On the device's clock the
 spans are read from a profiler trace: `benchmark/program_spans.py`.
@@ -118,7 +118,7 @@ def dump(compact: bool = False) -> dict:
     """One snapshot of the whole plane: registry instruments, the
     compile report, the fleet identity and the (already-resolved)
     memory ledger.  `compact` trims the per-program compile records to
-    totals (what bench.py embeds per JSON line).  Never compiles —
+    totals.  Never compiles —
     pending ledger entries stay pending (memory_report() resolves)."""
     out = registry().dump()
     rep = compile_report()

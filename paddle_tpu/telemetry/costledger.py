@@ -18,14 +18,15 @@ Cost model (the plane's usual contract):
     events: `telemetry.step_event` and the serving batcher call
     `observe(label, wall_ms, cold=...)` inside their existing
     sink-guarded blocks — with no sink attached nothing here runs
-    (the zero-overhead contract bench.py asserts), and cold calls
-    (XLA compile in the wall) are excluded like every other timing
-    surface in the repo.
+    (tests/test_program_contracts.py
+    `test_observability_surface_leaves_the_train_step_identical` holds
+    the program half), and cold calls (XLA compile in the wall) are
+    excluded like every other timing surface in the repo.
   * The roofline verdict uses the backend's CALIBRATED peaks: the
-    bf16 matmul peak (bench.py's table) and the HBM stream bandwidth,
-    scaled by the CALIBRATION_r05 efficiency anchor (mfu_assumption
-    0.6 — llama-1B implied 0.689, bert-base 0.576).  Override with
-    `configure_peaks()` or the PEAK_FLOPS / PEAK_HBM_GBPS envs.
+    bf16 matmul peak and the HBM stream bandwidth (CHIP_PEAKS below),
+    scaled by an efficiency anchor of 0.6 that rests on two pre-ledger
+    points and has not been measured since.  `configure_peaks()` is
+    the override.
 
 Report shape (per program): flops, bytes_accessed, arithmetic
 intensity (flops/byte), roofline ``bound`` ("compute" when intensity
@@ -82,7 +83,6 @@ _peaks_override: Dict[str, float] = {}
 # ici = per-chip all-reduce bandwidth (bytes/s per device, the
 # bidirectional-ring figure the exposed-comm column divides by;
 # PEAK_ICI_GBPS env overrides for other fabrics — DCN, PCIe hosts).
-# bench.py's serving roofline assumes the same v5e 0.82 TB/s.
 _V4 = {"chip": "v4", "flops": 275e12, "hbm": 1.23e12, "ici": 300e9}
 _V5E = {"chip": "v5e", "flops": 197e12, "hbm": 0.82e12, "ici": 160e9}
 _V5P = {"chip": "v5p", "flops": 459e12, "hbm": 2.77e12, "ici": 600e9}
@@ -91,8 +91,8 @@ CHIP_PEAKS = {"TPU v4": _V4,
               "TPU v5 lite": _V5E, "TPU v5e": _V5E,
               "TPU v5": _V5P, "TPU v5p": _V5P,
               "TPU v6 lite": _V6E, "TPU v6e": _V6E}
-# CALIBRATION_r05 anchor: predictions at mfu_assumption 0.6 landed
-# within 0.88-1.04x of measured full steps on the real chip
+# pre-ledger anchor (two points on one chip, round 5): not measured
+# since
 CALIBRATED_EFFICIENCY = 0.6
 # CPU placeholder peaks: tier-1 exercises the plumbing, not the
 # numbers (tests pin behavior through configure_peaks).  Reached only
@@ -102,11 +102,10 @@ _CPU_PEAKS = {"chip": None, "flops": 100e9, "hbm": 50e9, "ici": 10e9}
 
 
 def _chip_peaks() -> dict:
-    """This backend's row of peaks — THE one device sniffing
-    (bench.chip_peak_flops delegates here): the CPU placeholders on a
-    CPU backend, else the CHIP_PEAKS row of the device's kind.  A device
-    the table does not know raises: an unknown chip is an error, not a
-    default."""
+    """This backend's row of peaks — THE one device sniffing: the CPU
+    placeholders on a CPU backend, else the CHIP_PEAKS row of the
+    device's kind.  A device the table does not know raises: an unknown
+    chip is an error, not a default."""
     import jax
     if jax.default_backend() == "cpu":
         return _CPU_PEAKS
@@ -120,11 +119,8 @@ def _chip_peaks() -> dict:
 
 
 def chip_peak_flops() -> float:
-    """Canonical bf16 matmul peak for this backend (bench.py's MFU
-    lines and the roofline both read it from HERE): PEAK_FLOPS env
-    override, else this device's row of CHIP_PEAKS."""
-    if "PEAK_FLOPS" in os.environ:
-        return float(os.environ["PEAK_FLOPS"])
+    """Canonical bf16 matmul peak for this backend: this device's row
+    of CHIP_PEAKS."""
     return _chip_peaks()["flops"]
 
 
@@ -172,12 +168,6 @@ def backend_peaks() -> dict:
     row = _chip_peaks()
     chip, flops, hbm = row["chip"], row["flops"], row["hbm"]
     source = f"chip-table:{chip}" if chip else "default:cpu"
-    if "PEAK_FLOPS" in os.environ:
-        flops = float(os.environ["PEAK_FLOPS"])
-        source += "+env"
-    if "PEAK_HBM_GBPS" in os.environ:
-        hbm = float(os.environ["PEAK_HBM_GBPS"]) * 1e9
-        source += "+env"
     eff = CALIBRATED_EFFICIENCY
     with _lock:
         flops = _peaks_override.get("flops_per_sec", flops)
@@ -212,8 +202,7 @@ def cost_of(compiled) -> dict:
 def model_train_flops(n_params: float, tokens: float,
                       phase: str = "full",
                       remat_flops_per_token: float = 0.0) -> float:
-    """Analytic model-FLOP accounting for dense LM training — the ONE
-    derivation tools/profile_mfu.py and bench.py's MFU lines share
+    """Analytic model-FLOP accounting for dense LM training
     (regression-pinned): 2N/tok forward, 4N/tok backward, 6N/tok full
     step; `remat_flops_per_token` adds the recompute replay FLOPs the
     hardware actually executes (bwd/full phases only)."""

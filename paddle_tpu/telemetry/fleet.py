@@ -4,8 +4,9 @@
 multi-process timeline merge).
 
 Three pieces, all HOST-plane (nothing here can touch a compiled
-program; bench.py extends the r11 byte-identical-HLO assert across the
-fleet flags):
+program; tests/test_program_contracts.py arms the fleet identity and
+the straggler flag beside the sinks and holds the train step
+byte-identical):
 
   * :class:`FleetSink` — a regular telemetry sink a WORKER attaches
     beside its JSONL log: every N `train.step` events it PUTs a compact

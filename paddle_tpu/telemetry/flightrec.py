@@ -93,10 +93,9 @@ _STEP_EVENTS = ("train.step", "serve.chunk")
 
 
 # ---------------------------------------------------------------------------
-# env fingerprint (shared with bench.py — the r16 capture-id contract:
-# perf records compare only between identical fingerprints, and an
-# incident bundle carries the same identity so a rendered incident can
-# be matched against the BENCH baselines it drifted from)
+# env fingerprint (the r16 capture-id contract: perf records compare
+# only between identical fingerprints, and an incident bundle carries
+# that identity)
 
 _FINGERPRINT_FLAGS = (
     "FLAGS_fused_ce", "FLAGS_bf16_adamw_moments",
@@ -104,18 +103,14 @@ _FINGERPRINT_FLAGS = (
     "FLAGS_kv_cache_dtype", "FLAGS_kv_page_size",
     "FLAGS_serve_spec_tokens", "FLAGS_serve_draft_layers",
 )
-_FINGERPRINT_ENVS = ("BENCH_BATCH", "BENCH_RECOMPUTE_LAYERS",
-                     "BENCH_OFFLOAD_SIZE", "BENCH_OFFLOAD_PREFETCH",
-                     "BENCH_LONGCTX_SEQ", "BENCH_LONGCTX_REMAT",
-                     "BENCH_UNET_DTYPE", "PEAK_FLOPS")
+_FINGERPRINT_ENVS = ()      # no environment name changes a metric today
 
 
 def env_fingerprint(flags=_FINGERPRINT_FLAGS,
                     envs=_FINGERPRINT_ENVS) -> dict:
     """Environment fingerprint (ISSUE 12): jax/jaxlib versions,
     backend + device kind, and the metric-relevant flags/envs.  THE one
-    derivation — bench.py's capture lines and the incident bundles
-    share it, so their capture ids agree."""
+    derivation, so every capture id agrees."""
     fp = {}
     try:
         import jax
@@ -137,10 +132,8 @@ def env_fingerprint(flags=_FINGERPRINT_FLAGS,
 
 
 def capture_id(fp: Optional[dict] = None) -> str:
-    """Stable id of the env fingerprint (BENCH_CAPTURE_ID overrides):
-    the perf sentry's match key."""
-    if "BENCH_CAPTURE_ID" in os.environ:
-        return os.environ["BENCH_CAPTURE_ID"]
+    """Stable id of the env fingerprint: the perf sentry's match
+    key."""
     import hashlib
     blob = json.dumps(fp if fp is not None else env_fingerprint(),
                       sort_keys=True).encode()
@@ -395,10 +388,10 @@ def attached() -> Optional[FlightRecorder]:
 
 
 def detach() -> Optional[FlightRecorder]:
-    """Detach and RETURN the process recorder (so a bench/test scope
-    can `restore()` it after running with its own temporary one — a
-    production recorder armed via FLAGS_flightrec_dir must survive a
-    bench run's asserts)."""
+    """Detach and RETURN the process recorder (so a test scope can
+    `restore()` it after running with its own temporary one — a
+    production recorder armed via FLAGS_flightrec_dir must survive
+    it)."""
     global _RECORDER
     rec = _RECORDER
     if rec is not None:
@@ -410,7 +403,8 @@ def detach() -> Optional[FlightRecorder]:
 def restore(recorder: Optional[FlightRecorder]
             ) -> Optional[FlightRecorder]:
     """Re-attach a recorder previously returned by `detach()` (no-op
-    on None).  The save/restore pair bench.py's asserts use."""
+    on None).  The save/restore pair a test scope uses
+    (tests/test_program_contracts.py)."""
     global _RECORDER
     if recorder is None:
         return None

@@ -9,13 +9,12 @@ Cost model (the plane's usual contract):
   * Trainers REGISTER a provider at first call — one `seen`-set check
     per step, one aval-ization (ShapeDtypeStructs, no live buffers
     pinned) on the first.  Registration never lowers, never compiles,
-    never touches the step program (bench.py's byte-identical-HLO
-    assert covers the armed plane).
+    never touches the step program (tests/test_program_contracts.py
+    `test_observability_surface_leaves_the_train_step_identical`).
   * RESOLUTION is lazy and explicit: `memory_report()` (or
     `analysis.lint_peak_hbm`) lowers+compiles each pending provider
     once and caches the stats — the cost is paid exactly when someone
-    asks for the numbers, the way tools/profile_mfu pays for its phase
-    probes.  The AOT path (FLAGS_compile_cache_dir) captures stats for
+    asks for the numbers.  The AOT path (FLAGS_compile_cache_dir) captures stats for
     free at its own `.lower()`/compile.
   * Labels are a small fixed space ("jit.TrainStep.step",
     "ShardedTrainStep.step", "serve_step.decode", ...): a new trainer

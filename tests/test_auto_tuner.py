@@ -122,3 +122,16 @@ class TestTune:
         ranked = tune(LLAMA_1B, n_devices=8, global_batch_size=64,
                       chip="v5p", compile_check=True, top_k=1)
         assert ranked
+
+
+class TestCostModelForm:
+    def test_step_time_is_linear_in_inverse_efficiency(self):
+        """e(m) = C/m + F in the assumed matmul efficiency m: the form a
+        calibration against measured steps solves for m."""
+        def e(m):
+            return estimate_step_time(LLAMA_1B, _cand(), 64, chip="v5p",
+                                      mfu_assumption=m)
+        C = (e(0.6) - e(1.0)) / (1 / 0.6 - 1.0)
+        F = e(1.0) - C
+        # two points fix the line; a third lies on it
+        np.testing.assert_allclose(C / 0.8 + F, e(0.8), rtol=1e-9)

@@ -6,7 +6,7 @@ compiles, probe contract pinned), measured-wall feeds from the live
 train.step/serve.chunk events, the FLAGS_mfu_floor drift check
 (perf.drift events + analysis.lint_mfu_floor), the named_scope
 per-layer attribution census, the shared FLOP-accounting derivations
-(paddle.flops / tools.profile_mfu regression pins), and the
+(paddle.flops / model_train_flops regression pins), and the
 memory_report share=None graceful degrade (satellite bugfix).
 """
 import os
@@ -57,10 +57,10 @@ def _tiny_llama(n_layers=1):
 # shared derivations (satellite 1: one FLOP accounting, pinned)
 
 class TestSharedDerivations:
-    def test_model_train_flops_pins_profile_mfu_accounting(self):
-        """The analytic accounting tools/profile_mfu.py always used —
-        2N/4N/6N per token, remat added to the backward — must come
-        back out of the shared helper unchanged."""
+    def test_model_train_flops_accounting_pinned(self):
+        """The analytic accounting — 2N/4N/6N per token, remat added to
+        the backward — must come back out of the shared helper
+        unchanged."""
         n, tok, remat = 1.5e9, 8192.0, 3.0e6
         f = costledger.model_train_flops
         assert f(n, tok, "fwd") == 2.0 * n * tok
@@ -486,21 +486,14 @@ class TestRooflineVerdict:
         costledger.reset()
         assert costledger.backend_peaks()["flops_per_sec"] != 123.0
 
-    def test_bench_peak_delegates_to_ledger_table(self, monkeypatch):
-        """ONE peak table for the whole repo, keyed by device_kind:
-        bench.chip_peak_flops and the ledger agree on it, the CPU
-        placeholders are reached only through a CPU backend, a device
-        the table does not know raises, and PEAK_FLOPS overrides."""
+    def test_peaks_come_from_one_table(self, monkeypatch):
+        """ONE peak table, keyed by device_kind: the CPU placeholders
+        are reached only through a CPU backend, and a device the table
+        does not know raises."""
         import types
         import jax
-        sys.path.insert(0, REPO)
-        try:
-            import bench
-        finally:
-            sys.path.pop(0)
-        monkeypatch.delenv("PEAK_FLOPS", raising=False)
         assert costledger.backend_peaks()["source"] == "default:cpu"
-        assert bench.chip_peak_flops() == costledger.chip_peak_flops() \
+        assert costledger.chip_peak_flops() \
             == costledger.backend_peaks()["flops_per_sec"]
 
         def on_chip(kind):
@@ -510,16 +503,14 @@ class TestRooflineVerdict:
                 lambda *a: [types.SimpleNamespace(device_kind=kind)])
 
         on_chip("TPU v5 lite")
-        assert bench.chip_peak_flops() \
+        assert costledger.chip_peak_flops() \
             == costledger.CHIP_PEAKS["TPU v5 lite"]["flops"] == 197e12
         assert costledger.backend_peaks()["chip"] == "v5e"
         on_chip("TPU v9 imaginary")
         with pytest.raises(ValueError, match="TPU v9 imaginary"):
-            bench.chip_peak_flops()
+            costledger.chip_peak_flops()
         with pytest.raises(ValueError, match="CHIP_PEAKS"):
             costledger.backend_peaks()
-        monkeypatch.setenv("PEAK_FLOPS", "123.0")
-        assert bench.chip_peak_flops() == 123.0
 
 
 # ---------------------------------------------------------------------------

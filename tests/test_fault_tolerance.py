@@ -760,8 +760,7 @@ class TestSigtermDrainProtocol:
 class TestZeroOverhead:
     def test_flags_off_no_ckpt_io_no_fault_hits(self, tmp_path):
         """The flags-off step path performs zero checkpoint IO and
-        never consults the armed-fault machinery (bench.py asserts the
-        same invariant before every config)."""
+        never consults the armed-fault machinery."""
         assert not fault.is_active()
         writes = ckpt.WRITE_CALLS
         hits_before = fault.hit_counts()

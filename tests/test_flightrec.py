@@ -504,7 +504,7 @@ class TestNumerics:
 
     def test_flags_off_step_returns_plain_tuple(self):
         # numerics off: the compiled call keeps its historic 4-tuple
-        # (the bench byte-identical assert covers the HLO half)
+        # (tests/test_program_contracts.py holds the HLO half)
         step, x = _mlp_step()
         step(x, x)
         assert not getattr(step, "_numerics", True)

@@ -4,6 +4,7 @@ the extras module semantics vs numpy, and in-place write-back variants.
 Reference: python/paddle/__init__.py __all__ (418 names);
 tensor/manipulation.py, math.py; yaml `inplace:` annotations.
 """
+import os
 import re
 
 import numpy as np
@@ -14,7 +15,10 @@ import paddle_tpu as paddle
 
 
 def test_reference_all_surface_complete():
-    src = open("/root/reference/python/paddle/__init__.py").read()
+    reference = "/root/reference/python/paddle/__init__.py"
+    if not os.path.exists(reference):
+        pytest.skip(f"the reference checkout is not mounted: {reference}")
+    src = open(reference).read()
     m = re.search(r"__all__ = \[(.*?)\]", src, re.S)
     names = re.findall(r"'([^']+)'", m.group(1))
     missing = [n for n in names if not hasattr(paddle, n)]

@@ -419,8 +419,8 @@ class TestFusedAdamWFp32Params:
 class TestMultiTensorAdamW:
     """Opt-in multi-tensor grouping (FLAGS_multi_tensor_adamw): small
     params flatten into ONE fused call; must match the per-param path
-    bit-for-bit semantics-wise.  Default OFF by measurement (neutral on
-    llama, -4.3% on bert — PROFILE_r05.md)."""
+    bit-for-bit semantics-wise.  Default OFF (round 5, pre-ledger:
+    neutral on llama, -4.3% on bert; not measured since)."""
 
     def test_grouped_matches_per_param(self):
         import paddle_tpu as paddle

@@ -1,8 +1,7 @@
 """Perf-regression sentry tests (ISSUE 12): tools/perf_report.py
 wired into tier-1 like the chaos_check/fleet_report selftests, plus
 unit coverage of the comparison rules (spread-aware thresholds,
-cross-environment refusal, comparable=false skip) and the bench.py
-env-fingerprint satellite."""
+cross-environment refusal, comparable=false skip)."""
 import json
 import os
 import sys
@@ -183,76 +182,3 @@ class TestCLI:
         assert cli.main(["--trajectory", str(tmp_path),
                          "--current", str(cur)]) == 1
 
-
-_MATRIX_PARENT = """
-import json, subprocess, sys
-sys.path.insert(0, %r)
-import bench
-calls = []
-def fake_run(cmd, env=None, **kw):
-    name = env["BENCH_CONFIG"]
-    calls.append(name)
-    if name == "bert":
-        return subprocess.CompletedProcess(
-            cmd, 1, stdout="", stderr="RESOURCE_EXHAUSTED: boom")
-    return subprocess.CompletedProcess(
-        cmd, 0, stdout=json.dumps({"metric": name}), stderr="")
-subprocess.run = fake_run
-rc = bench.main()
-assert calls == list(bench.CONFIGS), calls     # one child each, no retry
-assert "jax" not in sys.modules and "paddle_tpu" not in sys.modules
-print("RC", rc)
-"""
-
-
-class TestBenchMatrixParent:
-    def test_parent_stays_off_jax_and_fails_on_child_error(self):
-        """The chip belongs to one process: the default-matrix parent
-        may not import jax (or paddle_tpu) while its children need the
-        chip, runs each config once (no shared-chip retry), and exits
-        non-zero when any child printed a _bench_error."""
-        import subprocess
-        env = {k: v for k, v in os.environ.items()
-               if k not in ("BENCH_CONFIG", "BENCH_OFFLOAD")}
-        out = subprocess.run(
-            [sys.executable, "-c", _MATRIX_PARENT % REPO], env=env,
-            text=True, capture_output=True, timeout=120)
-        assert out.returncode == 0, out.stderr[-2000:]
-        lines = out.stdout.strip().splitlines()
-        assert lines[-1] == "RC 1"
-        metrics = [json.loads(l)["metric"] for l in lines[:-1]]
-        assert "bert_bench_error" in metrics
-        assert "llama" in metrics and "hybrid" in metrics
-
-
-class TestBenchFingerprint:
-    """Satellite 2: bench.py JSON lines carry the env fingerprint +
-    capture id, and one-shot lines are marked comparable=false."""
-
-    @pytest.fixture()
-    def bench(self):
-        sys.path.insert(0, REPO)
-        try:
-            import bench
-        finally:
-            sys.path.pop(0)
-        return bench
-
-    def test_emit_carries_fingerprint_and_capture_id(self, bench,
-                                                     capsys):
-        bench._emit("m", 123.0, "u", 1.0, 0.01, [1.0, 2.0, 3.0])
-        rec = json.loads(capsys.readouterr().out.strip().splitlines()[0])
-        assert rec["capture_id"] == bench._capture_id()
-        assert rec["env"]["jax"] and rec["env"]["backend"]
-        assert "FLAGS_weight_only_dtype" in rec["env"]["flags"]
-        assert "comparable" not in rec        # 3 reps: comparable
-        bench._emit("m1", 5.0, "u", 1.0, 0.0, [5.0])
-        rec = json.loads(capsys.readouterr().out.strip().splitlines()[0])
-        assert rec["comparable"] is False     # one-shot line
-
-    def test_capture_id_is_fingerprint_stable(self, bench,
-                                              monkeypatch):
-        a = bench._capture_id()
-        assert a == bench._capture_id()       # cached + deterministic
-        monkeypatch.setenv("BENCH_CAPTURE_ID", "forced")
-        assert bench._capture_id() == "forced"

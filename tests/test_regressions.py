@@ -1,4 +1,4 @@
-"""Regression tests for round-1 advisor findings (ADVICE.md).
+"""Regression tests for round-1 advisor findings.
 
 Each test pins a specific fixed defect:
   1. distributed checkpoint multi-rank shard merge

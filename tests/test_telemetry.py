@@ -12,8 +12,8 @@ The contracts under test:
     (acceptance criterion);
   * with no sink attached the plane is free: emit() is a no-op, span()
     is the profiler's annotation and nothing else (no record, no clock
-    read), programs are byte-identical (bench.py asserts the HLO half;
-    here the host half);
+    read), programs are byte-identical (tests/test_program_contracts.py
+    holds the HLO half; here the host half);
   * every producer (trainers, serving batcher, watchdog, fault
     registry, checkpoint runtime, io prefetcher) publishes its events;
   * ContinuousBatcher.stats() counters SURVIVE a forced program
@@ -921,17 +921,8 @@ class TestExportersAndFacade:
         assert rep["spans"]["serve.device_wait"]["self_max_ms"] == 8.0
         assert "serve.step" in cli.render(rep)
 
-    def test_dump_snapshot_and_bench_field(self, capsys):
+    def test_dump_compact_snapshot(self):
         telemetry.counter("x").inc(5)
         d = telemetry.dump(compact=True)
         assert d["counters"]["x"] >= 5
         assert "programs" not in d["compile"]
-        # bench.py JSON lines carry the snapshot (acceptance)
-        sys.path.insert(0, REPO)
-        try:
-            import bench
-        finally:
-            sys.path.pop(0)
-        bench._emit("m", 1.0, "u", 1.0, 0.0, [1.0])
-        rec = json.loads(capsys.readouterr().out.strip())
-        assert "telemetry" in rec and "counters" in rec["telemetry"]
