@@ -5,9 +5,21 @@ Reference: the reference fuses the AdamW update in CUDA
 tensor) so one kernel reads grad + moments + master once.  TPU-native
 equivalent: one Pallas pass that reads (grad, m, v, master) and writes
 (param, m, v[, master]) with input/output aliasing, so the state updates
-IN PLACE — the optimizer step's HBM traffic is exactly one read + one
-write of the state, and XLA never materialises intermediate fp32 copies
-of the parameter.
+IN PLACE and XLA never materialises intermediate fp32 copies of the
+parameter.
+
+The kernel's blocks are cut from the leaf as the leaf lies in memory
+(`block_plan`).  On the TPU an array is stored in tiles of its last two
+dimensions ((8, 128) fp32, (16, 128) bf16), so a reshape that changes
+the last dimension moves every byte: taking every operand as
+`[n / 1024, 1024]` puts seven leaf-sized copies around each call (78 ms
+of the train cell's 325 ms step when the kernel did; ledger, PR 29).  A
+leaf of two or more dimensions whose last two tile is therefore
+blocked `(br, bc)` over those two, whole rows where they fit, leading
+dimensions as grid axes, and nothing is reshaped: the step's HBM traffic
+for it is exactly one read and one write of the state.  Anything else
+(norm scales and biases, a shape off the tile grid, the multi-tensor
+concatenation) goes raveled and padded through a 1-D grid.
 
 Two storage schemes:
   - half params + fp32 master (reference O2): outputs a fresh half param
@@ -36,7 +48,7 @@ SMEM; weight decay and betas are compile-time constants.
 from __future__ import annotations
 
 import functools
-import os
+import math
 
 import jax
 import jax.numpy as jnp
@@ -44,20 +56,16 @@ from ._x64 import x64_off
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-__all__ = ["fused_adamw", "adamw_hostside"]
+__all__ = ["fused_adamw", "adamw_hostside", "block_plan", "moved_dtypes"]
 
-# elements per grid step: in+out blocks (up to 4 f32 + 2 bf16 each way)
-# double-buffered must fit the ~16 MiB scoped VMEM
-_CHUNK = 64 * 1024
+# flat path, elements per grid step: in+out blocks (up to 4 f32 + 2 bf16
+# each way) double-buffered must fit the ~16 MiB scoped VMEM
+_FLAT_CHUNK = 64 * 1024
 
-# block-size budget: measured NOT to move throughput (178-201 GB/s at
-# 8MB and 14MB alike — the kernel is bound elsewhere); 8MB stays safely
-# under scoped VMEM for every moment dtype
-try:
-    _VMEM_BUDGET = int(os.environ.get("PDTPU_ADAMW_VMEM_BUDGET",
-                                      8 * 1024 * 1024))
-except ValueError:
-    _VMEM_BUDGET = 8 * 1024 * 1024
+# own-shape path, bytes of one grid step's blocks, operands and results,
+# double-buffered: what lets a 14336-wide fp32 row with bf16 moments be
+# one block of 16 rows (11 MiB), under the 16 MiB of scoped VMEM
+_VMEM_BUDGET = 12 * 1024 * 1024
 
 
 def _interpret():
@@ -137,6 +145,74 @@ def _kernel_fp32_ef(lr_ref, c1_ref, c2_ref, g_ref, m_ref, v_ref, p_ref,
     _split_ef(v, v_out, ef_out)
 
 
+def moved_dtypes(grad_dtype, m_dtype, v_dtype, out_dtype, ef_dtype=None):
+    """(operand dtypes, result dtypes) of one leaf's update, in the
+    kernels' argument order: g, m, v, master[, ef] in; param, m, v
+    [, master][, ef] out (fp32 params: the param IS the master)."""
+    ef = () if ef_dtype is None else (jnp.dtype(ef_dtype),)
+    ins = (jnp.dtype(grad_dtype), jnp.dtype(m_dtype), jnp.dtype(v_dtype),
+           jnp.dtype(jnp.float32)) + ef
+    outs = (jnp.dtype(out_dtype), ins[1], ins[2])
+    if outs[0] != jnp.float32:
+        outs += (jnp.dtype(jnp.float32),)
+    return ins, outs + ef
+
+
+def block_plan(shape, dtypes):
+    """(path, grid, block) of one leaf's `pallas_call`: a pure function of
+    the leaf's shape and of the dtypes of everything the kernel moves for
+    it (operands and results, `moved_dtypes`), so it is decided at trace
+    time and the tests can ask it what the kernel will do.
+
+    "own": the last dimension is a multiple of 128 and the second-to-last
+    of the narrowest dtype's sublane packing (8 rows of 4-byte elements,
+    16 of 2-byte).  The grid runs over the leaf AS IT LIES IN MEMORY:
+    block (br, bc) of its last two dimensions, every leading dimension a
+    squeezed grid axis; no operand or result is reshaped, so on the chip
+    (tiles of the last two dimensions) nothing is relaid.  bc is the
+    widest 128-multiple divisor of the row that fits the VMEM budget
+    double-buffered at the packing's rows (the whole row is one
+    contiguous run of tiles), br then the largest divisor of the rows
+    that still fits.
+
+    "flat": everything else (1-D leaves, shapes off the tile grid, a
+    multi-tensor group's concatenation): the raveled leaf padded to whole
+    packed tiles, `_FLAT_CHUNK` elements a grid step.  For a leaf of two
+    or more dimensions that ravel is a relayout; such leaves are the
+    small ones.
+    """
+    shape = tuple(int(d) for d in shape)
+    sizes = [jnp.dtype(d).itemsize for d in dtypes]
+    sub = 32 // min(sizes)
+    if len(shape) >= 2 and shape[-1] % 128 == 0 and shape[-2] % sub == 0:
+        rows, cols = shape[-2:]
+        # elements of one block: operands and results, double-buffered
+        fit = _VMEM_BUDGET // (2 * sum(sizes))
+        bc = 128 * max(d for d in range(1, cols // 128 + 1)
+                       if (cols // 128) % d == 0 and sub * 128 * d <= fit)
+        br = sub * max(r for r in range(1, rows // sub + 1)
+                       if (rows // sub) % r == 0 and sub * r * bc <= fit)
+        lead = shape[:-2]
+        return ("own", lead + (rows // br, cols // bc),
+                (None,) * len(lead) + (br, bc))
+    padded = _flat_len(shape)
+    chunk = min(_FLAT_CHUNK, padded)
+    return "flat", (-(-padded // chunk),), (chunk,)
+
+
+def _flat_len(shape):
+    """Elements of the flat path's work array: the leaf padded to the
+    packed-tile granule (bf16 packs (16,128) sublane tiles = 2048 elems;
+    also covers fp32 (8,128) = 1024) so every block offset AND the final
+    partial block stay sublane-aligned for Mosaic."""
+    n = math.prod(shape)
+    return n + -n % 2048
+
+
+_KERNELS = {(True, False): _kernel_fp32, (True, True): _kernel_fp32_ef,
+            (False, False): _kernel_master, (False, True): _kernel_master_ef}
+
+
 def fused_adamw(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
                 eps=1e-8, wd=0.0, decoupled=True, out_dtype=jnp.bfloat16,
                 ef=None):
@@ -152,143 +228,57 @@ def fused_adamw(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
     element (the new residual).
 
     lr: scalar f32 (traced); step: scalar int (traced, 1-based).
+
+    The grid is `block_plan`'s: a leaf that tiles is blocked in its own
+    shape, and no copy surrounds the kernel.
     """
     shape = grad.shape
-    n = int(np_prod(shape))
     stepf = jnp.asarray(step, jnp.float32)
     c1 = (1.0 - jnp.float32(b1) ** stepf).reshape(1)
     c2 = (1.0 - jnp.float32(b2) ** stepf).reshape(1)
     lr1 = jnp.asarray(lr, jnp.float32).reshape(1)
 
-    # big tensors: 2-D (rows, 1024) blocks — native (8,128)/(16,128)
-    # tiling, large contiguous DMAs per grid step.  Fallback: flat 1-D
-    # chunks for shapes that don't divide.
-    lanes = 1024
-    fp32_params_mode = jnp.dtype(out_dtype) == jnp.float32
-    if n % lanes == 0 and (n // lanes) % 8 == 0:
-        # Mosaic needs the sublane dim divisible by 8 (or the full
-        # array); block rows sized so the double-buffered operand +
-        # result set stays well under the ~16 MiB scoped VMEM
-        rows = n // lanes
-        esz = (jnp.dtype(grad.dtype).itemsize + 4  # g + master
-               + 2 * jnp.dtype(m.dtype).itemsize)  # moments in
-        esz += esz if fp32_params_mode else esz + 2  # outputs
-        if ef is not None:
-            esz += 2 * jnp.dtype(ef.dtype).itemsize  # ef in + out
-        br = next((d for d in (256, 128, 64, 32, 16, 8)
-                   if rows % d == 0
-                   and 2 * d * lanes * esz <= _VMEM_BUDGET),
-                  None)
-        if br is None:
-            br = next(d for d in (256, 128, 64, 32, 16, 8)
-                      if rows % d == 0)
-            br = min(br, 8)
-            if rows % br:
-                br = None
-    else:
-        br = None
-    pad = 0
-    if br is not None:
-        work_shape = (rows, lanes)
-        grid = (rows // br,)
-        blk = pl.BlockSpec((br, lanes), lambda i: (i, 0))
-    else:
-        # flat path: pad to the packed-tile granule (bf16 packs (16,128)
-        # sublane tiles = 2048 elems; also covers fp32 (8,128)=1024) so
-        # every block offset AND the final partial block stay
-        # sublane-aligned for Mosaic
-        align = 2048
-        n_pad = -n % align
-        pad = n_pad
-        work_shape = (n + n_pad,)
-        chunk = min(_CHUNK, n + n_pad)
-        grid = ((n + n_pad + chunk - 1) // chunk,)
-        blk = pl.BlockSpec((chunk,), lambda i: (i,))
-
-    def _flat(a):
-        a = a.reshape((n,))
-        return jnp.pad(a, (0, pad)) if pad else a
-
-    def _pack(a):
-        return _flat(a) if pad else a.reshape(work_shape)
-
-    g1, m1, v1, mst1 = (_pack(grad), _pack(m), _pack(v), _pack(master))
-    ef1 = _pack(ef) if ef is not None else None
-    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     fp32_params = jnp.dtype(out_dtype) == jnp.float32
-    kw = dict(b1=b1, b2=b2, eps=eps, wd=wd, decoupled=decoupled)
+    ins = [grad, m, v, master] + ([] if ef is None else [ef])
+    in_dtypes, out_dtypes = moved_dtypes(
+        grad.dtype, m.dtype, v.dtype, out_dtype,
+        None if ef is None else ef.dtype)
+    path, grid, block = block_plan(shape, in_dtypes + out_dtypes)
+    work_shape = shape
+    if path == "flat":
+        n = math.prod(shape)
+        work_shape = (_flat_len(shape),)
+        pad = work_shape[0] - n
+        ins = [a.reshape((n,)) for a in ins]
+        if pad:
+            ins = [jnp.pad(a, (0, pad)) for a in ins]
+    blk = pl.BlockSpec(block, lambda *ids: ids)
+    # operand index counts the 3 scalar SMEM refs first; each piece of
+    # state is aliased to the result that replaces it
+    aliases = {6: 0, 4: 1, 5: 2} if fp32_params else {4: 1, 5: 2, 6: 3}
+    if ef is not None:
+        aliases[7] = len(out_dtypes) - 1
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     with x64_off():
-        if fp32_params and ef is None:
-            # operand index counts the 3 scalar SMEM refs first
-            p1, m1, v1 = pl.pallas_call(
-                functools.partial(_kernel_fp32, **kw),
-                grid=grid,
-                in_specs=[smem, smem, smem, blk, blk, blk, blk],
-                out_specs=[blk, blk, blk],
-                out_shape=[
-                    jax.ShapeDtypeStruct(work_shape, jnp.float32),
-                    jax.ShapeDtypeStruct(work_shape, m.dtype),
-                    jax.ShapeDtypeStruct(work_shape, v.dtype),
-                ],
-                input_output_aliases={6: 0, 4: 1, 5: 2},
-                name="fused_adamw",
-                interpret=_interpret(),
-            )(lr1, c1, c2, g1, m1, v1, mst1)
-            mst1 = p1
-        elif fp32_params:
-            p1, m1, v1, ef1 = pl.pallas_call(
-                functools.partial(_kernel_fp32_ef, **kw),
-                grid=grid,
-                in_specs=[smem, smem, smem, blk, blk, blk, blk, blk],
-                out_specs=[blk, blk, blk, blk],
-                out_shape=[
-                    jax.ShapeDtypeStruct(work_shape, jnp.float32),
-                    jax.ShapeDtypeStruct(work_shape, m.dtype),
-                    jax.ShapeDtypeStruct(work_shape, v.dtype),
-                    jax.ShapeDtypeStruct(work_shape, ef.dtype),
-                ],
-                input_output_aliases={6: 0, 4: 1, 5: 2, 7: 3},
-                name="fused_adamw",
-                interpret=_interpret(),
-            )(lr1, c1, c2, g1, m1, v1, mst1, ef1)
-            mst1 = p1
-        elif ef is None:
-            p1, m1, v1, mst1 = pl.pallas_call(
-                functools.partial(_kernel_master, **kw),
-                grid=grid,
-                in_specs=[smem, smem, smem, blk, blk, blk, blk],
-                out_specs=[blk, blk, blk, blk],
-                out_shape=[
-                    jax.ShapeDtypeStruct(work_shape, out_dtype),
-                    jax.ShapeDtypeStruct(work_shape, m.dtype),
-                    jax.ShapeDtypeStruct(work_shape, v.dtype),
-                    jax.ShapeDtypeStruct(work_shape, jnp.float32),
-                ],
-                input_output_aliases={4: 1, 5: 2, 6: 3},
-                name="fused_adamw",
-                interpret=_interpret(),
-            )(lr1, c1, c2, g1, m1, v1, mst1)
-        else:
-            p1, m1, v1, mst1, ef1 = pl.pallas_call(
-                functools.partial(_kernel_master_ef, **kw),
-                grid=grid,
-                in_specs=[smem, smem, smem, blk, blk, blk, blk, blk],
-                out_specs=[blk, blk, blk, blk, blk],
-                out_shape=[
-                    jax.ShapeDtypeStruct(work_shape, out_dtype),
-                    jax.ShapeDtypeStruct(work_shape, m.dtype),
-                    jax.ShapeDtypeStruct(work_shape, v.dtype),
-                    jax.ShapeDtypeStruct(work_shape, jnp.float32),
-                    jax.ShapeDtypeStruct(work_shape, ef.dtype),
-                ],
-                input_output_aliases={4: 1, 5: 2, 6: 3, 7: 4},
-                name="fused_adamw",
-                interpret=_interpret(),
-            )(lr1, c1, c2, g1, m1, v1, mst1, ef1)
-    outs = (p1, m1, v1, mst1) + ((ef1,) if ef is not None else ())
-    if pad:
-        outs = tuple(a[:n] for a in outs)
-    return tuple(a.reshape(shape) for a in outs)
+        res = pl.pallas_call(
+            functools.partial(_KERNELS[fp32_params, ef is not None],
+                              b1=b1, b2=b2, eps=eps, wd=wd,
+                              decoupled=decoupled),
+            grid=grid,
+            in_specs=[smem, smem, smem] + [blk] * len(ins),
+            out_specs=[blk] * len(out_dtypes),
+            out_shape=[jax.ShapeDtypeStruct(work_shape, d)
+                       for d in out_dtypes],
+            input_output_aliases=aliases,
+            name="fused_adamw",
+            interpret=_interpret(),
+        )(lr1, c1, c2, *ins)
+    res = list(res)
+    if path == "flat":
+        res = [(a[:n] if pad else a).reshape(shape) for a in res]
+    if fp32_params:
+        res.insert(3, res[0])     # the new param IS the master
+    return tuple(res)
 
 
 def adamw_hostside(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
@@ -327,9 +317,3 @@ def adamw_hostside(grad, m, v, master, lr, step, *, b1=0.9, b2=0.999,
         out += ((vn - v_low.astype(jnp.float32)).astype(ef.dtype),)
     return out
 
-
-def np_prod(shape):
-    out = 1
-    for s in shape:
-        out *= int(s)
-    return out

@@ -172,21 +172,93 @@ def test_kernels_per_shard_on_four_chips(topo, for_chip):
             "add_rms_norm_bwd"} <= _kernels(text)
 
 
+# the leaves of `mistral7b_train_2k` (gate / up, down, q / o, k / v,
+# embedding and head, norms), the shards ZeRO (rows) and `mp` (columns)
+# make of the MLP's, an expert stack, and a long one-dimensional operand
+# (what `multi_tensor_adamw` concatenates)
+ADAMW_LEAVES = [(4096, 14336), (14336, 4096), (4096, 4096), (4096, 1024),
+                (32768, 4096), (4096,), (2048, 14336), (4096, 7168),
+                (4, 4096, 2048), (40 * 8192,)]
+
+
+def _adamw_text(for_chip, leaf, master, ef=False):
+    """Optimized HLO of one leaf's update with its state donated: bf16
+    moments, fp32 parameter (the param IS the master, what the cell
+    trains with) or bf16 parameter + fp32 master."""
+    from paddle_tpu.ops.pallas.fused_adamw import fused_adamw
+    bf, f32 = jnp.bfloat16, jnp.float32
+
+    def f(g, m, v, mst, lr, step, e=None):
+        out = fused_adamw(g, m, v, mst, lr, step, wd=0.1, ef=e,
+                          out_dtype=bf if master else f32)
+        # an fp32 param IS the master: the step keeps one of the two.
+        # A new bf16 param goes last: jit pairs donated arguments with
+        # results of their type in order, and it has the moments' type
+        return out[1:] + out[:1] if master else out[:3] + out[4:]
+    return for_chip(f, (leaf, bf if master else f32), (leaf, bf),
+                    (leaf, bf), (leaf, f32), ((), f32), ((), jnp.int32),
+                    *([(leaf, bf)] if ef else []),
+                    donate=(1, 2, 3, 6) if ef else (1, 2, 3))
+
+
+# %name = type[dims]{layout} op(%first_operand, of an array-valued instruction
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = (\w+\[([\d,]+)\]\{[^ ]*\}) "
+                    r"([\w\-]+)\(%([\w.\-]+)")
+
+
+def _leaf_sized(text, n):
+    """The instructions of an optimized program that RELAY a whole leaf:
+    a `reshape` or `transpose` whose result has `n` elements (a reshape
+    the compiler kept moves every byte on the chip: one that is free
+    became a `bitcast`), or a `copy` of that size into another layout.
+    A copy that changes only the memory space (`S(1)`: the compiler's
+    own prefetch of a small operand into VMEM and its write-back, on
+    either side of any kernel) is not one."""
+    instrs = {m.group(1): m.groups()[1:] for m in
+              map(_INSTR.match, text.splitlines()) if m}
+    space = re.compile(r"S\(\d+\)")
+    found = []
+    for typ, dims, op, operand in instrs.values():
+        if op not in ("reshape", "copy", "transpose") \
+                or math.prod(map(int, dims.split(","))) != n:
+            continue
+        src = instrs.get(operand, ("",))[0]
+        if op == "copy" and space.sub("", src) == space.sub("", typ):
+            continue
+        found.append((op, typ))
+    return found
+
+
 @pytest.mark.parametrize("master", [False, True],
                          ids=["fp32_param", "bf16_param_fp32_master"])
-def test_fused_adamw(for_chip, master):
-    """One [hidden, ffn] leaf, bf16 moments: the fp32-param variant (the
-    param IS the master — what chip_smoke trains with) and the
-    half-param + fp32-master variant."""
-    from paddle_tpu.ops.pallas.fused_adamw import fused_adamw
-    leaf, bf, f32 = (HIDDEN, FFN), jnp.bfloat16, jnp.float32
-
-    def f(g, m, v, mst, lr, step):
-        return fused_adamw(g, m, v, mst, lr, step, wd=0.1,
-                           out_dtype=bf if master else f32)
-    text = for_chip(f, (leaf, bf if master else f32), (leaf, bf),
-                    (leaf, bf), (leaf, f32), ((), f32), ((), jnp.int32))
+@pytest.mark.parametrize("leaf", ADAMW_LEAVES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_fused_adamw(for_chip, leaf, master):
+    """The fused AdamW kernel blocks each leaf as the leaf lies in memory
+    (ISSUE 31): compiled for the chip with its state donated, a leaf's
+    update is the `fused_adamw` kernel and NO `reshape`, `copy` or
+    `transpose` of the leaf's size: operands reach the kernel as the
+    step holds them and its results are the new state, in place.
+    Before, the kernel took every operand as `[n / 1024, 1024]` and four
+    of the cell's six shapes (`[4096, 14336]`, `[14336, 4096]`, `[4096,
+    4096]`, `[32768, 4096]`) failed this with SEVEN `reshape`s each
+    (gradient, parameter and both moments in; parameter and both moments
+    out: 78 ms of a 325 ms step; ledger, PR 29), as did both shard
+    shapes, the expert stack and the long 1-D operand (`[n]` to `[n /
+    1024, 1024]` is no bitcast either); only `[4096, 1024]` and `[4096]`
+    passed."""
+    text = _adamw_text(for_chip, leaf, master)
     assert "fused_adamw" in _kernels(text)
+    assert _leaf_sized(text, math.prod(leaf)) == []
+
+
+def test_fused_adamw_error_feedback(for_chip):
+    """The same contract with the `ef` residual riding along (a fifth
+    operand and result on the one block plan)."""
+    leaf = (HIDDEN, 14336)
+    text = _adamw_text(for_chip, leaf, master=False, ef=True)
+    assert "fused_adamw" in _kernels(text)
+    assert _leaf_sized(text, math.prod(leaf)) == []
 
 
 @pytest.mark.parametrize("kv_heads", [32, 8], ids=["mha", "gqa"])

@@ -416,6 +416,241 @@ class TestFusedAdamWFp32Params:
         assert fused[-1] < fused[0]
 
 
+_ADAMW_VARIANTS = [(False, False), (False, True), (True, False),
+                   (True, True)]
+_ADAMW_VARIANT_IDS = ["fp32_param", "fp32_param_ef", "master", "master_ef"]
+# (shape, moment dtype, path).  bf16 moments pack 16 rows a tile, so
+# [24, 128] tiles only with fp32 moments.
+_ADAMW_SHAPES = [((32, 256), "bfloat16", "own"),
+                 ((48, 384), "bfloat16", "own"),
+                 ((24, 128), "float32", "own"),
+                 ((2, 32, 256), "bfloat16", "own"),
+                 ((37, 130), "bfloat16", "flat"),
+                 ((5,), "bfloat16", "flat"),
+                 ((4096,), "bfloat16", "flat"),
+                 ((3, 5, 7), "bfloat16", "flat"),
+                 ((2, 24, 128), "bfloat16", "flat"),
+                 ((24, 128), "bfloat16", "flat")]
+
+
+def _adamw_operands(shape, master, ef, moment_dtype, seed=0):
+    import jax.numpy as jnp
+    rng = np.random.RandomState(seed)
+    g_dtype = jnp.bfloat16 if master else jnp.float32
+    g = jnp.asarray(rng.randn(*shape).astype(np.float32) * 0.1, g_dtype)
+    p = jnp.asarray(rng.randn(*shape).astype(np.float32))
+    zeros = jnp.zeros(shape, moment_dtype)
+    return g, [zeros, zeros, p] + ([zeros] if ef else [])
+
+
+def _adamw_two_steps(rule, shape, master, ef, moment_dtype, view=None):
+    """[(param, m, v, master[, ef]) after step 1, after step 2] of `rule`
+    (`fused_adamw` or its host-side twin), the state carried; `view`
+    reshapes the operands first (the same elements through another
+    block plan)."""
+    import jax.numpy as jnp
+    g, state = _adamw_operands(shape, master, ef, moment_dtype)
+    if view is not None:
+        g, state = g.reshape(view), [a.reshape(view) for a in state]
+    outs = []
+    for step in (1, 2):
+        out = rule(g, state[0], state[1], state[2], 1e-2, step, wd=0.1,
+                   out_dtype=jnp.bfloat16 if master else jnp.float32,
+                   ef=state[3] if ef else None)
+        outs.append([np.asarray(a.astype(jnp.float32)).reshape(shape)
+                     for a in out])
+        state = list(out[1:])
+    return outs
+
+
+class TestFusedAdamWOwnShape:
+    """ISSUE 31: the kernel blocks a leaf in the leaf's own shape
+    (`block_plan`); the update is elementwise, so how a leaf is blocked
+    may not change it."""
+
+    @pytest.mark.parametrize("master,ef", _ADAMW_VARIANTS,
+                             ids=_ADAMW_VARIANT_IDS)
+    @pytest.mark.parametrize("shape,moment_dtype,path", _ADAMW_SHAPES,
+                             ids=lambda v: "x".join(map(str, v))
+                             if isinstance(v, tuple) else str(v))
+    def test_every_plan_gives_the_same_update(self, shape, moment_dtype,
+                                              path, master, ef):
+        """All four kernel bodies, on shapes that tile and shapes that
+        must fall to the flat path, over two steps: the leaf in its own
+        shape, the same elements raveled (the flat path, the parent's
+        arithmetic for every such leaf) and `adamw_hostside` agree to a
+        unit or two in the last place of each result's storage.  Not to
+        the bit here: XLA's CPU code contracts the products differently
+        from one block shape to the next, and the twin takes its bias
+        corrections in double (it reads a unit off the kernel at the
+        parent too).  On the chip the blocking changes no bit (PERF.md
+        section 6, PR 31: own shape against the same elements flat)."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas.fused_adamw import (
+            adamw_hostside, block_plan, fused_adamw, moved_dtypes)
+        g_dtype = jnp.bfloat16 if master else jnp.float32
+        ins, outs = moved_dtypes(
+            g_dtype, moment_dtype, moment_dtype,
+            jnp.bfloat16 if master else jnp.float32,
+            moment_dtype if ef else None)
+        if path == "own" and moment_dtype == "float32" and master:
+            path = "flat"       # the bf16 gradient packs 16 rows a tile
+        assert block_plan(shape, ins + outs)[0] == path
+        n = int(np.prod(shape))
+        assert block_plan((n,), ins + outs)[0] == "flat"
+        args = (shape, master, ef, moment_dtype)
+        own = _adamw_two_steps(fused_adamw, *args)
+        flat = _adamw_two_steps(fused_adamw, *args, view=(n,))
+        host = _adamw_two_steps(adamw_hostside, *args)
+        full = dict(rtol=1e-6, atol=3e-7)
+        # a value stored in bf16 may land one bf16 step from the other's
+        half = full if moment_dtype == "float32" else \
+            dict(rtol=2 ** -7, atol=1e-12)
+        for got, others in zip(own, zip(flat, host)):
+            p, m, v, mst = got[:4]
+            for other in others:
+                np.testing.assert_allclose(m, other[1], **half)
+                np.testing.assert_allclose(mst, other[3], **full)
+                if master:
+                    np.testing.assert_allclose(p, other[0], rtol=2 ** -7,
+                                               atol=1e-7)
+                else:
+                    np.testing.assert_array_equal(p, mst)
+                if ef:  # the pair IS the second moment: compare the sum
+                    np.testing.assert_allclose(v + got[4],
+                                               other[2] + other[4],
+                                               rtol=1e-4, atol=1e-12)
+                else:
+                    np.testing.assert_allclose(v, other[2], **half)
+
+    def test_block_plan_blocks_fit_and_tile(self):
+        """The plan's own-shape blocks: full-width where the row fits the
+        budget, sublane-aligned rows, a grid that covers the leaf, leading
+        dimensions squeezed; and the flat path's padding."""
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas import fused_adamw as fa
+        f32, bf = jnp.float32, jnp.bfloat16
+        cell = sum(fa.moved_dtypes(f32, bf, bf, f32), ())
+        for shape in [(4096, 14336), (14336, 4096), (4096, 4096),
+                      (4096, 1024), (32768, 4096), (2048, 14336),
+                      (4096, 7168), (4096, 11008), (8, 4096, 2048),
+                      (2, 3, 32, 128)]:
+            path, grid, block = fa.block_plan(shape, cell)
+            assert path == "own"
+            lead = len(shape) - 2
+            assert block[:lead] == (None,) * lead
+            assert grid[:lead] == shape[:lead]
+            br, bc = block[lead:]
+            assert br % 16 == 0 and bc % 128 == 0
+            assert (grid[-2] * br, grid[-1] * bc) == shape[-2:]
+            per_elem = sum(jnp.dtype(d).itemsize for d in cell)
+            assert 2 * br * bc * per_elem <= fa._VMEM_BUDGET
+            # the widest block that fits: the whole row, or no wider
+            # divisor of the row would fit at the packing's 16 rows
+            wider = [d for d in range(bc + 128, shape[-1] + 1, 128)
+                     if shape[-1] % d == 0
+                     and 2 * 16 * d * per_elem <= fa._VMEM_BUDGET]
+            assert not wider, (shape, block, wider)
+        # the cell's widest row is one block, a contiguous run of tiles
+        assert fa.block_plan((4096, 14336), cell)[2] == (16, 14336)
+        assert fa.block_plan((4096, 4096), cell)[2] == (64, 4096)
+        # fp32 moments: 8 rows a tile
+        assert fa.block_plan((24, 128), (f32,) * 7) == ("own", (1, 1),
+                                                        (24, 128))
+        assert fa.block_plan((5,), cell) == ("flat", (1,), (2048,))
+        assert fa.block_plan((64 * 1024 + 1,), cell) == \
+            ("flat", (2,), (64 * 1024,))
+
+    def test_cell_config_has_no_two_dim_leaf_on_the_flat_path(self):
+        """Every leaf of `mistral-7b-v0.3` as `mistral7b_train_2k` trains
+        it (fp32 parameters, bf16 moments; the benchmark's own list of
+        them), whole and as the shards ZeRO (rows halved) and `mp`
+        (columns halved) make of it: every 2-D leaf is blocked in its own
+        shape; only the norms' vectors take the flat path."""
+        import json
+        import os
+        import sys
+        import jax.numpy as jnp
+        from paddle_tpu.ops.pallas.fused_adamw import (block_plan,
+                                                       moved_dtypes)
+        bench = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark")
+        sys.path.insert(0, bench)
+        try:
+            import weights
+        finally:
+            sys.path.remove(bench)
+        with open(os.path.join(bench, "configs",
+                               "mistral-7b-v0.3.json")) as f:
+            specs = weights.leaf_specs(json.load(f))
+        dtypes = sum(moved_dtypes(jnp.float32, jnp.bfloat16, jnp.bfloat16,
+                                  jnp.float32), ())
+        for _, shape, _, _ in specs:
+            views = [shape]
+            if len(shape) == 2:
+                views += [(shape[0] // 2, shape[1]),
+                          (shape[0], shape[1] // 2)]
+            for view in views:
+                want = "own" if len(view) == 2 else "flat"
+                assert block_plan(view, dtypes)[0] == want, view
+        assert {(4096, 14336), (14336, 4096), (4096, 4096), (4096, 1024),
+                (32768, 4096), (4096, 32768), (4096,)} == \
+            {shape for _, shape, _, _ in specs}
+
+    @pytest.mark.parametrize("master,ef", _ADAMW_VARIANTS,
+                             ids=_ADAMW_VARIANT_IDS)
+    def test_apply_update_under_shard_map_matches_one_chip(self, master,
+                                                           ef):
+        """`apply_update` with the state sharded: each device's kernel sees
+        its LOCAL shard, [32, 256] of [64, 512] on a 2 x 2 mesh (own
+        shape) and [10, 130] of [20, 260] (flat), and the gathered result
+        is the one-chip kernel's."""
+        import jax
+        import jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from paddle_tpu.distributed.topology import build_mesh
+        from paddle_tpu.framework.flags import set_flags
+        from paddle_tpu.optimizer.jit_update import apply_update
+        from paddle_tpu.optimizer.optimizer import Adam
+        mesh = build_mesh(sharding=2, mp=2, devices=jax.devices()[:4])
+        spec = P("sharding", "mp")
+        hp = dict(b1=0.9, b2=0.999, eps=1e-8, decoupled=True)
+        set_flags({"fused_adamw_interpret": True})
+        try:
+            for shape in [(64, 512), (20, 260)]:
+                g, (m, v, mst, *rest) = _adamw_operands(
+                    shape, master, ef, jnp.bfloat16)
+                p = mst.astype(jnp.bfloat16) if master else mst
+                s = {"moment1": m, "moment2": v}
+                if master:
+                    s["master"] = mst
+                if ef:
+                    s["ef"] = rest[0]
+                one_p, one_s = jax.jit(
+                    lambda p, g, s: apply_update(
+                        Adam._update, p, g, s, 1e-2, 0.1, 1, hp))(p, g, s)
+                put = lambda a: jax.device_put(a, NamedSharding(mesh, spec))
+                many_p, many_s = jax.jit(
+                    lambda p, g, s: apply_update(
+                        Adam._update, p, g, s, 1e-2, 0.1, 1, hp,
+                        fused_ok=False, mesh=mesh, spec=spec))(
+                    put(p), put(g), {k: put(a) for k, a in s.items()})
+                def close(a, b):    # to a step of the result's storage
+                    tol = dict(rtol=1e-6, atol=3e-7) \
+                        if a.dtype == jnp.float32 else \
+                        dict(rtol=2 ** -7, atol=1e-7)
+                    assert a.shape == b.shape and a.dtype == b.dtype
+                    np.testing.assert_allclose(
+                        np.asarray(a.astype(jnp.float32)),
+                        np.asarray(b.astype(jnp.float32)), **tol)
+                close(many_p, one_p)
+                assert set(many_s) == set(one_s)
+                for k in one_s:
+                    close(many_s[k], one_s[k])
+        finally:
+            set_flags({"fused_adamw_interpret": False})
+
+
 class TestMultiTensorAdamW:
     """Opt-in multi-tensor grouping (FLAGS_multi_tensor_adamw): small
     params flatten into ONE fused call; must match the per-param path
