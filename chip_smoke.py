@@ -357,7 +357,7 @@ def require_paged_kernel_equals_twin(cfg, page_size):
     from paddle_tpu import ops
     from paddle_tpu.ops.pallas import paged_attention as kernel
     n_kv, heads, hd = (cfg.num_key_value_heads, cfg.num_attention_heads,
-                       cfg.head_dim)
+                       cfg.attn_head_dim)
     rng = np.random.RandomState(SEED + 2)
 
     def normal(*shape):

@@ -207,8 +207,8 @@ class NaiveGate(_GateBase):
     use_capacity = False
     use_aux = False
 
-    def __init__(self, d_model, num_experts, top_k=2, **kw):
-        super().__init__(d_model, num_experts)
+    def __init__(self, d_model, num_experts, top_k=2, dtype=None, **kw):
+        super().__init__(d_model, num_experts, dtype=dtype)
         self.top_k = top_k
 
 
@@ -287,8 +287,10 @@ class MoELayer(Layer):
     `router_width` they are (stacked style only; count must equal
     num_experts).  gate="sigmoid" takes `routed_scaling`, `router_bias`
     and `shared_hidden` (one always-on swiglu expert that wide, computed
-    whole on every chip) and has no expert biases.  `dtype`: what the
-    leaves are held in (the default float32, as every Layer).
+    whole on every chip) and has no expert biases; `expert_bias=False`
+    leaves them out under gate="naive" too (the capacity gates' einsum
+    path always has them).  `dtype`: what the leaves are held in (the
+    default float32, as every Layer).
     """
 
     def __init__(self, d_model=None, d_hidden=None, num_experts=None,
@@ -297,8 +299,11 @@ class MoELayer(Layer):
                  moe_group=None, recompute_interval=0,
                  activation="gelu", experts_held=None, router_width=None,
                  routed_scaling=1.0, router_bias=False, shared_hidden=0,
-                 dtype=None, **kw):
+                 expert_bias=True, dtype=None, **kw):
         super().__init__(dtype=dtype)
+        if not expert_bias and gate not in ("naive", "sigmoid"):
+            raise ValueError("experts without biases need a dropless "
+                             "gate (naive|sigmoid) and stacked experts")
         held_n = num_experts if num_experts else len(experts or ())
         self.first_expert, count = experts_held or (0, held_n)
         width = router_width or held_n
@@ -322,6 +327,8 @@ class MoELayer(Layer):
             kwargs = {}
             if top_k is not None and cls in (NaiveGate, SigmoidGate):
                 kwargs["top_k"] = top_k
+            if cls is NaiveGate:
+                kwargs.update(dtype=dtype)
             if cls is SigmoidGate:
                 kwargs.update(scaling=routed_scaling, bias=router_bias,
                               dtype=dtype)
@@ -350,7 +357,7 @@ class MoELayer(Layer):
                 shape=[num_experts, d_hidden, d_model],
                 default_initializer=I.XavierUniform())
             self.b1 = self.b2 = None
-            if gate != "sigmoid":
+            if gate != "sigmoid" and expert_bias:
                 self.b1 = self.create_parameter(
                     shape=[num_experts, 1, w1_h], is_bias=True)
                 self.b2 = self.create_parameter(
@@ -397,9 +404,13 @@ class MoELayer(Layer):
                 topi, topw, _ = gate.route(tokens, vals["gate"],
                                            vals.get("bias"))
             else:
-                probs = jax.nn.softmax(
-                    tokens.astype(jnp.float32)
-                    @ vals["gate"].astype(jnp.float32), axis=-1)
+                # HIGHEST, as SigmoidGate.route: a TPU's default fp32
+                # product is one bf16 pass, and the choice of experts
+                # hangs on near-ties of these scores
+                probs = jax.nn.softmax(jnp.matmul(
+                    tokens.astype(jnp.float32),
+                    vals["gate"].astype(jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST), axis=-1)
                 topv, topi = jax.lax.top_k(probs, gate.top_k)
                 topw = topv / jnp.maximum(
                     jnp.sum(topv, -1, keepdims=True), 1e-9)

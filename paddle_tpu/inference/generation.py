@@ -189,6 +189,11 @@ def generate(model, input_ids, max_new_tokens: int = 32,
 
     The compiled program is cached per (model, shape, sampling config);
     repeat calls with the same prompt shape reuse it."""
+    if getattr(model, "block_diffusion", lambda: None)() is not None:
+        raise NotImplementedError(
+            "this loop decodes one token a step; a model that generates "
+            "by diffusion over blocks is served by "
+            "inference.ContinuousBatcher")
     ids = input_ids.value if isinstance(input_ids, Tensor) \
         else jnp.asarray(np.asarray(input_ids))
     ids = ids.astype(jnp.int32)

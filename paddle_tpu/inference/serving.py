@@ -99,10 +99,20 @@ robustness flags off (bench-asserted):
 
 Greedy decoding (temperature 0) — the deterministic serving mode whose
 per-sequence outputs are testable against isolated `generate()` runs.
+
+A scan step does NOT always yield one token a slot.  A model that says it
+generates by diffusion over blocks of L tokens (`model.block_diffusion()`,
+SDAR) is served by the same two programs with a BLOCK as a slot's decode
+state: a step is a denoise or a commit pass of the slot's block and yields
+0 or L tokens (`_step_fn`, "the block schedule"); speculative decoding is
+the other such path (`_spec_step_fn`).  Hence two counts: `decode_tokens`
+are tokens the decode side COMMITTED, `decode_lanes` valid lanes it
+processed.
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import os
 import threading
 import time
@@ -166,6 +176,55 @@ def unpack_handoff(blob: bytes):
 # admission priority order, highest first; shedding walks it in reverse
 SLO_CLASSES = ("interactive", "batch", "best_effort")
 
+# what the block schedule of a model that generates by diffusion counts a
+# step, on the device, summed over a chunk's scan steps and read with its
+# tokens (beside the model's own counts, stats()).  Each with its use:
+#   diffusion_denoise_passes          slot-passes that sampled; with the
+#       blocks committed (a commit pass each) the passes the device ran
+#   diffusion_blocks_committed, diffusion_committed_block_passes
+#       blocks committed and the passes THOSE blocks had had, their denoise
+#       passes and the commit (a window's slot-passes over its blocks would
+#       count the blocks its edges cut): passes a block, the schedule's cost
+#   diffusion_tokens_unmasked, diffusion_unmasked_by_threshold
+#       lanes fixed, and those of them the confidence threshold took: the
+#       share of the work the dynamic threshold saves (0 on random weights)
+#   decode_lanes   valid lanes the decode side processed (the operation
+#       counts' unit; `decode_tokens` is tokens COMMITTED)
+DIFFUSION_COUNTERS = ("diffusion_denoise_passes",
+                      "diffusion_blocks_committed",
+                      "diffusion_committed_block_passes",
+                      "diffusion_tokens_unmasked",
+                      "diffusion_unmasked_by_threshold", "decode_lanes")
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _slot_writes(state, masks, values):
+    """{field: [B, ...]} with `values` where `masks` [B] says so."""
+    return {f: jnp.where(masks[f].reshape((-1,) + (1,) * (old.ndim - 1)),
+                         values[f], old) for f, old in state.items()}
+
+
+def _step_quotas(block_length: int, denoising_steps: int) -> List[int]:
+    """Lanes each denoising step of a block must fix at least: L // S,
+    the first L mod S steps one more (SDAR's get_num_transfer_tokens)."""
+    L, S = block_length, denoising_steps
+    return [L // S + (s < L % S) for s in range(S)]
+
+
+def _unmask_choice(conf, masked, quota, threshold):
+    """The lanes a denoise pass fixes, [B, L] bool, and whether the
+    threshold chose them, [B]: every masked lane whose confidence is above
+    `threshold` if they number at least the pass's `quota` [B], else the
+    quota's most confident masked lanes (ties: the lower lane)."""
+    conf = jnp.where(masked, conf, -jnp.inf)
+    high = conf > threshold
+    rank = jnp.argsort(jnp.argsort(-conf, axis=-1, stable=True),
+                       axis=-1, stable=True)
+    by_threshold = jnp.sum(high, axis=-1) >= quota
+    return jnp.where(by_threshold[:, None], high,
+                     masked & (rank < quota[:, None])), by_threshold
+
+
 # what one step() spends its time in, each a `serve.<phase>` span.
 # `deliver` lies inside `harvest` as a span; as a duration `harvest` is
 # what is left without it, so the six add up to at most the step
@@ -179,6 +238,12 @@ class Request:
     prompt: np.ndarray              # [L] int32
     max_new_tokens: int
     tokens: List[int] = field(default_factory=list)
+    # parallel to `tokens`, for a model that generates by diffusion over
+    # blocks (empty otherwise): the denoising pass of its block at which
+    # each token was fixed — tokens are delivered in position order but
+    # fixed in confidence order, and what a token was conditioned on is
+    # the lanes fixed before it
+    token_passes: List[int] = field(default_factory=list)
     finished: bool = False
     # -- SLO / robustness state (ISSUE 9) --
     slo: str = "batch"
@@ -227,14 +292,24 @@ class Request:
     def output(self) -> np.ndarray:
         return np.asarray(self.tokens[: self.max_new_tokens], np.int32)
 
+    def output_passes(self) -> np.ndarray:
+        """token_passes of output()'s tokens."""
+        return np.asarray(self.token_passes[: self.max_new_tokens],
+                          np.int32)
+
 
 class ContinuousBatcher:
     """One model, `max_batch_size` sequence slots, insert/evict at
     chunk boundaries, chunked prefill through the decode program, KV
-    in a shared page pool.
+    in a shared page pool.  How a slot decodes is the MODEL's to say:
+    one token a step (autoregressive), or a block of L tokens in up to
+    denoising_steps + 1 passes (`model.block_diffusion()`; paged layout,
+    unified role, no speculation, prefill_chunk and page_size multiples
+    of L — anything else is refused here, not served wrong).
 
-    chunk: decode steps per host round trip (a per-token host loop
-    would pay a dispatch and a blocking transfer per token).
+    chunk: decode steps (for a block-diffusion model: passes) per host
+    round trip (a per-token host loop would pay a dispatch and a
+    blocking transfer per token).
     prefill_chunk: prompt tokens a slot being admitted consumes per
     step of the admission-mode scan (the decode-shaped chunk width).
     admit_steps: scan length of the admission-mode program (defaults
@@ -295,6 +370,12 @@ class ContinuousBatcher:
         self.chunk = int(chunk)
         self.prefill_chunk = max(1, min(int(prefill_chunk),
                                         self.max_len))
+        # how the model generates: None = one token a step; else the
+        # block schedule's four numbers (models.llama.LlamaConfig)
+        self._diffusion = getattr(model, "block_diffusion",
+                                  lambda: None)()
+        self.block_len = self._diffusion["block_length"] \
+            if self._diffusion else 1
         self.admit_steps = max(1, int(admit_steps)
                                if admit_steps is not None
                                else self.chunk // 4)
@@ -312,6 +393,11 @@ class ContinuousBatcher:
             else get_flag("serve_spec_tokens", 0)
         self.spec_k = max(0, int(k or 0))
         self._spec_w = self.spec_k + 1          # verify width
+        if self._diffusion:
+            self._refuse_for_diffusion(kv_layout, role)
+        # lanes a slot feeds the decode program a step: the token, the
+        # verify window, or the block
+        self._decode_width = max(self._spec_w, self.block_len)
         self._draft = None
         self._draft_names: List[str] = []
         self._draft_key = ()
@@ -467,8 +553,9 @@ class ContinuousBatcher:
             # evicts it: up to max(chunk, admit_steps)-1 junk decode
             # steps inside the finishing chunk (each advancing up to
             # spec_w rows under speculation), plus C-1 junk lanes
-            self._overshoot = max(self.chunk * self._spec_w,
-                                  self.admit_steps) + self._eff_chunk
+            self._overshoot = max(self.chunk * self._decode_width,
+                                  self.admit_steps * self.block_len) \
+                + self._eff_chunk
             self._alloc = PageAllocator(self.num_pages, self.page_size)
             self._plans: List[Optional[object]] = [None] * self.B
             self._cache = model.init_paged_cache(self.num_pages,
@@ -477,6 +564,17 @@ class ContinuousBatcher:
             spec = model.kv_row_spec(kv_dtype)
             self._kv_dtype = str(np.dtype(spec["dtype"]))
             self._pages_walked = spec["pages_walked"]
+            if self._diffusion and spec["scales"]:
+                raise ValueError(
+                    "int8 KV under the block schedule is not supported: "
+                    "every pass of a block rewrites its rows, and a page "
+                    "would be requantised denoising_steps + 1 times a "
+                    "block")
+            if self.page_size % self.block_len:
+                raise ValueError(
+                    f"page_size {self.page_size} is not a multiple of the "
+                    f"model's block length {self.block_len}: a shared "
+                    "prefix page must end where a block ends")
             self._page_table = jnp.zeros((self.B, self.pages_per_slot),
                                          jnp.int32)
         else:
@@ -490,11 +588,27 @@ class ContinuousBatcher:
         self._dcache = self._draft.init_cache(self.B, self._cache_len) \
             if self.spec_k else None
         self._pos = jnp.zeros((self.B,), jnp.int32)
+        # a slot's decode state: the token it feeds next, or (a model
+        # that generates by diffusion) its block — L ids, L masked
+        # flags, the pass at which each lane was fixed, and the passes
+        # the block has had (0: nothing yet, _step_fn seeds it)
         self._tok = jnp.zeros((self.B,), jnp.int32)
+        if self._diffusion:
+            BL = (self.B, self.block_len)
+            self._tok = {"ids": jnp.zeros(BL, jnp.int32),
+                         "masked": jnp.zeros(BL, bool),
+                         "fixed_at": jnp.zeros(BL, jnp.int32),
+                         "step": jnp.zeros((self.B,), jnp.int32)}
+        # the host's view of the blocks, for _kv_page_counts' replay
+        self._block_step_host = np.zeros((self.B,), np.int64)
+        self._block_masked_host = np.zeros((self.B,), np.int64)
         self._mode = jnp.zeros((self.B,), bool)  # True = prefilling
         self._plen = jnp.zeros((self.B,), jnp.int32)
         self._prompts = jnp.zeros((self.B, self.max_len), jnp.int32)
         self._done = jnp.ones((self.B,), bool)   # free slots are "done"
+        # slot -> {field: value}: what eviction and admission write of
+        # the slots' state, staged on the host until the next chunk
+        self._staged: Dict[int, dict] = {}
         self._mode_host = np.zeros((self.B,), bool)
         self._done_host = np.ones((self.B,), bool)
         self._pos_host = np.zeros((self.B,), np.int64)
@@ -519,8 +633,11 @@ class ContinuousBatcher:
         # the scan, read with the chunk's tokens, never under
         # speculation (its decode program is a different scan)
         names = getattr(model, "step_counter_names", tuple)()
-        self._counter_names = () if self.spec_k or kv_layout != "paged" \
-            else tuple(names)
+        self._model_counter_names = () if self.spec_k \
+            or kv_layout != "paged" else tuple(names)
+        # and what the block schedule counts beside them
+        self._counter_names = self._model_counter_names \
+            + (DIFFUSION_COUNTERS if self._diffusion else ())
         self._model_counts = dict.fromkeys(self._counter_names, 0)
         self._chunk_event = None        # serve.chunk's fields
         # per-request latency windows (bounded, same discipline as the
@@ -567,6 +684,31 @@ class ContinuousBatcher:
         sentinel_preflight(
             PassContext("serve", f"serve:B{self.B}", engine=self),
             level="build")
+
+    def _refuse_for_diffusion(self, kv_layout, role):
+        """What the block schedule does not compose with yet, said at
+        construction."""
+        L = self.block_len
+        if self.spec_k:
+            raise ValueError(
+                "speculative decoding drafts one token after another; a "
+                f"model that generates by diffusion over blocks of {L} "
+                "has no such draft (spec_tokens must be 0)")
+        if kv_layout != "paged":
+            raise TypeError(
+                "a model that generates by diffusion over blocks serves "
+                "through the paged pool only: the dense ring buffers' "
+                "step programs are not taught the block schedule")
+        if role != "unified":
+            raise ValueError(
+                f"role {role!r}: a hand-off ships a slot at a token "
+                "boundary, a block-diffusion slot stands inside a block; "
+                "only the unified role serves such a model")
+        if self.prefill_chunk % L:
+            raise ValueError(
+                f"prefill_chunk {self.prefill_chunk} is not a multiple of "
+                f"the model's block length {L}: prefill consumes whole "
+                "blocks")
 
     def preflight(self, *, level: str = "full", manager=None):
         """Full static sentinel over the serve step programs: the
@@ -927,6 +1069,51 @@ class ContinuousBatcher:
             _tel.emit("serve.requeue", req=req.req_id, slo=req.slo,
                       requeues=req.requeues)
 
+    def _stage(self, i: int, **fields):
+        """Stage a write of slot i's device-side state: `done`, `mode`,
+        `pos`, `plen`, `tok` (the token it feeds next; under the block
+        schedule the passes its block has had), `prompt` [max_len],
+        `pages` [pages_per_slot].  A later write of a field wins.
+        Nothing reads that state but the step programs, so every write
+        of a chunk boundary waits for `_flush_staged`."""
+        self._staged.setdefault(i, {}).update(fields)
+
+    def _flush_staged(self):
+        """Apply the staged slot writes in ONE program of fixed shapes,
+        whatever the number of slots written: a [B] mask and a
+        slot-shaped value for each field that some slot writes.  (One
+        `.at[i].set` a field a slot was 13 ms of dispatch a request
+        admitted and 6-9 a request finished, all of it with the device
+        idle; requests of one length end, and are replaced, together.)"""
+        if not self._staged:
+            return
+        state = {"done": self._done, "mode": self._mode, "pos": self._pos,
+                 "plen": self._plen, "prompt": self._prompts,
+                 "tok": self._tok["step"] if self._diffusion
+                 else self._tok}
+        if self.kv_layout == "paged":
+            state["pages"] = self._page_table
+        written = {f for fields in self._staged.values() for f in fields}
+        state = {f: state[f] for f in sorted(written)}
+        masks = {f: np.zeros((self.B,), bool) for f in state}
+        values = {f: np.zeros(a.shape, a.dtype) for f, a in state.items()}
+        for i, fields in self._staged.items():
+            for f, v in fields.items():
+                masks[f][i] = True
+                values[f][i] = v
+        self._staged.clear()
+        new = _slot_writes(state, masks, values)
+        self._done = new.get("done", self._done)
+        self._mode = new.get("mode", self._mode)
+        self._pos = new.get("pos", self._pos)
+        self._plen = new.get("plen", self._plen)
+        self._prompts = new.get("prompt", self._prompts)
+        if "pages" in new:
+            self._page_table = new["pages"]
+        if "tok" in new:
+            self._tok = dict(self._tok, step=new["tok"]) \
+                if self._diffusion else new["tok"]
+
     def _clear_slot(self, i: int):
         """Free slot i's device-side state: done/mode flags, and for
         the paged layout the slot's page mapping (prompt pages stay
@@ -935,19 +1122,17 @@ class ContinuousBatcher:
         if self._slots[i] is not None:
             self._no_freeze.discard(self._slots[i].req_id)
         self._slots[i] = None
-        self._done = self._done.at[i].set(True)
-        self._mode = self._mode.at[i].set(False)
-        self._mode_host[i] = False
-        self._done_host[i] = True
         # a free slot rests at depth 0: the paged kernel's walk follows
         # pos, and a stale depth would walk the null page that many times
-        self._pos = self._pos.at[i].set(0)
+        self._stage(i, done=True, mode=False, pos=0)
+        self._mode_host[i] = False
+        self._done_host[i] = True
         self._pos_host[i] = 0
         if self.kv_layout == "paged" and self._plans[i] is not None:
             self._alloc.release_plan(self._plans[i])
             self._plans[i] = None
-            self._page_table = self._page_table.at[i].set(
-                jnp.zeros((self.pages_per_slot,), jnp.int32))
+            self._stage(i, pages=np.zeros((self.pages_per_slot,),
+                                          np.int32))
 
     def _fault_slot(self, i: int, reason: str = "decode_fault"):
         """Slot i's decode came back poisoned: evict the slot (pages
@@ -976,11 +1161,13 @@ class ContinuousBatcher:
             # have discarded `tokens` and the re-decode may not have
             # caught back up to the delivered frontier
             req.tokens[:] = req.delivered_tokens
+            del req.token_passes[len(req.tokens):]
             req.partial = True
         else:
             # the re-decode re-emits every token bit-exactly (greedy),
             # so discarding them keeps tokens_produced honest
             req.tokens.clear()
+            req.token_passes.clear()
         # the re-decode re-serves the request from scratch: its spans
         # must describe the decode the user actually received
         req.t_admit = None
@@ -1217,7 +1404,13 @@ class ContinuousBatcher:
         used/free/cached, prefix-hit tokens, evictions, pool bytes).
         prefill_tokens/decode_tokens count scan-level WORK (every lane
         the programs advanced); tokens_produced counts only tokens that
-        survive to request outputs."""
+        survive to request outputs.  For a model that generates by
+        diffusion over blocks, decode_tokens are the tokens its commit
+        passes EMITTED (junk blocks of a finished slot included, as an
+        autoregressive slot's junk steps are), so prefill_token_share
+        keeps its meaning; the lanes the decode side processed, L a
+        slot-pass, are `decode_lanes`, beside the schedule's other
+        counts (DIFFUSION_COUNTERS)."""
         n = self._chunk_count
         occ = (self._occupancy_total / (n * self.B)) if n else 0.0
         times = sorted(self._chunk_times)
@@ -1368,6 +1561,7 @@ class ContinuousBatcher:
             if hit_eos:
                 req.tokens = req.tokens[: req.tokens.index(self.eos)
                                         + 1]
+                del req.token_passes[len(req.tokens):]
             if self.role == "prefill" and not self._mode_host[i] \
                     and req.tokens and not hit_eos \
                     and not self._done_host[i] \
@@ -1382,7 +1576,7 @@ class ContinuousBatcher:
                 # role flip strands mid-decode slots: they hand off
                 # at pos = prompt_len + k and resume elsewhere.
                 self._handoff_ready[req.req_id] = i
-                self._done = self._done.at[i].set(True)
+                self._stage(i, done=True)
                 self._done_host[i] = True
                 continue
             # capacity clamp: a slot whose ring buffer filled stops
@@ -1516,19 +1710,19 @@ class ContinuousBatcher:
                           chunk=self._chunk_no)
                 buf = np.zeros((self.max_len,), np.int32)
                 buf[: len(req.prompt)] = req.prompt
-                self._prompts = self._prompts.at[i].set(
-                    jnp.asarray(buf))
-                self._plen = self._plen.at[i].set(len(req.prompt))
-                self._tok = self._tok.at[i].set(0)
-                self._done = self._done.at[i].set(False)
+                # tok 0: an autoregressive slot's first input, or (the
+                # block schedule) no pass yet: the scan seeds the block
+                # from the prompt's tail when the slot first decodes
+                self._stage(i, prompt=buf, plen=len(req.prompt), tok=0,
+                            done=False)
+                self._block_step_host[i] = 0
                 self._done_host[i] = False
                 start = 0
                 if plan is not None:
                     self._plans[i] = plan
                     row = np.zeros((self.pages_per_slot,), np.int32)
                     row[: len(plan.pages)] = plan.pages
-                    self._page_table = self._page_table.at[i].set(
-                        jnp.asarray(row))
+                    self._stage(i, pages=row)
                     if plan.cow is not None:
                         # copy-on-write at the divergence boundary:
                         # clone the partially-matched page into the
@@ -1544,11 +1738,18 @@ class ContinuousBatcher:
                     start = plan.shared_tokens
                 # prefix-shared tokens are already resident: prefill
                 # starts at the divergence, or straight to decode when
-                # only the final prompt token remains
-                self._pos = self._pos.at[i].set(start)
+                # only the final prompt token remains.  Under the block
+                # schedule a row's K/V hangs on every token of its
+                # block, so shared rows are rounded down to whole blocks
+                # (a page ends where a block ends, and a mid-page
+                # divergence resumes in the slot's private copy), only
+                # the prompt's WHOLE blocks prefill, and its tail goes
+                # to the first block
+                L = self.block_len
+                start = start // L * L
+                prefilling = start < len(req.prompt) // L * L
+                self._stage(i, pos=start, mode=prefilling)
                 self._pos_host[i] = start
-                prefilling = start < len(req.prompt)
-                self._mode = self._mode.at[i].set(prefilling)
                 self._mode_host[i] = prefilling
 
     # -- compiled pieces ---------------------------------------------------
@@ -1568,6 +1769,8 @@ class ContinuousBatcher:
             # the draft — K and the draft's identity are part of what
             # the program baked in (satellite 2)
             base += ("spec", self.spec_k) + self._draft_key
+        if self._diffusion:
+            base += ("diffusion",) + tuple(self._diffusion.values())
         return base
 
     def _page_copy_fn(self):
@@ -1600,6 +1803,8 @@ class ContinuousBatcher:
         if role != "unified" and self.kv_layout != "paged":
             raise TypeError("disaggregated roles need kv_layout="
                             "'paged' (the hand-off ships pages)")
+        if self._diffusion:
+            self._refuse_for_diffusion(self.kv_layout, role)
         self.role = role
 
     def _page_export_fn(self):
@@ -1705,6 +1910,11 @@ class ContinuousBatcher:
         if self.role == "prefill":
             raise RuntimeError("prefill-role batcher cannot import a "
                                "hand-off")
+        if self._diffusion:
+            raise ValueError(
+                "a hand-off ships a slot at a token boundary, a "
+                "block-diffusion slot stands inside a block; only the "
+                "unified role, without hand-offs, serves such a model")
         if self.kv_layout != "paged":
             raise TypeError("import_handoff needs the paged KV layout")
         if int(meta["page_size"]) != self.page_size \
@@ -1775,20 +1985,14 @@ class ContinuousBatcher:
             self._handoff_bytes += nbytes
             buf = np.zeros((self.max_len,), np.int32)
             buf[: len(prompt)] = prompt
-            self._prompts = self._prompts.at[i].set(jnp.asarray(buf))
-            self._plen = self._plen.at[i].set(len(prompt))
-            self._tok = self._tok.at[i].set(
-                int(req.tokens[-1]) if req.tokens else 0)
-            self._done = self._done.at[i].set(False)
-            self._done_host[i] = False
             self._plans[i] = plan
             row = np.zeros((self.pages_per_slot,), np.int32)
             row[: len(plan.pages)] = plan.pages
-            self._page_table = self._page_table.at[i].set(
-                jnp.asarray(row))
-            self._pos = self._pos.at[i].set(pos)
+            self._stage(i, prompt=buf, plen=len(prompt),
+                        tok=int(req.tokens[-1]) if req.tokens else 0,
+                        done=False, pages=row, pos=pos, mode=False)
+            self._done_host[i] = False
             self._pos_host[i] = pos
-            self._mode = self._mode.at[i].set(False)
             self._mode_host[i] = False
             # the prompt's full chunks are valid through pos: complete
             # them now — this is the trie GRAFT that makes the prefix
@@ -1812,7 +2016,7 @@ class ContinuousBatcher:
         # conditions hold again) and the fleet livelocks on the
         # freeze/unfreeze ping-pong
         self._no_freeze.add(rid)
-        self._done = self._done.at[i].set(False)
+        self._stage(i, done=False)
         self._done_host[i] = False
 
     # -- hot-prefix replication (fleet-tier cache placement) ---------------
@@ -1874,7 +2078,41 @@ class ContinuousBatcher:
         argmax-sampled; a slot emits iff it decoded or consumed its
         FINAL prompt chunk (the emitted token then being the prompt's
         greedy first token — bit-identical to what a monolithic
-        prefill would sample).
+        prefill would sample).  That is the AUTOREGRESSIVE step: one
+        token a decoding slot.
+
+        THE BLOCK SCHEDULE (a model whose block_diffusion() gives a
+        block length L > 1; `block_core` below; `tok` in the carry is
+        then the slots' blocks).  Attention is block-causal (rows j <=
+        the end of the block that holds pos+lane), the logit of a lane
+        predicts that lane's own token, and per slot and step:
+
+          prefilling?  consume n=min(width, whole blocks left) prompt
+                       tokens; no lane needs the head.  When none is
+                       left the slot turns to decoding; the prompt's
+                       last plen mod L tokens go to its first block
+          decoding?    feed its block at lanes 0..L-1 at pos (a multiple
+                       of L; n=L): a fresh block holds the prompt's
+                       tokens at positions below plen and [MASK] in the
+                       rest.  While a lane is masked the pass DENOISES:
+                       in every masked lane x0 = argmax and its softmax
+                       confidence (fp32); all masked lanes above the
+                       threshold are fixed if they number at least the
+                       pass's quota, else the quota's most confident;
+                       pos stays.  With no lane masked the pass COMMITS:
+                       it emits the block (prompt lanes excluded), adds
+                       L to pos and leaves a fresh block
+          free/done?   n=0
+
+        The pad-lane discipline carries over: every pass of a block
+        writes K/V rows pos..pos+L-1 (a denoise pass with [MASK] in
+        some lanes); each pass overwrites what the one before left
+        there, and the rows are FINAL only after the commit pass, which
+        is the last to write them before pos moves on.  No query of
+        another block can see them earlier: they lie past every
+        committed row.  The scan yields [B, steps * L] tokens with -1
+        holes (the speculative program's harvest contract) and beside
+        them the pass at which each was fixed.
         """
         key = self._program_key(width, length)
         # first_use consults the MODEL-level store, not this batcher's
@@ -1909,9 +2147,95 @@ class ContinuousBatcher:
         draft = self._draft
         draft_names = self._draft_names
         counted = bool(self._counter_names)
+        n_model_counts = len(self._model_counter_names)
+        diffusion = self._diffusion
         from ..jit import _swapped_state
 
         def build():
+            def block_core(carry):
+                """One [B, C] step of the block schedule (_step_fn's
+                docstring), over the same carry layout."""
+                L, S = (diffusion["block_length"],
+                        diffusion["denoising_steps"])
+                (cache, dcache, page_table, blk, pos, mode, plen,
+                 prompts, done) = carry
+                prefilling = mode & ~done
+                decoding = ~mode & ~done
+                lanes = jnp.arange(C, dtype=jnp.int32)
+                idx = jnp.clip(pos[:, None] + lanes[None], 0,
+                               max_len - 1)
+                pref_x = jnp.take_along_axis(prompts, idx, axis=1)
+                # a block no pass has had yet: the prompt's tail, then
+                # [MASK].  Masked is a FLAG, not `id == mask`: a prompt
+                # may hold the mask id
+                fresh = (blk["step"] == 0)[:, None]
+                given = (pos[:, None] + lanes[None, :L]) < plen[:, None]
+                ids = jnp.where(
+                    fresh, jnp.where(given, pref_x[:, :L],
+                                     diffusion["mask_token_id"]),
+                    blk["ids"])
+                masked = jnp.where(fresh, ~given, blk["masked"])
+                fixed_at = jnp.where(fresh, jnp.where(given, -1, 0),
+                                     blk["fixed_at"])
+                dec_x = jnp.concatenate(
+                    [ids, jnp.zeros((ids.shape[0], C - L), jnp.int32)],
+                    axis=1)
+                x = jnp.where(prefilling[:, None], pref_x, dec_x)
+                whole = plen // L * L
+                n_valid = jnp.where(
+                    prefilling, jnp.minimum(C, whole - pos),
+                    jnp.where(decoding, L, 0)).astype(jnp.int32)
+                counts = None
+                if n_model_counts:
+                    from ..incubate.distributed.models.moe import \
+                        StepCounters
+                    counts = StepCounters(lanes[None] < n_valid[:, None])
+                # the head on the block's lanes only
+                lg, cache = model.forward_cached_paged(
+                    x, cache, page_table, pos, counts, head_lanes=L)
+                with jax.named_scope("diffusion.sample"):
+                    lg = lg.astype(jnp.float32)
+                    x0 = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+                    top = jnp.max(lg, axis=-1, keepdims=True)
+                    conf = 1.0 / jnp.sum(jnp.exp(lg - top), axis=-1)
+                    quota = jnp.asarray(_step_quotas(L, S), jnp.int32)[
+                        jnp.clip(blk["step"], 0, S - 1)]
+                    fix, by_threshold = _unmask_choice(
+                        conf, masked, quota,
+                        diffusion["confidence_threshold"])
+                with jax.named_scope("diffusion.update"):
+                    any_masked = jnp.any(masked, axis=-1)
+                    denoise = decoding & any_masked
+                    commit = decoding & ~any_masked
+                    fix = fix & denoise[:, None]
+                    emit = commit[:, None] & (fixed_at >= 0)
+                    out_tok = jnp.where(emit, ids, -1)
+                    out_pass = jnp.where(emit, fixed_at, -1)
+                    spent = jnp.sum(jnp.where(commit, blk["step"] + 1, 0))
+                    blk = {"ids": jnp.where(fix, x0, ids),
+                           "masked": masked & ~fix,
+                           "fixed_at": jnp.where(
+                               fix, blk["step"][:, None], fixed_at),
+                           "step": jnp.where(denoise, blk["step"] + 1, 0)}
+                    finishing = prefilling & (pos + n_valid >= whole)
+                    pos = pos + jnp.where(commit, L,
+                                          jnp.where(prefilling, n_valid, 0))
+                    mode = mode & ~finishing
+                    # a slot whose next block would start past its depth
+                    done = done | (pos >= max_len)
+                    n_pref = jnp.sum(jnp.where(prefilling, n_valid, 0))
+                    n_dec = jnp.sum(emit.astype(jnp.int32))
+                    schedule = jnp.stack([
+                        jnp.sum(denoise), jnp.sum(commit), spent,
+                        jnp.sum(fix),
+                        jnp.sum(fix & by_threshold[:, None]),
+                        jnp.sum(decoding) * L]).astype(jnp.int32)
+                if counts is not None:
+                    schedule = jnp.concatenate([counts.vector(), schedule])
+                carry = (cache, dcache, page_table, blk, pos, mode, plen,
+                         prompts, done)
+                return carry, (out_tok, n_pref, n_dec, schedule, out_pass)
+
             def step_core(carry):
                 """One [B, C] step over the shared carry layout; the
                 draft (speculation on) consumes the SAME x at the same
@@ -1978,14 +2302,35 @@ class ContinuousBatcher:
                                    counts.vector())
                 return carry, (out_tok, n_pref, n_dec)
 
+            def merged(steps):
+                """[K, n] counts of a block-schedule scan -> [n]: the
+                model's by its own rule, the schedule's summed."""
+                out = [jnp.sum(steps[:, n_model_counts:], axis=0)]
+                if n_model_counts:
+                    from ..incubate.distributed.models.moe import \
+                        StepCounters
+                    out.insert(0, StepCounters.merge(
+                        steps[:, :n_model_counts]))
+                return jnp.concatenate(out)
+
             def run_scan(cache, dcache, page_table, tok, pos, mode,
                          plen, prompts, done):
                 def body(carry, _):
-                    return step_core(carry)
+                    return block_core(carry) if diffusion \
+                        else step_core(carry)
                 carry = (cache, dcache, page_table, tok, pos, mode,
                          plen, prompts, done)
                 carry, ys = jax.lax.scan(body, carry, None, length=K)
                 toks, n_pref, n_dec = ys[:3]
+                if diffusion:
+                    # [K, B, L] -> [B, K * L]: a slot's row is its
+                    # emission stream in position order, -1 = no token
+                    # (the speculative program's harvest contract), and
+                    # beside it the pass at which each was fixed
+                    def stream(a):
+                        return a.transpose(1, 0, 2).reshape(a.shape[1], -1)
+                    return (carry, stream(toks), jnp.sum(n_pref),
+                            jnp.sum(n_dec), merged(ys[3]), stream(ys[4]))
                 counts = ()
                 if counted:
                     # a counting model's program has one more output
@@ -2043,6 +2388,7 @@ class ContinuousBatcher:
         return _model_program_cache(model, key, build)
 
     def _carry_args(self):
+        self._flush_staged()
         if self.kv_layout == "paged":
             pt = self._page_table
         else:
@@ -2223,7 +2569,8 @@ class ContinuousBatcher:
         elif self.spec_k:
             fn = self._spec_step_fn(record=False)
         else:
-            fn = self._step_fn(1, self.chunk, record=False)
+            fn = self._step_fn(self._decode_width, self.chunk,
+                               record=False)
         if self.spec_k:
             return fn.lower(self._param_vals(),
                             self._draft_param_vals(),
@@ -2239,7 +2586,11 @@ class ContinuousBatcher:
         kernel's IS the frontier, the latent XLA walk's is the deepest
         slot's block).  Replayed on the host from `_pos_host` and the slots' prompts as step_core advances
         them, no device read; a speculative decode step counts the one
-        token it is sure to advance.  (0, 0) for the dense layout."""
+        token it is sure to advance, and under the block schedule a
+        pass fixes the quota's lanes and no more (what the confidence
+        threshold fixes beyond it is in the data: a block then commits
+        earlier than counted here, and the count is a lower bound).
+        (0, 0) for the dense layout."""
         if self.kv_layout != "paged":
             return 0, 0
         from ..ops.pallas.paged_attention import pages_walked as to_frontier
@@ -2251,6 +2602,12 @@ class ContinuousBatcher:
         plen = np.array([len(r.prompt) if r is not None else 0
                          for r in self._slots], np.int64)
         live = walked = 0
+        if self._diffusion:
+            L, S = self.block_len, self._diffusion["denoising_steps"]
+            quotas = np.array(_step_quotas(L, S))
+            whole = plen // L * L
+            step = self._block_step_host.copy()
+            n_masked = self._block_masked_host.copy()
         for _ in range(steps):
             n = held = pages_walked(pos, width, self.page_size,
                                     self.pages_per_slot)
@@ -2260,6 +2617,21 @@ class ContinuousBatcher:
             walked += int(n.sum())
             live += int(held[occupied].sum())
             filling = mode & ~done
+            if self._diffusion:
+                decoding = ~mode & ~done
+                # a fresh block masks what lies past the prompt
+                n_masked = np.where(step == 0,
+                                    L - np.clip(plen - pos, 0, L), n_masked)
+                commit = decoding & (n_masked == 0)
+                n_masked -= np.where(
+                    decoding & ~commit,
+                    np.minimum(quotas[np.clip(step, 0, S - 1)], n_masked), 0)
+                step = np.where(decoding & ~commit, step + 1, 0)
+                pos += np.where(filling, np.minimum(width, whole - pos),
+                                np.where(commit, L, 0))
+                mode &= ~(filling & (pos >= whole))
+                done |= pos >= self.max_len
+                continue
             pos += np.where(filling, np.minimum(width, plen - pos), ~done)
             mode &= ~(filling & (pos >= plen))
             done |= pos >= self.max_len - 1
@@ -2276,12 +2648,13 @@ class ContinuousBatcher:
         t0 = time.perf_counter()
         kind = "admit" if mixed else "decode"
         ck = self._chunk_no
-        n_emit = n_acc = None
-        counted = []        # a counting model's one more output
-        # the chunk's program by its width and scan length (_spec_w is
-        # 1 without speculation)
+        n_emit = n_acc = passes = None
+        counted = []        # a counting model's one more output (and,
+        #                     under the block schedule, the tokens' passes)
+        # the chunk's program by its width and scan length
+        # (_decode_width is 1 for one token a step)
         width, steps = (self.prefill_chunk, self.admit_steps) if mixed \
-            else (self._spec_w, self.chunk)
+            else (self._decode_width, self.chunk)
         pages = self._kv_page_counts(width, steps)
         try:
             with self._phase("dispatch", kind=kind, chunk=ck,
@@ -2322,6 +2695,8 @@ class ContinuousBatcher:
                          self._mode, self._plen, self._prompts,
                          self._done, toks, n_pref, n_dec, *counted) = fn(
                             self._param_vals(), *self._carry_args())
+                        if self._diffusion:
+                            passes = counted.pop()
         except fault.FaultError:
             self._chunk_retries += 1
             self._consecutive_chunk_faults += 1
@@ -2351,17 +2726,24 @@ class ContinuousBatcher:
         # ONE batched host transfer per chunk — each device_get is a
         # blocking round trip, so fetching tokens/mode/done/pos/counters
         # separately would pay it six times per boundary
+        block = (self._tok["step"], self._tok["masked"]) \
+            if self._diffusion else None
         with self._phase("device_wait", kind=kind, chunk=ck):
             (toks, mode_h, done_h, pos_h, n_pref, n_dec, n_emit,
-             n_acc, counted) = jax.device_get(
+             n_acc, counted, passes, block) = jax.device_get(
                 (toks, self._mode, self._done, self._pos, n_pref, n_dec,
-                 n_emit, n_acc, counted))
+                 n_emit, n_acc, counted, passes, block))
+        if block is not None:
+            self._block_step_host = np.array(block[0], np.int64)
+            self._block_masked_host = np.sum(block[1], axis=1,
+                                             dtype=np.int64)
         counts = dict(zip(self._counter_names,
                           (int(v) for vec in counted for v in vec)))
         with self._phase("harvest", chunk=ck, **counts):
             self._count_model(counts)
             self._harvest(kind, t0, np.asarray(toks), mode_h, done_h,
-                          pos_h, int(n_pref), int(n_dec), n_emit, n_acc)
+                          pos_h, int(n_pref), int(n_dec), n_emit, n_acc,
+                          passes)
         return True
 
     def _count_model(self, counts):
@@ -2373,11 +2755,12 @@ class ContinuousBatcher:
                 if name.endswith("_max") else old + v
 
     def _harvest(self, kind, t0, toks, mode_h, done_h, pos_h, n_pref,
-                 n_dec, n_emit, n_acc):
+                 n_dec, n_emit, n_acc, passes=None):
         """What the host does with a chunk's outputs (`toks` is [B, K],
-        or [B, K*(k+1)] under speculation): the fault sweep, the
-        accounting, the prefix trie's progress, each slot's new tokens
-        and their delivery."""
+        [B, K*(k+1)] under speculation, or [B, K*L] with `passes`
+        beside it under the block schedule; -1 = no token): the fault
+        sweep, the accounting, the prefix trie's progress, each slot's
+        new tokens and their delivery."""
         from ..distributed import fault
         from .. import telemetry as _tel
         self._mode_host = np.array(mode_h)
@@ -2481,6 +2864,9 @@ class ContinuousBatcher:
             if req is None:
                 continue
             req.tokens.extend(int(t) for t in toks[i] if t >= 0)
+            if passes is not None:
+                req.token_passes.extend(
+                    int(p) for t, p in zip(toks[i], passes[i]) if t >= 0)
             if req.t_first is None and req.tokens:
                 req.t_first = t_harvest
                 req.first_token_chunk = self._chunk_no

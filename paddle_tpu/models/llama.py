@@ -119,21 +119,70 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0
     qk_rope_head_dim: int = 0
     v_head_dim: int = 0
+    # In LlamaAttention (no latent): a learned rmsnorm over each query
+    # and each key head, head_dim wide, before the rotary (Qwen3's).
     use_qk_norm: bool = False
     # a config's rope_scaling group ({"type": "deepseek_yarn", ...})
     rope_scaling: dict | None = None
-
-    @property
-    def head_dim(self):
-        return self.hidden_size // self.num_attention_heads
+    # the size of an attention head where the configuration states one
+    # (`head_dim` in its file), else None: read it as `attn_head_dim`,
+    # which then derives hidden_size // num_attention_heads.  (MLA heads
+    # have their own three sizes.)
+    head_dim: int | None = None
+    # moe_gate "naive": a dropless softmax router (fp32 softmax over all
+    # experts, top-k, weights renormalised over the chosen).  Expert
+    # biases are the MoELayer's own default; False for a model whose
+    # experts have none (only the dropless gates can do without)
+    moe_expert_bias: bool = True
+    # How the model GENERATES.  block_length 1: autoregressive, one
+    # token a step, causal attention.  block_length L > 1: by diffusion
+    # over blocks of L tokens (SDAR): attention is block-causal (query i
+    # sees key j iff j // L <= i // L, blocks aligned at position 0),
+    # the logit at position i predicts the token AT position i, and a
+    # block of [MASK] (mask_token_id) is filled in at most
+    # denoising_steps passes: every pass fixes the masked lanes whose
+    # confidence (softmax probability of the argmax) is above
+    # confidence_threshold if they number at least the pass's quota
+    # (block_length / denoising_steps), else the quota's most confident.
+    # ContinuousBatcher runs that schedule (block_diffusion()).
+    block_length: int = 1
+    denoising_steps: int = 1
+    mask_token_id: int | None = None
+    confidence_threshold: float = 0.9
 
     @property
     def latent_attention(self):
         return self.kv_lora_rank > 0
 
+    def block_diffusion(self):
+        """None for an autoregressive model, else how it generates:
+        {"block_length", "denoising_steps", "mask_token_id",
+        "confidence_threshold"}, checked."""
+        L, S = int(self.block_length), int(self.denoising_steps)
+        if L <= 1:
+            return None
+        if self.mask_token_id is None \
+                or not 0 <= self.mask_token_id < self.vocab_size:
+            raise ValueError(
+                f"block_length {L} needs a mask_token_id inside the "
+                f"vocabulary (got {self.mask_token_id})")
+        if not 1 <= S <= L:
+            raise ValueError(f"denoising_steps {S} not in 1..{L} "
+                             "(every pass fixes at least one lane)")
+        return {"block_length": L, "denoising_steps": S,
+                "mask_token_id": int(self.mask_token_id),
+                "confidence_threshold": float(self.confidence_threshold)}
+
     def expert_layer(self, layer_idx: int) -> bool:
         return self.moe_num_experts > 0 \
             and layer_idx >= self.first_k_dense_replace
+
+    @property
+    def attn_head_dim(self) -> int:
+        """An attention head's size: explicit (`head_dim`) or derived,
+        at the time it is read; a copy (`dataclasses.replace`) of a
+        config that states none still derives."""
+        return self.head_dim or self.hidden_size // self.num_attention_heads
 
     @property
     def compute_dtype(self):
@@ -220,7 +269,7 @@ class LlamaAttention(nn.Layer):
         from ..framework.tensor import Parameter
         self.config = config
         h = config.hidden_size
-        hd = config.head_dim
+        hd = config.attn_head_dim
         nh = config.num_attention_heads
         nkv = config.num_key_value_heads
         std = 1.0 / math.sqrt(h)
@@ -229,6 +278,19 @@ class LlamaAttention(nn.Layer):
         self.k_proj = Parameter(_init_weight([h, nkv * hd], std, pd))
         self.v_proj = Parameter(_init_weight([h, nkv * hd], std, pd))
         self.o_proj = Parameter(_init_weight([nh * hd, h], std, pd))
+        if config.use_qk_norm:
+            self.q_norm = Parameter(jnp.ones([hd], config.storage_dtype))
+            self.k_norm = Parameter(jnp.ones([hd], config.storage_dtype))
+
+    def _norm_names(self):
+        return ["q_norm", "k_norm"] if self.config.use_qk_norm else []
+
+    def _qk_norm(self, q, k, wqn, wkn):
+        """The per-head rmsnorms of q [.., nh, hd] and k [.., nkv, hd],
+        before the rotary."""
+        eps = self.config.rms_norm_eps
+        return (tpu_ops.xla_rms_norm(q, wqn.astype(q.dtype), eps),
+                tpu_ops.xla_rms_norm(k, wkn.astype(k.dtype), eps))
 
     def forward(self, x, cos, sin):
         cfg = self.config
@@ -236,17 +298,19 @@ class LlamaAttention(nn.Layer):
         cos_a = cos.value if isinstance(cos, Tensor) else cos
         sin_a = sin.value if isinstance(sin, Tensor) else sin
 
-        def _fn(v, wq, wk, wv, wo):
+        def _fn(v, wq, wk, wv, wo, *norms):
             from jax.ad_checkpoint import checkpoint_name
             cd = v.dtype
             b, s, h = v.shape
             q = (v @ wq.astype(cd)).reshape(b, s, cfg.num_attention_heads,
-                                            cfg.head_dim)
+                                            cfg.attn_head_dim)
             k = (v @ wk.astype(cd)).reshape(b, s, cfg.num_key_value_heads,
-                                            cfg.head_dim)
+                                            cfg.attn_head_dim)
             val = (v @ wv.astype(cd)).reshape(b, s,
                                               cfg.num_key_value_heads,
-                                              cfg.head_dim)
+                                              cfg.attn_head_dim)
+            if norms:
+                q, k = self._qk_norm(q, k, *norms)
             q, k = tpu_ops.apply_rope(q, k, cos_a, sin_a)
             # selective-recompute anchors: saving post-rope q/k/v lets the
             # flash backward replay only the attention kernel, not the
@@ -275,11 +339,14 @@ class LlamaAttention(nn.Layer):
                                              seq_axis=seq_axis,
                                              causal=True)
             if out is None:
-                out = tpu_ops.attention(q, k, val, causal=True)
+                out = tpu_ops.attention(q, k, val, causal=True,
+                                        block_length=cfg.block_length)
             out = checkpoint_name(out, "attn_out")
             return out.reshape(b, s, -1) @ wo.astype(cd)
         return run(_fn, x, self.q_proj, self.k_proj, self.v_proj,
-                   self.o_proj, name="attention")
+                   self.o_proj, *[getattr(self, n)
+                                  for n in self._norm_names()],
+                   name="attention")
 
     def _decode_qkv_rope(self, x, cos, sin):
         """Shared decode-path projection + rope for BOTH KV layouts —
@@ -288,11 +355,13 @@ class LlamaAttention(nn.Layer):
         cfg = self.config
         b, s, _ = x.shape
         q = _wo_mm(self, "q_proj", x).reshape(
-            b, s, cfg.num_attention_heads, cfg.head_dim)
+            b, s, cfg.num_attention_heads, cfg.attn_head_dim)
         k = _wo_mm(self, "k_proj", x).reshape(
-            b, s, cfg.num_key_value_heads, cfg.head_dim)
+            b, s, cfg.num_key_value_heads, cfg.attn_head_dim)
         v = _wo_mm(self, "v_proj", x).reshape(
-            b, s, cfg.num_key_value_heads, cfg.head_dim)
+            b, s, cfg.num_key_value_heads, cfg.attn_head_dim)
+        if cfg.use_qk_norm:
+            q, k = self._qk_norm(q, k, self.q_norm.value, self.k_norm.value)
         q, k = tpu_ops.apply_rope(q, k, cos, sin)
         return q, k, v
 
@@ -320,7 +389,9 @@ class LlamaAttention(nn.Layer):
                                     pos)
             v_cache = jax.vmap(upd)(v_cache, v.astype(v_cache.dtype),
                                     pos)
-        out = tpu_ops.cached_attention(q, k_cache, v_cache, pos)
+        out = tpu_ops.cached_attention(
+            q, k_cache, v_cache, pos,
+            block_length=self.config.block_length)
         out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
         return out, k_cache, v_cache
 
@@ -340,8 +411,9 @@ class LlamaAttention(nn.Layer):
         cache = dict(cache, k=kp, v=vp)
         if ks is not None:
             cache["k_scale"], cache["v_scale"] = ks, vs
-        out = tpu_ops.paged_attention(q, kp, vp, page_table, pos,
-                                      layer, ks, vs)
+        out = tpu_ops.paged_attention(
+            q, kp, vp, page_table, pos, layer, ks, vs,
+            block_length=self.config.block_length)
         out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
         return out, cache
 
@@ -353,25 +425,29 @@ class LlamaAttention(nn.Layer):
         cos_a = cos.value if isinstance(cos, Tensor) else cos
         sin_a = sin.value if isinstance(sin, Tensor) else sin
 
-        def _fn(v, wq, wk, wv):
+        def _fn(v, wq, wk, wv, *norms):
             cd = v.dtype
             b, s, h = v.shape
             q = (v @ wq.astype(cd)).reshape(b, s, cfg.num_attention_heads,
-                                            cfg.head_dim)
+                                            cfg.attn_head_dim)
             k = (v @ wk.astype(cd)).reshape(b, s, cfg.num_key_value_heads,
-                                            cfg.head_dim)
+                                            cfg.attn_head_dim)
             val = (v @ wv.astype(cd)).reshape(b, s,
                                               cfg.num_key_value_heads,
-                                              cfg.head_dim)
+                                              cfg.attn_head_dim)
+            if norms:
+                q, k = self._qk_norm(q, k, *norms)
             q, k = tpu_ops.apply_rope(q, k, cos_a, sin_a)
             return q, k, val
         return run(_fn, x, self.q_proj, self.k_proj, self.v_proj,
+                   *[getattr(self, n) for n in self._norm_names()],
                    name="qkv_rope")
 
     def core_attention(self, q, k, v):
         q, k, v = to_tensor_args(q, k, v)
-        return run(lambda a, b_, c: tpu_ops.attention(a, b_, c,
-                                                      causal=True),
+        L = self.config.block_length
+        return run(lambda a, b_, c: tpu_ops.attention(a, b_, c, causal=True,
+                                                      block_length=L),
                    q, k, v, name="core_attention")
 
     def output_proj(self, attn):
@@ -546,6 +622,9 @@ class LlamaDecoderLayer(nn.Layer):
         if self.expert_layer:
             from ..incubate.distributed.models.moe import MoELayer
             share = {}
+            if config.moe_gate == "naive":
+                share = dict(dtype=config.param_dtype or config.dtype,
+                             expert_bias=config.moe_expert_bias)
             if config.moe_gate == "sigmoid":
                 share = dict(
                     dtype=config.param_dtype or config.dtype,
@@ -742,7 +821,7 @@ class LlamaModel(nn.Layer):
             return tpu_ops.rope_cos_sin(
                 seq_len, cfg.qk_rope_head_dim, cfg.rope_theta, jnp.float32,
                 position_ids=position_ids, scaling=cfg.rope_scaling)
-        return tpu_ops.rope_cos_sin(seq_len, cfg.head_dim, cfg.rope_theta,
+        return tpu_ops.rope_cos_sin(seq_len, cfg.attn_head_dim, cfg.rope_theta,
                                     jnp.float32, position_ids=position_ids)
 
     def kv_row_spec(self, kv_dtype=None):
@@ -766,7 +845,7 @@ class LlamaModel(nn.Layer):
             return {"pools": {"kv": row}, "dtype": dt, "scales": 0,
                     "pages_walked": tpu_ops.latent_pages_walked}
         from ..ops.pallas.paged_attention import pages_walked
-        row = (cfg.num_key_value_heads, cfg.head_dim)
+        row = (cfg.num_key_value_heads, cfg.attn_head_dim)
         return {"pools": {"k": row, "v": row}, "dtype": dt,
                 "scales": cfg.num_key_value_heads if quant else 0,
                 "pages_walked": pages_walked}
@@ -780,7 +859,7 @@ class LlamaModel(nn.Layer):
                 "latent (MLA) attention serves through the paged pool "
                 "only (init_paged_cache / forward_cached_paged); the dense "
                 "ring-buffer layout has no latent form")
-        shape = (batch, max_len, cfg.num_key_value_heads, cfg.head_dim)
+        shape = (batch, max_len, cfg.num_key_value_heads, cfg.attn_head_dim)
         dt = cfg.compute_dtype
         return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
                 for _ in self.layers]
@@ -805,7 +884,7 @@ class LlamaModel(nn.Layer):
             return {"kv": jnp.zeros((num_pages, len(self.layers),
                                      page_size, width), dt)}
         shape = (num_pages, len(self.layers), cfg.num_key_value_heads,
-                 page_size, cfg.head_dim)
+                 page_size, cfg.attn_head_dim)
         cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
         if quant:
             sshape = shape[:3]
@@ -848,7 +927,7 @@ class LlamaModel(nn.Layer):
         s = input_ids.shape[1]
         positions = jnp.asarray(pos, jnp.int32)[..., None] \
             + jnp.arange(s, dtype=jnp.int32)
-        cos, sin = tpu_ops.rope_cos_sin(s, cfg.head_dim, cfg.rope_theta,
+        cos, sin = tpu_ops.rope_cos_sin(s, cfg.attn_head_dim, cfg.rope_theta,
                                         jnp.float32,
                                         position_ids=positions)
         x = jnp.take(self.embed_tokens.value,
@@ -907,6 +986,12 @@ class LlamaForCausalLM(nn.Layer):
     def kv_row_spec(self, kv_dtype=None):
         return self.llama.kv_row_spec(kv_dtype)
 
+    def block_diffusion(self):
+        """How the model generates, for the batcher (beside kv_row_spec
+        and step_counter_names): None = autoregressive, else
+        LlamaConfig.block_diffusion()'s description."""
+        return self.config.block_diffusion()
+
     def step_counter_names(self):
         """Names of the int32 counts a serve step of this model
         accumulates on the device (incubate...moe.StepCounters): those
@@ -927,11 +1012,16 @@ class LlamaForCausalLM(nn.Layer):
         return _wo_mm(self, "lm_head", x)
 
     def forward_cached_paged(self, input_ids, cache, page_table, pos,
-                             counters=None):
-        """Paged twin of forward_cached: returns (logits, new_cache)."""
+                             counters=None, head_lanes=None):
+        """Paged twin of forward_cached: returns (logits, new_cache).
+        `head_lanes` n: logits [b, n, V] of the first n lanes only (the
+        block of a model that generates by diffusion: no other lane of a
+        step needs the head)."""
         x, cache = self.llama.forward_cached_paged(input_ids, cache,
                                                    page_table, pos,
                                                    counters)
+        if head_lanes is not None:
+            x = x[:, :head_lanes]
         return self._lm_logits(x), cache
 
     def forward_cached(self, input_ids, cache, pos):
@@ -1015,7 +1105,7 @@ class EarlyExitDraft:
 
     def init_cache(self, batch: int, max_len: int):
         cfg = self.config
-        shape = (batch, max_len, cfg.num_key_value_heads, cfg.head_dim)
+        shape = (batch, max_len, cfg.num_key_value_heads, cfg.attn_head_dim)
         dt = cfg.compute_dtype
         return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
                 for _ in range(self.num_layers)]

@@ -148,15 +148,32 @@ def gqa_weighted_v(w, v):
     return out.reshape(b, h, sq, d)
 
 
+def block_end(pos, block_length):
+    """Last position of the block of `block_length` that holds position
+    `pos` (blocks aligned at 0): what a BLOCK-causal query at `pos` sees
+    up to — every token of its own block, both ways, and all of every
+    earlier block (generation by diffusion over blocks).  Callers ask it
+    only for block_length > 1: block length 1 is the causal mask, and
+    each attention traces for it the expression it always traced."""
+    return pos // block_length * block_length + (block_length - 1)
+
+
 def xla_attention(q, k, v, mask=None, causal=False, scale=None,
-                  dropout_p=0.0):
+                  dropout_p=0.0, block_length=1):
     """Reference math of phi flash_attn kernel, XLA-fused.
-    q/k/v: [b, s, h, d] (paddle flash-attn layout).  fp32 softmax."""
+    q/k/v: [b, s, h, d] (paddle flash-attn layout).  fp32 softmax.
+    `block_length` > 1 (with `causal`): the block-causal mask, key j
+    visible to query i iff j // L <= i // L."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     s = scale if scale is not None else 1.0 / (d ** 0.5)
     logits = gqa_scores(q, k) * s
-    if causal:
+    if causal and block_length > 1:
+        row = jnp.arange(sq, dtype=jnp.int32)[:, None] + (sk - sq)
+        cm = jnp.arange(sk, dtype=jnp.int32)[None] \
+            <= block_end(row, block_length)
+        logits = jnp.where(cm[None, None], logits, -1e30)
+    elif causal:
         cm = jnp.tril(jnp.ones((sq, sk), bool), k=sk - sq)
         logits = jnp.where(cm[None, None], logits, -1e30)
     if mask is not None:
@@ -173,7 +190,8 @@ def xla_attention(q, k, v, mask=None, causal=False, scale=None,
     return out.transpose(0, 2, 1, 3).astype(q.dtype)
 
 
-def cached_attention(q, k_cache, v_cache, q_pos0, scale=None):
+def cached_attention(q, k_cache, v_cache, q_pos0, scale=None,
+                     block_length=1):
     """Incremental-decode attention against a fixed-size KV ring buffer.
 
     q: [b, s_new, h, d] (queries for the tokens being appended);
@@ -181,7 +199,9 @@ def cached_attention(q, k_cache, v_cache, q_pos0, scale=None):
     valid; q_pos0: int32 scalar — global position of q's first token —
     or a PER-SLOT [b] vector (continuous batching: each sequence sits
     at its own depth).  Query i of slot b attends cache slots
-    j <= q_pos0[b] + i.
+    j <= q_pos0[b] + i; with `block_length` L > 1 (a model that
+    generates by diffusion over blocks) slots j <= block_end(q_pos0[b] +
+    i, L): its whole block and every earlier one.
 
     The vector form with s_new > 1 is the CHUNKED-PREFILL contract
     (inference/serving.py): a mixed batch where some slots decode one
@@ -209,12 +229,16 @@ def cached_attention(q, k_cache, v_cache, q_pos0, scale=None):
     pos0 = jnp.asarray(q_pos0, jnp.int32)
     if pos0.ndim == 0:
         pos_q = pos0 + jnp.arange(sq, dtype=jnp.int32)[:, None]
+        if block_length > 1:
+            pos_q = block_end(pos_q, block_length)
         valid = jnp.arange(sk, dtype=jnp.int32)[None, :] <= pos_q
         logits = jnp.where(valid[None, None], logits, -1e30)
     else:
         # PER-SLOT positions ([b] vector): each sequence in the batch
         # sits at its own depth — the continuous-batching decode form
         pos_q = pos0[:, None] + jnp.arange(sq, dtype=jnp.int32)[None]
+        if block_length > 1:
+            pos_q = block_end(pos_q, block_length)
         valid = jnp.arange(sk, dtype=jnp.int32)[None, None, :] \
             <= pos_q[:, :, None]
         logits = jnp.where(valid[:, None], logits, -1e30)
@@ -327,7 +351,8 @@ def _check_paged_args(q, k_pool, k_scale, v_scale):
 
 
 def xla_paged_attention(q, k_pool, v_pool, page_table, pos, layer,
-                        k_scale=None, v_scale=None, scale=None):
+                        k_scale=None, v_scale=None, scale=None,
+                        block_length=1):
     """jnp twin of pallas.paged_attention: materialize each slot's
     logical KV view with a `take`-based gather over the page table,
     dequant (int8 pools), then EXACTLY the dense cached_attention math
@@ -350,11 +375,13 @@ def xla_paged_attention(q, k_pool, v_pool, page_table, pos, layer,
             B, P_slot * ps, n_kv, hd)
 
     return cached_attention(q, gather(k_pool, k_scale),
-                            gather(v_pool, v_scale), pos, scale)
+                            gather(v_pool, v_scale), pos, scale,
+                            block_length)
 
 
 def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
-                    k_scale=None, v_scale=None, scale=None):
+                    k_scale=None, v_scale=None, scale=None,
+                    block_length=1):
     """Decode attention against the paged KV pool: Pallas kernel on TPU
     (it copies each slot's LIVE pages of this layer itself, all kv
     heads of a page a transfer, and applies an int8 pool's scales
@@ -362,15 +389,17 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
     elsewhere and for shapes the kernel's `supports` predicate refuses.
     The choice is made from the shapes alone: whatever the kernel
     raises — a lowering or compiler refusal included — reaches the
-    caller."""
+    caller.  `block_length`: as ops.cached_attention's."""
     _check_paged_args(q, k_pool, k_scale, v_scale)
     if _on_tpu():
         from .pallas import paged_attention as _k
         if _k.supports(k_pool.shape):
             return _k.paged_attention(q, k_pool, v_pool, page_table, pos,
-                                      layer, k_scale, v_scale, scale)
+                                      layer, k_scale, v_scale, scale,
+                                      block_length=block_length)
     return xla_paged_attention(q, k_pool, v_pool, page_table, pos,
-                               layer, k_scale, v_scale, scale)
+                               layer, k_scale, v_scale, scale,
+                               block_length)
 
 
 # ---------------------------------------------------------------------------
@@ -470,13 +499,18 @@ def latent_paged_attention(q_lat, q_rope, pool, page_table, pos, layer,
     return (acc / l[..., None]).astype(q_lat.dtype).reshape(B, C, h, R)
 
 
-def attention(q, k, v, mask=None, causal=False, scale=None, dropout_p=0.0):
+def attention(q, k, v, mask=None, causal=False, scale=None, dropout_p=0.0,
+              block_length=1):
     """Flash kernel or XLA, chosen from the backend setting and the
     shapes (flash_attention.supports) — never from an exception: what
-    the kernel raises reaches the caller."""
+    the kernel raises reaches the caller.  A block-causal mask
+    (`block_length` > 1) is XLA's: the flash kernel has the causal one."""
     backend = _attention_backend
     if backend == "auto":
         backend = "pallas" if _on_tpu() else "xla"
+    if block_length > 1:
+        return xla_attention(q, k, v, mask, causal, scale, dropout_p,
+                             block_length)
     if backend == "pallas" and mask is None and dropout_p == 0.0:
         from .pallas import flash_attention as _k
         out = _run_kernel(

@@ -18,7 +18,9 @@ Layout contract (paddle_tpu.models.llama.init_paged_cache):
   page_table     [B, pages_per_slot] int32; entry 0 is the reserved
                  null page (reads masked by position)
   pos            [B] int32 >= 0 — per-slot write depth; query lane c of
-                 slot b attends rows <= pos[b] + c
+                 slot b attends rows <= pos[b] + c, or with a block
+                 length L > 1 (generation by diffusion over blocks)
+                 rows <= the end of the block of L that holds pos[b] + c
 
 Grid: one step per (slot, block of `hb` kv heads) — `hb` is all of
 n_kv wherever that fits VMEM (`_blocking`).  Inside a step a loop runs
@@ -99,7 +101,7 @@ def _blocking(n_kv, rows, page_size, head_dim, kv_itemsize, q_itemsize,
 
 
 def _kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest, scale,
-            page_size, group, q_len, hb, T, quant):
+            page_size, group, q_len, hb, T, quant, block_length):
     if quant:
         ks_ref, vs_ref, *rest = rest
     o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref, buf_ref = rest
@@ -161,6 +163,9 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest, scale,
     # column c' of block i sits at global position (i*T)*ps + c'
     qpos = pos + jax.lax.broadcasted_iota(
         jnp.int32, (rows, T * ps), 0) // group
+    if block_length > 1:
+        # block-causal: a row sees to the end of its own block
+        qpos = qpos // block_length * block_length + (block_length - 1)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, T * ps), 1)
     col_page = jax.lax.broadcasted_iota(jnp.int32, (1, T * ps), 1) // ps
 
@@ -249,10 +254,14 @@ def supports(pool_shape, interpret=None) -> bool:
 
 def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                     k_scale=None, v_scale=None, scale=None,
-                    interpret=None, vmem_budget=_VMEM_BUDGET):
+                    interpret=None, vmem_budget=_VMEM_BUDGET,
+                    block_length=1):
     """q: [B, C, h, d]; pools [P, L, n_kv, ps, d]; page_table
     [B, P_slot] int32; pos [B] int32.  Returns [B, C, h, d] in
-    q.dtype.  Raises ValueError for shapes `supports` refuses —
+    q.dtype.  `block_length` L > 1: the block-causal mask (the walk's
+    bound, pages_walked, already reaches pos + C - 1: the caller keeps
+    pos and C multiples of L).  Raises ValueError for shapes `supports`
+    refuses —
     ops.paged_attention asks the predicate first and takes the jnp
     twin for those.  `vmem_budget` is the tests' handle on the head
     blocking; callers leave it alone."""
@@ -278,13 +287,15 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                  posv, jnp.asarray(layer, jnp.int32).reshape(1),
                  k_scale if quant else None, v_scale if quant else None,
                  scale=float(scale if scale is not None else d ** -0.5),
-                 interpret=bool(interp), vmem_budget=int(vmem_budget))
+                 interpret=bool(interp), vmem_budget=int(vmem_budget),
+                 block_length=int(block_length))
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("scale", "interpret", "vmem_budget"))
+                   static_argnames=("scale", "interpret", "vmem_budget",
+                                    "block_length"))
 def _call(q, k_pool, v_pool, pt, pos, layer, k_scale, v_scale, *, scale,
-          interpret, vmem_budget):
+          interpret, vmem_budget, block_length=1):
     B, C, h, d = q.shape
     P, L, n_kv, ps, _ = k_pool.shape
     P_slot = pt.shape[1]
@@ -321,7 +332,8 @@ def _call(q, k_pool, v_pool, pt, pos, layer, k_scale, v_scale, *, scale,
                 (1, P_slot, n_kv), lambda w, *prefetched: (w // nh, 0, 0),
                 memory_space=pltpu.SMEM))
     kern = functools.partial(_kernel, scale=scale, page_size=ps,
-                             group=group, q_len=C, hb=hb, T=T, quant=quant)
+                             group=group, q_len=C, hb=hb, T=T, quant=quant,
+                             block_length=block_length)
     with x64_off():
         out = pl.pallas_call(
             kern,

@@ -387,3 +387,40 @@ def test_dropless_experts_grouped_product(for_chip):
     text = for_chip(f, ((tokens, d), bf), ((tokens, 128), jnp.float32),
                     ((held, d, 2 * f_), bf), ((held, f_, d), bf))
     assert text.count("ragged-dot") >= 2
+
+
+@pytest.mark.parametrize("width", [4, 32], ids=["block", "admit"])
+def test_paged_attention_under_the_block_mask(for_chip, width):
+    """The kernel at sdar30b_serve_gen_c64's geometry (64 slots x 66 pages
+    of 16 rows, 32 query heads of 128 on 4 kv heads: group 8), block-causal
+    over blocks of 4: a decode pass's 4 lanes and an admission step's 32."""
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    slots, pages_per_slot, kv_heads, layers = 64, 66, 4, 7
+    pool_s = ((1 + slots * pages_per_slot, layers, kv_heads, PAGE_SIZE,
+               HEAD_DIM), jnp.bfloat16)
+
+    def f(q, kp, vp, pt, pos):
+        return paged_attention(q, kp, vp, pt, pos, layers - 1,
+                               block_length=4)
+    text = for_chip(f, ((slots, width, HEADS, HEAD_DIM), jnp.bfloat16),
+                    pool_s, pool_s, ((slots, pages_per_slot), jnp.int32),
+                    ((slots,), jnp.int32))
+    assert "paged_attention" in _kernels(text)
+
+
+def test_dropless_experts_all_held(for_chip):
+    """The softmax-routed expert layer with every expert of the router held
+    (128 experts 768 wide behind hidden 2048, 8 a token, a decode pass's
+    256 lanes): still the grouped-product kernel."""
+    import paddle_tpu  # noqa: F401  (x64 on, as every program of the repo)
+    from paddle_tpu.incubate.distributed.models.moe import dropless_experts
+    held, d, f_, k, tokens = 128, 2048, 768, 8, 256
+
+    def f(x, probs, valid, w1, w2):
+        topv, topi = jax.lax.top_k(probs, k)
+        return dropless_experts(x, topi, topv, w1, w2, "swiglu", valid=valid)
+    bf = jnp.bfloat16
+    text = for_chip(f, ((tokens, d), bf), ((tokens, held), jnp.float32),
+                    ((tokens,), jnp.bool_), ((held, d, 2 * f_), bf),
+                    ((held, f_, d), bf))
+    assert text.count("ragged-dot") >= 2

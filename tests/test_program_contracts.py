@@ -448,3 +448,35 @@ def test_serve_programs_donate_every_carry(plain_model):
     for kw in (dict(spec_tokens=2, draft_layers=1), {}):
         bat = ContinuousBatcher(plain_model, **_GEOMETRY, **_PLAIN, **kw)
         assert not lint_serve_programs(bat), kw
+
+
+def test_block_schedule_leaves_a_token_models_programs_alone(plain_model):
+    """A model with block length 1 compiles step programs without a
+    block carry, a `diffusion.` scope or an extra output; one that
+    generates by diffusion has all three, under keys of their own, and
+    donates the block with the other carries."""
+    from paddle_tpu.analysis import lint_serve_programs
+    bat = ContinuousBatcher(plain_model, **_GEOMETRY, **_PLAIN)
+    assert bat.block_len == 1 and bat._tok.shape == (2,)
+    for mixed in (False, True):
+        lowered = bat.lower_step(mixed=mixed)
+        assert "diffusion." not in lowered.as_text(debug_info=True)
+        # the eight carries, the tokens, the two token counts
+        assert len(lowered.out_info) == 11
+    paddle.seed(7)
+    blocks = LlamaForCausalLM(llama_tiny_config(
+        num_hidden_layers=1, hidden_size=32, intermediate_size=64,
+        num_attention_heads=2, num_key_value_heads=2, vocab_size=64,
+        block_length=4, denoising_steps=2, mask_token_id=63))
+    dif = ContinuousBatcher(blocks, **_GEOMETRY, **_PLAIN)
+    assert sorted(dif._tok) == ["fixed_at", "ids", "masked", "step"]
+    assert dif._program_key(4, dif.chunk) != bat._program_key(1, bat.chunk)
+    for mixed in (False, True):
+        lowered = dif.lower_step(mixed=mixed)
+        text = lowered.as_text(debug_info=True)
+        assert "diffusion.sample" in text and "diffusion.update" in text
+        # the block where the token was, and two more outputs: the
+        # schedule's counts and the tokens' passes
+        assert sorted(lowered.out_info[2]) == sorted(dif._tok)
+        assert len(lowered.out_info) == 13
+    assert not lint_serve_programs(dif)

@@ -167,6 +167,50 @@ def test_admission_no_recompile_per_prompt_length(model):
             and k[0] == "serve_step"] == []
 
 
+@pytest.mark.parametrize("layout", ["paged", "dense"])
+def test_a_boundary_s_slot_writes_are_one_program(model, layout, monkeypatch):
+    """What eviction and admission write of the slots' device state is
+    staged on the host and applied by ONE program of fixed shapes a chunk
+    boundary, whatever the number of requests that ended and were admitted
+    there (requests of one length end together: a dispatch a field a slot
+    was the host's largest cost in such a burst), and that program compiles
+    once."""
+    from paddle_tpu.inference import serving
+    calls = []
+    sound = serving._slot_writes
+    monkeypatch.setattr(
+        serving, "_slot_writes",
+        lambda state, masks, values: calls.append(
+            {f: int(m.sum()) for f, m in masks.items()})
+        or sound(state, masks, values))
+    before = sound._cache_size()
+    rng = np.random.RandomState(5)
+    prompts = [rng.randint(1, 128, L).astype(np.int32)
+               for L in (5, 9, 6, 12, 7, 10, 4, 8)]
+    bat = ContinuousBatcher(model, max_batch_size=4, max_len=64, chunk=4,
+                            kv_layout=layout)
+    ids = [bat.submit(p, 6) for p in prompts[:4]]
+    bat.step()
+    assert len(calls) == 1 and calls[0]["prompt"] == 4 \
+        and not bat._staged
+    # equal lengths: the four end in one chunk and four replace them
+    steps = 1
+    while bat.active == 4 and not bat._finished:
+        bat.step()
+        steps += 1
+    ids += [bat.submit(p, 6) for p in prompts[4:]]
+    n = len(calls)
+    bat.step()
+    assert len(calls) == n + 1 and calls[-1]["prompt"] == 4 \
+        and calls[-1]["done"] == 4
+    outs = bat.run()
+    assert len(calls) <= steps + 1 + bat.stats()["admit_chunks"] \
+        + bat.stats()["decode_chunks"]
+    assert sound._cache_size() - before <= 2     # admission; eviction alone
+    for rid, prompt in zip(ids, prompts):
+        np.testing.assert_array_equal(outs[rid], _isolated(model, prompt, 6))
+
+
 # ---------------------------------------------------------------------------
 # streaming token callbacks (ISSUE 11 satellite: the r13 leftover)
 
