@@ -17,13 +17,17 @@ calls, on ONE TPU chip and in ONE process:
           at this geometry and at the benchmark's serve cell's, where
           both sides' time a call is printed, and a second batcher
           answers two requests from an int8 pool.
+  latent  the latent-attention (MLA) kernel is held to ITS XLA twin on one
+          pool of 576-wide rows at the latent serve cell's geometry (64
+          slots, ragged depths, decode and admission widths), both sides'
+          time a call printed.
 
 For both, the compiled program's text must hold the Pallas kernels
 (`tpu_custom_call`): flash attention, rms norm, rope and fused AdamW in the
 train step, paged attention in the serve step.  The first failure of any
 phase ends the run with a non-zero exit code; nothing is caught.
 
-    python3 chip_smoke.py            # one chip, both phases
+    python3 chip_smoke.py            # one chip, all three phases
     python3 chip_smoke.py --chips 4  # ONLY the four-chip sharded trainer
                                      # and its one-chip comparison
 
@@ -326,6 +330,11 @@ def require_equal_to_generate(model, prompts, served):
 # defaults): 24 slots, 66 pages of 16 rows a slot, admission width 32.
 CELL_SLOTS, CELL_PAGES_PER_SLOT, CELL_CHUNK = 24, 66, 32
 TIMED_CALLS = 20
+# the latent (MLA) serve cell's geometry, sarvam105b_serve_chat_c64: 64
+# slots x 256 pages of 16 rows, 64 heads on rows of 512 + 64, one layer of
+# the pool (0.3 GB)
+LATENT_SLOTS, LATENT_PAGES_PER_SLOT, LATENT_CHUNK = 64, 256, 32
+LATENT_HEADS, LATENT_RANK, LATENT_ROPE, LATENT_SCALE = 64, 512, 64, 0.135
 
 
 def ms_a_call(fn, args):
@@ -340,6 +349,18 @@ def ms_a_call(fn, args):
         out = fn(*args)
     jax.block_until_ready(out)
     return (time.perf_counter() - t0) / TIMED_CALLS * 1e3
+
+
+def twin_gap(got, want):
+    """(fields to print, within tolerance?) of a kernel's output against
+    its twin's: PAGED_TOL_STEPS bf16 steps at the largest output,
+    relative and absolute."""
+    import numpy as np
+    tol = PAGED_TOL_STEPS * float(bf16_step(np.abs(want).max()))
+    fields = dict(max_abs_difference=float(np.abs(got - want).max()),
+                  largest_output=float(np.abs(want).max()), tolerance=tol)
+    return fields, bool(np.isfinite(got).all() and np.allclose(
+        got, want, rtol=PAGED_TOL_STEPS * 2.0 ** -8, atol=tol))
 
 
 def require_paged_kernel_equals_twin(cfg, page_size):
@@ -405,8 +426,7 @@ def require_paged_kernel_equals_twin(cfg, page_size):
                          "kernel")
                 got = np.asarray(fn(*args), np.float32)
                 want = np.asarray(twin(*args), np.float32)
-                tol = PAGED_TOL_STEPS * float(bf16_step(np.abs(want).max()))
-                err = float(np.abs(got - want).max())
+                gap, close = twin_gap(got, want)
                 times = dict(kernel_ms_a_call=ms_a_call(fn, args),
                              twin_ms_a_call=ms_a_call(twin, args)) \
                     if name == "cell" else {}
@@ -414,14 +434,78 @@ def require_paged_kernel_equals_twin(cfg, page_size):
                     geometry=name, pool="int8" if quant else "bf16",
                     width=width, live_pages=int(np.sum(kernel.pages_walked(
                         np.asarray(depths), width, page_size, per_slot))),
-                    max_abs_difference=err, largest_output=float(
-                        np.abs(want).max()), tolerance=tol, **times))
-                if not np.isfinite(got).all() or not np.allclose(
-                        got, want, rtol=PAGED_TOL_STEPS * 2.0 ** -8,
-                        atol=tol):
-                    fail(f"serve: the paged kernel leaves its twin by {err} "
+                    **gap, **times))
+                if not close:
+                    fail(f"serve: the paged kernel leaves its twin "
                          f"({name} geometry, pool int8={quant}, width "
-                         f"{width}, tolerance {tol})")
+                         f"{width}): {gap}")
+
+
+def phase_latent(page_size=16):
+    """The latent-attention kernel (ops/pallas/latent_attention.py)
+    against ops.xla_latent_paged_attention on one bf16 pool filled by the
+    repo's own writer (ops.latent_kv_update), scattered pages, at the
+    latent serve cell's geometry: decode (C = 1) and admission (C = 32)
+    widths; depths ragged as that cell's traffic leaves them (lognormal,
+    median 768, 64 to 3.5 k rows), among them a slot at depth 0, depths on
+    both sides of a page's and of a block's end, the table's last row, and
+    a free slot whose table is all null pages.  Each line carries both
+    sides' time a call (either includes 1.0 ms in which XLA copies the
+    pool, an argument here, out of the pages-minor layout it gives a
+    576-wide array: a step program pays that once a chunk)."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import ops
+    from paddle_tpu.ops.pallas import latent_attention as kernel
+    from paddle_tpu.ops.pallas.paged_attention import pages_walked
+    slots, per_slot, width = LATENT_SLOTS, LATENT_PAGES_PER_SLOT, \
+        LATENT_RANK + LATENT_ROPE
+    rng = np.random.RandomState(SEED + 3)
+
+    def normal(*shape):
+        return jnp.asarray(rng.standard_normal(shape), jnp.bfloat16)
+    n_pages = 1 + slots * per_slot                 # page 0: the null page
+    n_rows = per_slot * page_size
+    shape = (n_pages, 1, page_size, width)
+    if not kernel.supports(shape, LATENT_RANK, jnp.bfloat16):
+        fail(f"latent: latent_attention.supports refuses the pool {shape}")
+    table = 1 + rng.permutation(n_pages - 1).reshape(slots, per_slot)
+    table[1] = 0                                   # a free slot
+    table = jnp.asarray(table, jnp.int32)
+    write = jax.jit(ops.latent_kv_update, static_argnums=(4,),
+                    donate_argnums=(0,))
+    pool = jnp.zeros(shape, jnp.bfloat16)
+    for r0 in range(0, n_rows, 512):
+        pool = write(pool, table, jnp.full((slots,), r0, jnp.int32),
+                     normal(slots, 512, width), 0)
+    depths = np.clip(np.exp(rng.normal(np.log(768.0), 0.8, slots)),
+                     64, 3500).astype(np.int64)
+    depths[:8] = (0, 0, 15, 16, 255, 256, 257, n_rows - LATENT_CHUNK)
+    fn = jax.jit(ops.latent_paged_attention, static_argnums=(5, 6))
+    twin = jax.jit(ops.xla_latent_paged_attention, static_argnums=(5, 6))
+    for lanes in (1, LATENT_CHUNK):
+        q_lat = normal(slots, lanes, LATENT_HEADS, LATENT_RANK)
+        q_rope = normal(slots, lanes, LATENT_HEADS, LATENT_ROPE)
+        args = (q_lat, q_rope, pool, table, jnp.asarray(depths, jnp.int32),
+                0, LATENT_SCALE)
+        if "latent_attention" not in kernels_in(
+                fn.lower(*args).compile().as_text()):
+            fail("latent: ops.latent_paged_attention compiled without its "
+                 "kernel")
+        got = np.asarray(fn(*args), np.float32)
+        want = np.asarray(twin(*args), np.float32)
+        gap, close = twin_gap(got, want)
+        say(phase="latent", latent_kernel_vs_twin=dict(
+            width=lanes, live_pages=int(np.sum(pages_walked(
+                depths, lanes, page_size, per_slot))),
+            twin_pages=int(np.sum(ops.latent_pages_walked(
+                depths, lanes, page_size, per_slot))),
+            **gap, kernel_ms_a_call=ms_a_call(fn, args),
+            twin_ms_a_call=ms_a_call(twin, args)))
+        if not close:
+            fail(f"latent: the latent kernel leaves its twin (width "
+                 f"{lanes}): {gap}")
 
 
 def phase_serve():
@@ -554,6 +638,7 @@ def main():
         phase_train()
         release("train")
         phase_serve()
+        phase_latent()
     say(xla_cache=telemetry.compile_report()["xla_cache"])
     print(json.dumps({"ok": True, "device": device}), flush=True)
 

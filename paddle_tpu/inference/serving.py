@@ -2583,8 +2583,8 @@ class ContinuousBatcher:
         dispatched — `live`: up to the frontier of each occupied slot;
         `walked`: what the attention walks of all B (the bound its own
         module states, handed over in the model's kv_row_spec: the K/V
-        kernel's IS the frontier, the latent XLA walk's is the deepest
-        slot's block).  Replayed on the host from `_pos_host` and the slots' prompts as step_core advances
+        kernel's and the latent kernel's IS the frontier, the latent XLA
+        walk's is the deepest slot's block).  Replayed on the host from `_pos_host` and the slots' prompts as step_core advances
         them, no device read; a speculative decode step counts the one
         token it is sure to advance, and under the block schedule a
         pass fixes the quota's lanes and no more (what the confidence

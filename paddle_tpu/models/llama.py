@@ -842,8 +842,11 @@ class LlamaModel(nn.Layer):
                     "page would quantize the two against each other; use "
                     "kv_dtype auto|bfloat16|float32")
             row = (cfg.kv_lora_rank + cfg.qk_rope_head_dim,)
+            # the walk's bound follows the choice of program
+            # (ops.latent_paged_attention: the kernel or the XLA walk)
             return {"pools": {"kv": row}, "dtype": dt, "scales": 0,
-                    "pages_walked": tpu_ops.latent_pages_walked}
+                    "pages_walked": tpu_ops.latent_walk_bound(
+                        row[0], cfg.kv_lora_rank, dt)}
         from ..ops.pallas.paged_attention import pages_walked
         row = (cfg.num_key_value_heads, cfg.attn_head_dim)
         return {"pools": {"k": row, "v": row}, "dtype": dt,

@@ -24,7 +24,8 @@ __all__ = ["attention", "cached_attention", "rms_norm", "layer_norm",
            "rope", "apply_rope",
            "paged_attention", "xla_paged_attention", "paged_kv_update",
            "latent_kv_update", "latent_paged_attention",
-           "latent_pages_walked", "yarn_inv_freq", "yarn_mscale",
+           "xla_latent_paged_attention", "latent_pages_walked",
+           "latent_walk_bound", "yarn_inv_freq", "yarn_mscale",
            "xla_apply_rope",
            "swiglu", "get_attention_backend", "set_attention_backend",
            "kernel_mesh_scope",
@@ -430,11 +431,11 @@ def latent_kv_update(pool, page_table, pos, rows, layer):
 
 def latent_pages_walked(pos, q_len, page_size, pages_per_slot):
     """Pages of ITS table each slot's attention reads in one
-    latent_paged_attention call (numpy, on the host: the batcher's
-    `kv_pages_walked`): every slot walks whole blocks of
-    LATENT_BLOCK_ROWS key rows up to the DEEPEST slot's frontier — the
-    XLA walk cannot stop early for a shallow slot, which is what a
-    kernel with one grid step a slot would add."""
+    xla_latent_paged_attention call (numpy, on the host: the batcher's
+    `kv_pages_walked` where the XLA walk runs): every slot walks whole
+    blocks of LATENT_BLOCK_ROWS key rows up to the DEEPEST slot's frontier
+    — the XLA walk cannot stop early for a shallow slot, which is what the
+    kernel (ops/pallas/latent_attention.py: one slot a grid step) adds."""
     import numpy as np
     pb = max(1, LATENT_BLOCK_ROWS // page_size)
     frontier = int(np.max(pos)) + q_len - 1
@@ -442,22 +443,39 @@ def latent_pages_walked(pos, q_len, page_size, pages_per_slot):
     return np.full(np.shape(pos), min(blocks * pb, pages_per_slot), np.int64)
 
 
-def latent_paged_attention(q_lat, q_rope, pool, page_table, pos, layer,
-                           scale):
-    """Absorbed MLA attention against the latent pool.
+def _latent_kernel(pool_shape, rank, dtype):
+    """The Pallas module where latent_paged_attention takes the kernel for
+    such a pool, None where it takes the XLA walk: from the backend and
+    the shapes alone."""
+    if _on_tpu():
+        from .pallas import latent_attention as _k
+        if _k.supports(pool_shape, rank, dtype):
+            return _k
+    return None
 
-    q_lat [B, C, h, R]: each head's no-rope query carried into latent
-    space (q_nope Wuk^T); q_rope [B, C, h, r] rotated; pool
-    [P, L, ps, R + r]; query lane c of slot b sees rows j <= pos[b] + c.
-    Returns u [B, C, h, R] fp32-accumulated in q_lat.dtype: the
-    probability-weighted sum of the latents, which the caller carries
-    back out through Wuv.  All heads share a row, so a slot's C*h
-    queries are one [C*h, R + r] tile against its rows.
 
-    XLA ops: a walk over blocks of LATENT_BLOCK_ROWS rows (gathered by
-    page table) with an fp32 running softmax, as many blocks as the
-    deepest slot needs.  Scores of a whole 4 k-row table at once would
-    be [B, C*h, rows] fp32: 2 GB at 64 slots x 32 lanes."""
+def latent_walk_bound(width, rank, dtype):
+    """f(pos, q_len, page_size, pages_per_slot): the pages of its table
+    each slot's attention walks in the program latent_paged_attention
+    runs for a pool of rows `width` wide (the first `rank` the latent)
+    — the slot's own frontier where the kernel runs, latent_pages_walked
+    where the XLA walk does (Llama.kv_row_spec hands it to the batcher)."""
+    from .pallas.paged_attention import pages_walked
+
+    def bound(pos, q_len, page_size, pages_per_slot):
+        walk = pages_walked if _latent_kernel(
+            (1, 1, page_size, width), rank, dtype) else latent_pages_walked
+        return walk(pos, q_len, page_size, pages_per_slot)
+    return bound
+
+
+def xla_latent_paged_attention(q_lat, q_rope, pool, page_table, pos, layer,
+                               scale):
+    """jnp twin of pallas.latent_attention: a walk over blocks of
+    LATENT_BLOCK_ROWS rows (gathered by page table for ALL slots) with an
+    fp32 running softmax, as many blocks as the DEEPEST slot needs.
+    Scores of a whole 4 k-row table at once would be [B, C*h, rows] fp32:
+    2 GB at 64 slots x 32 lanes."""
     B, C, h, R = q_lat.shape
     P, L, ps, W = pool.shape
     P_slot = page_table.shape[1]
@@ -497,6 +515,32 @@ def latent_paged_attention(q_lat, q_rope, pool, page_table, pos, layer,
     # block 0 always holds row 0 <= every query's position, so l > 0
     _, l, acc = jax.lax.fori_loop(0, need, block, (m0, l0, acc0))
     return (acc / l[..., None]).astype(q_lat.dtype).reshape(B, C, h, R)
+
+
+def latent_paged_attention(q_lat, q_rope, pool, page_table, pos, layer,
+                           scale):
+    """Absorbed MLA attention against the latent pool.
+
+    q_lat [B, C, h, R]: each head's no-rope query carried into latent
+    space (q_nope Wuk^T); q_rope [B, C, h, r] rotated; pool
+    [P, L, ps, R + r]; query lane c of slot b sees rows j <= pos[b] + c.
+    Returns u [B, C, h, R] fp32-accumulated in q_lat.dtype: the
+    probability-weighted sum of the latents, which the caller carries
+    back out through Wuv.  All heads share a row, so a slot's C*h
+    queries are one [C*h, R + r] tile against its rows.
+
+    Pallas kernel on TPU (one slot a grid step over THAT slot's live
+    pages — see ops/pallas/latent_attention.py), the XLA walk elsewhere
+    and for shapes the kernel's `supports` predicate refuses.  The choice
+    is made from the backend and the shapes alone: whatever the kernel
+    raises — a lowering or compiler refusal included — reaches the
+    caller."""
+    kernel = _latent_kernel(pool.shape, q_lat.shape[3], pool.dtype)
+    if kernel is not None:
+        return kernel.latent_attention(q_lat, q_rope, pool, page_table, pos,
+                                       layer, scale)
+    return xla_latent_paged_attention(q_lat, q_rope, pool, page_table, pos,
+                                      layer, scale)
 
 
 def attention(q, k, v, mask=None, causal=False, scale=None, dropout_p=0.0,

@@ -244,7 +244,7 @@ def supports(pool_shape, interpret=None) -> bool:
     has no tiling.  No for latent (MLA) rows, a pool [P, L, ps, width]
     without a head axis and without a V pool: this kernel reads K and V
     of equal head size, a latent row is key (all of it) and value (its
-    first kv_lora_rank columns) at once — ops.latent_paged_attention."""
+    first kv_lora_rank columns) at once — latent_attention.py is theirs."""
     if len(pool_shape) != 5:
         return False
     interp = _interpret() if interpret is None else interpret

@@ -198,6 +198,149 @@ def test_latent_attention_walks_blocks(monkeypatch):
         .tolist() == [2, 2]
 
 
+def _latent_case(dtype, C, seed=9):
+    """One pool written by ops.latent_kv_update (pages of 8 rows, scattered
+    over the pool), 8 slots of 6 pages: depths on both sides of a page's
+    end (7, 8) and of a block's (15, 16, 17: blocks of 2 pages), a walk of
+    three blocks (37), a slot at depth 0 and a FREE slot, depth 0 and a
+    table of null pages only, which reads whatever the null page holds."""
+    rng = np.random.RandomState(seed)
+    B, h, R, r, ps, P_slot = 8, 4, 16, 8, 8, 6
+    table = 1 + rng.permutation(B * P_slot).reshape(B, P_slot)
+    table[1] = 0
+    table = jnp.asarray(table, jnp.int32)
+    pool = jnp.zeros((1 + B * P_slot, 2, ps, R + r), dtype)
+    for r0 in range(0, P_slot * ps, 12):
+        pool = tpu_ops.latent_kv_update(
+            pool, table, jnp.full((B,), r0, jnp.int32),
+            jnp.asarray(rng.randn(B, 12, R + r), dtype), 1)
+    pos = jnp.asarray([0, 0, 7, 8, 15, 16, 17, 37], jnp.int32)
+    return (jnp.asarray(rng.randn(B, C, h, R), dtype),
+            jnp.asarray(rng.randn(B, C, h, r), dtype), pool, table, pos)
+
+
+@pytest.mark.parametrize("C", [1, 3], ids=["decode", "lanes3"])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_kernel_equals_the_xla_walk(dtype, C):
+    """ops/pallas/latent_attention.py (interpret mode) against its twin on
+    one pool: blocks of 2 pages, and at 3 lanes (12 query rows) two blocks
+    of 8 query rows, the second half padding."""
+    from paddle_tpu.ops.pallas import latent_attention as kernel
+    args = _latent_case(jnp.dtype(dtype), C)
+    want = np.asarray(tpu_ops.xla_latent_paged_attention(*args, 1, 0.3),
+                      np.float32)
+    for query_rows, key_rows in ((8, 16), (2048, 256)):
+        got = kernel.latent_attention(*args, 1, 0.3, interpret=True,
+                                      query_rows=query_rows,
+                                      key_rows=key_rows)
+        assert got.dtype == args[0].dtype and got.shape == args[0].shape
+        # bf16: probabilities and outputs round at other partial sums
+        tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" \
+            else dict(rtol=2 ** -6, atol=2 ** -6)
+        np.testing.assert_allclose(np.asarray(got, np.float32), want, **tol)
+
+
+def test_latent_kernel_work_list_names_the_live_blocks():
+    """The grid's items: every (slot, query block, LIVE block) once, slot
+    by slot, and as many as the walk's bound gives."""
+    from paddle_tpu.ops.pallas import latent_attention as kernel
+    from paddle_tpu.ops.pallas.paged_attention import pages_walked
+    pos = np.array([0, 37, 16, 100], np.int32)       # 100: past the table
+    n_blocks = -(-pages_walked(pos, 3, 8, 6) // 2)
+    assert n_blocks.tolist() == [1, 3, 2, 3]
+    items, slot, qb, block, last = (np.asarray(a) for a in kernel._work_list(
+        jnp.asarray(pos), 3, 8, 6, 2, 2))
+    assert items == 2 * n_blocks.sum() and len(slot) == 4 * 2 * 3
+    want = [(b, q, i) for b in range(4) for q in range(2)
+            for i in range(n_blocks[b])]
+    assert list(zip(slot[:items], qb[:items], block[:items])) == want
+    assert last[:items].tolist() == [
+        pages_walked(pos, 3, 8, 6)[b] - 1 for b, _, _ in want]
+
+
+def test_latent_kernel_refuses_what_mosaic_would():
+    """supports: from the pool's shape, the rank and the dtype; on the chip
+    a page must lie on whole sublane tiles and the latent on whole lanes."""
+    from paddle_tpu.ops.pallas import latent_attention as kernel
+    bf, f32 = jnp.bfloat16, jnp.float32
+    assert kernel.supports((16385, 5, 16, 576), 512, bf, interpret=False)
+    assert kernel.supports((9, 2, 8, 576), 512, f32, interpret=False)
+    assert not kernel.supports((9, 2, 8, 576), 512, bf, interpret=False)
+    assert not kernel.supports((9, 2, 16, 564), 500, bf, interpret=False)
+    assert kernel.supports((9, 2, 8, 24), 16, f32, interpret=True)
+    assert not kernel.supports((9, 2, 4, 8, 128), 64, bf, interpret=True)
+    assert not kernel.supports((9, 2, 8, 24), 24, f32, interpret=True)
+    args = _latent_case(jnp.float32, 1)
+    with pytest.raises(ValueError, match="row width"):
+        kernel.latent_attention(args[0], args[1][..., :4], *args[2:], 1, 0.3)
+    with pytest.raises(ValueError, match="tiling needs"):
+        kernel.latent_attention(*args, 1, 0.3, interpret=False)
+
+
+def test_latent_walk_bound_follows_the_program(model, ids, monkeypatch):
+    """kv_row_spec's bound is the K/V kernel's frontier where
+    ops.latent_paged_attention takes the kernel and latent_pages_walked
+    where it takes the XLA walk; the batcher's host replay counts with it,
+    and the kernel serves the walk's tokens."""
+    from paddle_tpu.ops.pallas import latent_attention as kernel
+    from paddle_tpu.ops.pallas.paged_attention import pages_walked
+    pos = np.array([37, 0, 20, 5])
+
+    def bound():
+        return model.kv_row_spec()["pages_walked"](pos, 2, 8, 6)
+    np.testing.assert_array_equal(
+        bound(), tpu_ops.latent_pages_walked(pos, 2, 8, 6))
+
+    def serve():
+        bat = ContinuousBatcher(model, max_batch_size=3, max_len=64, chunk=4,
+                                prefill_chunk=8, page_size=8)
+        rids = [bat.submit(ids[0, :30], max_new_tokens=3),
+                bat.submit(ids[1, :5], max_new_tokens=3)]
+        bat.step()
+        first = bat.stats()
+        out = bat.run()
+        return first, [out[r] for r in rids], bat.pages_per_slot
+    walked, tokens, per_slot = serve()
+    # one block of 512 rows covers the table: all 3 slots walk all of it
+    assert walked["kv_pages_walked"] == 3 * per_slot
+    # the choice itself is the backend's and the shapes'; here the CPU is
+    # told it has the kernel (interpret mode: supports takes any tiling)
+    monkeypatch.setattr(tpu_ops, "_latent_kernel",
+                        lambda shape, rank, dtype: kernel.supports(
+                            shape, rank, dtype) and kernel)
+    np.testing.assert_array_equal(bound(), pages_walked(pos, 2, 8, 6))
+    live, kernel_tokens, _ = serve()
+    # both occupied slots at depth 0 and the free slot: a page each
+    assert live["kv_pages_live"] == 2 and live["kv_pages_walked"] == 3
+    for a, b in zip(tokens, kernel_tokens):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_walked_over_live_reads_the_dispatch_spans(monkeypatch):
+    """benchmark/metrics/kv_pages_walked_over_live.py: the sums of the two
+    ids over the traced chunks' serve.dispatch spans; nothing (and no
+    error) without a trace, without such spans or without the ids."""
+    import program_spans
+    from metrics import kv_pages_walked_over_live as reader
+
+    def span(name, **ids):
+        return program_spans.Span(name, 0.0, 1.0, ids, None)
+    programs = {
+        "ids": [span("serve.step", chunk=1),
+                span("serve.dispatch", kind="admit", chunk=1,
+                     kv_pages_live=40, kv_pages_walked=130),
+                span("serve.dispatch", kind="admit", chunk=2,
+                     kv_pages_live=60, kv_pages_walked=170)],
+        "no ids": [span("serve.dispatch", kind="admit", chunk=1)],
+        "no spans": []}
+    monkeypatch.setattr(
+        program_spans, "for_cell", lambda trace, cell: trace and
+        program_spans.Program(programs[trace], []))
+    assert reader.read("ids", {}, {}) == 3.0
+    for trace in (None, "no ids", "no spans"):
+        assert reader.read(trace, {}, {}) is None
+
+
 # -- (4) the shares add up --------------------------------------------------
 
 def test_four_shares_add_up_to_the_uncut_layer():
