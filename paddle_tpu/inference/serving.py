@@ -176,6 +176,14 @@ def unpack_handoff(blob: bytes):
 # admission priority order, highest first; shedding walks it in reverse
 SLO_CLASSES = ("interactive", "batch", "best_effort")
 
+# what stats() and each chunk's serve.dispatch span count beside
+# kv_pages_live / kv_pages_walked for a model with KINDS of attention
+# layer: the pages the attention walks, summed over the kind's layers,
+# the slots and the scan steps, and the pages that hold the rows a window
+# layer's VALID lanes may attend (occupied slots only)
+KIND_PAGE_COUNTS = ("kv_pages_walked_full", "kv_pages_walked_window",
+                    "kv_pages_window_needed")
+
 # what the block schedule of a model that generates by diffusion counts a
 # step, on the device, summed over a chunk's scan steps and read with its
 # tokens (beside the model's own counts, stats()).  Each with its use:
@@ -395,6 +403,15 @@ class ContinuousBatcher:
         self._spec_w = self.spec_k + 1          # verify width
         if self._diffusion:
             self._refuse_for_diffusion(kv_layout, role)
+        # KINDS of attention layer (sliding-window beside full): the
+        # model's kv_row_spec names them, and its paged cache is a pool a
+        # kind — a ring of O(window) rows a slot in the window layers
+        self._kinds = None
+        if hasattr(model, "kv_row_spec"):
+            self._kinds = model.kv_row_spec(kv_dtype).get("kinds")
+        if self._kinds:
+            self._refuse_for_kinds(kv_layout, role, prefix_sharing)
+            prefix_sharing = False
         # lanes a slot feeds the decode program a step: the token, the
         # verify window, or the block
         self._decode_width = max(self._spec_w, self.block_len)
@@ -558,12 +575,23 @@ class ContinuousBatcher:
                 + self._eff_chunk
             self._alloc = PageAllocator(self.num_pages, self.page_size)
             self._plans: List[Optional[object]] = [None] * self.B
+            # a window layer's ring: the pages its window and one step's
+            # lanes can straddle, a slot's own for its whole life —
+            # nothing to allocate or free (0: no such layers)
+            self.ring_pages = self._ring_pages(
+                self._kinds, self._eff_chunk, self.page_size)
+            more = {"window_pages": self.B * self.ring_pages} \
+                if self._kinds else {}
             self._cache = model.init_paged_cache(self.num_pages,
                                                  self.page_size,
-                                                 kv_dtype)
+                                                 kv_dtype, **more)
             spec = model.kv_row_spec(kv_dtype)
             self._kv_dtype = str(np.dtype(spec["dtype"]))
             self._pages_walked = spec["pages_walked"]
+            if self._kinds:
+                self._kv_pool_bytes = self._kind_pool_bytes(
+                    spec, self.num_pages, self.B * self.ring_pages,
+                    self.page_size)
             if self._diffusion and spec["scales"]:
                 raise ValueError(
                     "int8 KV under the block schedule is not supported: "
@@ -628,6 +656,11 @@ class ContinuousBatcher:
         # kernel walks
         self._kv_pages_live = 0
         self._kv_pages_walked = 0
+        # and, for a model with kinds of layer, the walk by kind (summed
+        # over the kind's layers too) beside the pages a window layer's
+        # valid lanes need: KIND_PAGE_COUNTS
+        self._kv_pages_by_kind = dict.fromkeys(KIND_PAGE_COUNTS, 0) \
+            if self._kinds else {}
         # what the MODEL counts a step on the device (a dropless expert
         # layer's routing, models.llama.step_counter_names): summed in
         # the scan, read with the chunk's tokens, never under
@@ -710,6 +743,34 @@ class ContinuousBatcher:
                 f"the model's block length {L}: prefill consumes whole "
                 "blocks")
 
+    def _refuse_for_kinds(self, kv_layout, role, prefix_sharing):
+        """What a model with sliding-window layers does not compose with
+        yet, said at construction: each of these moves or shares the
+        pages of a slot's WHOLE depth and would have to carry the rings'
+        rows along (ROADMAP R3)."""
+        what = "a model with sliding-window layers keeps a ring of " \
+            "O(window) rows a slot in those layers"
+        if kv_layout != "paged":
+            raise TypeError(
+                f"{what} of the PAGED pool: the dense ring buffers' step "
+                "programs hold every layer to the slot's whole depth "
+                "(kv_layout must be 'paged')")
+        if self.spec_k:
+            raise ValueError(
+                f"{what}: a rejected draft's rows would have overwritten "
+                "rows still inside the window (spec_tokens must be 0)")
+        if role != "unified":
+            raise ValueError(
+                f"role {role!r}: {what}, and a hand-off ships the pages "
+                "of the page table alone; only the unified role serves "
+                "such a model")
+        if prefix_sharing:
+            raise ValueError(
+                f"prefix_sharing=True: {what}, so a shared prefix's pages "
+                "hold the full layers' rows only and a request mapped "
+                "onto them would lack the window layers' (the default "
+                "resolves to off for such a model)")
+
     def preflight(self, *, level: str = "full", manager=None):
         """Full static sentinel over the serve step programs: the
         donation lint proves every donated paged carry (KV pool,
@@ -746,6 +807,17 @@ class ContinuousBatcher:
                         or auto)
         return ps, pages_per_slot, num_pages
 
+    @staticmethod
+    def _ring_pages(kinds, prefill_chunk, page_size) -> int:
+        """Pages of a slot's ring in the window layers' pool (0 for a
+        model without kinds): ops.ring_pages of the rows the model says a
+        slot needs there under the widest step."""
+        if not kinds:
+            return 0
+        from ..ops import ring_pages
+        return ring_pages(kinds["window"]["window"], prefill_chunk,
+                          page_size)
+
     @classmethod
     def paged_kv_bytes(cls, model, max_batch_size, max_len,
                        prefill_chunk: int = 32, page_size=None,
@@ -762,12 +834,30 @@ class ContinuousBatcher:
         # the row is the MODEL's to state (K and V heads, or one latent)
         spec = model.kv_row_spec(kv_dtype)
         layers = model.config.num_hidden_layers
+        table = B * p_slot * 4
+        if spec.get("kinds"):
+            # a pool a kind: the full layers' behind the page table, the
+            # window layers' rings (B slots of ring pages, no table)
+            held = cls._kind_pool_bytes(
+                spec, n_pages, B * cls._ring_pages(
+                    spec["kinds"], prefill_chunk, ps), ps)
+            return sum(held.values()) + table
         rows = sum(int(np.prod(row)) for row in spec["pools"].values())
         pool = n_pages * ps * layers * rows \
             * jnp.dtype(spec["dtype"]).itemsize
         scales = len(spec["pools"]) * n_pages * layers * spec["scales"] * 4
-        table = B * p_slot * 4
         return pool + scales + table
+
+    @staticmethod
+    def _kind_pool_bytes(spec, num_pages, window_pages, page_size):
+        """{kind: bytes} of the pools of a model with kinds of layer."""
+        item = jnp.dtype(spec["dtype"]).itemsize
+        out = {}
+        for kind, pages in (("full", num_pages), ("window", window_pages)):
+            k = spec["kinds"][kind]
+            rows = sum(int(np.prod(spec["pools"][n])) for n in k["pools"])
+            out[kind] = pages * page_size * len(k["layers"]) * rows * item
+        return out
 
     # -- public API --------------------------------------------------------
     def submit(self, input_ids, max_new_tokens: int = 32,
@@ -1435,6 +1525,7 @@ class ContinuousBatcher:
             # is ragged); a free slot walks its one or two pages
             "kv_pages_live": self._kv_pages_live,
             "kv_pages_walked": self._kv_pages_walked,
+            **self._kv_pages_by_kind,
             **self._model_counts,
             "compiled_programs": self.compiled_programs,
             "kv_layout": self.kv_layout,
@@ -1531,6 +1622,10 @@ class ContinuousBatcher:
                 evictions=self._alloc.evictions,
                 cow_copies=self._alloc.cow_copies,
             )
+            if self._kinds:
+                # the bytes each kind of layer really holds
+                out["kv_pool_bytes"] = dict(self._kv_pool_bytes)
+                out["kv_ring_pages"] = self.ring_pages
         else:
             out.update(prefix_hit_tokens=0, import_hit_tokens=0,
                        grafted_pages=0, evictions=0, cow_copies=0)
@@ -1771,6 +1866,8 @@ class ContinuousBatcher:
             base += ("spec", self.spec_k) + self._draft_key
         if self._diffusion:
             base += ("diffusion",) + tuple(self._diffusion.values())
+        if self._kinds:
+            base += ("ring", self.ring_pages)
         return base
 
     def _page_copy_fn(self):
@@ -1805,6 +1902,8 @@ class ContinuousBatcher:
                             "'paged' (the hand-off ships pages)")
         if self._diffusion:
             self._refuse_for_diffusion(self.kv_layout, role)
+        if self._kinds:
+            self._refuse_for_kinds(self.kv_layout, role, False)
         self.role = role
 
     def _page_export_fn(self):
@@ -1915,6 +2014,11 @@ class ContinuousBatcher:
                 "a hand-off ships a slot at a token boundary, a "
                 "block-diffusion slot stands inside a block; only the "
                 "unified role, without hand-offs, serves such a model")
+        if self._kinds:
+            raise ValueError(
+                "a hand-off ships the pages of the page table alone; a "
+                "model with sliding-window layers keeps rings beside them "
+                "that no hand-off carries")
         if self.kv_layout != "paged":
             raise TypeError("import_handoff needs the paged KV layout")
         if int(meta["page_size"]) != self.page_size \
@@ -2578,8 +2682,8 @@ class ContinuousBatcher:
         return fn.lower(self._param_vals(), *self._carry_args())
 
     def _kv_page_counts(self, width: int, steps: int):
-        """(live, walked): the pages one paged-attention call covers,
-        summed over the `steps` scan steps of the chunk about to be
+        """(live, walked, by kind): the pages one paged-attention call
+        covers, summed over the `steps` scan steps of the chunk about to be
         dispatched — `live`: up to the frontier of each occupied slot;
         `walked`: what the attention walks of all B (the bound its own
         module states, handed over in the model's kv_row_spec: the K/V
@@ -2590,9 +2694,17 @@ class ContinuousBatcher:
         pass fixes the quota's lanes and no more (what the confidence
         threshold fixes beyond it is in the data: a block then commits
         earlier than counted here, and the count is a lower bound).
-        (0, 0) for the dense layout."""
+        (0, 0) for the dense layout.
+
+        The third entry is {} but for a model with KINDS of layer:
+        `live` and `walked` are then the FULL layers' call (a slot's
+        whole depth), and it gives KIND_PAGE_COUNTS: each kind's walk
+        times its layers (the window layers' from the same bound the
+        kernel uses, its fifth argument the window) and the pages the
+        window layers' valid lanes need."""
         if self.kv_layout != "paged":
-            return 0, 0
+            return 0, 0, {}
+        from ..ops.pallas.paged_attention import first_page
         from ..ops.pallas.paged_attention import pages_walked as to_frontier
         pages_walked = self._pages_walked
         pos = self._pos_host.astype(np.int64)
@@ -2602,6 +2714,11 @@ class ContinuousBatcher:
         plen = np.array([len(r.prompt) if r is not None else 0
                          for r in self._slots], np.int64)
         live = walked = 0
+        by_kind = dict.fromkeys(self._kv_pages_by_kind, 0)
+        if self._kinds:
+            W, ps = self._kinds["window"]["window"], self.page_size
+            n_full, n_win = (len(self._kinds[k]["layers"])
+                             for k in ("full", "window"))
         if self._diffusion:
             L, S = self.block_len, self._diffusion["denoising_steps"]
             quotas = np.array(_step_quotas(L, S))
@@ -2617,6 +2734,15 @@ class ContinuousBatcher:
             walked += int(n.sum())
             live += int(held[occupied].sum())
             filling = mode & ~done
+            if self._kinds:
+                valid = np.where(filling, np.minimum(width, plen - pos),
+                                 ~done)
+                need = (pos + valid - 1) // ps - first_page(pos, ps, W) + 1
+                by_kind["kv_pages_walked_full"] += n_full * int(n.sum())
+                by_kind["kv_pages_walked_window"] += n_win * int(
+                    pages_walked(pos, width, ps, self.ring_pages, W).sum())
+                by_kind["kv_pages_window_needed"] += n_win * int(
+                    need[occupied & (valid > 0)].sum())
             if self._diffusion:
                 decoding = ~mode & ~done
                 # a fresh block masks what lies past the prompt
@@ -2635,7 +2761,7 @@ class ContinuousBatcher:
             pos += np.where(filling, np.minimum(width, plen - pos), ~done)
             mode &= ~(filling & (pos >= plen))
             done |= pos >= self.max_len - 1
-        return live, walked
+        return live, walked, by_kind
 
     def _run_chunk(self, mixed: bool) -> bool:
         """One scan chunk, in the phases `serve.dispatch`,
@@ -2655,11 +2781,11 @@ class ContinuousBatcher:
         # (_decode_width is 1 for one token a step)
         width, steps = (self.prefill_chunk, self.admit_steps) if mixed \
             else (self._decode_width, self.chunk)
-        pages = self._kv_page_counts(width, steps)
+        *pages, by_kind = self._kv_page_counts(width, steps)
         try:
             with self._phase("dispatch", kind=kind, chunk=ck,
                              kv_pages_live=pages[0],
-                             kv_pages_walked=pages[1]):
+                             kv_pages_walked=pages[1], **by_kind):
                 if self.spec_k and not mixed:
                     fn = self._spec_step_fn()
                 else:
@@ -2714,6 +2840,8 @@ class ContinuousBatcher:
         self._consecutive_chunk_faults = 0
         self._kv_pages_live += pages[0]
         self._kv_pages_walked += pages[1]
+        for name, v in by_kind.items():
+            self._kv_pages_by_kind[name] += v
         if self._watch.last_reported:
             self._hung_chunks += 1
             _tel.counter("serve.hung_chunks").inc()

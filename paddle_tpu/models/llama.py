@@ -149,10 +149,59 @@ class LlamaConfig:
     denoising_steps: int = 1
     mask_token_id: int | None = None
     confidence_threshold: float = 0.9
+    # KINDS of attention layer.  layer_types names each layer's kind,
+    # "full_attention" or "sliding_attention" (None: every layer full);
+    # a sliding layer's lane at position i sees rows j <= i with
+    # i - j < sliding_window (the token itself and the sliding_window - 1
+    # before it).  rope_layer_types: the kinds whose q and k take the
+    # rotary (None: every kind; EXAONE's hybrid models rotate in the
+    # sliding layers only).  In the paged pool a sliding layer keeps a
+    # RING of O(sliding_window) rows a slot, a full layer the slot's
+    # whole depth (kv_row_spec, init_paged_cache).
+    layer_types: tuple | None = None
+    sliding_window: int = 0
+    rope_layer_types: tuple | None = None
 
     @property
     def latent_attention(self):
         return self.kv_lora_rank > 0
+
+    def layer_window(self, layer_idx: int) -> int:
+        """The window of layer `layer_idx`'s attention, 0 for a full
+        layer; the kinds are checked here, where every reader passes."""
+        if self.layer_types is None:
+            return 0
+        kinds = tuple(self.layer_types)
+        if len(kinds) != self.num_hidden_layers or set(kinds) - {
+                "full_attention", "sliding_attention"}:
+            raise ValueError(
+                f"layer_types names {len(kinds)} layers of kinds "
+                f"{sorted(set(kinds))}; the model has "
+                f"{self.num_hidden_layers}, each full_attention or "
+                "sliding_attention")
+        if kinds[layer_idx] == "full_attention":
+            return 0
+        if self.sliding_window < 1:
+            raise ValueError("a sliding_attention layer needs "
+                             "sliding_window >= 1")
+        if self.latent_attention or self.block_length > 1:
+            raise ValueError(
+                "sliding_attention layers are LlamaAttention's under the "
+                "causal mask: latent (MLA) rows and the block-causal mask "
+                "have no window")
+        return int(self.sliding_window)
+
+    def layer_rotary(self, layer_idx: int) -> bool:
+        if self.rope_layer_types is None:
+            return True
+        kind = "sliding_attention" if self.layer_window(layer_idx) \
+            else "full_attention"
+        return kind in tuple(self.rope_layer_types)
+
+    def window_layers(self):
+        """Indices of the sliding-window layers, () without any."""
+        return tuple(i for i in range(self.num_hidden_layers)
+                     if self.layer_window(i))
 
     def block_diffusion(self):
         """None for an autoregressive model, else how it generates:
@@ -264,10 +313,16 @@ class LlamaRMSNorm(nn.Layer):
 
 
 class LlamaAttention(nn.Layer):
-    def __init__(self, config: LlamaConfig):
+    def __init__(self, config: LlamaConfig, layer_idx: int = 0):
         super().__init__(dtype=config.dtype)
         from ..framework.tensor import Parameter
         self.config = config
+        # this layer's kind: its window (0: full attention), whether its
+        # q and k take the rotary, and whether the model has kinds at all
+        # (its paged cache is then a pool a kind)
+        self.window = config.layer_window(layer_idx)
+        self.rotary = config.layer_rotary(layer_idx)
+        self.kinds = bool(config.window_layers())
         h = config.hidden_size
         hd = config.attn_head_dim
         nh = config.num_attention_heads
@@ -311,7 +366,8 @@ class LlamaAttention(nn.Layer):
                                               cfg.attn_head_dim)
             if norms:
                 q, k = self._qk_norm(q, k, *norms)
-            q, k = tpu_ops.apply_rope(q, k, cos_a, sin_a)
+            if self.rotary:
+                q, k = tpu_ops.apply_rope(q, k, cos_a, sin_a)
             # selective-recompute anchors: saving post-rope q/k/v lets the
             # flash backward replay only the attention kernel, not the
             # projections; the attention output feeds o_proj's weight grad
@@ -340,7 +396,8 @@ class LlamaAttention(nn.Layer):
                                              causal=True)
             if out is None:
                 out = tpu_ops.attention(q, k, val, causal=True,
-                                        block_length=cfg.block_length)
+                                        block_length=cfg.block_length,
+                                        window=self.window)
             out = checkpoint_name(out, "attn_out")
             return out.reshape(b, s, -1) @ wo.astype(cd)
         return run(_fn, x, self.q_proj, self.k_proj, self.v_proj,
@@ -362,7 +419,8 @@ class LlamaAttention(nn.Layer):
             b, s, cfg.num_key_value_heads, cfg.attn_head_dim)
         if cfg.use_qk_norm:
             q, k = self._qk_norm(q, k, self.q_norm.value, self.k_norm.value)
-        q, k = tpu_ops.apply_rope(q, k, cos, sin)
+        if self.rotary:
+            q, k = tpu_ops.apply_rope(q, k, cos, sin)
         return q, k, v
 
     def forward_cached(self, x, cos, sin, k_cache, v_cache, pos):
@@ -391,7 +449,7 @@ class LlamaAttention(nn.Layer):
                                     pos)
         out = tpu_ops.cached_attention(
             q, k_cache, v_cache, pos,
-            block_length=self.config.block_length)
+            block_length=self.config.block_length, window=self.window)
         out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
         return out, k_cache, v_cache
 
@@ -402,9 +460,17 @@ class LlamaAttention(nn.Layer):
         slot's page table (ops.paged_kv_update — int8 pools quantize
         here) and attention gathers by page table
         (ops.paged_attention: Pallas on TPU, take-gather twin
-        elsewhere).  Returns (out, cache)."""
+        elsewhere).  Returns (out, cache).
+
+        A model with KINDS of layer (config.layer_types) hands each layer
+        the table of its kind's pool and its index in THAT pool: a full
+        layer "k"/"v" and the slots' page table, a sliding layer
+        "k_window"/"v_window" and the slots' rings (_paged_by_kind)."""
         b, s, _ = x.shape
         q, k, v = self._decode_qkv_rope(x, cos, sin)
+        if self.kinds:
+            return self._paged_by_kind(q, k, v, cache, page_table, pos,
+                                       layer)
         kp, vp, ks, vs = tpu_ops.paged_kv_update(
             cache["k"], cache["v"], cache.get("k_scale"),
             cache.get("v_scale"), page_table, pos, k, v, layer)
@@ -416,6 +482,22 @@ class LlamaAttention(nn.Layer):
             block_length=self.config.block_length)
         out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
         return out, cache
+
+    def _paged_by_kind(self, q, k, v, cache, table, pos, layer):
+        """The cache write and the attention of one layer of a model with
+        kinds, under the scope that names the kind (`attn.window` /
+        `attn.full`): both index the kind's WHOLE carried pool by
+        (page, layer), as every paged model's do."""
+        b, s = q.shape[:2]
+        kn, vn = ("k_window", "v_window") if self.window else ("k", "v")
+        with jax.named_scope("attn.window" if self.window else "attn.full"):
+            kp, vp, _, _ = tpu_ops.paged_kv_update(
+                cache[kn], cache[vn], None, None, table, pos, k, v, layer,
+                ring=bool(self.window))
+            out = tpu_ops.paged_attention(q, kp, vp, table, pos, layer,
+                                          window=self.window)
+        out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
+        return out, dict(cache, **{kn: kp, vn: vp})
 
     # split entry points for the selective-recompute block structure
     # (forward above stays the single fused path)
@@ -437,7 +519,8 @@ class LlamaAttention(nn.Layer):
                                               cfg.attn_head_dim)
             if norms:
                 q, k = self._qk_norm(q, k, *norms)
-            q, k = tpu_ops.apply_rope(q, k, cos_a, sin_a)
+            if self.rotary:
+                q, k = tpu_ops.apply_rope(q, k, cos_a, sin_a)
             return q, k, val
         return run(_fn, x, self.q_proj, self.k_proj, self.v_proj,
                    *[getattr(self, n) for n in self._norm_names()],
@@ -445,9 +528,10 @@ class LlamaAttention(nn.Layer):
 
     def core_attention(self, q, k, v):
         q, k, v = to_tensor_args(q, k, v)
-        L = self.config.block_length
+        L, W = self.config.block_length, self.window
         return run(lambda a, b_, c: tpu_ops.attention(a, b_, c, causal=True,
-                                                      block_length=L),
+                                                      block_length=L,
+                                                      window=W),
                    q, k, v, name="core_attention")
 
     def output_proj(self, attn):
@@ -617,7 +701,7 @@ class LlamaDecoderLayer(nn.Layer):
             or layer_idx < config.recompute_layers)
         # the block's two halves are chosen from the config, per layer
         self.self_attn = LlamaMLAttention(config) \
-            if config.latent_attention else LlamaAttention(config)
+            if config.latent_attention else LlamaAttention(config, layer_idx)
         self.expert_layer = config.expert_layer(layer_idx)
         if self.expert_layer:
             from ..incubate.distributed.models.moe import MoELayer
@@ -831,7 +915,16 @@ class LlamaModel(nn.Layer):
         entries a pool carries (0 unless int8), "pages_walked": the
         bound of the attention's walk over a slot's table, f(pos, q_len,
         page_size, pages_per_slot)}.  A page of a pool is
-        [layers, *row[:-1], page_size, row[-1]]."""
+        [layers, *row[:-1], page_size, row[-1]].
+
+        A model with KINDS of layer (config.layer_types) adds "kinds":
+        {"full": {"layers", "window": 0, "pools": ("k", "v")}, "window":
+        {"layers", "window": W, "pools": ("k_window", "v_window"),
+        "rows": f(q_len)}}: which layers are of each kind, the pools that
+        hold them (a pool's layer axis counts the kind's layers only) and
+        how many rows a slot needs in a window layer, W + q_len - 1 (in a
+        full layer: its whole depth); `pages_walked` then takes the
+        layer's window as a fifth argument."""
         cfg = self.config
         dt, quant = _resolve_kv_dtype(cfg, kv_dtype)
         if cfg.latent_attention:
@@ -849,9 +942,28 @@ class LlamaModel(nn.Layer):
                         row[0], cfg.kv_lora_rank, dt)}
         from ..ops.pallas.paged_attention import pages_walked
         row = (cfg.num_key_value_heads, cfg.attn_head_dim)
-        return {"pools": {"k": row, "v": row}, "dtype": dt,
+        spec = {"pools": {"k": row, "v": row}, "dtype": dt,
                 "scales": cfg.num_key_value_heads if quant else 0,
                 "pages_walked": pages_walked}
+        sliding = cfg.window_layers()
+        if sliding:
+            if quant:
+                raise ValueError(
+                    "int8 KV is not implemented for a model with "
+                    "sliding-window layers: a ring page is rewritten in "
+                    "place and would be requantised as it turns; use "
+                    "kv_dtype auto|bfloat16|float32")
+            W = cfg.sliding_window
+            spec["pools"].update(k_window=row, v_window=row)
+            spec["kinds"] = {
+                "full": {"layers": tuple(
+                    i for i in range(cfg.num_hidden_layers)
+                    if i not in sliding), "window": 0,
+                    "pools": ("k", "v")},
+                "window": {"layers": sliding, "window": W,
+                           "pools": ("k_window", "v_window"),
+                           "rows": lambda q_len: W + q_len - 1}}
+        return spec
 
     def init_cache(self, batch: int, max_len: int):
         """Per-layer KV ring buffers [b, max_len, n_kv, hd] in the
@@ -862,13 +974,15 @@ class LlamaModel(nn.Layer):
                 "latent (MLA) attention serves through the paged pool "
                 "only (init_paged_cache / forward_cached_paged); the dense "
                 "ring-buffer layout has no latent form")
+        # (a sliding-window layer's dense buffer holds the whole depth:
+        # the window is its mask's, not its size's; generate() only)
         shape = (batch, max_len, cfg.num_key_value_heads, cfg.attn_head_dim)
         dt = cfg.compute_dtype
         return [(jnp.zeros(shape, dt), jnp.zeros(shape, dt))
                 for _ in self.layers]
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         kv_dtype=None):
+                         kv_dtype=None, window_pages=None):
         """Paged KV pool (ISSUE 7): ONE device-resident page pool per
         K and V, [num_pages, layers, n_kv, page_size, head_dim] — one
         (page, layer, kv head) is a contiguous [page_size, head_dim]
@@ -877,7 +991,15 @@ class LlamaModel(nn.Layer):
         the reserved null page (unmapped table entries point there;
         reads of its rows are position-masked).  kv_dtype: None reads
         FLAGS_kv_cache_dtype ('auto' = compute dtype; 'int8' adds
-        per-page per-head fp32 scales alongside the pool)."""
+        per-page per-head fp32 scales alongside the pool).
+
+        A model with KINDS of layer keeps a pool a kind: "k"/"v" over the
+        full layers only, [num_pages, full layers, ...], behind the
+        slots' page tables, and "k_window"/"v_window" over the sliding
+        layers, [window_pages, sliding layers, ...]: `window_pages` =
+        slots x ops.ring_pages(window, q_len, page_size), slot b's ring
+        the pages b * ring .. (b + 1) * ring - 1, its own for its whole
+        life (no null page: a free slot's junk lands in its own ring)."""
         cfg = self.config
         dt, quant = _resolve_kv_dtype(cfg, kv_dtype)
         if cfg.latent_attention:
@@ -886,6 +1008,18 @@ class LlamaModel(nn.Layer):
             (width,) = self.kv_row_spec(kv_dtype)["pools"]["kv"]
             return {"kv": jnp.zeros((num_pages, len(self.layers),
                                      page_size, width), dt)}
+        kinds = self.kv_row_spec(kv_dtype).get("kinds")
+        if kinds:
+            if not window_pages:
+                raise ValueError(
+                    "a model with sliding-window layers needs window_pages "
+                    "(slots x ops.ring_pages(window, q_len, page_size))")
+            tail = (cfg.num_key_value_heads, page_size, cfg.attn_head_dim)
+            full = (num_pages, len(kinds["full"]["layers"])) + tail
+            ring = (int(window_pages), len(kinds["window"]["layers"])) + tail
+            return {"k": jnp.zeros(full, dt), "v": jnp.zeros(full, dt),
+                    "k_window": jnp.zeros(ring, dt),
+                    "v_window": jnp.zeros(ring, dt)}
         shape = (num_pages, len(self.layers), cfg.num_key_value_heads,
                  page_size, cfg.attn_head_dim)
         cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -912,10 +1046,25 @@ class LlamaModel(nn.Layer):
         x = jnp.take(self.embed_tokens.value,
                      input_ids.astype(jnp.int32),
                      axis=0).astype(cfg.compute_dtype)
+        sliding = cfg.window_layers()
+        if sliding:
+            # a pool a kind: each layer gets its kind's table (the slots'
+            # rings are a constant of the shapes: slot b owns pages
+            # b * ring .. of the window pool) and its index in THAT pool
+            B = input_ids.shape[0]
+            ring = cache["k_window"].shape[0] // B
+            rings = jnp.arange(B, dtype=jnp.int32)[:, None] * ring \
+                + jnp.arange(ring, dtype=jnp.int32)[None]
+            at = {"full": 0, "window": 0}
         for li, layer in enumerate(self.layers):
+            table, index = page_table, li
+            if sliding:
+                kind = "window" if li in sliding else "full"
+                table = rings if kind == "window" else page_table
+                index, at[kind] = at[kind], at[kind] + 1
             with jax.named_scope(f"llama.layer{li}"):
                 x, cache = layer.forward_cached_paged(
-                    x, cos, sin, cache, page_table, pos, li, counters)
+                    x, cos, sin, cache, table, pos, index, counters)
         w = self.norm.weight.value
         with jax.named_scope("llama.norm"):
             return tpu_ops.rms_norm(x, w.astype(x.dtype),
@@ -982,9 +1131,9 @@ class LlamaForCausalLM(nn.Layer):
         return self.llama.init_cache(batch, max_len)
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         kv_dtype=None):
+                         kv_dtype=None, window_pages=None):
         return self.llama.init_paged_cache(num_pages, page_size,
-                                           kv_dtype)
+                                           kv_dtype, window_pages)
 
     def kv_row_spec(self, kv_dtype=None):
         return self.llama.kv_row_spec(kv_dtype)

@@ -159,17 +159,43 @@ def block_end(pos, block_length):
     return pos // block_length * block_length + (block_length - 1)
 
 
+def window_visible(k_idx, q_pos, window, ring_rows):
+    """Which rows of a buffer of `ring_rows` rows a SLIDING-WINDOW query
+    at position `q_pos` sees: buffer row r holds position
+    k = q_pos - (q_pos - r) mod ring_rows, the newest position <= q_pos
+    that lands there (a ring: position p lies in row p mod ring_rows; a
+    buffer that holds the whole depth is the ring that never wraps), and
+    the query sees it iff k >= 0 and q_pos - k < window: the token itself
+    and the window - 1 before it.  The caller keeps window + lanes - 1
+    rows a slot, so no row a lane may see has been overwritten."""
+    k_pos = q_pos - (q_pos - k_idx) % ring_rows
+    return (k_pos >= 0) & (q_pos - k_pos < window)
+
+
+def _check_window(window, block_length):
+    if window and block_length > 1:
+        raise ValueError("a sliding window under the block-causal mask "
+                         "(block_length > 1) is not defined here")
+
+
 def xla_attention(q, k, v, mask=None, causal=False, scale=None,
-                  dropout_p=0.0, block_length=1):
+                  dropout_p=0.0, block_length=1, window=0):
     """Reference math of phi flash_attn kernel, XLA-fused.
     q/k/v: [b, s, h, d] (paddle flash-attn layout).  fp32 softmax.
     `block_length` > 1 (with `causal`): the block-causal mask, key j
-    visible to query i iff j // L <= i // L."""
+    visible to query i iff j // L <= i // L.  `window` W > 0 (with
+    `causal`): key j visible to query i iff j <= i and i - j < W."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
     s = scale if scale is not None else 1.0 / (d ** 0.5)
     logits = gqa_scores(q, k) * s
-    if causal and block_length > 1:
+    _check_window(window, block_length)
+    if causal and window:
+        row = jnp.arange(sq, dtype=jnp.int32)[:, None] + (sk - sq)
+        col = jnp.arange(sk, dtype=jnp.int32)[None]
+        cm = (col <= row) & (row - col < window)
+        logits = jnp.where(cm[None, None], logits, -1e30)
+    elif causal and block_length > 1:
         row = jnp.arange(sq, dtype=jnp.int32)[:, None] + (sk - sq)
         cm = jnp.arange(sk, dtype=jnp.int32)[None] \
             <= block_end(row, block_length)
@@ -192,7 +218,7 @@ def xla_attention(q, k, v, mask=None, causal=False, scale=None,
 
 
 def cached_attention(q, k_cache, v_cache, q_pos0, scale=None,
-                     block_length=1):
+                     block_length=1, window=0):
     """Incremental-decode attention against a fixed-size KV ring buffer.
 
     q: [b, s_new, h, d] (queries for the tokens being appended);
@@ -202,7 +228,10 @@ def cached_attention(q, k_cache, v_cache, q_pos0, scale=None,
     at its own depth).  Query i of slot b attends cache slots
     j <= q_pos0[b] + i; with `block_length` L > 1 (a model that
     generates by diffusion over blocks) slots j <= block_end(q_pos0[b] +
-    i, L): its whole block and every earlier one.
+    i, L): its whole block and every earlier one; with `window` W > 0
+    (a sliding-window layer) the rows window_visible() names: the
+    buffer's S_max rows are then read as a RING (row r holds the newest
+    position congruent to r), which a buffer of the whole depth is too.
 
     The vector form with s_new > 1 is the CHUNKED-PREFILL contract
     (inference/serving.py): a mixed batch where some slots decode one
@@ -228,11 +257,16 @@ def cached_attention(q, k_cache, v_cache, q_pos0, scale=None,
     s = scale if scale is not None else 1.0 / (d ** 0.5)
     logits = gqa_scores(q, k_cache) * s
     pos0 = jnp.asarray(q_pos0, jnp.int32)
+    _check_window(window, block_length)
     if pos0.ndim == 0:
         pos_q = pos0 + jnp.arange(sq, dtype=jnp.int32)[:, None]
         if block_length > 1:
             pos_q = block_end(pos_q, block_length)
-        valid = jnp.arange(sk, dtype=jnp.int32)[None, :] <= pos_q
+        if window:
+            valid = window_visible(jnp.arange(sk, dtype=jnp.int32)[None, :],
+                                   pos_q, window, sk)
+        else:
+            valid = jnp.arange(sk, dtype=jnp.int32)[None, :] <= pos_q
         logits = jnp.where(valid[None, None], logits, -1e30)
     else:
         # PER-SLOT positions ([b] vector): each sequence in the batch
@@ -240,8 +274,13 @@ def cached_attention(q, k_cache, v_cache, q_pos0, scale=None,
         pos_q = pos0[:, None] + jnp.arange(sq, dtype=jnp.int32)[None]
         if block_length > 1:
             pos_q = block_end(pos_q, block_length)
-        valid = jnp.arange(sk, dtype=jnp.int32)[None, None, :] \
-            <= pos_q[:, :, None]
+        if window:
+            valid = window_visible(
+                jnp.arange(sk, dtype=jnp.int32)[None, None, :],
+                pos_q[:, :, None], window, sk)
+        else:
+            valid = jnp.arange(sk, dtype=jnp.int32)[None, None, :] \
+                <= pos_q[:, :, None]
         logits = jnp.where(valid[:, None], logits, -1e30)
     w = jax.nn.softmax(logits, axis=-1)
     out = gqa_weighted_v(w.astype(v_cache.dtype), v_cache)
@@ -257,8 +296,19 @@ def _dequant_pages(pages, scales):
     return pages.astype(jnp.float32) * scales[..., None, None]
 
 
+def ring_pages(window, q_len, page_size):
+    """Pages of the RING a slot keeps in a sliding-window layer's pool:
+    the window + q_len - 1 rows that the lanes of one step may attend
+    (lane c at position pos + c sees rows pos + c - window + 1 ..
+    pos + c) can straddle one page more than they fill.  Row r of a slot
+    lies in ring page (r // page_size) mod ring_pages; a step's write
+    then overwrites exactly rows that have left every later lane's
+    window, and the walk of one step meets no ring page twice."""
+    return -(-(window + q_len - 1) // page_size) + 1
+
+
 def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
-                    k_new, v_new, layer):
+                    k_new, v_new, layer, ring=False):
     """Write one step's K/V rows into the paged pool (the paged twin of
     the dense path's per-slot dynamic_update_slice).
 
@@ -289,17 +339,30 @@ def paged_kv_update(k_pool, v_pool, k_scale, v_scale, page_table, pos,
     with the rows scattered one by one (`pool.at[page, layer, :, row]`,
     as ops.latent_kv_update writes its kernel-less pool) it prefers
     page rows above kv heads, and copies as much (ISSUE 28;
-    tests/test_chip_compile.py holds the compiled program to this)."""
+    tests/test_chip_compile.py holds the compiled program to this).
+
+    `ring`: the table is a slot's RING (ring_pages() entries of a
+    sliding-window layer's pool): logical page p lies in entry
+    p mod P_slot, so the window of pages wraps instead of clamping."""
     P, L, n_kv, ps, hd = k_pool.shape
     B, C = k_new.shape[0], k_new.shape[1]
     P_slot = page_table.shape[1]
     n_t = -(-C // ps) + 1          # pages a C-row write can straddle
     quant = k_pool.dtype == jnp.int8
     pos = jnp.asarray(pos, jnp.int32)
-    p0 = jnp.clip(pos // ps, 0, max(P_slot - n_t, 0))
-    win = jnp.clip(p0[:, None] + jnp.arange(n_t, dtype=jnp.int32)[None],
-                   0, P_slot - 1)                            # [B, n_t]
-    ids = jnp.take_along_axis(page_table, win, axis=1)       # [B, n_t]
+    if ring:
+        if n_t > P_slot:
+            raise ValueError(f"a write of {C} rows straddles {n_t} pages, "
+                             f"the ring has {P_slot}")
+        p0 = pos // ps
+        win = p0[:, None] + jnp.arange(n_t, dtype=jnp.int32)[None]
+        ids = jnp.take_along_axis(page_table, win % P_slot, axis=1)
+    else:
+        p0 = jnp.clip(pos // ps, 0, max(P_slot - n_t, 0))
+        win = jnp.clip(p0[:, None]
+                       + jnp.arange(n_t, dtype=jnp.int32)[None],
+                       0, P_slot - 1)                        # [B, n_t]
+        ids = jnp.take_along_axis(page_table, win, axis=1)   # [B, n_t]
     rel0 = pos - p0 * ps
     start = win * ps                # window pages' first logical row
     touched = (start < (pos + C)[:, None]) \
@@ -353,13 +416,17 @@ def _check_paged_args(q, k_pool, k_scale, v_scale):
 
 def xla_paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                         k_scale=None, v_scale=None, scale=None,
-                        block_length=1):
+                        block_length=1, window=0):
     """jnp twin of pallas.paged_attention: materialize each slot's
     logical KV view with a `take`-based gather over the page table,
     dequant (int8 pools), then EXACTLY the dense cached_attention math
     — masked rows exp to 0.0 exactly, so the padded logical depth
     (P_slot*ps vs the dense cache_len) cannot perturb the softmax and
-    the paged path stays bit-identical to the dense one off-TPU."""
+    the paged path stays bit-identical to the dense one off-TPU.
+    `window` W > 0: a sliding-window layer; the table's P_slot pages are
+    the slot's ring (logical page p in entry p mod P_slot: a table of
+    the whole depth never wraps) and cached_attention reads the gathered
+    rows as one."""
     _check_paged_args(q, k_pool, k_scale, v_scale)
     B = q.shape[0]
     P, L, n_kv, ps, hd = k_pool.shape
@@ -377,12 +444,12 @@ def xla_paged_attention(q, k_pool, v_pool, page_table, pos, layer,
 
     return cached_attention(q, gather(k_pool, k_scale),
                             gather(v_pool, v_scale), pos, scale,
-                            block_length)
+                            block_length, window)
 
 
 def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                     k_scale=None, v_scale=None, scale=None,
-                    block_length=1):
+                    block_length=1, window=0):
     """Decode attention against the paged KV pool: Pallas kernel on TPU
     (it copies each slot's LIVE pages of this layer itself, all kv
     heads of a page a transfer, and applies an int8 pool's scales
@@ -390,17 +457,21 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
     elsewhere and for shapes the kernel's `supports` predicate refuses.
     The choice is made from the shapes alone: whatever the kernel
     raises — a lowering or compiler refusal included — reaches the
-    caller.  `block_length`: as ops.cached_attention's."""
+    caller.  `block_length`: as ops.cached_attention's.  `window` W > 0:
+    a sliding-window layer against its ring (xla_paged_attention); the
+    kernel's walk then starts at the window's first page."""
     _check_paged_args(q, k_pool, k_scale, v_scale)
+    _check_window(window, block_length)
     if _on_tpu():
         from .pallas import paged_attention as _k
         if _k.supports(k_pool.shape):
             return _k.paged_attention(q, k_pool, v_pool, page_table, pos,
                                       layer, k_scale, v_scale, scale,
-                                      block_length=block_length)
+                                      block_length=block_length,
+                                      window=window)
     return xla_paged_attention(q, k_pool, v_pool, page_table, pos,
                                layer, k_scale, v_scale, scale,
-                               block_length)
+                               block_length, window)
 
 
 # ---------------------------------------------------------------------------
@@ -544,14 +615,18 @@ def latent_paged_attention(q_lat, q_rope, pool, page_table, pos, layer,
 
 
 def attention(q, k, v, mask=None, causal=False, scale=None, dropout_p=0.0,
-              block_length=1):
+              block_length=1, window=0):
     """Flash kernel or XLA, chosen from the backend setting and the
     shapes (flash_attention.supports) — never from an exception: what
     the kernel raises reaches the caller.  A block-causal mask
-    (`block_length` > 1) is XLA's: the flash kernel has the causal one."""
+    (`block_length` > 1) and a sliding window (`window` > 0) are XLA's:
+    the flash kernel has the causal mask alone."""
     backend = _attention_backend
     if backend == "auto":
         backend = "pallas" if _on_tpu() else "xla"
+    if window:
+        return xla_attention(q, k, v, mask, causal, scale, dropout_p,
+                             block_length, window)
     if block_length > 1:
         return xla_attention(q, k, v, mask, causal, scale, dropout_p,
                              block_length)

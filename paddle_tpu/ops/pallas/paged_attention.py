@@ -69,13 +69,26 @@ def _interpret():
     return jax.default_backend() != "tpu"
 
 
-def pages_walked(pos, q_len, page_size, pages_per_slot):
+def first_page(pos, page_size, window):
+    """The logical page that holds the oldest row a sliding-window lane
+    at depth `pos` may attend, row max(pos - window + 1, 0): where a
+    window layer's walk STARTS.  Arithmetic for numpy vectors and int32
+    scalars alike, as pages_walked."""
+    low = pos - (window - 1)
+    return (low - low * (low < 0)) // page_size
+
+
+def pages_walked(pos, q_len, page_size, pages_per_slot, window=0):
     """Pages of its table a slot at depth `pos` walks for `q_len` query
     lanes: up to the frontier page (pos + q_len - 1) // page_size, at
-    least one and never past the table.  Plain arithmetic, so it serves
-    a numpy vector on the host (the batcher's `kv_pages_walked`) and an
-    int32 scalar in the kernel (the walk's bound) alike."""
+    least one and never past the table; with a `window` from the
+    window's first page (first_page) and not from page 0.  Plain
+    arithmetic, so it serves a numpy vector on the host (the batcher's
+    `kv_pages_walked`) and an int32 scalar in the kernel (the walk's
+    bound) alike."""
     n = (pos + (q_len - 1)) // page_size + 1
+    if window:
+        n = n - first_page(pos, page_size, window)
     n = n + (1 - n) * (n < 1)
     return n - (n - pages_per_slot) * (n > pages_per_slot)
 
@@ -101,7 +114,7 @@ def _blocking(n_kv, rows, page_size, head_dim, kv_itemsize, q_itemsize,
 
 
 def _kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest, scale,
-            page_size, group, q_len, hb, T, quant, block_length):
+            page_size, group, q_len, hb, T, quant, block_length, window=0):
     if quant:
         ks_ref, vs_ref, *rest = rest
     o_ref, kbuf, vbuf, sem, acc_ref, m_ref, l_ref, buf_ref = rest
@@ -116,14 +129,28 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest, scale,
     layer = layer_ref[0]
     loop = jax.lax.fori_loop
 
-    def walk(slot):
-        return pages_walked(pos_ref[slot], q_len, ps, P_slot)
+    if window:
+        # a sliding-window layer: the walk covers the pages that hold
+        # rows pos - window + 1 .. pos + q_len - 1 and no others, and the
+        # table is the slot's ring (logical page p in entry p mod P_slot;
+        # a table of the whole depth never wraps)
+        def walk(slot):
+            return pages_walked(pos_ref[slot], q_len, ps, P_slot, window)
+
+        def entry(slot, j):
+            return (first_page(pos_ref[slot], ps, window) + j) % P_slot
+    else:
+        def walk(slot):
+            return pages_walked(pos_ref[slot], q_len, ps, P_slot)
+
+        def entry(slot, j):
+            return j
 
     def copies(slot, heads, j, buf, t):
-        """The K and V transfers of table entry j of `slot` into place t
+        """The K and V transfers of page j of `slot`'s walk into place t
         of buffer `buf` (a wait needs only the destination's size, so
         it may name any page)."""
-        src = (pt_ref[slot, j], layer, pl.ds(heads * hb, hb))
+        src = (pt_ref[slot, entry(slot, j)], layer, pl.ds(heads * hb, hb))
         return (pltpu.make_async_copy(k_hbm.at[src], kbuf.at[buf, t],
                                       sem.at[0, buf]),
                 pltpu.make_async_copy(v_hbm.at[src], vbuf.at[buf, t],
@@ -167,12 +194,15 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest, scale,
         # block-causal: a row sees to the end of its own block
         qpos = qpos // block_length * block_length + (block_length - 1)
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, T * ps), 1)
+    if window:
+        # column c' of block i sits at position (first + i*T)*ps + c'
+        col = col + first_page(pos, ps, window) * ps
     col_page = jax.lax.broadcasted_iota(jnp.int32, (1, T * ps), 1) // ps
 
     def page_scales(ref, i, hh):
         """[1, T*ps]: each column's page scale for head hh of block i."""
         def place(t, row):
-            j = jnp.minimum(i * T + t, P_slot - 1)
+            j = entry(b, jnp.minimum(i * T + t, P_slot - 1))
             return jnp.where(col_page == t, ref[0, j, hblk * hb + hh], row)
         return loop(0, T, place, jnp.zeros((1, T * ps), jnp.float32),
                     unroll=True)
@@ -194,6 +224,8 @@ def _kernel(pt_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest, scale,
             return c
         loop(0, jnp.minimum(n_pages - i * T, T), wait, 0)
         visible = (i * (T * ps) + col) <= qpos
+        if window:
+            visible &= qpos - (i * (T * ps) + col) < window
 
         def head(hh, carry):
             q = q_ref[0, hh]                              # [rows, d]
@@ -255,12 +287,16 @@ def supports(pool_shape, interpret=None) -> bool:
 def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                     k_scale=None, v_scale=None, scale=None,
                     interpret=None, vmem_budget=_VMEM_BUDGET,
-                    block_length=1):
+                    block_length=1, window=0):
     """q: [B, C, h, d]; pools [P, L, n_kv, ps, d]; page_table
     [B, P_slot] int32; pos [B] int32.  Returns [B, C, h, d] in
     q.dtype.  `block_length` L > 1: the block-causal mask (the walk's
     bound, pages_walked, already reaches pos + C - 1: the caller keeps
-    pos and C multiples of L).  Raises ValueError for shapes `supports`
+    pos and C multiples of L).  `window` W > 0: a sliding-window layer:
+    lane c sees rows pos + c - W + 1 .. pos + c, the walk covers the
+    pages that hold such rows and no others, and the table is the
+    slot's ring of at least ops.ring_pages(W, C, page_size) entries.
+    Raises ValueError for shapes `supports`
     refuses —
     ops.paged_attention asks the predicate first and takes the jnp
     twin for those.  `vmem_budget` is the tests' handle on the head
@@ -278,6 +314,15 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
     quant = k_pool.dtype == jnp.int8
     if quant and (k_scale is None or v_scale is None):
         raise ValueError("int8 KV pool needs k_scale/v_scale")
+    if window and block_length > 1:
+        raise ValueError("no sliding window under the block-causal mask")
+    if window:
+        from .. import ring_pages
+        if ring_pages(window, q.shape[1], ps) > page_table.shape[1]:
+            raise ValueError(
+                f"a window of {window} rows under {q.shape[1]} lanes "
+                f"straddles more pages than the table's "
+                f"{page_table.shape[1]}")
     posv = jnp.asarray(pos, jnp.int32)
     if posv.ndim == 0:
         posv = jnp.broadcast_to(posv, q.shape[:1])
@@ -288,14 +333,14 @@ def paged_attention(q, k_pool, v_pool, page_table, pos, layer,
                  k_scale if quant else None, v_scale if quant else None,
                  scale=float(scale if scale is not None else d ** -0.5),
                  interpret=bool(interp), vmem_budget=int(vmem_budget),
-                 block_length=int(block_length))
+                 block_length=int(block_length), window=int(window))
 
 
 @functools.partial(jax.jit,
                    static_argnames=("scale", "interpret", "vmem_budget",
-                                    "block_length"))
+                                    "block_length", "window"))
 def _call(q, k_pool, v_pool, pt, pos, layer, k_scale, v_scale, *, scale,
-          interpret, vmem_budget, block_length=1):
+          interpret, vmem_budget, block_length=1, window=0):
     B, C, h, d = q.shape
     P, L, n_kv, ps, _ = k_pool.shape
     P_slot = pt.shape[1]
@@ -333,7 +378,7 @@ def _call(q, k_pool, v_pool, pt, pos, layer, k_scale, v_scale, *, scale,
                 memory_space=pltpu.SMEM))
     kern = functools.partial(_kernel, scale=scale, page_size=ps,
                              group=group, q_len=C, hb=hb, T=T, quant=quant,
-                             block_length=block_length)
+                             block_length=block_length, window=window)
     with x64_off():
         out = pl.pallas_call(
             kern,

@@ -435,3 +435,79 @@ def test_dropless_experts_all_held(for_chip):
                     ((tokens,), jnp.bool_), ((held, d, 2 * f_), bf),
                     ((held, f_, d), bf))
     assert text.count("ragged-dot") >= 2
+
+
+@pytest.mark.parametrize("width", [1, 32], ids=["decode", "admit"])
+def test_paged_attention_over_a_ring(for_chip, width):
+    """The kernel with a WINDOW at kexaone236b_serve_mixed_c64's geometry
+    (64 slots, each a ring of 11 pages of 16 rows in a pool of 4 sliding
+    layers, 64 query heads of 128 on 8 kv heads: group 8, window 128): the
+    walk starts at the window's first page, the table entry is the logical
+    page mod the ring, all in the scalar core."""
+    from paddle_tpu import ops
+    from paddle_tpu.ops.pallas.paged_attention import paged_attention
+    slots, kv_heads, heads, layers, window = 64, 8, 64, 4, 128
+    ring = ops.ring_pages(window, 32, PAGE_SIZE)
+    assert ring == 11
+    pool_s = ((slots * ring, layers, kv_heads, PAGE_SIZE, HEAD_DIM),
+              jnp.bfloat16)
+
+    def f(q, kp, vp, pt, pos):
+        return paged_attention(q, kp, vp, pt, pos, layers - 1, window=window)
+    text = for_chip(f, ((slots, width, heads, HEAD_DIM), jnp.bfloat16),
+                    pool_s, pool_s, ((slots, ring), jnp.int32),
+                    ((slots,), jnp.int32))
+    assert "paged_attention" in _kernels(text)
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["decode", "admit"])
+def test_window_model_step_programs_copy_neither_pool(one_chip, for_chip,
+                                                      mixed):
+    """BOTH step programs of ContinuousBatcher over a tiny model with KINDS
+    of layer (L L L G L, window 128, 8 query heads of 128 on 2 kv heads, 4
+    of 16 experts; 4 slots x 512 rows, pages of 16, a ring of 11), compiled
+    for the described chip with the kernels in: the full layers' pool and
+    the window layers' pool are each indexed whole by (page, layer), so the
+    compiled programs hold no pool-sized (or pool-layer-sized) `copy`,
+    `slice` or `dynamic-update-slice` of either (PR 28's rule, for two
+    pools), and the `paged_attention` kernel serves both kinds."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(5)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=5, num_attention_heads=8, num_key_value_heads=2,
+        head_dim=HEAD_DIM, use_qk_norm=True, max_position_embeddings=1024,
+        rope_theta=1e6, dtype="bfloat16",
+        layer_types=("sliding_attention",) * 3
+        + ("full_attention", "sliding_attention"), sliding_window=128,
+        rope_layer_types=("sliding_attention",), first_k_dense_replace=1,
+        moe_gate="sigmoid", moe_num_experts=4, moe_first_expert=4,
+        moe_router_width=16, moe_top_k=3, moe_intermediate_size=128,
+        moe_shared_experts=1, moe_routed_scaling=2.5, moe_router_bias=True))
+    model.eval()
+    bat = ContinuousBatcher(model, max_batch_size=4, max_len=512)
+    assert bat.ring_pages == 11 and bat.page_size == PAGE_SIZE
+    width, steps = (bat.prefill_chunk, bat.admit_steps) if mixed \
+        else (1, bat.chunk)
+    fn = bat._step_fn(width, steps, record=False)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (bat._param_vals(), *bat._carry_args()))
+    text = fn.lower(*args).compile().as_text()
+    assert "paged_attention" in _kernels(text)
+    big = set()
+    for name in ("k", "k_window"):
+        pages, layers, *rest = bat._cache[name].shape
+        big |= {pages * math.prod(rest), pages * layers * math.prod(rest)}
+    for op in ("copy", "slice", "dynamic-update-slice", "transpose"):
+        found = [r for r in _results(text, op) if math.prod(r[0]) in big]
+        assert not found, f"pool-sized {op}: {found}"
+    scatters = [r for r in _results(text, "scatter")
+                if math.prod(r[0]) in big]
+    # K and V of each of the five layers, on the default order (the
+    # compiler drops the one-layer full pool's unit axis)
+    assert len(scatters) == 2 * 5 and all(
+        r[1] == ",".join(map(str, reversed(range(len(r[0])))))
+        for r in scatters), sorted(set(scatters))

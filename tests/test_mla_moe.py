@@ -343,11 +343,15 @@ def test_walked_over_live_reads_the_dispatch_spans(monkeypatch):
 
 # -- (4) the shares add up --------------------------------------------------
 
-def test_four_shares_add_up_to_the_uncut_layer():
-    """8 experts over 4 chips: each share routes over all 8, normalises
-    over all the chosen and computes its 2 experts' part; the parts summed,
-    the shared expert counted once, are the uncut reference layer."""
-    d, f, E, k = 32, 16, 8, 3
+@pytest.mark.parametrize("E, held, k", [(8, 2, 3), (128, 16, 8)],
+                         ids=["four_of_2", "eight_of_16"])
+def test_four_shares_add_up_to_the_uncut_layer(E, held, k):
+    """E experts over E / held chips (8 over 4, and a 128-wide router over
+    eight chips of 16, top 8: k-exaone-236b-a23b's layer): each share routes
+    over all E, normalises over all the chosen and computes its own
+    experts' part; the parts summed, the shared expert counted once, are
+    the uncut reference layer."""
+    d, f = 32, 16
     base = tiny_cfg(hidden_size=d, moe_intermediate_size=f, router_width=E,
                     num_experts_per_tok=k)
     rng = np.random.RandomState(11)
@@ -364,22 +368,23 @@ def test_four_shares_add_up_to_the_uncut_layer():
         whole, x, dict(base, num_experts=E, experts_held=[0, E]), mm)
     shared = ref.swiglu(x, whole["shared_w1"], whole["shared_w2"], mm)
     total = jnp.zeros_like(x)
-    for first in range(0, E, 2):
-        layer = MoELayer(d_model=d, d_hidden=f, num_experts=2,
+    for first in range(0, E, held):
+        layer = MoELayer(d_model=d, d_hidden=f, num_experts=held,
                          gate="sigmoid", top_k=k, activation="swiglu",
-                         experts_held=(first, 2), router_width=E,
+                         experts_held=(first, held), router_width=E,
                          routed_scaling=2.5, router_bias=True,
                          shared_hidden=f)
         vals = {"gate": whole["router"], "bias": whole["router_bias"],
-                "w1": whole["experts_w1"][first:first + 2],
-                "w2": whole["experts_w2"][first:first + 2],
+                "w1": whole["experts_w1"][first:first + held],
+                "w2": whole["experts_w2"][first:first + held],
                 "shared_w1": whole["shared_w1"],
                 "shared_w2": whole["shared_w2"]}
         part = layer._dropless(x, vals)
         np.testing.assert_allclose(            # the share against ITS reference
             np.asarray(part), np.asarray(ref.expert_layer(
                 dict(whole, experts_w1=vals["w1"], experts_w2=vals["w2"]), x,
-                dict(base, num_experts=2, experts_held=[first, first + 2]),
+                dict(base, num_experts=held,
+                     experts_held=[first, first + held]),
                 mm)), rtol=1e-4, atol=1e-5)
         total = total + (part - shared)
     np.testing.assert_allclose(np.asarray(total + shared), np.asarray(uncut),

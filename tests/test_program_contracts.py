@@ -480,3 +480,57 @@ def test_block_schedule_leaves_a_token_models_programs_alone(plain_model):
         assert sorted(lowered.out_info[2]) == sorted(dif._tok)
         assert len(lowered.out_info) == 13
     assert not lint_serve_programs(dif)
+
+
+def _kind_models():
+    """(name, model, batcher knobs) of the four kinds of model the accepted
+    cells serve, tiny: dense GQA, MHA with an int8 pool, latent rows, the
+    block schedule."""
+    small = dict(num_hidden_layers=2, hidden_size=32, intermediate_size=64,
+                 num_attention_heads=4, vocab_size=64)
+
+    def build(**cfg):
+        paddle.seed(11)
+        return LlamaForCausalLM(llama_tiny_config(**dict(small, **cfg)))
+    return [
+        ("dense_gqa", lambda **kw: build(num_key_value_heads=2, **kw), {}),
+        ("mha_int8", lambda **kw: build(num_key_value_heads=4, **kw),
+         dict(kv_dtype="int8")),
+        ("latent", lambda **kw: build(
+            num_key_value_heads=4, kv_lora_rank=16, qk_nope_head_dim=8,
+            qk_rope_head_dim=8, v_head_dim=8, **kw), {}),
+        ("block", lambda **kw: build(
+            num_key_value_heads=2, block_length=4, denoising_steps=2,
+            mask_token_id=63, **kw), {})]
+
+
+@pytest.mark.parametrize("which", range(4),
+                         ids=["dense_gqa", "mha_int8", "latent", "block"])
+def test_kinds_of_layer_leave_a_model_without_them_alone(which):
+    """A model without sliding-window layers compiles the step programs it
+    always did: no pool of a second kind, no `attn.window` / `attn.full`
+    scope, no ring in the program key, no count by kind in stats(); and a
+    model whose `layer_types` call EVERY layer `full_attention` lowers to
+    the same text as one that names no kinds (PR 34 held the four models'
+    eight programs, and the paged kernel's jaxpr in six variants, to the
+    parent commit's text: PERF.md section 6)."""
+    _, build, knobs = _kind_models()[which]
+    plain = build()
+    bat = ContinuousBatcher(plain, **_GEOMETRY, **_PLAIN, **knobs)
+    assert bat._kinds is None and bat.ring_pages == 0
+    assert "ring" not in bat._program_key(1, bat.chunk)
+    assert not set(bat._cache) & {"k_window", "v_window"}
+    assert not [k for k in bat.stats() if k.startswith(
+        ("kv_pages_walked_", "kv_pages_window_", "kv_pool_bytes"))]
+    texts = []
+    for mixed in (False, True):
+        lowered = bat.lower_step(mixed=mixed)
+        assert "attn.window" not in lowered.as_text(debug_info=True)
+        assert "attn.full" not in lowered.as_text(debug_info=True)
+        texts.append(lowered.as_text())
+    named = ContinuousBatcher(
+        build(layer_types=("full_attention",) * 2, sliding_window=8),
+        **_GEOMETRY, **_PLAIN, **knobs)
+    assert named._kinds is None
+    assert [named.lower_step(mixed=m).as_text()
+            for m in (False, True)] == texts
