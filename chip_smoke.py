@@ -21,6 +21,15 @@ calls, on ONE TPU chip and in ONE process:
           pool of 576-wide rows at the latent serve cell's geometry (64
           slots, ragged depths, decode and admission widths), both sides'
           time a call printed.
+  moe     the expert layer's dropless path alone
+          (incubate...moe.dropless_experts) at the three expert serve
+          cells' admission geometry (2,048 tokens, 8 experts a token of a
+          router 128 wide), the full-length program against the ladder of
+          sorted-buffer lengths at 512, 2,048, 4,096 and 16,384
+          assignments held: results compared, a call's device time split
+          by scope (moe.dispatch / moe.experts / moe.combine).  NOT part
+          of the default run:
+              python3 -c "import chip_smoke; chip_smoke.phase_moe()"
 
 For both, the compiled program's text must hold the Pallas kernels
 (`tpu_custom_call`): flash attention, rms norm, rope and fused AdamW in the
@@ -335,6 +344,15 @@ TIMED_CALLS = 20
 # the pool (0.3 GB)
 LATENT_SLOTS, LATENT_PAGES_PER_SLOT, LATENT_CHUNK = 64, 256, 32
 LATENT_HEADS, LATENT_RANK, LATENT_ROPE, LATENT_SCALE = 64, 512, 64, 0.135
+# the expert layer at the three expert serve cells' admission step: (hidden,
+# an expert's width, experts HELD of the router's MOE_ROUTER), and how many
+# of a step's MOE_TOKENS * MOE_TOP_K assignments are held by a valid lane
+MOE_GEOMETRIES = {"k-exaone-236b-a23b": (6144, 2048, 16),
+                  "sarvam-105b": (4096, 2048, 32),
+                  "sdar-30b-a3b-chat": (2048, 768, 128)}
+MOE_TOKENS, MOE_TOP_K, MOE_ROUTER = 2048, 8, 128
+MOE_HELD = (512, 2048, 4096, 16384)
+MOE_TRACED_CALLS = 5
 
 
 def ms_a_call(fn, args):
@@ -506,6 +524,102 @@ def phase_latent(page_size=16):
         if not close:
             fail(f"latent: the latent kernel leaves its twin (width "
                  f"{lanes}): {gap}")
+
+
+def moe_routing(rng, count, n_held):
+    """topi [MOE_TOKENS, MOE_TOP_K] (distinct experts a token) and valid
+    [MOE_TOKENS] with exactly n_held assignments held by a valid lane: a
+    layer that holds a share gives each valid token as few held choices as
+    reach n_held (1 where n_held tokens exist), one that holds every expert
+    of the router makes n_held / k tokens valid."""
+    import numpy as np
+    S, k, width = MOE_TOKENS, MOE_TOP_K, MOE_ROUTER
+    n_tok, per = (n_held // k, k) if count == width \
+        else (min(S, n_held), n_held // min(S, n_held))
+    topi = np.empty((S, k), np.int32)
+    for s in range(S):
+        held = rng.permutation(count)[:per]
+        absent = count + rng.permutation(width - count)[:k - per]
+        topi[s] = rng.permutation(np.concatenate([held, absent]))
+    return topi, np.arange(S) < n_tok
+
+
+def scope_ms_a_call(fn, args, calls):
+    """Device time of one call in ms by the expert layer's scopes, from a
+    profiler trace of `calls` calls (under the benchmark's .bench_trace):
+    the leaf operations' `op_name`s as benchmark/program_spans.py reads
+    them (the grouped product's kernels carry none and are named
+    `ragged-dot-*`: with moe.experts)."""
+    import os
+    import jax
+    sys.path.insert(0, os.path.join(os.path.dirname(
+        os.path.abspath(__file__)), "benchmark"))
+    try:
+        import harness
+        import program_spans
+    finally:
+        sys.path.pop(0)
+    jax.block_until_ready(fn(*args))
+    tracer = harness.Tracer("chip_smoke_moe", True)
+    tracer.start()
+    for _ in range(calls):
+        out = fn(*args)
+    jax.block_until_ready(out)
+    tracer.stop()
+    split = dict.fromkeys(("moe.dispatch", "moe.experts", "moe.combine",
+                           "other"), 0.0)
+    for name, dur in program_spans.load(tracer.xplane_path()).device_leaves:
+        scope = "moe.experts" if "ragged-dot" in name else next(
+            (s for s in split if s in name), "other")
+        split[scope] += dur * 1e-6 / calls
+    return {k: round(v, 3) for k, v in split.items()}
+
+
+def phase_moe():
+    """`dropless_experts` alone, the full-length program (the only rung all
+    S*k: what every call ran before PR 35) against the ladder, at the three
+    expert cells' admission geometry: the table that sets the rungs."""
+    import functools
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.incubate.distributed.models import moe
+    S, k = MOE_TOKENS, MOE_TOP_K
+    if jax.devices()[0].platform != "tpu":
+        fail("moe: a device time comes from a TPU's trace only")
+    rng = np.random.RandomState(SEED + 4)
+    key = jax.random.PRNGKey(SEED + 4)
+
+    def experts(lengths, x, topi, topw, valid, w1, w2):
+        return moe._sorted_experts(x, topi, topw, w1, w2, "swiglu", 0, valid,
+                                   None, None, None, lengths)
+    programs = {"full": jax.jit(functools.partial(experts, (S * k,))),
+                "ladder": jax.jit(functools.partial(
+                    experts, moe.sorted_lengths(S * k)))}
+    say(phase="moe", tokens=S, top_k=k, router=MOE_ROUTER,
+        lengths=moe.sorted_lengths(S * k))
+    for name, (d, f, count) in MOE_GEOMETRIES.items():
+        w1 = jax.random.normal(key, (count, d, 2 * f), jnp.bfloat16) * 0.02
+        w2 = jax.random.normal(key, (count, f, d), jnp.bfloat16) * 0.02
+        x = jax.random.normal(key, (S, d), jnp.bfloat16)
+        topw = jax.random.uniform(key, (S, k), jnp.float32)
+        for n_held in MOE_HELD:
+            topi, valid = moe_routing(rng, count, n_held)
+            args = (x, jnp.asarray(topi), topw, jnp.asarray(valid), w1, w2)
+            got = {p: np.asarray(fn(*args)) for p, fn in programs.items()}
+            gap, close = twin_gap(got["ladder"], got["full"])
+            line = dict(config=name, hidden=d, expert_width=f, held=count,
+                        assignments_held=n_held, **gap)
+            for p, fn in programs.items():
+                line[p + "_ms_a_call"] = round(ms_a_call(fn, args), 3)
+                line[p + "_scope_ms"] = scope_ms_a_call(
+                    fn, args, MOE_TRACED_CALLS)
+            say(phase="moe", **line)
+            if not close:
+                fail(f"moe: the ladder leaves the full-length program "
+                     f"({name}, {n_held} held): {gap}")
+        del w1, w2, x, args, got
+        release("moe")
 
 
 def phase_serve():

@@ -25,7 +25,9 @@ is the caller's choice, as in the reference).  The gates WITHOUT a capacity
 (naive, sigmoid) drop nothing: their assignments are sorted by expert and
 the experts run as one grouped product over the sorted rows
 (`dropless_experts`), so the work grows with the assignments, not with
-experts x tokens.
+experts x tokens; and the sorted buffer is as long as the assignments a
+layer HOLDS for valid lanes, rounded up to a rung of a short ladder
+(`sorted_lengths`), not as long as all tokens x k.
 
 A layer may hold only ITS SHARE of the experts (`experts_held=(first,
 count)` of a router `router_width` wide): it routes over all of them,
@@ -35,6 +37,7 @@ on one chip the layer runs without the exchange.
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import List, Optional
 
@@ -53,9 +56,33 @@ __all__ = ["MoELayer", "NaiveGate", "SwitchGate", "GShardGate",
            "SigmoidGate", "ExpertMLP", "StepCounters", "dropless_experts",
            "COUNTER_NAMES"]
 
-# what a dropless layer counts a step, on the device (StepCounters)
+# what a dropless layer counts a step, on the device (StepCounters): every
+# name a SUM over layers and steps but one that ends in `_max`, a maximum.
+# `moe_rows_sorted` is the length of the sorted buffer a layer took (the
+# rung of `sorted_lengths`); over `moe_assignments_held` it says how tight
+# the buffer sits around the rows that are used.
 COUNTER_NAMES = ("moe_assignments", "moe_assignments_held",
-                 "moe_expert_steps_hit", "moe_tokens_per_expert_max")
+                 "moe_expert_steps_hit", "moe_rows_sorted",
+                 "moe_tokens_per_expert_max")
+_N_SUMS = sum(not name.endswith("_max") for name in COUNTER_NAMES)
+
+# A rung is left out where it is shorter than this: below it the row-sized
+# ops cost less than the conditional around them (chip_smoke.py, phase
+# `moe`: the table in CHANGES.md, PR 35).
+MIN_RUNG_ROWS = 1024
+# The rows go back to their tokens by a scatter-add of R rows where R is at
+# most this fraction of S*k, by the parent's gather of S*k rows above it
+# (the same table: the scatter-add costs 0.33 ms a 1,024 rows of 6,144, the
+# gather, select and sum out of a buffer of R rows 0.7 ms whatever R).
+GATHER_BACK_OVER = 8
+
+
+def sorted_lengths(n):
+    """The lengths the sorted buffer of `n` = S*k assignments may take,
+    ascending, from `n` alone: a sixteenth, a quarter, all of them.  The
+    last is always `n`, so every routing is served whole."""
+    return tuple(n // q for q in (16, 4)
+                 if n % q == 0 and n // q >= MIN_RUNG_ROWS) + (n,)
 
 
 class StepCounters:
@@ -65,20 +92,22 @@ class StepCounters:
     (the others are routed nowhere and cost no expert work).  `vector()`
     is int32 [len(COUNTER_NAMES)]: assignments of valid tokens,
     those that chose an expert held here, (layer, held expert) pairs
-    with at least one token, and the largest single (layer, expert)
-    load — the first three add over layers and steps, the last is a
-    maximum."""
+    with at least one token, the rows of the sorted buffers the layers
+    took, and the largest single (layer, expert) load — the sums add
+    over layers and steps, the last is a maximum."""
 
     def __init__(self, valid=None):
         self.valid = None if valid is None else valid.reshape(-1)
-        self._sums = jnp.zeros((3,), jnp.int32)
+        self._sums = jnp.zeros((_N_SUMS,), jnp.int32)
         self._max = jnp.zeros((), jnp.int32)
 
-    def add(self, n_valid, top_k, sizes):
-        """sizes [count]: valid tokens each held expert got."""
+    def add(self, n_valid, top_k, sizes, rows_sorted):
+        """sizes [count]: valid tokens each held expert got; rows_sorted:
+        the length of the buffer the layer sorted them into."""
         self._sums = self._sums + jnp.stack(
             [n_valid * top_k, jnp.sum(sizes),
-             jnp.sum((sizes > 0).astype(jnp.int32))]).astype(jnp.int32)
+             jnp.sum((sizes > 0).astype(jnp.int32)),
+             rows_sorted]).astype(jnp.int32)
         self._max = jnp.maximum(self._max, jnp.max(sizes))
 
     def vector(self):
@@ -86,9 +115,9 @@ class StepCounters:
 
     @staticmethod
     def merge(steps):
-        """[steps, 4] of a scan -> [4]."""
-        return jnp.concatenate([jnp.sum(steps[:, :3], axis=0),
-                                jnp.max(steps[:, 3:], axis=0)])
+        """[steps, len(COUNTER_NAMES)] of a scan -> [len(COUNTER_NAMES)]."""
+        return jnp.concatenate([jnp.sum(steps[:, :_N_SUMS], axis=0),
+                                jnp.max(steps[:, _N_SUMS:], axis=0)])
 
 
 def dropless_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
@@ -98,11 +127,22 @@ def dropless_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
     ids over the router's whole width; topw [S, k] fp32; w1
     [count, d, *], w2 [count, *, d] the held experts first..first+count
     (optional biases [count, 1, *]).  The S*k assignments are sorted by
-    held expert (absent experts' and invalid tokens' last), the rows
-    gathered in that order and both products run grouped
+    held expert (absent experts' and invalid tokens' last); the first R
+    of them, R the shortest of `sorted_lengths(S*k)` that holds every
+    assignment HELD (chosen on the device, `jax.lax.switch`), are
+    gathered in that order, both products run grouped
     (`jax.lax.ragged_dot`: a grouped-matmul kernel on TPU that stops at
-    the last group's end), then each token sums its k rows back in
-    fp32.  Returns [S, d] fp32."""
+    the last group's end), and each token sums its rows back in fp32.
+    The longest rung is all S*k, so nothing is ever dropped.  Returns
+    [S, d] fp32."""
+    return _sorted_experts(tokens, topi, topw, w1, w2, act, first, valid,
+                           b1, b2, counters, sorted_lengths(topi.size))
+
+
+def _sorted_experts(tokens, topi, topw, w1, w2, act, first, valid, b1, b2,
+                    counters, lengths):
+    """`dropless_experts` over the given rungs: `(S*k,)` where the caller
+    knows at trace time that every assignment is held (no conditional)."""
     S, k = topi.shape
     count = w1.shape[0]
     local = topi.astype(jnp.int32) - first
@@ -116,12 +156,32 @@ def dropless_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
         # TPU lowering takes no 64-bit group sizes
         sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0,
                         promote_integers=False)
-        rows = jnp.take(tokens, order // k, axis=0)          # [S*k, d]
+    if len(lengths) == 1:
+        rung = 0
+        y = _rung(S * k, act, tokens, order, key, sizes, held, topw, w1, w2,
+                  b1, b2)
+    else:
+        n_held = jnp.sum(sizes, promote_integers=False)
+        rung = sum((n_held > r).astype(jnp.int32) for r in lengths[:-1])
+        y = jax.lax.switch(
+            rung, [functools.partial(_rung, r, act) for r in lengths],
+            tokens, order, key, sizes, held, topw, w1, w2, b1, b2)
     if counters is not None:
         n_valid = S if valid is None else jnp.sum(valid.astype(jnp.int32))
-        counters.add(n_valid, k, sizes)
+        counters.add(n_valid, k, sizes,
+                     jnp.asarray(lengths, jnp.int32)[rung])
+    return y
+
+
+def _rung(R, act, tokens, order, key, sizes, held, topw, w1, w2, b1, b2):
+    """The layer's result [S, d] fp32 from the first R sorted assignments:
+    every held one lies among them (the caller's choice of R)."""
+    S, k = topw.shape
+    with jax.named_scope("moe.dispatch"):
+        taken = order[:R]
+        rows = jnp.take(tokens, taken // k, axis=0)              # [R, d]
     with jax.named_scope("moe.experts"):
-        expert = jnp.take(key, order)     # of each sorted row
+        expert = jnp.take(key, taken)      # of each sorted row
         h = jax.lax.ragged_dot(rows, w1, sizes)
         if b1 is not None:
             h = h + jnp.take(b1[:, 0], expert, axis=0, mode="clip")
@@ -130,12 +190,22 @@ def dropless_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
         if b2 is not None:
             out = out + jnp.take(b2[:, 0], expert, axis=0, mode="clip")
     with jax.named_scope("moe.combine"):
-        back = jnp.zeros((S * k,), jnp.int32).at[order].set(
-            jnp.arange(S * k, dtype=jnp.int32))
-        mine = jnp.take(out, back, axis=0).reshape(S, k, -1)
-        # rows past the last group are whatever the kernel left there
-        mine = jnp.where(held[..., None], mine.astype(jnp.float32), 0.0)
-        return jnp.sum(mine * topw[..., None].astype(jnp.float32), axis=1)
+        if R * GATHER_BACK_OVER > S * k:
+            # each token gathers its k rows: S*k rows moved whatever R is
+            back = jnp.zeros((S * k,), jnp.int32).at[order].set(
+                jnp.arange(S * k, dtype=jnp.int32))
+            mine = jnp.take(out, back, axis=0).reshape(S, k, -1)
+            # rows past the last group are whatever the kernel left there
+            mine = jnp.where(held[..., None], mine.astype(jnp.float32), 0.0)
+            return jnp.sum(mine * topw[..., None].astype(jnp.float32),
+                           axis=1)
+        # each row is added to its token's, weighted, in fp32: R rows moved
+        live = jnp.take(held.reshape(-1), taken)
+        weight = jnp.take(topw.reshape(-1).astype(jnp.float32), taken)
+        mine = jnp.where(live[:, None],
+                         out.astype(jnp.float32) * weight[:, None], 0.0)
+        return jnp.zeros((S, out.shape[-1]), jnp.float32).at[
+            taken // k].add(mine)
 
 
 def _topk_dispatch(gates, k, capacity):
@@ -417,9 +487,15 @@ class MoELayer(Layer):
 
         def cast(name):
             return None if vals.get(name) is None else vals[name].astype(cd)
-        y = dropless_experts(tokens, topi, topw, cast("w1"), cast("w2"),
-                             act, self.first_expert, valid, cast("b1"),
-                             cast("b2"), counters)
+        # what is known while tracing is decided while tracing: with no
+        # lane invalid and every expert of the router held here, every
+        # assignment is held and the buffer is all S*k rows
+        n = topi.size
+        every_held = valid is None and self.num_experts == gate.num_experts
+        y = _sorted_experts(tokens, topi, topw, cast("w1"), cast("w2"),
+                            act, self.first_expert, valid, cast("b1"),
+                            cast("b2"), counters,
+                            (n,) if every_held else sorted_lengths(n))
         if vals.get("shared_w1") is not None:
             with jax.named_scope("moe.shared"):
                 h = _expert_act(tokens @ cast("shared_w1"), "swiglu")

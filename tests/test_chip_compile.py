@@ -437,6 +437,93 @@ def test_dropless_experts_all_held(for_chip):
     assert text.count("ragged-dot") >= 2
 
 
+def _computations(text):
+    """{name: its lines} of every computation of an HLO module's text."""
+    found, name = {}, None
+    for line in text.splitlines():
+        m = re.match(r"(?:ENTRY )?%([\w.-]+) \(.*\{$", line)
+        if m:
+            name = m.group(1)
+            found[name] = []
+        elif name is not None:
+            found[name].append(line)
+    return found
+
+
+def _closure(computations, root):
+    """The text of a computation and of every one it calls, however deep
+    (fusions, loops, the grouped product's own computations)."""
+    seen, todo = set(), [root]
+    while todo:
+        name = todo.pop()
+        if name in seen or name not in computations:
+            continue
+        seen.add(name)
+        for line in computations[name]:
+            todo.extend(re.findall(r"%([\w.-]+)", line.split(" = ", 1)[-1]))
+    return "\n".join(line for name in sorted(seen)
+                     for line in computations[name])
+
+
+def _row_sized(text, rows, least):
+    """Dims of every instruction result `rows` long and at least `least`
+    wide: a buffer of sorted rows."""
+    pat = re.compile(r" = \w+\[(%d),(\d+)\]\{" % rows)
+    return [(int(m.group(1)), int(m.group(2)))
+            for m in map(pat.search, text.splitlines())
+            if m and int(m.group(2)) >= least]
+
+
+@pytest.mark.parametrize("d, f_, held", [(6144, 2048, 16), (2048, 768, 128)],
+                         ids=["kexaone236b", "sdar30b"])
+def test_dropless_experts_ladder_at_an_admission_step(for_chip, d, f_, held):
+    """The expert layer at the expert cells' REAL admission geometry (2,048
+    tokens, 8 of a router 128 wide; kexaone236b_serve_mixed_c64 holds 16
+    experts 2048 wide behind hidden 6144, sdar30b_serve_gen_c64 all 128, 768
+    wide behind 2048; a `valid` mask): one conditional over the ladder's
+    three rungs.  The SHORTEST rung (a sixteenth: what a window step takes)
+    holds no buffer of S*k rows at all: its rows come and go by R; the
+    MIDDLE one gathers, multiplies and activates R rows and only its combine
+    is the full-length program's gather of S*k rows (cheaper than a
+    scatter-add above an eighth of S*k: CHANGES.md, PR 35); every rung runs
+    the grouped product twice; and the conditional copies neither expert
+    stack (0.8 and 0.4 GB a layer on kexaone)."""
+    import paddle_tpu  # noqa: F401  (x64 on, as every program of the repo)
+    from paddle_tpu.incubate.distributed.models.moe import (
+        dropless_experts, sorted_lengths)
+    k, tokens = 8, 2048
+    n = tokens * k
+    assert sorted_lengths(n) == (1024, 4096, n)
+
+    def f(x, scores, valid, w1, w2):
+        topv, topi = jax.lax.top_k(scores, k)
+        return dropless_experts(x, topi, topv, w1, w2, "swiglu", valid=valid)
+    bf = jnp.bfloat16
+    text = for_chip(f, ((tokens, d), bf), ((tokens, 128), jnp.float32),
+                    ((tokens,), jnp.bool_), ((held, d, 2 * f_), bf),
+                    ((held, f_, d), bf))
+    conditionals = re.findall(r"conditional\(.*branch_computations=\{([^}]*)\}",
+                              text)
+    assert len(conditionals) == 1, conditionals
+    branches = [b.strip().lstrip("%") for b in conditionals[0].split(",")]
+    assert len(branches) == 3
+    computations = _computations(text)
+    low, middle, top = (_closure(computations, b) for b in branches)
+    for branch in (low, middle, top):
+        assert branch.count("ragged-dot") >= 2
+    assert _row_sized(low, 1024, f_) and not _row_sized(low, n, f_)
+    assert not _row_sized(low, 4096, f_)
+    # the middle rung: the combine's rows alone are S*k long (as wide as the
+    # stream), nothing the dispatch or the activation make
+    assert _row_sized(middle, 4096, f_)
+    assert {width for _, width in _row_sized(middle, n, f_)} <= {d}
+    assert (n, 2 * f_) in _row_sized(top, n, f_)
+    stacks = {held * d * 2 * f_, held * f_ * d}
+    copied = [r for r in _results(text, "copy") + _results(text, "copy-start")
+              if math.prod(r[0]) in stacks]
+    assert not copied, f"an expert stack is copied: {copied}"
+
+
 @pytest.mark.parametrize("width", [1, 32], ids=["decode", "admit"])
 def test_paged_attention_over_a_ring(for_chip, width):
     """The kernel with a WINDOW at kexaone236b_serve_mixed_c64's geometry

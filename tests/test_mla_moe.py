@@ -15,8 +15,9 @@ import jax.numpy as jnp
 import paddle_tpu as paddle
 from paddle_tpu import ops as tpu_ops
 from paddle_tpu.inference import ContinuousBatcher
+from paddle_tpu.incubate.distributed.models import moe as moe_module
 from paddle_tpu.incubate.distributed.models.moe import (
-    MoELayer, SigmoidGate, StepCounters, dropless_experts)
+    MoELayer, SigmoidGate, StepCounters, dropless_experts, sorted_lengths)
 
 BENCH = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "benchmark")
@@ -341,6 +342,18 @@ def test_walked_over_live_reads_the_dispatch_spans(monkeypatch):
         assert reader.read(trace, {}, {}) is None
 
 
+def test_rows_sorted_over_held_reads_the_counters():
+    """benchmark/metrics/moe_rows_sorted_over_held.py: the window's rows of
+    the sorted buffers over the assignments held; nothing (and no error)
+    from a program without the counter, or with nothing held."""
+    from metrics import moe_rows_sorted_over_held as reader
+    assert reader.read(None, {"moe_rows_sorted": 4096,
+                              "moe_assignments_held": 1280}, {}) == 3.2
+    for counters in ({}, {"moe_assignments_held": 1280},
+                     {"moe_rows_sorted": 4096, "moe_assignments_held": 0}):
+        assert reader.read(None, counters, {}) is None
+
+
 # -- (4) the shares add up --------------------------------------------------
 
 @pytest.mark.parametrize("E, held, k", [(8, 2, 3), (128, 16, 8)],
@@ -414,28 +427,258 @@ def test_router_bias_moves_the_choice_not_the_weight():
 
 # -- (6) dropless under skew ------------------------------------------------
 
-@pytest.mark.parametrize("target, held_rows", [(5, 12), (1, 0)])
-def test_dropless_under_skew(target, held_rows):
-    """Every token to ONE held expert (no capacity drops a row), and every
-    token to absent experts (the share adds nothing)."""
+def _full_length_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
+                         b1=None, b2=None, counters=None):
+    """The oracle: `dropless_experts` as it was before the sorted buffer
+    followed the assignments held (PR 34's text, to the letter but for the
+    counter's fourth sum): all S*k sorted rows gathered, multiplied,
+    gathered back, selected and summed."""
+    S, k = topi.shape
+    count = w1.shape[0]
+    local = topi.astype(jnp.int32) - first
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held = held & valid[:, None]
+    with jax.named_scope("moe.dispatch"):
+        key = jnp.where(held, local, count).reshape(-1)
+        order = jnp.argsort(key, stable=True)
+        # int32 whatever jax_enable_x64 says: the grouped product's
+        # TPU lowering takes no 64-bit group sizes
+        sizes = jnp.sum(jax.nn.one_hot(key, count, dtype=jnp.int32), axis=0,
+                        promote_integers=False)
+        rows = jnp.take(tokens, order // k, axis=0)          # [S*k, d]
+    if counters is not None:
+        n_valid = S if valid is None else jnp.sum(valid.astype(jnp.int32))
+        counters.add(n_valid, k, sizes, S * k)
+    with jax.named_scope("moe.experts"):
+        expert = jnp.take(key, order)     # of each sorted row
+        h = jax.lax.ragged_dot(rows, w1, sizes)
+        if b1 is not None:
+            h = h + jnp.take(b1[:, 0], expert, axis=0, mode="clip")
+        out = jax.lax.ragged_dot(
+            moe_module._expert_act(h, act).astype(rows.dtype), w2, sizes)
+        if b2 is not None:
+            out = out + jnp.take(b2[:, 0], expert, axis=0, mode="clip")
+    with jax.named_scope("moe.combine"):
+        back = jnp.zeros((S * k,), jnp.int32).at[order].set(
+            jnp.arange(S * k, dtype=jnp.int32))
+        mine = jnp.take(out, back, axis=0).reshape(S, k, -1)
+        # rows past the last group are whatever the kernel left there
+        mine = jnp.where(held[..., None], mine.astype(jnp.float32), 0.0)
+        return jnp.sum(mine * topw[..., None].astype(jnp.float32), axis=1)
+
+
+@pytest.fixture()
+def short_rungs(monkeypatch):
+    """The ladder at a test's sizes: a rung may be as short as one row."""
+    monkeypatch.setattr(moe_module, "MIN_RUNG_ROWS", 1)
+
+
+def _rung_of(lengths, n_held):
+    return next(r for r in lengths if n_held <= r)
+
+
+@pytest.mark.parametrize("chosen, held_rows", [
+    ((5, 12), 16), ((1, 0), 0), ((5, 6), 32)],
+    ids=["one held", "none held", "every one held"])
+def test_dropless_under_skew(short_rungs, chosen, held_rows):
+    """Every token to ONE held expert (no capacity drops a row), every token
+    to absent experts (the share adds nothing), and every token to held
+    experts ONLY: all S*k assignments are held, the longest rung serves
+    them, nothing is dropped."""
     rng = np.random.RandomState(4)
-    S, d, f, first = 12, 16, 8, 4
+    S, d, f, first = 16, 16, 8, 4
+    assert sorted_lengths(2 * S) == (2, 8, 32)
     w1 = jnp.asarray(rng.randn(4, d, 2 * f), jnp.float32)
     w2 = jnp.asarray(rng.randn(4, f, d), jnp.float32)
     x = jnp.asarray(rng.randn(S, d), jnp.float32)
-    topi = jnp.stack([jnp.full((S,), target), jnp.full((S,), 12)], 1)
+    topi = jnp.stack([jnp.full((S,), e) for e in chosen], 1)
     topw = jnp.asarray(rng.rand(S, 2), jnp.float32)
     counters = StepCounters()
     y = np.asarray(dropless_experts(x, topi, topw, w1, w2, "swiglu", first,
                                     counters=counters))
     want = np.zeros((S, d), np.float32)
-    if held_rows:
-        gu = np.asarray(x) @ np.asarray(w1[target - first])
-        act = gu[:, :f] / (1 + np.exp(-gu[:, :f])) * gu[:, f:]
-        want = np.asarray(topw)[:, :1] * (act @ np.asarray(w2[target - first]))
+    for j, e in enumerate(chosen):
+        if first <= e < first + 4:
+            gu = np.asarray(x) @ np.asarray(w1[e - first])
+            act = gu[:, :f] / (1 + np.exp(-gu[:, :f])) * gu[:, f:]
+            want += np.asarray(topw)[:, j:j + 1] * (
+                act @ np.asarray(w2[e - first]))
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+    hit = held_rows // S
     assert counters.vector().tolist() == [
-        2 * S, held_rows, int(held_rows > 0), held_rows]
+        2 * S, held_rows, hit, _rung_of((2, 8, 32), held_rows),
+        S if hit else 0]
+
+
+# -- (6b) the sorted buffer follows the assignments held ----------------------
+
+def _routing(rng, S, k, first, count, width, n_held, with_valid):
+    """topi [S, k] (distinct ids a token), valid [S] or None, and how many
+    assignments are held by a valid token: `n_held` of them where a share
+    is held (any number can be), the nearest multiple of k not below it
+    where every expert is (a valid token's k are all held)."""
+    every = count == width
+    want = np.zeros((S, k), bool)
+    if every:
+        want[:-(-n_held // k)] = True
+    else:
+        want.reshape(-1)[rng.permutation(S * k)[:n_held]] = True
+    valid = np.ones((S,), bool)
+    if with_valid:
+        # a share: up to a quarter of the tokens invalid, as many as leave
+        # room for n_held assignments among the others
+        valid = want.any(1) if every else np.arange(S) >= min(
+            S // 4, (S * k - n_held) // k)
+        if not every:
+            # what an invalid token chose is held by nobody
+            want[~valid] = False
+            spare = np.flatnonzero(valid.repeat(k) & ~want.reshape(-1))
+            short = n_held - int(want.sum())
+            want.reshape(-1)[rng.permutation(spare)[:short]] = True
+    absent = np.setdiff1d(np.arange(width), np.arange(first, first + count))
+    topi = np.zeros((S, k), np.int64)
+    for s in range(S):
+        inside = first + rng.permutation(count)
+        outside = rng.permutation(absent) if len(absent) else inside[::-1]
+        picks = np.where(want[s], inside[:k], outside[:k])
+        if not valid[s] and not every:
+            picks = (first + rng.permutation(width - first))[:k]
+        topi[s] = picks
+    ids = topi - first
+    held = int((((ids >= 0) & (ids < count)) & valid[:, None]).sum())
+    return (jnp.asarray(topi, jnp.int32),
+            jnp.asarray(valid) if with_valid else None, held)
+
+
+S_LADDER, K_LADDER = 32, 8                 # 256 assignments: rungs 16, 64, 256
+
+
+# every expert held and every lane valid, all S*k are held: one case
+LADDER_CASES = [(n, share, with_valid)
+                for n in (0, 1, 16, 17, 64, 65, 256)
+                for share, with_valid in ((True, False), (True, True),
+                                          (False, True))] + [(256, False,
+                                                              False)]
+
+
+@pytest.mark.parametrize("leaves", ["float32 biased", "bfloat16 biased",
+                                    "bfloat16 plain"])
+@pytest.mark.parametrize(
+    "n_held, share, with_valid", LADDER_CASES,
+    ids=[f"{n} held, {'a share' if share else 'every expert'}, "
+         f"{'valid' if with_valid else 'all'} lanes"
+         for n, share, with_valid in LADDER_CASES])
+def test_laddered_experts_equal_the_full_length_program(
+        short_rungs, n_held, share, with_valid, leaves):
+    """`dropless_experts` over the rung it takes against the full-length
+    program, at 0, 1, R and R + 1 assignments held for every rung R and at
+    all S*k.  The longest rung is the oracle's own program: equal to the
+    last bit.  The shortest sums a token's rows in another order (a
+    scatter-add, not a sum over k; the middle one keeps the gather):
+    within fp32 rounding of the sum.  With BIASES in bfloat16 the compiler
+    also rounds `out + b2` where it fuses it into another pass: within ONE
+    bfloat16 step of the largest output (0.39 of a step read here)."""
+    S, k, d, f = S_LADDER, K_LADDER, 16, 8
+    lengths = sorted_lengths(S * k)
+    assert lengths == (16, 64, 256)
+    first, count, width = (8, 8, 32) if share else (0, 8, 8)
+    dtype, biased = leaves.split()
+    rng = np.random.RandomState(n_held + 7 * share + 3 * with_valid)
+    topi, valid, held = _routing(rng, S, k, first, count, width, n_held,
+                                 with_valid)
+    assert held == n_held or (not share and 0 <= held - n_held < k)
+    dt = jnp.dtype(dtype)
+    x = jnp.asarray(rng.randn(S, d), dt)
+    w1 = jnp.asarray(rng.randn(count, d, 2 * f) * 0.3, dt)
+    w2 = jnp.asarray(rng.randn(count, f, d) * 0.3, dt)
+    b1 = b2 = None
+    if biased == "biased":
+        b1 = jnp.asarray(rng.randn(count, 1, 2 * f) * 0.1, dt)
+        b2 = jnp.asarray(rng.randn(count, 1, d) * 0.1, dt)
+    topw = jnp.asarray(rng.rand(S, k), jnp.float32)
+
+    def run(fn):
+        # one compiled program a side (eager, the oracle would run op by
+        # op and the ladder's branches as programs: rounded differently)
+        def program(*leaves):
+            counters = StepCounters(valid)
+            y = fn(*leaves[:5], "swiglu", first, valid, *leaves[5:],
+                   counters)
+            return y, counters.vector()
+        y, counts = jax.jit(program)(x, topi, topw, w1, w2, b1, b2)
+        return np.asarray(y), counts.tolist()
+    got, got_c = run(dropless_experts)
+    want, want_c = run(_full_length_experts)
+    assert got.dtype == np.float32 and got.shape == (S, d)
+    rung = _rung_of(lengths, held)
+    if rung == S * k:
+        np.testing.assert_array_equal(got, want)
+    else:
+        largest = max(np.abs(want).max(), 2.0 ** -100)
+        step = 2.0 ** (np.floor(np.log2(largest)) - 7) \
+            if leaves == "bfloat16 biased" else k * 2.0 ** -23 * largest
+        np.testing.assert_allclose(got, want, rtol=0, atol=step)
+    if held == 0:
+        assert not got.any()
+    assert got_c[1] == held and got_c[3] == rung and want_c[3] == S * k
+    assert got_c[:3] + got_c[4:] == want_c[:3] + want_c[4:]
+
+
+def test_grad_through_a_rung_equals_the_full_length_programs(short_rungs):
+    """The conditional and the scatter-add differentiate: the gradient of a
+    laddered call (a share, some lanes invalid, the middle rung) is the
+    full-length program's."""
+    S, k, d, f, first, count = S_LADDER, K_LADDER, 16, 8, 8, 8
+    rng = np.random.RandomState(11)
+    topi, valid, held = _routing(rng, S, k, first, count, 32, 40, True)
+    assert _rung_of(sorted_lengths(S * k), held) == 64
+    args = (jnp.asarray(rng.randn(S, d), jnp.float32),
+            jnp.asarray(rng.rand(S, k), jnp.float32),
+            jnp.asarray(rng.randn(count, d, 2 * f) * 0.3, jnp.float32),
+            jnp.asarray(rng.randn(count, f, d) * 0.3, jnp.float32))
+    mix = jnp.asarray(rng.randn(S, d), jnp.float32)
+
+    def loss(fn):
+        return lambda x, topw, w1, w2: jnp.sum(mix * fn(
+            x, topi, topw, w1, w2, "swiglu", first, valid))
+    got = jax.grad(loss(dropless_experts), argnums=(0, 1, 2, 3))(*args)
+    want = jax.grad(loss(_full_length_experts), argnums=(0, 1, 2, 3))(*args)
+    for g, w in zip(got, want):
+        assert float(jnp.abs(w).max()) > 0
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w), rtol=1e-5,
+                                   atol=1e-6 * float(jnp.abs(w).max()))
+
+
+def test_every_expert_held_and_every_lane_valid_is_the_full_length_program(
+        short_rungs, monkeypatch):
+    """What the layer knows while tracing it decides while tracing: every
+    expert of the router held and no lane invalid (training through the
+    `naive` gate), every assignment is held: no conditional, and the jaxpr
+    is the full-length program's to the letter.  A share, or a step with
+    invalid lanes, takes the ladder."""
+    paddle.seed(1)
+    layer = MoELayer(d_model=8, d_hidden=16, num_experts=4, gate="naive",
+                     top_k=2)
+    vals = {k: t.value for k, t in layer._dropless_leaves().items()}
+    x = jnp.asarray(np.random.RandomState(0).randn(2, 16, 8), jnp.float32)
+
+    def text(**kw):
+        return str(jax.make_jaxpr(lambda v: layer._dropless(v, vals, **kw))(x))
+    plain = text()
+    assert "cond" not in plain and "ragged_dot" in plain
+    assert "cond[" in text(valid=jnp.ones((32,), bool))
+    monkeypatch.setattr(
+        moe_module, "_sorted_experts",
+        lambda *args: _full_length_experts(*args[:-1]))
+    assert text() == plain
+    paddle.seed(1)
+    share = MoELayer(d_model=8, d_hidden=16, num_experts=4, gate="naive",
+                     top_k=2, experts_held=(0, 4), router_width=8)
+    monkeypatch.undo()
+    monkeypatch.setattr(moe_module, "MIN_RUNG_ROWS", 1)
+    assert "cond[" in str(jax.make_jaxpr(lambda v: share._dropless(
+        v, {k: t.value for k, t in share._dropless_leaves().items()}))(x))
 
 
 def test_naive_gate_takes_the_sorted_dispatch():
@@ -511,10 +754,12 @@ def test_attention_scale_carries_mscale_squared(model):
 
 # -- (8) the counters -------------------------------------------------------
 
-def test_step_counters_equal_a_host_recount(model, ids):
-    """moe_assignments_held / moe_expert_steps_hit / the largest load of one
-    paged step with junk lanes, against numpy over the reference's routing
-    of the same hidden states (the program's own, layer by layer)."""
+def test_step_counters_equal_a_host_recount(short_rungs, model, ids):
+    """moe_assignments_held / moe_expert_steps_hit / moe_rows_sorted / the
+    largest load of one paged step with junk lanes, against numpy over the
+    reference's routing of the same hidden states (the program's own, layer
+    by layer); the rows sorted are the rung each layer took of the ladder
+    over its 16 x 3 assignments."""
     cfg = tiny_cfg()
     x = jnp.asarray(ids[:, :8])
     n_valid = jnp.asarray([8, 3])
@@ -544,7 +789,9 @@ def test_step_counters_equal_a_host_recount(model, ids):
         for li in (1, 2):
             del model.llama.layers[li].mlp._dropless
     keep = np.asarray(valid).reshape(-1)
-    held = hit = biggest = 0
+    lengths = sorted_lengths(16 * 3)
+    assert lengths == (3, 12, 48)
+    held = hit = biggest = rows = 0
     for xv, vals in seen:
         chosen, _ = ref.routing(jnp.asarray(xv.reshape(-1, 64)),
                                 jnp.asarray(vals["gate"]),
@@ -553,8 +800,10 @@ def test_step_counters_equal_a_host_recount(model, ids):
         loads = np.bincount(chosen.reshape(-1), minlength=16)[4:8]
         held, hit = held + loads.sum(), hit + (loads > 0).sum()
         biggest = max(biggest, loads.max())
-    assert counters.vector().tolist() == [11 * 3 * 2, held, hit, biggest]
-    assert 0 < held < 11 * 3 * 2
+        rows += _rung_of(lengths, loads.sum())
+    assert counters.vector().tolist() == [11 * 3 * 2, held, hit, rows,
+                                          biggest]
+    assert 0 < held < 11 * 3 * 2 and held <= rows < 2 * 48
 
 
 def test_batcher_counts_on_the_device_and_reports_in_stats(model, ids):
@@ -572,6 +821,13 @@ def test_batcher_counts_on_the_device_and_reports_in_stats(model, ids):
     assert st["moe_assignments"] == work * 3 * 2
     assert 0 < st["moe_assignments_held"] < st["moe_assignments"]
     assert 0 < st["moe_expert_steps_hit"] <= st["chunks"] * 4 * 2 * 4
+    # the rungs taken, recounted: at these sizes (3 slots x 8 lanes x 3 an
+    # admission step, 3 x 1 x 3 a decode step) the ladder has one rung, all
+    # S*k, for each of the 2 expert layers of every step of every chunk
+    assert sorted_lengths(3 * 8 * 3) == (72,)
+    assert st["moe_rows_sorted"] == 2 * 3 * 3 * (
+        st["admit_chunks"] * bat.admit_steps * bat.prefill_chunk
+        + st["decode_chunks"] * bat.chunk)
     assert 0 < st["moe_tokens_per_expert_max"] <= 2 * 8
     assert model.step_counter_names() == tuple(
         k for k in st if k.startswith("moe_"))

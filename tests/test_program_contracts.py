@@ -534,3 +534,46 @@ def test_kinds_of_layer_leave_a_model_without_them_alone(which):
     assert named._kinds is None
     assert [named.lower_step(mixed=m).as_text()
             for m in (False, True)] == texts
+
+
+def _conditionals(lowered):
+    """The conditionals of a lowered program (`jax.lax.switch` and
+    `jax.lax.cond` both lower to `stablehlo.case`, or `stablehlo.if`)."""
+    text = lowered.as_text()
+    return text.count("stablehlo.case") + text.count("stablehlo.if")
+
+
+@pytest.mark.parametrize("which", range(4),
+                         ids=["dense_gqa", "mha_int8", "latent", "block"])
+def test_a_model_without_expert_layers_compiles_no_conditional(which):
+    """The expert layer chooses the length of its sorted buffer on the
+    device (`jax.lax.switch`, PR 35); a model without expert layers has
+    nothing to choose: neither serve step program of the four kinds of
+    model holds a conditional (the dense and train cells' programs were held
+    to the parent commit's text once: CHANGES.md, PR 35)."""
+    _, build, knobs = _kind_models()[which]
+    bat = ContinuousBatcher(build(), **_GEOMETRY, **_PLAIN, **knobs)
+    assert bat.model.step_counter_names() == ()
+    for mixed in (False, True):
+        assert _conditionals(bat.lower_step(mixed=mixed)) == 0
+
+
+def test_the_train_step_and_the_expert_ladder_by_conditionals(monkeypatch):
+    """The tiny llama train step holds no conditional either; the serve
+    programs of a model WITH expert layers hold one a layer where the
+    ladder has more than one rung (here a rung may be one row), so the
+    count above is a count of something."""
+    step, ids = _llama_step()
+    assert "stablehlo.case" not in step.compiled_hlo(ids, ids,
+                                                     optimized=False)
+    from paddle_tpu.incubate.distributed.models import moe
+    monkeypatch.setattr(moe, "MIN_RUNG_ROWS", 1)
+    paddle.seed(11)
+    experts = LlamaForCausalLM(llama_tiny_config(
+        num_hidden_layers=2, hidden_size=32, intermediate_size=64,
+        num_attention_heads=4, num_key_value_heads=2, vocab_size=64,
+        moe_num_experts=4, moe_top_k=2, moe_gate="naive"))
+    bat = ContinuousBatcher(experts, **_GEOMETRY, **_PLAIN)
+    assert bat.model.step_counter_names()
+    for mixed in (False, True):
+        assert _conditionals(bat.lower_step(mixed=mixed)) == 2
