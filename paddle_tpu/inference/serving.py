@@ -687,6 +687,7 @@ class ContinuousBatcher:
         self._occupancy_total = 0
         self._prefill_tok_total = 0
         self._decode_tok_total = 0
+        self._lanes = self._lanes_valid = 0
         self._programs_used: set = set()
         self._first_use = False
         # HBM memory ledger (ISSUE 10): register both step programs as
@@ -1090,8 +1091,6 @@ class ContinuousBatcher:
             req.on_token(req.req_id, burst, done)
         except Exception:
             self._cb_errors += 1
-            from .. import telemetry as _tel
-            _tel.counter("serve.callback_errors").inc()
         return len(burst)
 
     # -- robustness plumbing (ISSUE 9) -------------------------------------
@@ -1347,7 +1346,6 @@ class ContinuousBatcher:
                     self._shed(q.popleft(), "drain")
                     n_shed += 1
         from .. import telemetry as _tel
-        _tel.counter("serve.drains").inc()
         if _tel.active():
             _tel.emit("serve.drain", phase="begin", shed=n_shed,
                       in_flight=self.active, grace_s=grace)
@@ -1515,6 +1513,10 @@ class ContinuousBatcher:
             "avg_occupancy": occ,
             "prefill_tokens": self._prefill_tok_total,
             "decode_tokens": self._decode_tok_total,
+            # lanes the step programs computed (slots x width x steps)
+            # and those that carried a prompt or decode token
+            "lanes": self._lanes,
+            "lanes_valid": self._lanes_valid,
             "tokens_produced": self.tokens_produced,
             "chunk_time_p50": times[len(times) // 2] if times else 0.0,
             "chunk_time_max": self._chunk_time_max,
@@ -2239,7 +2241,6 @@ class ContinuousBatcher:
             if _tel.active():
                 _tel.emit("serve.recompile",
                           dict(self.stats(), program=str(key)))
-            _tel.counter("serve.recompiles").inc()
         if record:
             self._programs_used.add(key)
         model = self.model
@@ -2380,11 +2381,12 @@ class ContinuousBatcher:
                     # draft prefill rides the admit chunk (logits
                     # discarded — XLA DCEs the draft's lm head here)
                     _, dcache = draft.forward_cached(x, dcache, pos)
-                last = jnp.clip(n_valid - 1, 0, C - 1)
-                lg_last = jnp.take_along_axis(
-                    lg, last[:, None, None], axis=1)[:, 0]
-                nxt = jnp.argmax(lg_last.astype(jnp.float32),
-                                 axis=-1).astype(jnp.int32)
+                with jax.named_scope("serve.sample"):
+                    last = jnp.clip(n_valid - 1, 0, C - 1)
+                    lg_last = jnp.take_along_axis(
+                        lg, last[:, None, None], axis=1)[:, 0]
+                    nxt = jnp.argmax(lg_last.astype(jnp.float32),
+                                     axis=-1).astype(jnp.int32)
                 finishing = prefilling & (pos + n_valid >= plen)
                 emit = finishing | (~prefilling & ~done)
                 pos = pos + n_valid
@@ -2557,7 +2559,6 @@ class ContinuousBatcher:
                 if _tel.active():
                     _tel.emit("serve.recompile",
                               dict(self.stats(), program=str(key)))
-                _tel.counter("serve.recompiles").inc()
             self._programs_used.add(key)
         model = self.model
         names = self._names
@@ -2578,8 +2579,9 @@ class ContinuousBatcher:
                     dcache, dtok, dpos = dc
                     dlg, dcache = draft.forward_cached(
                         dtok[:, None], dcache, dpos)
-                    nxt = jnp.argmax(dlg[:, 0].astype(jnp.float32),
-                                     axis=-1).astype(jnp.int32)
+                    with jax.named_scope("serve.sample"):
+                        nxt = jnp.argmax(dlg[:, 0].astype(jnp.float32),
+                                         axis=-1).astype(jnp.int32)
                     return (dcache, nxt, dpos + 1), nxt
                 (dcache, _, _), drafts = jax.lax.scan(
                     dbody, (dcache, tok, pos), None, length=Kd + 1)
@@ -2593,8 +2595,9 @@ class ContinuousBatcher:
                         x, cache, page_table, pos)
                 else:
                     lg, cache = model.forward_cached(x, cache, pos)
-                tgt = jnp.argmax(lg.astype(jnp.float32),
-                                 axis=-1).astype(jnp.int32)  # [B, K+1]
+                with jax.named_scope("serve.sample"):
+                    tgt = jnp.argmax(lg.astype(jnp.float32),
+                                     axis=-1).astype(jnp.int32)  # [B, K+1]
 
                 # -- accept the longest matching prefix + bonus ------
                 match = (drafts[:, :Kd] == tgt[:, :Kd]).astype(
@@ -2783,8 +2786,8 @@ class ContinuousBatcher:
             else (self._decode_width, self.chunk)
         *pages, by_kind = self._kv_page_counts(width, steps)
         try:
-            with self._phase("dispatch", kind=kind, chunk=ck,
-                             kv_pages_live=pages[0],
+            with self._phase("dispatch", kind=kind, chunk=ck, steps=steps,
+                             width=width, kv_pages_live=pages[0],
                              kv_pages_walked=pages[1], **by_kind):
                 if self.spec_k and not mixed:
                     fn = self._spec_step_fn()
@@ -2826,7 +2829,6 @@ class ContinuousBatcher:
         except fault.FaultError:
             self._chunk_retries += 1
             self._consecutive_chunk_faults += 1
-            _tel.counter("serve.chunk_retries").inc()
             if _tel.active():
                 _tel.emit("serve.chunk_fault", kind=kind,
                           retries=self._chunk_retries)
@@ -2844,7 +2846,6 @@ class ContinuousBatcher:
             self._kv_pages_by_kind[name] += v
         if self._watch.last_reported:
             self._hung_chunks += 1
-            _tel.counter("serve.hung_chunks").inc()
             if _tel.active():
                 _tel.emit("serve.hung", kind=kind,
                           wall_ms=round(
@@ -2867,7 +2868,15 @@ class ContinuousBatcher:
                                              dtype=np.int64)
         counts = dict(zip(self._counter_names,
                           (int(v) for vec in counted for v in vec)))
-        with self._phase("harvest", chunk=ck, **counts):
+        # the lanes the chunk's programs computed, and those that carried
+        # a prompt token or a decoding slot's token (or block: L lanes)
+        lanes = self.B * width * steps
+        lanes_valid = int(n_pref) + (counts["decode_lanes"]
+                                     if self._diffusion else int(n_dec))
+        with self._phase("harvest", chunk=ck, lanes=lanes,
+                         lanes_valid=lanes_valid, **counts):
+            self._lanes += lanes
+            self._lanes_valid += lanes_valid
             self._count_model(counts)
             self._harvest(kind, t0, np.asarray(toks), mode_h, done_h,
                           pos_h, int(n_pref), int(n_dec), n_emit, n_acc,
@@ -2942,16 +2951,11 @@ class ContinuousBatcher:
             self._spec_accepted += accepted
             self._spec_steps += n_active
             self._spec_emit_window.extend(int(v) for v in ne[active])
-            _tel.counter("serve.spec_drafted").inc(drafted)
-            _tel.counter("serve.spec_accepted").inc(accepted)
             if _tel.active():
                 _tel.emit("serve.spec", drafted=drafted,
                           accepted=accepted, steps=n_active,
                           accept_rate=round(accepted / drafted, 4)
                           if drafted else 0.0)
-                for v in ne[active]:
-                    _tel.histogram("serve.accepted_per_step") \
-                        .observe(float(v))
         if self.kv_layout == "paged":
             # prompt pages that finished filling this chunk become
             # shareable for the NEXT admission
@@ -2959,7 +2963,6 @@ class ContinuousBatcher:
                 if plan is not None and plan.nodes:
                     self._alloc.mark_progress(plan,
                                               int(self._pos_host[i]))
-        _tel.counter("serve.chunks").inc()       # sink or not
         if _tel.active():
             # published by _close_chunk, once the step's last phase is
             # counted
@@ -2969,7 +2972,6 @@ class ContinuousBatcher:
                 occupancy=self.active, slots=self.B,
                 prefill_tokens=n_pref, decode_tokens=n_dec,
                 first_use=self._first_use)
-            _tel.histogram("serve.chunk_ms").observe(dt * 1e3)
             # cost ledger measured-wall feed (ISSUE 12): the chunk
             # wall lands on the ledger label of the very program that
             # ran it; first_use walls (may include the compile) are

@@ -411,17 +411,25 @@ class LlamaAttention(nn.Layer):
         identical here (they differ only in where K/V land)."""
         cfg = self.config
         b, s, _ = x.shape
-        q = _wo_mm(self, "q_proj", x).reshape(
-            b, s, cfg.num_attention_heads, cfg.attn_head_dim)
-        k = _wo_mm(self, "k_proj", x).reshape(
-            b, s, cfg.num_key_value_heads, cfg.attn_head_dim)
-        v = _wo_mm(self, "v_proj", x).reshape(
-            b, s, cfg.num_key_value_heads, cfg.attn_head_dim)
-        if cfg.use_qk_norm:
-            q, k = self._qk_norm(q, k, self.q_norm.value, self.k_norm.value)
-        if self.rotary:
-            q, k = tpu_ops.apply_rope(q, k, cos, sin)
+        with jax.named_scope("attn.project"):
+            q = _wo_mm(self, "q_proj", x).reshape(
+                b, s, cfg.num_attention_heads, cfg.attn_head_dim)
+            k = _wo_mm(self, "k_proj", x).reshape(
+                b, s, cfg.num_key_value_heads, cfg.attn_head_dim)
+            v = _wo_mm(self, "v_proj", x).reshape(
+                b, s, cfg.num_key_value_heads, cfg.attn_head_dim)
+            if cfg.use_qk_norm:
+                q, k = self._qk_norm(q, k, self.q_norm.value,
+                                     self.k_norm.value)
+            if self.rotary:
+                q, k = tpu_ops.apply_rope(q, k, cos, sin)
         return q, k, v
+
+    def _out_proj(self, out):
+        """The decode paths' o_proj product of attention's [b, s, h, d]."""
+        b, s = out.shape[:2]
+        with jax.named_scope("attn.project"):
+            return _wo_mm(self, "o_proj", out.reshape(b, s, -1))
 
     def forward_cached(self, x, cos, sin, k_cache, v_cache, pos):
         """Decode-path attention: project the s_new tokens in x, write
@@ -429,7 +437,6 @@ class LlamaAttention(nn.Layer):
         whole cache (see ops.cached_attention).  Returns (out, k_cache,
         v_cache).  Raw jax values in and out — the generation loop is
         one jitted program, not a taped eager path."""
-        b, s, _ = x.shape
         q, k, v = self._decode_qkv_rope(x, cos, sin)
         pos = jnp.asarray(pos, jnp.int32)
         z = jnp.zeros((), jnp.int32)
@@ -450,8 +457,7 @@ class LlamaAttention(nn.Layer):
         out = tpu_ops.cached_attention(
             q, k_cache, v_cache, pos,
             block_length=self.config.block_length, window=self.window)
-        out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
-        return out, k_cache, v_cache
+        return self._out_proj(out), k_cache, v_cache
 
     def forward_cached_paged(self, x, cos, sin, cache, page_table, pos,
                              layer):
@@ -466,38 +472,38 @@ class LlamaAttention(nn.Layer):
         the table of its kind's pool and its index in THAT pool: a full
         layer "k"/"v" and the slots' page table, a sliding layer
         "k_window"/"v_window" and the slots' rings (_paged_by_kind)."""
-        b, s, _ = x.shape
         q, k, v = self._decode_qkv_rope(x, cos, sin)
         if self.kinds:
             return self._paged_by_kind(q, k, v, cache, page_table, pos,
                                        layer)
-        kp, vp, ks, vs = tpu_ops.paged_kv_update(
-            cache["k"], cache["v"], cache.get("k_scale"),
-            cache.get("v_scale"), page_table, pos, k, v, layer)
+        with jax.named_scope("attn.cache_write"):
+            kp, vp, ks, vs = tpu_ops.paged_kv_update(
+                cache["k"], cache["v"], cache.get("k_scale"),
+                cache.get("v_scale"), page_table, pos, k, v, layer)
         cache = dict(cache, k=kp, v=vp)
         if ks is not None:
             cache["k_scale"], cache["v_scale"] = ks, vs
-        out = tpu_ops.paged_attention(
-            q, kp, vp, page_table, pos, layer, ks, vs,
-            block_length=self.config.block_length)
-        out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
-        return out, cache
+        with jax.named_scope("attn.attend"):
+            out = tpu_ops.paged_attention(
+                q, kp, vp, page_table, pos, layer, ks, vs,
+                block_length=self.config.block_length)
+        return self._out_proj(out), cache
 
     def _paged_by_kind(self, q, k, v, cache, table, pos, layer):
         """The cache write and the attention of one layer of a model with
         kinds, under the scope that names the kind (`attn.window` /
         `attn.full`): both index the kind's WHOLE carried pool by
         (page, layer), as every paged model's do."""
-        b, s = q.shape[:2]
         kn, vn = ("k_window", "v_window") if self.window else ("k", "v")
         with jax.named_scope("attn.window" if self.window else "attn.full"):
-            kp, vp, _, _ = tpu_ops.paged_kv_update(
-                cache[kn], cache[vn], None, None, table, pos, k, v, layer,
-                ring=bool(self.window))
-            out = tpu_ops.paged_attention(q, kp, vp, table, pos, layer,
-                                          window=self.window)
-        out = _wo_mm(self, "o_proj", out.reshape(b, s, -1))
-        return out, dict(cache, **{kn: kp, vn: vp})
+            with jax.named_scope("attn.cache_write"):
+                kp, vp, _, _ = tpu_ops.paged_kv_update(
+                    cache[kn], cache[vn], None, None, table, pos, k, v,
+                    layer, ring=bool(self.window))
+            with jax.named_scope("attn.attend"):
+                out = tpu_ops.paged_attention(q, kp, vp, table, pos, layer,
+                                              window=self.window)
+        return self._out_proj(out), dict(cache, **{kn: kp, vn: vp})
 
     # split entry points for the selective-recompute block structure
     # (forward above stays the single fused path)
@@ -836,11 +842,11 @@ class LlamaDecoderLayer(nn.Layer):
             # handles raw jax values; aux loss is irrelevant at decode)
             x = x + self.mlp(h).value
         else:
-            x = x + _wo_mm(self.mlp, "down_proj",
-                           tpu_ops.swiglu(_wo_mm(self.mlp, "gate_proj",
-                                                 h),
-                                          _wo_mm(self.mlp, "up_proj",
-                                                 h)))
+            with jax.named_scope("mlp"):
+                x = x + _wo_mm(self.mlp, "down_proj",
+                               tpu_ops.swiglu(
+                                   _wo_mm(self.mlp, "gate_proj", h),
+                                   _wo_mm(self.mlp, "up_proj", h)))
         return x, kv_state
 
     def forward_cached(self, x, cos, sin, k_cache, v_cache, pos):
@@ -1043,9 +1049,10 @@ class LlamaModel(nn.Layer):
         positions = jnp.asarray(pos, jnp.int32)[..., None] \
             + jnp.arange(s, dtype=jnp.int32)
         cos, sin = self._rope_tables(s, positions)
-        x = jnp.take(self.embed_tokens.value,
-                     input_ids.astype(jnp.int32),
-                     axis=0).astype(cfg.compute_dtype)
+        with jax.named_scope("llama.embed"):
+            x = jnp.take(self.embed_tokens.value,
+                         input_ids.astype(jnp.int32),
+                         axis=0).astype(cfg.compute_dtype)
         sliding = cfg.window_layers()
         if sliding:
             # a pool a kind: each layer gets its kind's table (the slots'
@@ -1158,10 +1165,11 @@ class LlamaForCausalLM(nn.Layer):
         """Decode-path lm head: tied embeddings stay unquantized (the
         embedding is gathered elsewhere); an untied head rides the
         weight-only packed path like every other decode matmul."""
-        if self.config.tie_word_embeddings:
-            w = self.llama.embed_tokens.value
-            return x @ w.T.astype(x.dtype)
-        return _wo_mm(self, "lm_head", x)
+        with jax.named_scope("llama.lm_head"):
+            if self.config.tie_word_embeddings:
+                w = self.llama.embed_tokens.value
+                return x @ w.T.astype(x.dtype)
+            return _wo_mm(self, "lm_head", x)
 
     def forward_cached_paged(self, input_ids, cache, page_table, pos,
                              counters=None, head_lanes=None):

@@ -44,13 +44,22 @@ exporter reads from:
                                     the models' llama.layer{i}/attn/mlp)
   ContinuousBatcher.step            serve.step (chunk) > serve.evict,
                                     serve.admit (admitted),
-                                    serve.dispatch (kind, chunk),
+                                    serve.dispatch (kind, chunk, steps,
+                                    width, pages walked),
                                     serve.device_wait (kind, chunk),
-                                    serve.harvest (chunk) >
+                                    serve.harvest (chunk, lanes,
+                                    lanes_valid, model counts) >
                                     serve.deliver (tokens); instants
                                     (`telemetry.mark`) serve.req.admit /
                                     serve.req.first_token /
                                     serve.req.done (req, chunk)
+  the serve step programs           jax.named_scope: llama.embed,
+                                    llama.layer{i}/attn.project,
+                                    attn.cache_write, attn.attend (in
+                                    attn.window / attn.full where the
+                                    model has kinds), mla.*, mlp, moe.*,
+                                    llama.norm, llama.lm_head,
+                                    serve.sample, diffusion.*
   profiler.RecordEvent              the caller's name (kind=record_event)
 
 Cost contract: with no sink attached an event is one truthiness check

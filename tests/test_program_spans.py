@@ -271,3 +271,172 @@ def test_step_program_scopes_are_metadata_alone(monkeypatch):
         ln for ln in scoped.splitlines() if "transpose(jvp(" in ln)
     assert _strip_metadata(scoped) != scoped
     assert _strip_metadata(scoped) == _strip_metadata(bare)
+
+
+# -- the serve step program's scopes (ISSUE 36) ------------------------------
+
+_SERVE_SMALL = dict(vocab_size=64, hidden_size=32, intermediate_size=64,
+                    num_hidden_layers=4, num_attention_heads=4,
+                    max_position_embeddings=64)
+_EXPERTS = dict(moe_num_experts=4, moe_top_k=2, moe_intermediate_size=16)
+# the four serve cells' model classes, tiny
+SERVE_MODELS = {
+    "dense_mha": dict(num_key_value_heads=4),
+    "kinds_gqa": dict(
+        num_key_value_heads=2, head_dim=8, use_qk_norm=True,
+        layer_types=("sliding_attention",) * 3 + ("full_attention",),
+        sliding_window=8, rope_layer_types=("sliding_attention",)),
+    "latent_sigmoid": dict(
+        num_key_value_heads=4, kv_lora_rank=16, qk_nope_head_dim=8,
+        qk_rope_head_dim=8, v_head_dim=8, first_k_dense_replace=1,
+        moe_gate="sigmoid", moe_first_expert=4, moe_router_width=8,
+        moe_shared_experts=1, moe_routed_scaling=2.5, moe_router_bias=True,
+        **dict(_EXPERTS, moe_num_experts=4)),
+    "block_softmax": dict(
+        num_key_value_heads=2, block_length=4, denoising_steps=2,
+        mask_token_id=63, moe_gate="naive", moe_expert_bias=False,
+        **_EXPERTS),
+}
+_SERVE_GEOMETRY = dict(max_batch_size=2, max_len=32, chunk=4,
+                       prefill_chunk=4)
+
+
+def _serve_programs(which):
+    """Both step programs (decode, admission) of a fresh tiny batcher,
+    compiled, as text."""
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(11)
+    model = LlamaForCausalLM(LlamaConfig(**dict(_SERVE_SMALL,
+                                                **SERVE_MODELS[which])))
+    model.eval()
+    bat = ContinuousBatcher(model, **_SERVE_GEOMETRY)
+    return [bat.lower_step(mixed=mixed).compile().as_text()
+            for mixed in (False, True)]
+
+
+_SCOPED = {}
+
+
+def _scoped_programs(which):
+    if which not in _SCOPED:
+        _SCOPED[which] = _serve_programs(which)
+    return _SCOPED[which]
+
+
+def _serve_parts():
+    import os
+    import sys
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmark")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from metrics import _serve_parts
+    return _serve_parts
+
+
+def _renamed(hlo_text):
+    """The text with each `%name` replaced by its order of first
+    appearance: where the compiler numbers two equal instructions in
+    another order, the program is the same."""
+    names = {}
+    return re.sub(r"%[\w.\-]+", lambda m: names.setdefault(
+        m.group(0), f"%{len(names)}"), hlo_text)
+
+
+@pytest.mark.parametrize("which", sorted(SERVE_MODELS))
+def test_serve_step_scopes_are_metadata_alone(which, monkeypatch):
+    """The scopes that split the serve step by layer (`attn.project`,
+    `attn.cache_write`, `attn.attend`, `mlp`, `llama.embed`,
+    `llama.lm_head`, `serve.sample`) name operations and change nothing
+    else: both compiled step programs of each serve model class equal, once
+    metadata is stripped, those compiled with `jax.named_scope` nulled."""
+    scoped = _scoped_programs(which)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = _serve_programs(which)
+    latent = "latent" in which
+    for text, plain in zip(scoped, bare):
+        for scope in ("llama.embed", "llama.lm_head", "llama.norm",
+                      "mla.project" if latent else "attn.project",
+                      "mla.attend" if latent else "attn.attend"):
+            assert scope in text and scope not in plain, scope
+        assert ("serve.sample" in text) == ("block" not in which)
+        assert ("attn.window/attn.attend" in text) == ("kinds" in which)
+        assert _strip_metadata(text) != text
+        assert _renamed(_strip_metadata(text)) == \
+            _renamed(_strip_metadata(plain))
+
+
+@pytest.mark.parametrize("which", sorted(SERVE_MODELS))
+def test_every_product_of_the_serve_step_lies_in_a_named_part(which):
+    """Every `dot`, `convolution` and `custom-call` of both compiled step
+    programs goes to a named part of `serve_step_device_ms` (the benchmark's
+    table, first match wins), none to `other`; and each part the model
+    has holds some."""
+    parts = _serve_parts()
+    want = {"projections", "attention", "head"}
+    cfg = SERVE_MODELS[which]
+    if "moe_gate" in cfg:
+        want.add("experts")
+    if cfg.get("moe_gate") != "naive":
+        want.add("mlp")            # dense layers, or a first dense layer
+    for text in _scoped_programs(which):
+        found = set()
+        for line in text.splitlines():
+            if not re.search(r"= \S+ (dot|convolution|custom-call)\(",
+                             line):
+                continue
+            op = re.search(r'op_name="([^"]*)"', line)
+            part = parts.part_of(op.group(1) if op else "")
+            assert part != "other", line.strip()[:200]
+            found.add(part)
+        assert found == want
+
+
+def test_the_first_matching_part_takes_an_operation():
+    parts = _serve_parts()
+    layer = "jit(serve_step)/while/body/llama.layer4/"
+    assert parts.part_of(layer + "attn.window/attn.attend/pallas_call") \
+        == "attention"
+    assert parts.part_of(layer + "moe.experts/mlp/dot_general") == "experts"
+    assert parts.part_of(layer + "attn.project/dot_general") \
+        == "projections"
+    assert parts.part_of("ragged-dot-none") == "experts"
+    assert parts.part_of(layer + "rms_norm_fwd/pallas_call") == "other"
+
+
+def test_lanes_valid_is_the_hand_count():
+    """One admission chunk of 4 steps x 2 slots x 4 lanes over prompts of
+    6 and 3 tokens: slot 0 carries 4 + 2 prompt tokens and then 1 + 1
+    decode tokens, slot 1 3 prompt tokens and then 1 + 1 + 1: 14 of 32
+    lanes; the decode chunk after it, 4 steps x 2 slots x 1 lane, all 8.
+    The ids ride the chunk's spans: `steps` and `width` on
+    `serve.dispatch`, `lanes` and `lanes_valid` on `serve.harvest`."""
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(11)
+    model = LlamaForCausalLM(LlamaConfig(**dict(
+        _SERVE_SMALL, **SERVE_MODELS["dense_mha"])))
+    model.eval()
+    bat = ContinuousBatcher(model, max_batch_size=2, max_len=32, chunk=4,
+                            prefill_chunk=4, admit_steps=4)
+    rng = np.random.RandomState(5)
+    bat.submit(_prompt(rng, 6), 12)
+    bat.submit(_prompt(rng, 3), 12)
+    sink = telemetry.add_sink(telemetry.MemorySink())
+    try:
+        bat.step()
+        assert (bat.stats()["lanes"], bat.stats()["lanes_valid"]) == (32, 14)
+        bat.step()
+    finally:
+        telemetry.remove_sink(sink)
+    got = bat.stats()
+    assert (got["lanes"], got["lanes_valid"]) == (40, 22)
+    assert got["lanes_valid"] == got["prefill_tokens"] + got["decode_tokens"]
+    dispatch = [(r["kind"], r["steps"], r["width"]) for r in sink.records
+                if r["event"] == "serve.dispatch"]
+    harvest = [(r["lanes"], r["lanes_valid"]) for r in sink.records
+               if r["event"] == "serve.harvest"]
+    assert dispatch == [("admit", 4, 4), ("decode", 4, 1)]
+    assert harvest == [(32, 14), (8, 8)]
