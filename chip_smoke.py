@@ -27,9 +27,14 @@ calls, on ONE TPU chip and in ONE process:
           router 128 wide), the full-length program against the ladder of
           sorted-buffer lengths at 512, 2,048, 4,096 and 16,384
           assignments held: results compared, a call's device time split
-          by scope (moe.dispatch / moe.experts / moe.combine).  NOT part
-          of the default run:
+          by scope (moe.dispatch / moe.experts / moe.combine); before it the
+          grouped product alone (ops.grouped_matmul, the Pallas kernel,
+          beside jax.lax.ragged_dot) at 512, 2,048 and 16,384 rows, ms a
+          call and GB/s.  NOT part of the default run:
               python3 -c "import chip_smoke; chip_smoke.phase_moe()"
+  lowering  seconds to trace and lower both serve step programs of a
+          model built as the window cell's, and the kernel bodies they
+          hold (also NOT part of the default run; see phase_lowering).
 
 For both, the compiled program's text must hold the Pallas kernels
 (`tpu_custom_call`): flash attention, rms norm, rope and fused AdamW in the
@@ -353,6 +358,11 @@ MOE_GEOMETRIES = {"k-exaone-236b-a23b": (6144, 2048, 16),
 MOE_TOKENS, MOE_TOP_K, MOE_ROUTER = 2048, 8, 128
 MOE_HELD = (512, 2048, 4096, 16384)
 MOE_TRACED_CALLS = 5
+# the grouped product alone (ops.grouped_matmul against jax.lax.ragged_dot):
+# rows of the sorted buffer and the rows held among them — a decode step of
+# the sigmoid cells (64 slots x 8: about 64 held), sdar30b_serve_gen_c64's
+# decode pass (1,920 of 2,048) and an admission step's whole buffer
+GROUPED_ROWS_HELD = ((512, 64), (2048, 1920), (16384, 16384))
 
 
 def ms_a_call(fn, args):
@@ -575,10 +585,110 @@ def scope_ms_a_call(fn, args, calls):
     return {k: round(v, 3) for k, v in split.items()}
 
 
+def grouped_sizes(rng, count, held):
+    """`held` rows over `count` experts, skewed as a router leaves them
+    (some experts empty, a few large): int32 [count]."""
+    import numpy as np
+    p = 1.0 / np.arange(1, count + 1) ** 1.2
+    return np.bincount(rng.choice(count, held, p=p / p.sum()),
+                       minlength=count).astype(np.int32)
+
+
+def phase_grouped():
+    """ops.grouped_matmul (the Pallas kernel on a TPU) beside
+    jax.lax.ragged_dot, both products of the three expert cells' layers at
+    their real widths, at GROUPED_ROWS_HELD: the kernel must be in the
+    compiled program and equal ragged_dot on the held rows; each line
+    carries both sides' ms a call and GB/s (the hit experts' weights once,
+    the held rows in and out)."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import ops
+    if jax.devices()[0].platform != "tpu":
+        fail("grouped: the kernel runs on a TPU only")
+    rng = np.random.RandomState(SEED + 5)
+    key = jax.random.PRNGKey(SEED + 5)
+    kernel = jax.jit(ops.grouped_matmul)
+    twin = jax.jit(jax.lax.ragged_dot)
+    for name, (d, f, count) in MOE_GEOMETRIES.items():
+        for product, (K, N) in (("gate_up", (d, 2 * f)), ("down", (f, d))):
+            w = jax.random.normal(key, (count, K, N), jnp.bfloat16) * K ** -0.5
+            for R, held in GROUPED_ROWS_HELD:
+                sizes = grouped_sizes(rng, count, held)
+                rows = jax.random.normal(key, (R, K), jnp.bfloat16)
+                args = (rows, w, jnp.asarray(sizes))
+                if "grouped_matmul" not in kernels_in(
+                        kernel.lower(*args).compile().as_text()):
+                    fail("grouped: ops.grouped_matmul compiled without its "
+                         "kernel")
+                got = np.asarray(kernel(*args), np.float32)[:held]
+                want = np.asarray(twin(*args), np.float32)[:held]
+                gap, close = twin_gap(got, want)
+                nbytes = 2 * (int((sizes > 0).sum()) * K * N + held * (K + N))
+                times = {side: ms_a_call(fn, args)
+                         for side, fn in (("kernel", kernel), ("twin", twin))}
+                say(phase="grouped", config=name, product=product, rows=R,
+                    held=held, hit=int((sizes > 0).sum()), K=K, N=N, **gap,
+                    kernel_ms_a_call=round(times["kernel"], 4),
+                    twin_ms_a_call=round(times["twin"], 4),
+                    kernel_gb_s=round(nbytes / times["kernel"] / 1e6, 1),
+                    twin_gb_s=round(nbytes / times["twin"] / 1e6, 1))
+                if not close:
+                    fail(f"grouped: the kernel leaves ragged_dot ({name} "
+                         f"{product}, {R} rows): {gap}")
+                del rows, args
+            del w
+        release("grouped")
+
+
+def phase_lowering():
+    """Seconds this host takes to trace and lower the two serve step
+    programs of a model built as kexaone236b_serve_mixed_c64's (5 layers of
+    kinds L L L G L, layer 0 dense, 16 of 128 experts held, 8 a token, a
+    shared expert; 64 slots of 32 admission lanes: a ladder of three rungs
+    in the admission program, one in the decode program) at small widths,
+    and the `grouped_matmul` kernel bodies each lowered program holds.  It
+    imports whatever `paddle_tpu` the path gives, so the same function
+    times a checkout without the kernel:
+        cd <other checkout> && python3 -c "import runpy; runpy.run_path(
+            '<this file>', run_name='lowering')"
+    """
+    import jax
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(SEED)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=1024, hidden_size=512, intermediate_size=1024,
+        num_hidden_layers=5, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=128, use_qk_norm=True, max_position_embeddings=1024,
+        rope_theta=1e6, dtype="bfloat16",
+        layer_types=("sliding_attention",) * 3
+        + ("full_attention", "sliding_attention"), sliding_window=128,
+        rope_layer_types=("sliding_attention",), first_k_dense_replace=1,
+        moe_gate="sigmoid", moe_num_experts=16, moe_first_expert=0,
+        moe_router_width=128, moe_top_k=8, moe_intermediate_size=256,
+        moe_shared_experts=1, moe_routed_scaling=2.5, moe_router_bias=True))
+    model.eval()
+    bat = ContinuousBatcher(model, max_batch_size=64, max_len=1024)
+    line = dict(paddle_tpu=paddle.__file__, slots=64,
+                prefill_chunk=bat.prefill_chunk, backend=jax.default_backend())
+    for kind, mixed in (("admit", True), ("decode", False)):
+        t0 = time.perf_counter()
+        text = bat.lower_step(mixed=mixed).as_text()
+        line[kind + "_trace_lower_s"] = round(time.perf_counter() - t0, 3)
+        line[kind + "_grouped_matmul_bodies"] = text.count(
+            'kernel_name = "grouped_matmul"')
+    say(phase="lowering", **line)
+
+
 def phase_moe():
-    """`dropless_experts` alone, the full-length program (the only rung all
-    S*k: what every call ran before PR 35) against the ladder, at the three
-    expert cells' admission geometry: the table that sets the rungs."""
+    """The grouped product alone (`phase_grouped`); then `dropless_experts`
+    alone, the full-length program (the only rung all S*k: what every call
+    ran before the ladder) against the ladder, at the three expert cells'
+    admission geometry: the table that sets the rungs."""
+    phase_grouped()
     import functools
     import numpy as np
     import jax
@@ -759,3 +869,5 @@ def main():
 
 if __name__ == "__main__":
     main()
+elif __name__ == "lowering":
+    phase_lowering()

@@ -24,10 +24,10 @@ Tokens over capacity are dropped (combine weight 0 → residual passthrough
 is the caller's choice, as in the reference).  The gates WITHOUT a capacity
 (naive, sigmoid) drop nothing: their assignments are sorted by expert and
 the experts run as one grouped product over the sorted rows
-(`dropless_experts`), so the work grows with the assignments, not with
-experts x tokens; and the sorted buffer is as long as the assignments a
-layer HOLDS for valid lanes, rounded up to a rung of a short ladder
-(`sorted_lengths`), not as long as all tokens x k.
+(`dropless_experts`, `ops.grouped_matmul`), so the work grows with the
+assignments, not with experts x tokens; and the sorted buffer is as long
+as the assignments a layer HOLDS for valid lanes, rounded up to a rung of
+a short ladder (`sorted_lengths`), not as long as all tokens x k.
 
 A layer may hold only ITS SHARE of the experts (`experts_held=(first,
 count)` of a router `router_width` wide): it routes over all of them,
@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ..... import ops
 from .....nn import Layer
 from .....nn import functional as F
 from .....nn import initializer as I
@@ -60,10 +61,12 @@ __all__ = ["MoELayer", "NaiveGate", "SwitchGate", "GShardGate",
 # name a SUM over layers and steps but one that ends in `_max`, a maximum.
 # `moe_rows_sorted` is the length of the sorted buffer a layer took (the
 # rung of `sorted_lengths`); over `moe_assignments_held` it says how tight
-# the buffer sits around the rows that are used.
+# the buffer sits around the rows that are used.  `moe_rows_walked` is the
+# rows the layer's first grouped product walked (ops.grouped_rows_walked:
+# the kernel's row tiles that hold rows, or the whole buffer).
 COUNTER_NAMES = ("moe_assignments", "moe_assignments_held",
                  "moe_expert_steps_hit", "moe_rows_sorted",
-                 "moe_tokens_per_expert_max")
+                 "moe_rows_walked", "moe_tokens_per_expert_max")
 _N_SUMS = sum(not name.endswith("_max") for name in COUNTER_NAMES)
 
 # A rung is left out where it is shorter than this: below it the row-sized
@@ -93,21 +96,23 @@ class StepCounters:
     is int32 [len(COUNTER_NAMES)]: assignments of valid tokens,
     those that chose an expert held here, (layer, held expert) pairs
     with at least one token, the rows of the sorted buffers the layers
-    took, and the largest single (layer, expert) load — the sums add
-    over layers and steps, the last is a maximum."""
+    took, the rows their grouped products walked, and the largest single
+    (layer, expert) load — the sums add over layers and steps, the last is
+    a maximum."""
 
     def __init__(self, valid=None):
         self.valid = None if valid is None else valid.reshape(-1)
         self._sums = jnp.zeros((_N_SUMS,), jnp.int32)
         self._max = jnp.zeros((), jnp.int32)
 
-    def add(self, n_valid, top_k, sizes, rows_sorted):
+    def add(self, n_valid, top_k, sizes, rows_sorted, rows_walked):
         """sizes [count]: valid tokens each held expert got; rows_sorted:
-        the length of the buffer the layer sorted them into."""
+        the length of the buffer the layer sorted them into; rows_walked:
+        the rows of it the grouped product walked."""
         self._sums = self._sums + jnp.stack(
             [n_valid * top_k, jnp.sum(sizes),
              jnp.sum((sizes > 0).astype(jnp.int32)),
-             rows_sorted]).astype(jnp.int32)
+             rows_sorted, rows_walked]).astype(jnp.int32)
         self._max = jnp.maximum(self._max, jnp.max(sizes))
 
     def vector(self):
@@ -131,8 +136,9 @@ def dropless_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
     of them, R the shortest of `sorted_lengths(S*k)` that holds every
     assignment HELD (chosen on the device, `jax.lax.switch`), are
     gathered in that order, both products run grouped
-    (`jax.lax.ragged_dot`: a grouped-matmul kernel on TPU that stops at
-    the last group's end), and each token sums its rows back in fp32.
+    (`ops.grouped_matmul`: on a TPU a Pallas kernel that streams each hit
+    expert's weights once and walks only the row tiles that hold rows),
+    and each token sums its rows back in fp32.
     The longest rung is all S*k, so nothing is ever dropped.  Returns
     [S, d] fp32."""
     return _sorted_experts(tokens, topi, topw, w1, w2, act, first, valid,
@@ -168,8 +174,12 @@ def _sorted_experts(tokens, topi, topw, w1, w2, act, first, valid, b1, b2,
             tokens, order, key, sizes, held, topw, w1, w2, b1, b2)
     if counters is not None:
         n_valid = S if valid is None else jnp.sum(valid.astype(jnp.int32))
+        walked = [ops.grouped_rows_walked(sizes, (r, tokens.shape[1]),
+                                          w1.shape, tokens.dtype)
+                  for r in lengths]
         counters.add(n_valid, k, sizes,
-                     jnp.asarray(lengths, jnp.int32)[rung])
+                     jnp.asarray(lengths, jnp.int32)[rung],
+                     jnp.stack(walked)[rung])
     return y
 
 
@@ -182,11 +192,11 @@ def _rung(R, act, tokens, order, key, sizes, held, topw, w1, w2, b1, b2):
         rows = jnp.take(tokens, taken // k, axis=0)              # [R, d]
     with jax.named_scope("moe.experts"):
         expert = jnp.take(key, taken)      # of each sorted row
-        h = jax.lax.ragged_dot(rows, w1, sizes)
+        h = ops.grouped_matmul(rows, w1, sizes)
         if b1 is not None:
             h = h + jnp.take(b1[:, 0], expert, axis=0, mode="clip")
-        out = jax.lax.ragged_dot(_expert_act(h, act).astype(rows.dtype),
-                                 w2, sizes)
+        out = ops.grouped_matmul(_expert_act(h, act).astype(rows.dtype), w2,
+                                 sizes)
         if b2 is not None:
             out = out + jnp.take(b2[:, 0], expert, axis=0, mode="clip")
     with jax.named_scope("moe.combine"):

@@ -25,7 +25,8 @@ __all__ = ["attention", "cached_attention", "rms_norm", "layer_norm",
            "paged_attention", "xla_paged_attention", "paged_kv_update",
            "latent_kv_update", "latent_paged_attention",
            "xla_latent_paged_attention", "latent_pages_walked",
-           "latent_walk_bound", "yarn_inv_freq", "yarn_mscale",
+           "latent_walk_bound", "grouped_matmul", "grouped_rows_walked",
+           "yarn_inv_freq", "yarn_mscale",
            "xla_apply_rope",
            "swiglu", "get_attention_backend", "set_attention_backend",
            "kernel_mesh_scope",
@@ -640,6 +641,78 @@ def attention(q, k, v, mask=None, causal=False, scale=None, dropout_p=0.0,
         if out is not None:
             return out
     return xla_attention(q, k, v, mask, causal, scale, dropout_p)
+
+
+# ---------------------------------------------------------------------------
+# grouped matmul (the expert layer's products)
+# ---------------------------------------------------------------------------
+def _grouped_kernel(rows_shape, w_shape, dtype):
+    """The Pallas module where grouped_matmul takes the kernel for such
+    operands, None where it takes jax.lax.ragged_dot: from the backend and
+    the shapes alone."""
+    if _on_tpu():
+        from .pallas import grouped_matmul as _k
+        if _k.supports(rows_shape, w_shape, dtype):
+            return _k
+    return None
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _grouped_product(rows, w, sizes, kernel):
+    if kernel:
+        from .pallas import grouped_matmul as _k
+        return _k.grouped_matmul(rows, w, sizes)
+    return jax.lax.ragged_dot(rows, w, sizes)
+
+
+def _grouped_fwd(rows, w, sizes, kernel):
+    return _grouped_product(rows, w, sizes, kernel), (rows, w, sizes)
+
+
+def _grouped_bwd(kernel, res, g):
+    rows, w, sizes = res
+    _, vjp = jax.vjp(lambda r, w_: jax.lax.ragged_dot(r, w_, sizes), rows, w)
+    return (*vjp(g), None)
+
+
+_grouped_product.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("kernel",))
+def _grouped_matmul(rows, w, sizes, kernel):
+    return _grouped_product(rows, w, sizes, kernel)
+
+
+def grouped_matmul(rows, w, sizes):
+    """rows [R, K] sorted by group, w [G, K, N], sizes [G] int32 ->
+    [R, N] in rows.dtype: group g's rows times w[g], as
+    jax.lax.ragged_dot (rows past the last group's end are left
+    undefined).  On a TPU, for shapes its `supports` takes, the Pallas
+    kernel ops/pallas/grouped_matmul.py (each hit group's weights streamed
+    once, only the row tiles that hold rows walked); ragged_dot elsewhere.
+    The choice is made from the backend and the shapes alone: what the
+    kernel raises reaches the caller.  The gradient is ragged_dot's own
+    (its transposes) on either path.
+
+    Every call goes through ONE jitted function: the kernel is traced and
+    lowered once per distinct (R, K, N, dtype) in a program, however many
+    layers and rungs call it (a Pallas kernel called directly is lowered
+    again at every call site)."""
+    kernel = _grouped_kernel(rows.shape, w.shape, rows.dtype) is not None
+    return _grouped_matmul(rows, w, jnp.asarray(sizes, jnp.int32),
+                           kernel=kernel)
+
+
+def grouped_rows_walked(sizes, rows_shape, w_shape, dtype):
+    """Rows grouped_matmul walks for rows [R, K] against w [G, K, N] in
+    the program it runs: the kernel's work list (row tiles that hold rows
+    x the tile's rows) where the kernel runs, all R where ragged_dot does.
+    An int32 scalar."""
+    kernel = _grouped_kernel(rows_shape, w_shape, dtype)
+    if kernel is not None:
+        return kernel.rows_walked(jnp.asarray(sizes, jnp.int32),
+                                  rows_shape[0])
+    return jnp.asarray(rows_shape[0], jnp.int32)
 
 
 # ---------------------------------------------------------------------------

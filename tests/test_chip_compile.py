@@ -384,9 +384,10 @@ def test_latent_pool_write_and_attention(for_chip, width):
 
 def test_dropless_experts_grouped_product(for_chip):
     """The share-aware expert layer's dispatch at the cell's widths (hidden
-    4096, experts 2048 wide, 8 of 128 a token, 8 held here): the grouped
-    product is the TPU's own ragged-dot kernel, and its group sizes are
-    32-bit although the package turns jax_enable_x64 on."""
+    4096, experts 2048 wide, 8 of 128 a token, 8 held here): both grouped
+    products are the repo's `grouped_matmul` kernel (no `ragged-dot` is
+    left), and its group sizes, work list and tiles are 32-bit although
+    the package turns jax_enable_x64 on."""
     import paddle_tpu  # noqa: F401  (x64 on, as every program of the repo)
     from paddle_tpu.incubate.distributed.models.moe import dropless_experts
     held, d, f_, k, tokens = 8, 4096, 2048, 8, 256
@@ -397,7 +398,7 @@ def test_dropless_experts_grouped_product(for_chip):
     bf = jnp.bfloat16
     text = for_chip(f, ((tokens, d), bf), ((tokens, 128), jnp.float32),
                     ((held, d, 2 * f_), bf), ((held, f_, d), bf))
-    assert text.count("ragged-dot") >= 2
+    assert _grouped_calls(text) == 2 and "ragged-dot" not in text
 
 
 @pytest.mark.parametrize("width", [4, 32], ids=["block", "admit"])
@@ -422,7 +423,7 @@ def test_paged_attention_under_the_block_mask(for_chip, width):
 def test_dropless_experts_all_held(for_chip):
     """The softmax-routed expert layer with every expert of the router held
     (128 experts 768 wide behind hidden 2048, 8 a token, a decode pass's
-    256 lanes): still the grouped-product kernel."""
+    256 lanes): still the `grouped_matmul` kernel, both products."""
     import paddle_tpu  # noqa: F401  (x64 on, as every program of the repo)
     from paddle_tpu.incubate.distributed.models.moe import dropless_experts
     held, d, f_, k, tokens = 128, 2048, 768, 8, 256
@@ -434,7 +435,14 @@ def test_dropless_experts_all_held(for_chip):
     text = for_chip(f, ((tokens, d), bf), ((tokens, held), jnp.float32),
                     ((tokens,), jnp.bool_), ((held, d, 2 * f_), bf),
                     ((held, f_, d), bf))
-    assert text.count("ragged-dot") >= 2
+    assert _grouped_calls(text) == 2 and "ragged-dot" not in text
+
+
+def _grouped_calls(text):
+    """The `grouped_matmul` kernel's custom calls in compiled HLO text."""
+    return sum('custom_call_target="tpu_custom_call"' in line
+               and "grouped_matmul/pallas_call" in line
+               for line in text.splitlines())
 
 
 def _computations(text):
@@ -486,8 +494,8 @@ def test_dropless_experts_ladder_at_an_admission_step(for_chip, d, f_, held):
     MIDDLE one gathers, multiplies and activates R rows and only its combine
     is the full-length program's gather of S*k rows (cheaper than a
     scatter-add above an eighth of S*k: CHANGES.md, PR 35); every rung runs
-    the grouped product twice; and the conditional copies neither expert
-    stack (0.8 and 0.4 GB a layer on kexaone)."""
+    the `grouped_matmul` kernel twice; and neither the conditional nor the
+    kernel copies an expert stack (0.8 and 0.4 GB a layer on kexaone)."""
     import paddle_tpu  # noqa: F401  (x64 on, as every program of the repo)
     from paddle_tpu.incubate.distributed.models.moe import (
         dropless_experts, sorted_lengths)
@@ -510,7 +518,7 @@ def test_dropless_experts_ladder_at_an_admission_step(for_chip, d, f_, held):
     computations = _computations(text)
     low, middle, top = (_closure(computations, b) for b in branches)
     for branch in (low, middle, top):
-        assert branch.count("ragged-dot") >= 2
+        assert _grouped_calls(branch) == 2 and "ragged-dot" not in branch
     assert _row_sized(low, 1024, f_) and not _row_sized(low, n, f_)
     assert not _row_sized(low, 4096, f_)
     # the middle rung: the combine's rows alone are S*k long (as wide as the
@@ -598,3 +606,42 @@ def test_window_model_step_programs_copy_neither_pool(one_chip, for_chip,
     assert len(scatters) == 2 * 5 and all(
         r[1] == ",".join(map(str, reversed(range(len(r[0])))))
         for r in scatters), sorted(set(scatters))
+
+
+def test_grouped_matmul_is_lowered_once_per_shape(one_chip, for_chip,
+                                                  monkeypatch):
+    """BOTH step programs of ContinuousBatcher over a tiny sigmoid-expert
+    model (3 expert layers, 4 of 16 experts held, 4 a token; 4 slots of 32
+    admission lanes: 512 assignments, a ladder of three rungs 32 / 128 /
+    512; a decode step's 16, one rung), LOWERED for the described chip:
+    each program holds ONE `grouped_matmul` Mosaic body per distinct (R, K,
+    N, dtype) of its products (2 products a rung), not one per call site (3
+    layers x rungs x 2).  A Pallas kernel is traced and lowered at every
+    call site it is called from: `ops.grouped_matmul` reaches it through
+    one jitted function, whose lowering the program shares."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.incubate.distributed.models import moe
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    monkeypatch.setattr(moe, "MIN_RUNG_ROWS", 32)
+    paddle.seed(5)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        num_hidden_layers=4, num_attention_heads=2, num_key_value_heads=2,
+        head_dim=HEAD_DIM, max_position_embeddings=512, dtype="bfloat16",
+        first_k_dense_replace=1, moe_gate="sigmoid", moe_num_experts=4,
+        moe_first_expert=4, moe_router_width=16, moe_top_k=4,
+        moe_intermediate_size=128, moe_shared_experts=1,
+        moe_routed_scaling=2.5, moe_router_bias=True))
+    model.eval()
+    bat = ContinuousBatcher(model, max_batch_size=4, max_len=256)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (bat._param_vals(), *bat._carry_args()))
+    for width, steps in ((bat.prefill_chunk, bat.admit_steps),
+                         (1, bat.chunk)):
+        rungs = moe.sorted_lengths(4 * width * 4)
+        assert len(rungs) == (3 if width > 1 else 1)
+        text = bat._step_fn(width, steps, record=False).lower(*args).as_text()
+        bodies = text.count('kernel_name = "grouped_matmul"')
+        assert bodies == 2 * len(rungs), (width, bodies)

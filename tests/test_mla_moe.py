@@ -428,11 +428,13 @@ def test_router_bias_moves_the_choice_not_the_weight():
 # -- (6) dropless under skew ------------------------------------------------
 
 def _full_length_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
-                         b1=None, b2=None, counters=None):
+                         b1=None, b2=None, counters=None,
+                         product=jax.lax.ragged_dot):
     """The oracle: `dropless_experts` as it was before the sorted buffer
     followed the assignments held (PR 34's text, to the letter but for the
-    counter's fourth sum): all S*k sorted rows gathered, multiplied,
-    gathered back, selected and summed."""
+    counter's fourth and fifth sums): all S*k sorted rows gathered,
+    multiplied by `product` (`jax.lax.ragged_dot` by default), gathered
+    back, selected and summed."""
     S, k = topi.shape
     count = w1.shape[0]
     local = topi.astype(jnp.int32) - first
@@ -449,13 +451,13 @@ def _full_length_experts(tokens, topi, topw, w1, w2, act, first=0, valid=None,
         rows = jnp.take(tokens, order // k, axis=0)          # [S*k, d]
     if counters is not None:
         n_valid = S if valid is None else jnp.sum(valid.astype(jnp.int32))
-        counters.add(n_valid, k, sizes, S * k)
+        counters.add(n_valid, k, sizes, S * k, S * k)
     with jax.named_scope("moe.experts"):
         expert = jnp.take(key, order)     # of each sorted row
-        h = jax.lax.ragged_dot(rows, w1, sizes)
+        h = product(rows, w1, sizes)
         if b1 is not None:
             h = h + jnp.take(b1[:, 0], expert, axis=0, mode="clip")
-        out = jax.lax.ragged_dot(
+        out = product(
             moe_module._expert_act(h, act).astype(rows.dtype), w2, sizes)
         if b2 is not None:
             out = out + jnp.take(b2[:, 0], expert, axis=0, mode="clip")
@@ -506,9 +508,10 @@ def test_dropless_under_skew(short_rungs, chosen, held_rows):
                 act @ np.asarray(w2[e - first]))
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
     hit = held_rows // S
+    rung = _rung_of((2, 8, 32), held_rows)
+    # ragged_dot (the CPU's grouped product) walks the whole rung
     assert counters.vector().tolist() == [
-        2 * S, held_rows, hit, _rung_of((2, 8, 32), held_rows),
-        S if hit else 0]
+        2 * S, held_rows, hit, rung, rung, S if hit else 0]
 
 
 # -- (6b) the sorted buffer follows the assignments held ----------------------
@@ -622,7 +625,8 @@ def test_laddered_experts_equal_the_full_length_program(
     if held == 0:
         assert not got.any()
     assert got_c[1] == held and got_c[3] == rung and want_c[3] == S * k
-    assert got_c[:3] + got_c[4:] == want_c[:3] + want_c[4:]
+    assert got_c[4] == rung and want_c[4] == S * k
+    assert got_c[:3] + got_c[5:] == want_c[:3] + want_c[5:]
 
 
 def test_grad_through_a_rung_equals_the_full_length_programs(short_rungs):
@@ -655,8 +659,9 @@ def test_every_expert_held_and_every_lane_valid_is_the_full_length_program(
     """What the layer knows while tracing it decides while tracing: every
     expert of the router held and no lane invalid (training through the
     `naive` gate), every assignment is held: no conditional, and the jaxpr
-    is the full-length program's to the letter.  A share, or a step with
-    invalid lanes, takes the ladder."""
+    is the full-length program's to the letter (its products the layer's
+    own, `ops.grouped_matmul`).  A share, or a step with invalid lanes,
+    takes the ladder."""
     paddle.seed(1)
     layer = MoELayer(d_model=8, d_hidden=16, num_experts=4, gate="naive",
                      top_k=2)
@@ -670,7 +675,8 @@ def test_every_expert_held_and_every_lane_valid_is_the_full_length_program(
     assert "cond[" in text(valid=jnp.ones((32,), bool))
     monkeypatch.setattr(
         moe_module, "_sorted_experts",
-        lambda *args: _full_length_experts(*args[:-1]))
+        lambda *args: _full_length_experts(
+            *args[:-1], product=tpu_ops.grouped_matmul))
     assert text() == plain
     paddle.seed(1)
     share = MoELayer(d_model=8, d_hidden=16, num_experts=4, gate="naive",
@@ -707,6 +713,278 @@ def test_naive_gate_takes_the_sorted_dispatch():
         v, {k: t.value for k, t in moe._dropless_leaves().items()}))(
             jnp.asarray(x)))
     assert "ragged_dot" in text
+
+
+# -- (6c) the grouped-matmul kernel -----------------------------------------
+
+def _host_walk(sizes, R):
+    """The (group, row tile) items the kernel's work list should hold, in
+    order, and the rows they cover: numpy, from the sizes alone."""
+    tm = min(128, R)
+    items, start = [], 0
+    for g, n in enumerate(np.asarray(sizes).tolist()):
+        if n:
+            items += [(g, t) for t in range(start // tm,
+                                            (start + n - 1) // tm + 1)]
+        start += n
+    return items, len(items) * tm
+
+
+def _grouped_case(R, sizes, dtype, N=128, seed=3):
+    rng = np.random.RandomState(seed)
+    dt = jnp.dtype(dtype)
+    rows = jnp.asarray(rng.randn(R, 128), dt)
+    w = jnp.asarray(rng.randn(len(sizes), 128, N) * 128 ** -0.5, dt)
+    return rows, w, jnp.asarray(sizes, jnp.int32)
+
+
+def _held_sizes(rng, groups, held):
+    """`held` rows over `groups` groups, skewed (a Zipf-like draw: some
+    groups empty, a few large), as a router leaves them."""
+    p = 1.0 / np.arange(1, groups + 1) ** 1.2
+    return np.bincount(rng.choice(groups, held, p=p / p.sum()),
+                       minlength=groups)
+
+
+def _rung_sizes(groups, rung):
+    """At a rung of the ladder of 2,048 assignments with the shortest rung
+    128 (the cells' 16,384 and 1,024, an eighth): the held rows of a step
+    that takes this rung (just above the rung below it), over the held
+    experts of kexaone236b_serve_mixed_c64 (16) or sdar30b_serve_gen_c64
+    (128)."""
+    rng = np.random.RandomState(groups + rung)
+    below = {128: 0, 512: 128, 2048: 512}[rung]
+    return _held_sizes(rng, groups, below + 1 + rng.randint(rung - below))
+
+
+GROUPED_CASES = {
+    "empty groups": (256, [0, 40, 0, 0, 100, 3, 0, 60]),
+    "a group across three tiles": (512, [5, 300, 7, 0, 0, 0, 0, 0]),
+    "all rows in one group": (384, [0, 0, 384, 0, 0, 0, 0, 0]),
+    "rows past the last group": (512, [10, 20, 0, 5, 0, 0, 0, 0]),
+    "nothing held": (256, [0] * 8),
+    **{f"{cell} rung {rung}": (rung, _rung_sizes(groups, rung))
+       for cell, groups in (("kexaone", 16), ("sdar", 128))
+       for rung in (128, 512, 2048)},
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("case", list(GROUPED_CASES))
+def test_grouped_matmul_kernel_equals_ragged_dot(case, dtype):
+    """ops/pallas/grouped_matmul.py (interpret mode) against
+    jax.lax.ragged_dot on every row a group owns; the rows past the last
+    group's end are the caller's to mask (the expert layer's combine does).
+    fp32 within rounding of the fp32 sums; bf16 within ONE bfloat16 step
+    of each output (both round an fp32 sum, summed in another order) and
+    that order's fp32 slack, which matters where a sum cancels to near 0."""
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    R, sizes = GROUPED_CASES[case]
+    rows, w, sizes = _grouped_case(R, sizes, dtype)
+    n = int(sizes.sum())
+    got = kernel.grouped_matmul(rows, w, sizes, interpret=True)
+    assert got.dtype == rows.dtype and got.shape == (R, 128)
+    want = np.asarray(jax.lax.ragged_dot(rows, w, sizes), np.float32)[:n]
+    got = np.asarray(got, np.float32)[:n]
+    if dtype == "float32":
+        np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-5)
+    else:
+        step = 2.0 ** (np.floor(np.log2(np.maximum(np.abs(want), 2.0 ** -100)))
+                       - 7)
+        assert (np.abs(got - want) <= step + 2.0 ** -16 * np.abs(want).max(
+            initial=0.0)).all()
+
+
+def test_grouped_matmul_work_list_names_the_tiles_that_hold_rows():
+    """The grid's items: every (group, row tile) a non-empty group's rows
+    touch, group by group, once; the rows they cover are what
+    `rows_walked` (the `moe_rows_walked` counter) counts."""
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    for R, sizes in GROUPED_CASES.values():
+        want, walked = _host_walk(sizes, R)
+        tm = kernel.row_tile(R)
+        items, group, tile, starts, ends = (np.asarray(a) for a in
+                                            kernel._work_list(jnp.asarray(
+                                                sizes, jnp.int32), R, tm))
+        assert len(group) == R // tm + len(sizes) - 1
+        assert list(zip(group[:items], tile[:items])) == want
+        assert int(kernel.rows_walked(jnp.asarray(sizes, jnp.int32), R)) \
+            == walked
+        np.testing.assert_array_equal(ends - starts, sizes)
+
+
+def test_grouped_matmul_refuses_what_mosaic_would():
+    """supports: the three expert cells' products at every rung and decode
+    width; not K or N off whole 128-lane tiles, R off whole row tiles of
+    whole sublane tiles, an int8 or 3-D operand, or mismatched depths."""
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    bf, f32 = jnp.bfloat16, jnp.float32
+    for d, f, held in ((6144, 2048, 16), (4096, 2048, 32), (2048, 768, 128)):
+        for R in (512, 1024, 2048, 4096, 16384):
+            assert kernel.supports((R, d), (held, d, 2 * f), bf)
+            assert kernel.supports((R, f), (held, f, d), bf)
+    assert kernel.col_tile(6144, 4096, bf) == 512
+    assert kernel.col_tile(2048, 1536, bf) == 1536
+    assert kernel.col_tile(2048, 6144, bf) == 2048
+    assert kernel.supports((16, 128), (4, 128, 256), bf)
+    assert kernel.supports((8, 128), (4, 128, 256), f32)
+    assert not kernel.supports((8, 128), (4, 128, 256), bf)
+    assert not kernel.supports((200, 128), (4, 128, 256), bf)
+    assert not kernel.supports((256, 96), (4, 96, 256), bf)
+    assert not kernel.supports((256, 128), (4, 128, 200), bf)
+    assert not kernel.supports((256, 128), (4, 128, 256), jnp.int8)
+    assert not kernel.supports((256, 128), (4, 256, 256), bf)
+    assert not kernel.supports((2, 256, 128), (4, 128, 256), bf)
+    rows, w, sizes = _grouped_case(256, [100, 0, 50, 6], "float32")
+    with pytest.raises(ValueError, match="tiling needs"):
+        kernel.grouped_matmul(rows, w.astype(bf), sizes)
+    with pytest.raises(ValueError, match="tiling needs"):
+        kernel.grouped_matmul(rows[:200], w, sizes)
+
+
+@pytest.fixture()
+def grouped_kernel(monkeypatch):
+    """ops.grouped_matmul takes the kernel (interpret mode) on the CPU:
+    the choice is the backend's and the shapes', and the CPU is told it
+    has the kernel."""
+    from paddle_tpu.ops.pallas import grouped_matmul as kernel
+    monkeypatch.setattr(tpu_ops, "_grouped_kernel",
+                        lambda rows, w, dtype: kernel.supports(
+                            rows, w, dtype) and kernel or None)
+    return kernel
+
+
+@pytest.mark.parametrize("path", ["ragged_dot", "kernel"])
+def test_grouped_matmul_gradient_is_ragged_dots(path, request):
+    """ops.grouped_matmul differentiates by ragged_dot's own transposes on
+    either path: the gradients of rows and weights equal ragged_dot's to
+    the last bit (the loss is linear in the product, so both backward
+    passes see the same cotangent), the forward within fp32 rounding."""
+    if path == "kernel":
+        request.getfixturevalue("grouped_kernel")
+    rows, w, sizes = _grouped_case(384, [0, 150, 90, 0, 30, 0, 0, 4],
+                                   "float32", N=256)
+    mix = jnp.asarray(np.random.RandomState(8).randn(384, 256), jnp.float32)
+    held = (jnp.arange(384) < 274)[:, None]    # rows past the end: undefined
+
+    def loss(product):
+        return lambda r, w_: jnp.sum(jnp.where(held, mix * product(r, w_,
+                                                                   sizes), 0.0))
+    got = jax.value_and_grad(loss(tpu_ops.grouped_matmul), (0, 1))(rows, w)
+    want = jax.value_and_grad(loss(jax.lax.ragged_dot), (0, 1))(rows, w)
+    np.testing.assert_allclose(float(got[0]), float(want[0]), rtol=1e-5)
+    for g, r in zip(got[1], want[1]):
+        assert float(jnp.abs(r).max()) > 0
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
+
+
+KERNEL_LADDER_CASES = [(n, share, with_valid)
+                       for n in (0, 1, 17, 65, 256)
+                       for share, with_valid in ((True, True),
+                                                 (False, True))]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize(
+    "n_held, share, with_valid", KERNEL_LADDER_CASES,
+    ids=[f"{n} held, {'a share' if share else 'every expert'}"
+         for n, share, with_valid in KERNEL_LADDER_CASES])
+def test_dropless_through_the_kernel_equals_the_full_length_program(
+        short_rungs, grouped_kernel, n_held, share, with_valid, dtype):
+    """`dropless_experts` with both products on the kernel (through
+    ops.grouped_matmul) against the full-length program on ragged_dot, at
+    every rung of the ladder of 256 assignments (16, 64, 256: row tiles
+    of 16, 64 and 128 rows); and the `moe_rows_walked` counter against a
+    recount of the tiles that hold rows.  fp32 within rounding of the
+    sums; bf16 within TWO bfloat16 steps of the largest output (the first
+    product's output rounds to bf16 before the second)."""
+    S, k, d, f = S_LADDER, K_LADDER, 128, 128
+    lengths = sorted_lengths(S * k)
+    first, count, width = (8, 8, 32) if share else (0, 8, 8)
+    rng = np.random.RandomState(n_held + 7 * share)
+    topi, valid, held = _routing(rng, S, k, first, count, width, n_held,
+                                 with_valid)
+    dt = jnp.dtype(dtype)
+    x = jnp.asarray(rng.randn(S, d), dt)
+    w1 = jnp.asarray(rng.randn(count, d, 2 * f) * d ** -0.5, dt)
+    w2 = jnp.asarray(rng.randn(count, f, d) * f ** -0.5, dt)
+    topw = jnp.asarray(rng.rand(S, k), jnp.float32)
+
+    def run(fn):
+        def program(*leaves):
+            counters = StepCounters(valid)
+            y = fn(*leaves, "swiglu", first, valid, None, None, counters)
+            return y, counters.vector()
+        y, counts = jax.jit(program)(x, topi, topw, w1, w2)
+        return np.asarray(y), counts.tolist()
+    got, got_c = run(dropless_experts)
+    want, want_c = run(_full_length_experts)
+    largest = max(np.abs(want).max(), 2.0 ** -100)
+    if dtype == "float32":
+        np.testing.assert_allclose(got, want, rtol=0, atol=1e-5 * largest)
+    else:
+        step = 2.0 ** (np.floor(np.log2(largest)) - 7)
+        np.testing.assert_allclose(got, want, rtol=0, atol=2 * step)
+    if held == 0:
+        assert not got.any()
+    ids = np.asarray(topi) - first
+    mine = (ids >= 0) & (ids < count)
+    if valid is not None:
+        mine &= np.asarray(valid)[:, None]
+    sizes = np.bincount(ids[mine], minlength=count)
+    rung = _rung_of(lengths, held)
+    assert got_c[3] == rung and got_c[4] == _host_walk(sizes, rung)[1]
+    assert got_c[:4] + got_c[5:] == want_c[:3] + [rung] + want_c[5:]
+
+
+def test_rows_walked_over_held_reads_the_counters():
+    """benchmark/metrics/moe_rows_walked_over_held.py: the window's rows
+    walked over the assignments held; nothing (and no error) from a
+    program without the counter, or with nothing held."""
+    from metrics import moe_rows_walked_over_held as reader
+    assert reader.read(None, {"moe_rows_walked": 10240,
+                              "moe_assignments_held": 1280}, {}) == 8.0
+    for counters in ({}, {"moe_assignments_held": 1280},
+                     {"moe_rows_walked": 4096, "moe_assignments_held": 0}):
+        assert reader.read(None, counters, {}) is None
+
+
+def test_grouped_matmul_roofline_reads_the_harvest_spans(monkeypatch):
+    """benchmark/metrics/grouped_matmul_roofline.py: the experts' weights
+    once a (layer, step, held expert) hit and the held rows in and out,
+    from the traced chunks' serve.harvest ids, over the device time of the
+    `grouped_matmul` kernels and the HBM peak; nothing (and no error)
+    without a trace, without the ids, or where no such kernel ran (the
+    products were ragged_dot's)."""
+    import program_spans
+    import trace_reduce
+    from metrics import grouped_matmul_roofline as reader
+
+    def span(name, **ids):
+        return program_spans.Span(name, 0.0, 1.0, ids, None)
+    programs = {
+        "kernel": [span("serve.harvest", chunk=1, moe_expert_steps_hit=10,
+                        moe_assignments_held=100),
+                   span("serve.harvest", chunk=2, moe_expert_steps_hit=30,
+                        moe_assignments_held=300),
+                   span("serve.dispatch", chunk=2, steps=4)],
+        "no ids": [span("serve.harvest", chunk=1)],
+        "ragged_dot": [span("serve.harvest", chunk=1, moe_expert_steps_hit=1,
+                            moe_assignments_held=8)]}
+    monkeypatch.setattr(
+        program_spans, "for_cell", lambda trace, cell: trace and
+        program_spans.Program(programs[trace], []))
+    monkeypatch.setattr(
+        trace_reduce, "kernel_seconds", lambda trace, pattern:
+        0.002 if trace == "kernel" and pattern == "grouped_matmul" else None)
+    cell = {"config": {"hidden_size": 2048, "moe_intermediate_size": 768},
+            "peaks": {"hbm_bytes_per_s": 819e9, "bf16_flops_per_s": 197e12},
+            "chips": 1}
+    nbytes = 2.0 * (40 * 3 * 2048 * 768 + 400 * (2 * 2048 + 3 * 768))
+    assert reader.read("kernel", {}, cell) == pytest.approx(
+        100.0 * nbytes / 819e9 / 0.002)
+    for trace in (None, "no ids", "ragged_dot"):
+        assert reader.read(trace, {}, cell) is None
 
 
 # -- (7) YaRN ---------------------------------------------------------------
@@ -802,7 +1080,7 @@ def test_step_counters_equal_a_host_recount(short_rungs, model, ids):
         biggest = max(biggest, loads.max())
         rows += _rung_of(lengths, loads.sum())
     assert counters.vector().tolist() == [11 * 3 * 2, held, hit, rows,
-                                          biggest]
+                                          rows, biggest]
     assert 0 < held < 11 * 3 * 2 and held <= rows < 2 * 48
 
 
@@ -828,6 +1106,8 @@ def test_batcher_counts_on_the_device_and_reports_in_stats(model, ids):
     assert st["moe_rows_sorted"] == 2 * 3 * 3 * (
         st["admit_chunks"] * bat.admit_steps * bat.prefill_chunk
         + st["decode_chunks"] * bat.chunk)
+    # ragged_dot, the CPU's grouped product, walks the whole buffer
+    assert st["moe_rows_walked"] == st["moe_rows_sorted"]
     assert 0 < st["moe_tokens_per_expert_max"] <= 2 * 8
     assert model.step_counter_names() == tuple(
         k for k in st if k.startswith("moe_"))

@@ -577,3 +577,50 @@ def test_the_train_step_and_the_expert_ladder_by_conditionals(monkeypatch):
     assert bat.model.step_counter_names()
     for mixed in (False, True):
         assert _conditionals(bat.lower_step(mixed=mixed)) == 2
+
+
+def _grouped_products(lowered):
+    """Calls of the expert layers' grouped product (`ops.grouped_matmul`,
+    one jitted function: `call @_grouped_matmul` in the lowered text)."""
+    return lowered.as_text().count("call @_grouped_matmul")
+
+
+@pytest.mark.parametrize("which", range(4),
+                         ids=["dense_gqa", "mha_int8", "latent", "block"])
+def test_a_model_without_expert_layers_calls_no_grouped_product(which):
+    """The grouped product (`ops.grouped_matmul`: a Pallas kernel on a TPU,
+    reached through one jitted function) exists only in expert layers:
+    neither serve step program of the four kinds of model without them
+    calls it, nor does the tiny llama train step, so their programs are
+    what they were before it (the dense and train cells' step programs
+    were held, text for text, to those of the commit before it)."""
+    _, build, knobs = _kind_models()[which]
+    bat = ContinuousBatcher(build(), **_GEOMETRY, **_PLAIN, **knobs)
+    for mixed in (False, True):
+        lowered = bat.lower_step(mixed=mixed)
+        assert _grouped_products(lowered) == 0
+        assert "ragged_dot" not in lowered.as_text()
+    if which == 0:
+        step, ids = _llama_step()
+        assert "_grouped_matmul" not in step.compiled_hlo(ids, ids,
+                                                          optimized=False)
+
+
+def test_an_expert_model_calls_the_grouped_product_at_every_product(
+        monkeypatch):
+    """The count above is a count of something: a model with 2 expert
+    layers calls it twice a layer and rung, and every call is the one
+    function's."""
+    from paddle_tpu.incubate.distributed.models import moe
+    monkeypatch.setattr(moe, "MIN_RUNG_ROWS", 1)
+    paddle.seed(11)
+    experts = LlamaForCausalLM(llama_tiny_config(
+        num_hidden_layers=2, hidden_size=32, intermediate_size=64,
+        num_attention_heads=4, num_key_value_heads=2, vocab_size=64,
+        moe_num_experts=4, moe_top_k=2, moe_gate="naive"))
+    bat = ContinuousBatcher(experts, **_GEOMETRY, **_PLAIN)
+    for mixed in (False, True):
+        lowered = bat.lower_step(mixed=mixed)
+        width = bat.prefill_chunk if mixed else bat._decode_width
+        rungs = moe.sorted_lengths(_GEOMETRY["max_batch_size"] * width * 2)
+        assert _grouped_products(lowered) == 2 * 2 * len(rungs)
