@@ -642,6 +642,79 @@ def phase_grouped():
         release("grouped")
 
 
+# the hybrid serve cell's state pool: 36 state layers, 64 slots, 64 heads of
+# 64, a state of 128 (graniteh3b_serve_chat_c64)
+SSM_GEOMETRY = (36, 64, 64, 64, 128)
+
+
+def phase_ssm():
+    """ops.ssm_scan (the Pallas kernel on a TPU) beside ops.xla_ssm_scan at
+    the hybrid serve cell's geometry, a decode call (1 lane) and an
+    admission call (32 lanes, 20 valid a slot), the pool donated and passed
+    back call after call as the step program does: the kernel must be in
+    the compiled program and equal the twin; each line carries both sides'
+    ms a call and GB/s of the layer's state read and written."""
+    import numpy as np
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu import ops
+    if jax.devices()[0].platform != "tpu":
+        fail("ssm: the kernel runs on a TPU only")
+    L, B, H, P, N = SSM_GEOMETRY
+    key = jax.random.PRNGKey(SEED + 6)
+    a = -jnp.exp(jnp.linspace(0.0, 2.7, H, dtype=jnp.float32))
+    d = jnp.ones((H,), jnp.float32)
+    reset = jnp.zeros((B,), jnp.int32)
+    state_bytes = 2 * B * H * P * N * 4
+    for width, valid in ((1, 1), (32, 20)):
+        ks = jax.random.split(key, 4)
+        x = jax.random.normal(ks[0], (B, width, H, P), jnp.bfloat16)
+        bm = (jax.random.normal(ks[1], (B, width, N)) * 0.1).astype(
+            jnp.bfloat16)
+        cm = (jax.random.normal(ks[2], (B, width, N)) * 0.1).astype(
+            jnp.bfloat16)
+        dt = jax.random.uniform(ks[3], (B, width, H), jnp.float32, 0.001,
+                                0.1)
+        n = jnp.full((B,), valid, jnp.int32)
+        out, times = {}, {}
+        for side, fn in (("kernel", ops.ssm_scan), ("twin", None)):
+            def call(state, x, bm, cm, dt, n, layer, fn=fn):
+                if fn is not None:
+                    return fn(state, x, bm, cm, dt, a, d, n, reset, layer)
+                lanes = jnp.arange(width)
+                m = jnp.where(lanes[None, :, None] < n[:, None, None], dt,
+                              0.0)
+                return ops.xla_ssm_scan(state, x, bm, cm, m, a, d, reset,
+                                        layer)
+            step = jax.jit(call, donate_argnums=(0,))
+            state = jax.random.normal(key, (L, B, H, P, N), jnp.float32)
+            if side == "kernel" and "ssm_scan" not in kernels_in(
+                    step.lower(state, x, bm, cm, dt, n, jnp.int32(3))
+                    .compile().as_text()):
+                fail("ssm: ops.ssm_scan compiled without its kernel")
+            y, state = step(state, x, bm, cm, dt, n, jnp.int32(3))
+            out[side] = (np.asarray(y[:, :valid], np.float32),
+                         np.asarray(state[3, :4], np.float32))
+            jax.block_until_ready(state)
+            t0 = time.perf_counter()
+            for i in range(TIMED_CALLS):
+                y, state = step(state, x, bm, cm, dt, n, jnp.int32(i % L))
+            jax.block_until_ready(state)
+            times[side] = (time.perf_counter() - t0) / TIMED_CALLS * 1e3
+            del state, y
+        gap, close = twin_gap(out["kernel"][0], out["twin"][0])
+        state_gap = float(np.abs(out["kernel"][1] - out["twin"][1]).max())
+        say(phase="ssm", width=width, valid=valid, **gap,
+            state_max_abs_difference=state_gap,
+            kernel_ms_a_call=round(times["kernel"], 4),
+            twin_ms_a_call=round(times["twin"], 4),
+            kernel_state_gb_s=round(state_bytes / times["kernel"] / 1e6, 1),
+            twin_state_gb_s=round(state_bytes / times["twin"] / 1e6, 1))
+        if not close:
+            fail(f"ssm: the kernel leaves the twin ({width} lanes): {gap}")
+    release("ssm")
+
+
 def phase_lowering():
     """Seconds this host takes to trace and lower the two serve step
     programs of a model built as kexaone236b_serve_mixed_c64's (5 layers of

@@ -409,8 +409,15 @@ class ContinuousBatcher:
         self._kinds = None
         if hasattr(model, "kv_row_spec"):
             self._kinds = model.kv_row_spec(kv_dtype).get("kinds")
-        if self._kinds:
+        # and STATE-SPACE layers beside the attention layers: a state a
+        # slot in each (kv_row_spec's "state" kind), advanced by the step
+        # by each slot's valid lanes only
+        self._state = (self._kinds or {}).get("state")
+        if self._state:
+            self._refuse_for_state(kv_layout, role, prefix_sharing)
+        elif self._kinds:
             self._refuse_for_kinds(kv_layout, role, prefix_sharing)
+        if self._kinds:
             prefix_sharing = False
         # lanes a slot feeds the decode program a step: the token, the
         # verify window, or the block
@@ -540,6 +547,7 @@ class ContinuousBatcher:
                               2 * self.spec_k + 2) if self.spec_k \
             else self.prefill_chunk
         self._cache_len = self.max_len + self._eff_chunk - 1
+        self._state_bytes = {}
         if kv_layout == "paged":
             from .paged_kv import PageAllocator
             (self.page_size, self.pages_per_slot,
@@ -581,7 +589,9 @@ class ContinuousBatcher:
             self.ring_pages = self._ring_pages(
                 self._kinds, self._eff_chunk, self.page_size)
             more = {"window_pages": self.B * self.ring_pages} \
-                if self._kinds else {}
+                if self.ring_pages else {}
+            if self._state:
+                more = {"state_slots": self.B}
             self._cache = model.init_paged_cache(self.num_pages,
                                                  self.page_size,
                                                  kv_dtype, **more)
@@ -591,7 +601,13 @@ class ContinuousBatcher:
             if self._kinds:
                 self._kv_pool_bytes = self._kind_pool_bytes(
                     spec, self.num_pages, self.B * self.ring_pages,
-                    self.page_size)
+                    self.page_size, self.B)
+            if self._state:
+                # {state array: bytes of all slots' in all state layers}
+                self._state_bytes = {
+                    name: int(self._cache[name].size
+                              * self._cache[name].dtype.itemsize)
+                    for name in self._state["arrays"]}
             if self._diffusion and spec["scales"]:
                 raise ValueError(
                     "int8 KV under the block schedule is not supported: "
@@ -660,7 +676,7 @@ class ContinuousBatcher:
         # over the kind's layers too) beside the pages a window layer's
         # valid lanes need: KIND_PAGE_COUNTS
         self._kv_pages_by_kind = dict.fromkeys(KIND_PAGE_COUNTS, 0) \
-            if self._kinds else {}
+            if self._kinds and "window" in self._kinds else {}
         # what the MODEL counts a step on the device (a dropless expert
         # layer's routing, models.llama.step_counter_names): summed in
         # the scan, read with the chunk's tokens, never under
@@ -772,6 +788,33 @@ class ContinuousBatcher:
                 "onto them would lack the window layers' (the default "
                 "resolves to off for such a model)")
 
+    def _refuse_for_state(self, kv_layout, role, prefix_sharing):
+        """What a model with state-space layers does not compose with yet,
+        said at construction: each of these moves, shares or rewinds a
+        slot's pages, and a slot's state, which sums its WHOLE history,
+        would have to go with them (ROADMAP R4).  (int8 KV: kv_row_spec
+        refuses it.)"""
+        what = "a model with state-space layers carries a state a slot " \
+            "that sums every token the slot has seen"
+        if kv_layout != "paged":
+            raise TypeError(
+                f"{what}, beside the PAGED pool: the dense ring buffers' "
+                "step programs carry no state (kv_layout must be 'paged')")
+        if self.spec_k:
+            raise ValueError(
+                f"{what}: a rejected draft token would already be in it, "
+                "and a state cannot be rewound (spec_tokens must be 0)")
+        if role != "unified":
+            raise ValueError(
+                f"role {role!r}: {what}, and a hand-off ships the pages of "
+                "the page table alone; only the unified role serves such a "
+                "model")
+        if prefix_sharing:
+            raise ValueError(
+                f"prefix_sharing=True: {what}, so a request mapped onto a "
+                "shared prefix's pages would lack the state at the prefix's "
+                "end (the default resolves to off for such a model)")
+
     def preflight(self, *, level: str = "full", manager=None):
         """Full static sentinel over the serve step programs: the
         donation lint proves every donated paged carry (KV pool,
@@ -811,9 +854,9 @@ class ContinuousBatcher:
     @staticmethod
     def _ring_pages(kinds, prefill_chunk, page_size) -> int:
         """Pages of a slot's ring in the window layers' pool (0 for a
-        model without kinds): ops.ring_pages of the rows the model says a
-        slot needs there under the widest step."""
-        if not kinds:
+        model without window layers): ops.ring_pages of the rows the model
+        says a slot needs there under the widest step."""
+        if not kinds or "window" not in kinds:
             return 0
         from ..ops import ring_pages
         return ring_pages(kinds["window"]["window"], prefill_chunk,
@@ -838,10 +881,11 @@ class ContinuousBatcher:
         table = B * p_slot * 4
         if spec.get("kinds"):
             # a pool a kind: the full layers' behind the page table, the
-            # window layers' rings (B slots of ring pages, no table)
+            # window layers' rings (B slots of ring pages, no table), the
+            # state layers' states (B slots)
             held = cls._kind_pool_bytes(
                 spec, n_pages, B * cls._ring_pages(
-                    spec["kinds"], prefill_chunk, ps), ps)
+                    spec["kinds"], prefill_chunk, ps), ps, B)
             return sum(held.values()) + table
         rows = sum(int(np.prod(row)) for row in spec["pools"].values())
         pool = n_pages * ps * layers * rows \
@@ -850,14 +894,22 @@ class ContinuousBatcher:
         return pool + scales + table
 
     @staticmethod
-    def _kind_pool_bytes(spec, num_pages, window_pages, page_size):
-        """{kind: bytes} of the pools of a model with kinds of layer."""
+    def _kind_pool_bytes(spec, num_pages, window_pages, page_size, slots):
+        """{kind: bytes} of the pools of a model with kinds of layer (and
+        of the states of its state layers, `slots` of each)."""
         item = jnp.dtype(spec["dtype"]).itemsize
         out = {}
         for kind, pages in (("full", num_pages), ("window", window_pages)):
-            k = spec["kinds"][kind]
+            k = spec["kinds"].get(kind)
+            if k is None:
+                continue
             rows = sum(int(np.prod(spec["pools"][n])) for n in k["pools"])
             out[kind] = pages * page_size * len(k["layers"]) * rows * item
+        state = spec["kinds"].get("state")
+        if state is not None:
+            out["state"] = slots * len(state["layers"]) * sum(
+                int(np.prod(shape)) * jnp.dtype(dt).itemsize
+                for shape, dt in state["arrays"].values())
         return out
 
     # -- public API --------------------------------------------------------
@@ -1904,7 +1956,9 @@ class ContinuousBatcher:
                             "'paged' (the hand-off ships pages)")
         if self._diffusion:
             self._refuse_for_diffusion(self.kv_layout, role)
-        if self._kinds:
+        if self._state:
+            self._refuse_for_state(self.kv_layout, role, False)
+        elif self._kinds:
             self._refuse_for_kinds(self.kv_layout, role, False)
         self.role = role
 
@@ -2019,8 +2073,8 @@ class ContinuousBatcher:
         if self._kinds:
             raise ValueError(
                 "a hand-off ships the pages of the page table alone; a "
-                "model with sliding-window layers keeps rings beside them "
-                "that no hand-off carries")
+                "model with sliding-window or state-space layers keeps "
+                "rings or states beside them that no hand-off carries")
         if self.kv_layout != "paged":
             raise TypeError("import_handoff needs the paged KV layout")
         if int(meta["page_size"]) != self.page_size \
@@ -2254,7 +2308,13 @@ class ContinuousBatcher:
         counted = bool(self._counter_names)
         n_model_counts = len(self._model_counter_names)
         diffusion = self._diffusion
+        state = bool(self._state)
         from ..jit import _swapped_state
+
+        def stateful(n_valid):
+            """A model with state-space layers is told each slot's valid
+            lanes: its states advance by those alone."""
+            return {"lanes_valid": n_valid} if state else {}
 
         def build():
             def block_core(carry):
@@ -2374,7 +2434,7 @@ class ContinuousBatcher:
                         x, cache, page_table, pos, counts)
                 elif paged:
                     lg, cache = model.forward_cached_paged(
-                        x, cache, page_table, pos)
+                        x, cache, page_table, pos, **stateful(n_valid))
                 else:
                     lg, cache = model.forward_cached(x, cache, pos)
                 if spec:
@@ -2718,7 +2778,8 @@ class ContinuousBatcher:
                          for r in self._slots], np.int64)
         live = walked = 0
         by_kind = dict.fromkeys(self._kv_pages_by_kind, 0)
-        if self._kinds:
+        window = bool(by_kind)
+        if window:
             W, ps = self._kinds["window"]["window"], self.page_size
             n_full, n_win = (len(self._kinds[k]["layers"])
                              for k in ("full", "window"))
@@ -2737,7 +2798,7 @@ class ContinuousBatcher:
             walked += int(n.sum())
             live += int(held[occupied].sum())
             filling = mode & ~done
-            if self._kinds:
+            if window:
                 valid = np.where(filling, np.minimum(width, plen - pos),
                                  ~done)
                 need = (pos + valid - 1) // ps - first_page(pos, ps, W) + 1
@@ -2785,10 +2846,16 @@ class ContinuousBatcher:
         width, steps = (self.prefill_chunk, self.admit_steps) if mixed \
             else (self._decode_width, self.chunk)
         *pages, by_kind = self._kv_page_counts(width, steps)
+        # a model with state-space layers: the bytes of each of its state
+        # arrays that the chunk's scan steps read and write, once each a
+        # step, all slots'
+        state_ids = {f"{name}_state_bytes": 2 * steps * n
+                     for name, n in self._state_bytes.items()}
         try:
             with self._phase("dispatch", kind=kind, chunk=ck, steps=steps,
                              width=width, kv_pages_live=pages[0],
-                             kv_pages_walked=pages[1], **by_kind):
+                             kv_pages_walked=pages[1], **by_kind,
+                             **state_ids):
                 if self.spec_k and not mixed:
                     fn = self._spec_step_fn()
                 else:

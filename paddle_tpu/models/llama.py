@@ -161,6 +161,28 @@ class LlamaConfig:
     layer_types: tuple | None = None
     sliding_window: int = 0
     rope_layer_types: tuple | None = None
+    # STATE-SPACE layers (Mamba-2, GraniteMoeHybrid's): a layer whose kind
+    # in layer_types is "mamba" mixes with LlamaMamba2Mixer in place of
+    # attention.  mamba_n_heads heads of mamba_d_head, a state of
+    # mamba_d_state a head row, one group of B and C, a causal depthwise
+    # conv of mamba_d_conv taps with a bias.  In the paged cache such a
+    # layer keeps a STATE a slot (kv_row_spec's "state" kind), not rows.
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    # the dtype a slot's SSM state is STORED in between steps (the scan
+    # computes in fp32 either way)
+    mamba_state_dtype: str = "float32"
+    # GraniteMoeHybrid's multipliers, None where a model has none (then
+    # they add no operation to any program): the embedding's scale, the
+    # scale of every sublayer's output into the residual stream, the
+    # softmax scale of attention (in place of head_dim ** -0.5) and the
+    # divisor of the logits
+    embedding_multiplier: float | None = None
+    residual_multiplier: float | None = None
+    attention_multiplier: float | None = None
+    logits_scaling: float | None = None
 
     @property
     def latent_attention(self):
@@ -173,13 +195,13 @@ class LlamaConfig:
             return 0
         kinds = tuple(self.layer_types)
         if len(kinds) != self.num_hidden_layers or set(kinds) - {
-                "full_attention", "sliding_attention"}:
+                "full_attention", "sliding_attention", "mamba"}:
             raise ValueError(
                 f"layer_types names {len(kinds)} layers of kinds "
                 f"{sorted(set(kinds))}; the model has "
-                f"{self.num_hidden_layers}, each full_attention or "
-                "sliding_attention")
-        if kinds[layer_idx] == "full_attention":
+                f"{self.num_hidden_layers}, each full_attention, "
+                "sliding_attention or mamba")
+        if kinds[layer_idx] in ("full_attention", "mamba"):
             return 0
         if self.sliding_window < 1:
             raise ValueError("a sliding_attention layer needs "
@@ -202,6 +224,29 @@ class LlamaConfig:
         """Indices of the sliding-window layers, () without any."""
         return tuple(i for i in range(self.num_hidden_layers)
                      if self.layer_window(i))
+
+    def state_layers(self):
+        """Indices of the state-space (mamba) layers, () without any."""
+        if self.layer_types is None or "mamba" not in self.layer_types:
+            return ()
+        self.layer_window(0)                 # the kinds' check
+        if self.latent_attention or self.block_length > 1 \
+                or self.window_layers():
+            raise ValueError(
+                "mamba layers stand beside full LlamaAttention layers of "
+                "an autoregressive model")
+        return tuple(i for i, k in enumerate(self.layer_types)
+                     if k == "mamba")
+
+    @property
+    def mamba_inner(self) -> int:
+        """Width of a mamba layer's x (and z): heads x head size."""
+        return self.mamba_n_heads * self.mamba_d_head
+
+    @property
+    def mamba_conv_dim(self) -> int:
+        """Channels of the conv: x, B and C side by side."""
+        return self.mamba_inner + 2 * self.mamba_d_state
 
     def block_diffusion(self):
         """None for an autoregressive model, else how it generates:
@@ -396,6 +441,7 @@ class LlamaAttention(nn.Layer):
                                              causal=True)
             if out is None:
                 out = tpu_ops.attention(q, k, val, causal=True,
+                                        scale=cfg.attention_multiplier,
                                         block_length=cfg.block_length,
                                         window=self.window)
             out = checkpoint_name(out, "attn_out")
@@ -476,6 +522,9 @@ class LlamaAttention(nn.Layer):
         if self.kinds:
             return self._paged_by_kind(q, k, v, cache, page_table, pos,
                                        layer)
+        if cache["k"].shape[-1] != self.config.attn_head_dim:
+            return self._paged_packed(q, k, v, cache, page_table, pos,
+                                      layer)
         with jax.named_scope("attn.cache_write"):
             kp, vp, ks, vs = tpu_ops.paged_kv_update(
                 cache["k"], cache["v"], cache.get("k_scale"),
@@ -488,6 +537,36 @@ class LlamaAttention(nn.Layer):
                 q, kp, vp, page_table, pos, layer, ks, vs,
                 block_length=self.config.block_length)
         return self._out_proj(out), cache
+
+    def _paged_packed(self, q, k, v, cache, table, pos, layer):
+        """The cache write and the attention of a layer whose pool lays
+        several K/V heads side by side in a row (kv_row_spec: heads
+        narrower than the paged kernel's row): K and V go in as they are
+        (heads adjacent in memory ARE the packed row), each query head is
+        widened to the row with zeros outside its own K/V head's lanes,
+        and its output read back from those lanes, so the scores and the
+        output are exact.  The softmax scale is the head's own
+        (attention_multiplier or head_dim ** -0.5), not the row's."""
+        cfg = self.config
+        b, s, nh, d = q.shape
+        pk, nkv = cache["k"].shape[-1] // d, cfg.num_key_value_heads
+        slot = (jnp.arange(nh) // (nh // nkv)) % pk
+        place = jax.nn.one_hot(slot, pk, dtype=q.dtype)        # [nh, pk]
+        with jax.named_scope("attn.cache_write"):
+            kp, vp, _, _ = tpu_ops.paged_kv_update(
+                cache["k"], cache["v"], None, None, table, pos,
+                k.reshape(b, s, nkv // pk, pk * d),
+                v.reshape(b, s, nkv // pk, pk * d), layer)
+        with jax.named_scope("attn.attend"):
+            qw = (q[:, :, :, None, :] * place[:, :, None]).reshape(
+                b, s, nh, pk * d)
+            scale = cfg.attention_multiplier or d ** -0.5
+            out = tpu_ops.paged_attention(
+                qw, kp, vp, table, pos, layer, scale=scale,
+                block_length=cfg.block_length)
+            out = jnp.sum(out.reshape(b, s, nh, pk, d)
+                          * place[:, :, None], axis=3)
+        return self._out_proj(out), dict(cache, k=kp, v=vp)
 
     def _paged_by_kind(self, q, k, v, cache, table, pos, layer):
         """The cache write and the attention of one layer of a model with
@@ -535,9 +614,10 @@ class LlamaAttention(nn.Layer):
     def core_attention(self, q, k, v):
         q, k, v = to_tensor_args(q, k, v)
         L, W = self.config.block_length, self.window
-        return run(lambda a, b_, c: tpu_ops.attention(a, b_, c, causal=True,
-                                                      block_length=L,
-                                                      window=W),
+        return run(lambda a, b_, c: tpu_ops.attention(
+                       a, b_, c, causal=True,
+                       scale=self.config.attention_multiplier,
+                       block_length=L, window=W),
                    q, k, v, name="core_attention")
 
     def output_proj(self, attn):
@@ -675,6 +755,136 @@ class LlamaMLAttention(nn.Layer):
         return out, dict(cache, kv=pool)
 
 
+def conv_window_after(win, n_valid, taps):
+    """The conv window a slot carries into its next step: of win [b,
+    taps - 1 + lanes, channels] (the window it came with, then this step's
+    inputs), the taps - 1 inputs before lane n_valid of each slot — the
+    window shifted by the slot's valid lanes, not by the step's width."""
+    at = n_valid[:, None] + jnp.arange(taps - 1, dtype=jnp.int32)
+    return jnp.take_along_axis(win, at[..., None], axis=1)
+
+
+class LlamaMamba2Mixer(nn.Layer):
+    """Mamba-2 mixer of a state-space layer (GraniteMoeHybrid's), one group
+    of B and C.  With h the layer's normed input [b, s, hidden]:
+
+      [z | xBC | dt] = h W_in                      (no bias)
+      xBC = silu(causal depthwise conv1d(xBC) + conv_bias)   d_conv taps
+      [x | B | C] = xBC;  x as H heads of P
+      dt = softplus(dt + dt_bias);  A = -exp(A_log)           per head
+      h_t = exp(dt_t A) h_{t-1} + dt_t x_t (x) B_t            [H, P, N] fp32
+      y_t = h_t C_t + D x_t
+      out = rmsnorm(y * silu(z); norm) W_out       (the gate before the norm)
+
+    The recurrence is ops.ssm_scan's (the Pallas kernel on a TPU).  The
+    whole-sequence forward starts from a zero state; the paged path carries
+    a state a slot in the cache: "ssm" [state layers, slots, H, P, N] (in
+    config.mamba_state_dtype) and "conv" [state layers, slots, d_conv - 1,
+    conv channels], the last
+    inputs of the conv, each advanced by the slot's VALID lanes only (a
+    pad lane that advanced a state would leave it wrong for good, where a
+    pad lane's K/V row is overwritten before anyone reads it)."""
+
+    def __init__(self, config: LlamaConfig):
+        super().__init__(dtype=config.dtype)
+        from ..framework.tensor import Parameter
+        self.config = config
+        h, H = config.hidden_size, config.mamba_n_heads
+        inner, conv = config.mamba_inner, config.mamba_conv_dim
+        pd = config.param_dtype or config.dtype
+        self.in_proj = Parameter(_init_weight([h, inner + conv + H],
+                                              h ** -0.5, pd))
+        self.conv_weight = Parameter(_init_weight(
+            [config.mamba_d_conv, conv], config.mamba_d_conv ** -0.5, pd))
+        self.conv_bias = Parameter(jnp.zeros([conv], config.storage_dtype))
+        # the recurrence's own numbers stay float32 whatever the dtype
+        self.dt_bias = Parameter(jnp.full([H], -4.0, jnp.float32))
+        self.A_log = Parameter(jnp.zeros([H], jnp.float32))
+        self.D = Parameter(jnp.ones([H], jnp.float32))
+        self.norm = Parameter(jnp.ones([inner], config.storage_dtype))
+        self.out_proj = Parameter(_init_weight([inner, h], inner ** -0.5, pd))
+
+    _NAMES = ("in_proj", "conv_weight", "conv_bias", "dt_bias", "A_log", "D",
+              "norm", "out_proj")
+
+    def _mix(self, h, conv_prev, pool, layer, n_valid, reset, w):
+        """h [b, s, hidden] -> (out [b, s, hidden], the conv window of each
+        slot's last d_conv - 1 valid inputs, the state pool).  conv_prev
+        [b, d_conv - 1, channels]: the inputs before lane 0 (zero where a
+        slot starts fresh); w: {name: value} of the mixer's leaves."""
+        cfg = self.config
+        b, s, _ = h.shape
+        H, P, N = cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state
+        inner, K, cd = cfg.mamba_inner, cfg.mamba_d_conv, h.dtype
+        with jax.named_scope("ssm.project"):
+            zxbcdt = h @ w["in_proj"].astype(cd)
+            z = zxbcdt[..., :inner]
+            xbc = zxbcdt[..., inner:inner + cfg.mamba_conv_dim]
+            dt = zxbcdt[..., inner + cfg.mamba_conv_dim:]
+        with jax.named_scope("ssm.conv"):
+            win = jnp.concatenate([conv_prev.astype(cd), xbc], axis=1)
+            taps = w["conv_weight"].astype(jnp.float32)
+            acc = sum(win[:, k:k + s].astype(jnp.float32) * taps[k]
+                      for k in range(K)) + w["conv_bias"].astype(jnp.float32)
+            xbc = jax.nn.silu(acc).astype(cd)
+            conv_next = conv_window_after(win, n_valid, K)
+        x = xbc[..., :inner].reshape(b, s, H, P)
+        bm = xbc[..., inner:inner + N]
+        cm = xbc[..., inner + N:]
+        with jax.named_scope("ssm.scan"):
+            dt = jax.nn.softplus(dt.astype(jnp.float32) + w["dt_bias"])
+            a = -jnp.exp(w["A_log"].astype(jnp.float32))
+            y, pool = tpu_ops.ssm_scan(pool, x, bm, cm, dt, a,
+                                       w["D"].astype(jnp.float32), n_valid,
+                                       reset, layer)
+        with jax.named_scope("ssm.norm"):
+            g = y.reshape(b, s, inner) \
+                * jax.nn.silu(z.astype(jnp.float32))
+            g = g * jax.lax.rsqrt(jnp.mean(g * g, -1, keepdims=True)
+                                  + cfg.rms_norm_eps)
+            g = (g * w["norm"].astype(jnp.float32)).astype(cd)
+        with jax.named_scope("ssm.out"):
+            out = g @ w["out_proj"].astype(cd)
+        return out, conv_next, pool
+
+    def forward(self, x):
+        """The whole sequence from a zero state (a one-layer pool)."""
+        (x,) = to_tensor_args(x)
+        cfg = self.config
+
+        def _fn(v, *ws):
+            b, s, _ = v.shape
+            conv = jnp.zeros((b, cfg.mamba_d_conv - 1, cfg.mamba_conv_dim),
+                             jnp.float32)
+            pool = jnp.zeros((1, b, cfg.mamba_n_heads, cfg.mamba_d_head,
+                              cfg.mamba_d_state), jnp.float32)
+            out, _, _ = self._mix(v, conv, pool, 0,
+                                  jnp.full((b,), s, jnp.int32),
+                                  jnp.ones((b,), jnp.int32),
+                                  dict(zip(self._NAMES, ws)))
+            return out
+        return run(_fn, x, *[getattr(self, n) for n in self._NAMES],
+                   name="mamba2_mixer")
+
+    def forward_cached_paged(self, h, cache, pos, layer, lanes_valid=None):
+        """One step's lanes of every slot through the slots' states (layer
+        `layer` of the cache's "ssm" and "conv"): a slot at depth 0 starts
+        from zero state, and `lanes_valid` [b] (None: every lane) lanes of
+        each slot advance it.  Returns (out, cache)."""
+        b, s, _ = h.shape
+        n = jnp.full((b,), s, jnp.int32) if lanes_valid is None \
+            else jnp.asarray(lanes_valid, jnp.int32)
+        fresh = jnp.asarray(pos, jnp.int32) == 0
+        conv_prev = jnp.where(fresh[:, None, None], 0,
+                              cache["conv"][layer])
+        out, conv_next, pool = self._mix(
+            h, conv_prev, cache["ssm"], layer, n, fresh.astype(jnp.int32),
+            {name: getattr(self, name).value for name in self._NAMES})
+        conv = cache["conv"].at[layer].set(
+            conv_next.astype(cache["conv"].dtype))
+        return out, dict(cache, ssm=pool, conv=conv)
+
+
 class LlamaMLP(nn.Layer):
     def __init__(self, config: LlamaConfig):
         super().__init__(dtype=config.dtype)
@@ -705,9 +915,16 @@ class LlamaDecoderLayer(nn.Layer):
         self._recompute = config.recompute and (
             config.recompute_layers is None
             or layer_idx < config.recompute_layers)
-        # the block's two halves are chosen from the config, per layer
-        self.self_attn = LlamaMLAttention(config) \
-            if config.latent_attention else LlamaAttention(config, layer_idx)
+        # the block's two halves are chosen from the config, per layer:
+        # a state-space layer mixes with its Mamba-2 mixer, any other
+        # attends
+        self.state_layer = layer_idx in config.state_layers()
+        if self.state_layer:
+            self.mamba = LlamaMamba2Mixer(config)
+        else:
+            self.self_attn = LlamaMLAttention(config) \
+                if config.latent_attention \
+                else LlamaAttention(config, layer_idx)
         self.expert_layer = config.expert_layer(layer_idx)
         if self.expert_layer:
             from ..incubate.distributed.models.moe import MoELayer
@@ -811,13 +1028,26 @@ class LlamaDecoderLayer(nn.Layer):
         x = x + self.mlp(self.post_attention_layernorm(x))
         return run(constrain_activation, x, name="constrain_resid")
 
+    def _residual(self, y):
+        """A sublayer's output as it enters the residual stream: scaled by
+        the config's residual_multiplier where it has one."""
+        m = self.config.residual_multiplier
+        if m is None:
+            return y
+        if isinstance(y, Tensor):
+            return run(lambda v: v * m, y, name="residual_multiplier")
+        return y * m
+
     def _block(self, x, cos, sin):
         from ..parallel.sharded_trainer import constrain_activation
-        with jax.named_scope("attn"):
-            a = self.self_attn(self.input_layernorm(x), cos, sin)
-        x, h = self._add_norm_mid(x, a)
+        if self.state_layer:
+            a = self.mamba(self.input_layernorm(x))
+        else:
+            with jax.named_scope("attn"):
+                a = self.self_attn(self.input_layernorm(x), cos, sin)
+        x, h = self._add_norm_mid(x, self._residual(a))
         with jax.named_scope("mlp"):
-            x = x + self.mlp(h)
+            x = x + self._residual(self.mlp(h))
         return run(constrain_activation, x, name="constrain_resid")
 
     def _block_cached(self, x, cos, sin, attend, counters=None):
@@ -832,21 +1062,21 @@ class LlamaDecoderLayer(nn.Layer):
         ln2 = self.post_attention_layernorm.weight.value
         h = tpu_ops.rms_norm(x, ln1.astype(x.dtype), cfg.rms_norm_eps)
         attn, kv_state = attend(h)
-        x = x + attn
+        x = x + self._residual(attn)
         h = tpu_ops.rms_norm(x, ln2.astype(x.dtype), cfg.rms_norm_eps)
         if self.expert_layer and counters is not None:
-            x = x + self.mlp(h, valid=counters.valid,
-                             counters=counters).value
+            x = x + self._residual(self.mlp(h, valid=counters.valid,
+                                            counters=counters).value)
         elif self.expert_layer:
             # MoE decode: route through the expert layer (dispatch
             # handles raw jax values; aux loss is irrelevant at decode)
-            x = x + self.mlp(h).value
+            x = x + self._residual(self.mlp(h).value)
         else:
             with jax.named_scope("mlp"):
-                x = x + _wo_mm(self.mlp, "down_proj",
-                               tpu_ops.swiglu(
-                                   _wo_mm(self.mlp, "gate_proj", h),
-                                   _wo_mm(self.mlp, "up_proj", h)))
+                x = x + self._residual(_wo_mm(
+                    self.mlp, "down_proj",
+                    tpu_ops.swiglu(_wo_mm(self.mlp, "gate_proj", h),
+                                   _wo_mm(self.mlp, "up_proj", h))))
         return x, kv_state
 
     def forward_cached(self, x, cos, sin, k_cache, v_cache, pos):
@@ -859,10 +1089,14 @@ class LlamaDecoderLayer(nn.Layer):
         return x, k_cache, v_cache
 
     def forward_cached_paged(self, x, cos, sin, cache, page_table, pos,
-                             layer, counters=None):
+                             layer, counters=None, lanes_valid=None):
         """Raw-jax paged decode block (see
-        LlamaAttention.forward_cached_paged)."""
+        LlamaAttention.forward_cached_paged; a state-space layer's,
+        LlamaMamba2Mixer.forward_cached_paged: `lanes_valid` is its)."""
         def attend(h):
+            if self.state_layer:
+                return self.mamba.forward_cached_paged(h, cache, pos, layer,
+                                                       lanes_valid)
             return self.self_attn.forward_cached_paged(
                 h, cos, sin, cache, page_table, pos, layer)
         return self._block_cached(x, cos, sin, attend, counters)
@@ -893,15 +1127,19 @@ class LlamaModel(nn.Layer):
         # scope census and chrome-trace lanes attribute work per layer
         # instead of one opaque program
         with jax.named_scope("llama.embed"):
-            x = run(lambda w: constrain_activation(
+            x = run(lambda w: constrain_activation(self._embed_scale(
                         jnp.take(w, input_ids.value.astype(jnp.int32),
-                                 axis=0).astype(cfg.compute_dtype)),
+                                 axis=0).astype(cfg.compute_dtype))),
                     self.embed_tokens, name="embedding")
         for i, layer in enumerate(self.layers):
             with jax.named_scope(f"llama.layer{i}"):
                 x = layer(x, cos, sin)
         with jax.named_scope("llama.norm"):
             return self.norm(x)
+
+    def _embed_scale(self, x):
+        m = self.config.embedding_multiplier
+        return x if m is None else x * m
 
     def _rope_tables(self, seq_len, position_ids=None):
         """cos/sin over the rotated dims: the whole head, or an MLA
@@ -946,11 +1184,36 @@ class LlamaModel(nn.Layer):
             return {"pools": {"kv": row}, "dtype": dt, "scales": 0,
                     "pages_walked": tpu_ops.latent_walk_bound(
                         row[0], cfg.kv_lora_rank, dt)}
-        from ..ops.pallas.paged_attention import pages_walked
-        row = (cfg.num_key_value_heads, cfg.attn_head_dim)
+        from ..ops.pallas.paged_attention import heads_per_row, pages_walked
+        # heads narrower than the paged kernel's row lie side by side in
+        # one (forward_cached_paged follows the pool's row); an int8 pool
+        # keeps a scale a (page, head) and window pools a head a row
+        pack = 1 if quant or cfg.window_layers() else heads_per_row(
+            cfg.attn_head_dim, cfg.num_key_value_heads)
+        row = (cfg.num_key_value_heads // pack, cfg.attn_head_dim * pack)
         spec = {"pools": {"k": row, "v": row}, "dtype": dt,
                 "scales": cfg.num_key_value_heads if quant else 0,
                 "pages_walked": pages_walked}
+        state = cfg.state_layers()
+        if state:
+            if quant:
+                raise ValueError(
+                    "int8 KV is not implemented for a model with state-space "
+                    "layers: its attention layers are few and a slot's state "
+                    "dwarfs its rows; use kv_dtype auto|bfloat16|float32")
+            # a STATE a slot in each mamba layer, beside the attention
+            # layers' pages: {name: (shape of one slot's, dtype)}
+            spec["kinds"] = {
+                "full": {"layers": tuple(
+                    i for i in range(cfg.num_hidden_layers)
+                    if i not in state), "window": 0, "pools": ("k", "v")},
+                "state": {"layers": state, "arrays": {
+                    "ssm": ((cfg.mamba_n_heads, cfg.mamba_d_head,
+                             cfg.mamba_d_state),
+                            jnp.dtype(cfg.mamba_state_dtype)),
+                    "conv": ((cfg.mamba_d_conv - 1, cfg.mamba_conv_dim),
+                             cfg.compute_dtype)}}}
+            return spec
         sliding = cfg.window_layers()
         if sliding:
             if quant:
@@ -980,6 +1243,12 @@ class LlamaModel(nn.Layer):
                 "latent (MLA) attention serves through the paged pool "
                 "only (init_paged_cache / forward_cached_paged); the dense "
                 "ring-buffer layout has no latent form")
+        if cfg.state_layers():
+            raise NotImplementedError(
+                "a model with state-space layers serves through the paged "
+                "cache only (init_paged_cache: a state a slot beside the "
+                "attention layers' pages); the dense ring buffers carry no "
+                "state")
         # (a sliding-window layer's dense buffer holds the whole depth:
         # the window is its mask's, not its size's; generate() only)
         shape = (batch, max_len, cfg.num_key_value_heads, cfg.attn_head_dim)
@@ -988,7 +1257,7 @@ class LlamaModel(nn.Layer):
                 for _ in self.layers]
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         kv_dtype=None, window_pages=None):
+                         kv_dtype=None, window_pages=None, state_slots=None):
         """Paged KV pool (ISSUE 7): ONE device-resident page pool per
         K and V, [num_pages, layers, n_kv, page_size, head_dim] — one
         (page, layer, kv head) is a contiguous [page_size, head_dim]
@@ -1005,7 +1274,13 @@ class LlamaModel(nn.Layer):
         layers, [window_pages, sliding layers, ...]: `window_pages` =
         slots x ops.ring_pages(window, q_len, page_size), slot b's ring
         the pages b * ring .. (b + 1) * ring - 1, its own for its whole
-        life (no null page: a free slot's junk lands in its own ring)."""
+        life (no null page: a free slot's junk lands in its own ring).
+
+        A model with STATE-SPACE layers keeps "k"/"v" over its attention
+        layers only and, beside them, each kv_row_spec "state" array for
+        `state_slots` slots: "ssm" [state layers, slots, H, P, N] fp32 and
+        "conv" [state layers, slots, d_conv - 1, channels], slot b's its
+        own for its whole life."""
         cfg = self.config
         dt, quant = _resolve_kv_dtype(cfg, kv_dtype)
         if cfg.latent_attention:
@@ -1014,7 +1289,20 @@ class LlamaModel(nn.Layer):
             (width,) = self.kv_row_spec(kv_dtype)["pools"]["kv"]
             return {"kv": jnp.zeros((num_pages, len(self.layers),
                                      page_size, width), dt)}
-        kinds = self.kv_row_spec(kv_dtype).get("kinds")
+        spec = self.kv_row_spec(kv_dtype)
+        kinds = spec.get("kinds")
+        if kinds and "state" in kinds:
+            if not state_slots:
+                raise ValueError("a model with state-space layers needs "
+                                 "state_slots (the batcher's slots)")
+            n = len(kinds["state"]["layers"])
+            full = (num_pages, len(kinds["full"]["layers"])) \
+                + spec["pools"]["k"][:-1] + (page_size,) \
+                + spec["pools"]["k"][-1:]
+            cache = {"k": jnp.zeros(full, dt), "v": jnp.zeros(full, dt)}
+            for name, (shape, sdt) in kinds["state"]["arrays"].items():
+                cache[name] = jnp.zeros((n, int(state_slots)) + shape, sdt)
+            return cache
         if kinds:
             if not window_pages:
                 raise ValueError(
@@ -1038,11 +1326,13 @@ class LlamaModel(nn.Layer):
         return cache
 
     def forward_cached_paged(self, input_ids, cache, page_table, pos,
-                             counters=None):
+                             counters=None, lanes_valid=None):
         """Paged twin of forward_cached: input_ids [b, s_new]; cache:
         init_paged_cache pytree; page_table [b, pages_per_slot] int32;
         pos [b] int32 per-slot depths; counters: a moe StepCounters
-        for a model with dropless expert layers (step_counter_names).
+        for a model with dropless expert layers (step_counter_names);
+        lanes_valid [b]: how many of a slot's lanes carry a token, which a
+        state-space layer's state advances by (None: all of them).
         Returns (hidden, new_cache)."""
         cfg = self.config
         s = input_ids.shape[1]
@@ -1050,9 +1340,11 @@ class LlamaModel(nn.Layer):
             + jnp.arange(s, dtype=jnp.int32)
         cos, sin = self._rope_tables(s, positions)
         with jax.named_scope("llama.embed"):
-            x = jnp.take(self.embed_tokens.value,
-                         input_ids.astype(jnp.int32),
-                         axis=0).astype(cfg.compute_dtype)
+            x = self._embed_scale(jnp.take(
+                self.embed_tokens.value, input_ids.astype(jnp.int32),
+                axis=0).astype(cfg.compute_dtype))
+        state = cfg.state_layers()
+        more = {"lanes_valid": lanes_valid} if state else {}
         sliding = cfg.window_layers()
         if sliding:
             # a pool a kind: each layer gets its kind's table (the slots'
@@ -1063,15 +1355,22 @@ class LlamaModel(nn.Layer):
             rings = jnp.arange(B, dtype=jnp.int32)[:, None] * ring \
                 + jnp.arange(ring, dtype=jnp.int32)[None]
             at = {"full": 0, "window": 0}
+        if state:
+            # a layer's index in its kind's pool or state arrays
+            at = {"full": 0, "state": 0}
         for li, layer in enumerate(self.layers):
             table, index = page_table, li
             if sliding:
                 kind = "window" if li in sliding else "full"
                 table = rings if kind == "window" else page_table
                 index, at[kind] = at[kind], at[kind] + 1
+            if state:
+                kind = "state" if li in state else "full"
+                index, at[kind] = at[kind], at[kind] + 1
             with jax.named_scope(f"llama.layer{li}"):
                 x, cache = layer.forward_cached_paged(
-                    x, cos, sin, cache, table, pos, index, counters)
+                    x, cos, sin, cache, table, pos, index, counters,
+                    **more)
         w = self.norm.weight.value
         with jax.named_scope("llama.norm"):
             return tpu_ops.rms_norm(x, w.astype(x.dtype),
@@ -1129,18 +1428,19 @@ class LlamaForCausalLM(nn.Layer):
         with jax.named_scope("llama.lm_head"):
             if self.config.tie_word_embeddings:
                 w = self.llama.embed_tokens
-                return run(lambda v, e: v @ e.T.astype(v.dtype), x, w,
-                           name="lm_head")
-            return run(lambda v, w: v @ w.astype(v.dtype), x,
-                       self.lm_head, name="lm_head")
+                return run(lambda v, e: self._logits_scale(
+                    v @ e.T.astype(v.dtype)), x, w, name="lm_head")
+            return run(lambda v, w: self._logits_scale(
+                v @ w.astype(v.dtype)), x, self.lm_head, name="lm_head")
 
     def init_cache(self, batch: int, max_len: int):
         return self.llama.init_cache(batch, max_len)
 
     def init_paged_cache(self, num_pages: int, page_size: int,
-                         kv_dtype=None, window_pages=None):
+                         kv_dtype=None, window_pages=None, state_slots=None):
         return self.llama.init_paged_cache(num_pages, page_size,
-                                           kv_dtype, window_pages)
+                                           kv_dtype, window_pages,
+                                           state_slots)
 
     def kv_row_spec(self, kv_dtype=None):
         return self.llama.kv_row_spec(kv_dtype)
@@ -1168,18 +1468,23 @@ class LlamaForCausalLM(nn.Layer):
         with jax.named_scope("llama.lm_head"):
             if self.config.tie_word_embeddings:
                 w = self.llama.embed_tokens.value
-                return x @ w.T.astype(x.dtype)
-            return _wo_mm(self, "lm_head", x)
+                return self._logits_scale(x @ w.T.astype(x.dtype))
+            return self._logits_scale(_wo_mm(self, "lm_head", x))
+
+    def _logits_scale(self, logits):
+        d = self.config.logits_scaling
+        return logits if d is None else logits / d
 
     def forward_cached_paged(self, input_ids, cache, page_table, pos,
-                             counters=None, head_lanes=None):
+                             counters=None, head_lanes=None,
+                             lanes_valid=None):
         """Paged twin of forward_cached: returns (logits, new_cache).
         `head_lanes` n: logits [b, n, V] of the first n lanes only (the
         block of a model that generates by diffusion: no other lane of a
-        step needs the head)."""
+        step needs the head).  `lanes_valid`: LlamaModel's."""
         x, cache = self.llama.forward_cached_paged(input_ids, cache,
                                                    page_table, pos,
-                                                   counters)
+                                                   counters, lanes_valid)
         if head_lanes is not None:
             x = x[:, :head_lanes]
         return self._lm_logits(x), cache

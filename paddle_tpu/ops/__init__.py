@@ -26,6 +26,7 @@ __all__ = ["attention", "cached_attention", "rms_norm", "layer_norm",
            "latent_kv_update", "latent_paged_attention",
            "xla_latent_paged_attention", "latent_pages_walked",
            "latent_walk_bound", "grouped_matmul", "grouped_rows_walked",
+           "ssm_scan", "xla_ssm_scan",
            "yarn_inv_freq", "yarn_mscale",
            "xla_apply_rope",
            "swiglu", "get_attention_backend", "set_attention_backend",
@@ -713,6 +714,71 @@ def grouped_rows_walked(sizes, rows_shape, w_shape, dtype):
         return kernel.rows_walked(jnp.asarray(sizes, jnp.int32),
                                   rows_shape[0])
     return jnp.asarray(rows_shape[0], jnp.int32)
+
+
+# ---------------------------------------------------------------------------
+# the state-space scan (a Mamba-2 mixer's recurrence over a step's lanes)
+# ---------------------------------------------------------------------------
+def _ssm_kernel(state_shape, width, itemsize=4):
+    """The Pallas module where ssm_scan takes the kernel, None where it
+    takes the XLA recurrence: from the backend and the shapes alone."""
+    if _on_tpu():
+        from .pallas import ssm_scan as _k
+        if _k.supports(state_shape, width, itemsize):
+            return _k
+    return None
+
+
+@jax.jit
+def xla_ssm_scan(state, x, b, c, dt, a, d, reset, layer):
+    """jnp twin of pallas.ssm_scan, the recurrence itself, lane after lane
+    (`lax.scan`): the same operands and results, dt already masked."""
+    h0 = state[layer].astype(jnp.float32)                 # [B, H, P, N]
+    h0 = jnp.where(reset[:, None, None, None] > 0, 0.0, h0)
+
+    def lane(h, t):
+        x_t, b_t, c_t, dt_t = t
+        decay = jnp.exp(dt_t * a)[..., None, None]
+        h = decay * h + (dt_t[..., None] * x_t)[..., None] \
+            * b_t[:, None, None, :]
+        y = jnp.einsum("bhpn,bn->bhp", h, c_t,
+                       precision=jax.lax.Precision.HIGHEST) \
+            + d[:, None] * x_t
+        return h, y
+    lanes = (x.astype(jnp.float32).transpose(1, 0, 2, 3),
+             b.astype(jnp.float32).transpose(1, 0, 2),
+             c.astype(jnp.float32).transpose(1, 0, 2),
+             dt.astype(jnp.float32).transpose(1, 0, 2))
+    h, y = jax.lax.scan(lane, h0, lanes)
+    return y.transpose(1, 0, 2, 3), state.at[layer].set(
+        h.astype(state.dtype))
+
+
+def ssm_scan(state, x, b, c, dt, a, d, n_valid, reset, layer):
+    """Advance one state layer of every slot across a step's lanes.
+
+    state [layers, B, H, P, N], the pool (`layer` picks the layer), stored
+    in fp32 or bf16 and advanced in fp32;
+    x [B, W, H, P]; b, c [B, W, N] (one group); dt [B, W, H] after its
+    softplus; a (A = -exp(A_log)) and d [H]; n_valid [B] int32: the lanes
+    0..n-1 of slot b advance its state, the lanes past them leave it (and
+    nothing else) as it was; reset [B]: the slots that start from a zero
+    state.  Returns (y [B, W, H, P] fp32: h_t C_t + D x_t, the pad lanes'
+    junk; the pool).  On a TPU, for shapes its `supports` takes, the Pallas
+    kernel ops/pallas/ssm_scan.py (each slot's state of this layer read once
+    and written once, in place); the lane-by-lane recurrence elsewhere.
+    A pad lane is a lane whose dt is 0: nothing of it reaches the state."""
+    W = x.shape[1]
+    lanes = jnp.arange(W, dtype=jnp.int32)
+    dt = jnp.where(lanes[None, :, None] < n_valid[:, None, None],
+                   dt.astype(jnp.float32), 0.0)
+    reset = jnp.asarray(reset, jnp.int32)
+    kernel = _ssm_kernel(state.shape, W, state.dtype.itemsize)
+    if kernel is not None:
+        return kernel.ssm_scan(state, x, b, c, dt, a, d, reset, layer)
+    return xla_ssm_scan(state, x, b, c, dt, jnp.asarray(a, jnp.float32),
+                        jnp.asarray(d, jnp.float32), reset,
+                        jnp.asarray(layer, jnp.int32))
 
 
 # ---------------------------------------------------------------------------

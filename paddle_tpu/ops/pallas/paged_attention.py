@@ -13,7 +13,9 @@ Layout contract (paddle_tpu.models.llama.init_paged_cache):
 
   k_pool/v_pool  [num_pages, layers, n_kv, page_size, head_dim] — all
                  kv heads of one page in one layer are ONE contiguous
-                 block, and that block is what a transfer moves
+                 block, and that block is what a transfer moves (heads
+                 narrower than ROW_LANES lie side by side, heads_per_row
+                 of them a row, so head_dim here is the row's width)
   k/v scales     [num_pages, layers, n_kv] fp32  (int8 pools only)
   page_table     [B, pages_per_slot] int32; entry 0 is the reserved
                  null page (reads masked by position)
@@ -53,6 +55,8 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 # key rows of one product: the MXU's tile on every TPU generation
 _BLOCK_ROWS = 128
+# lanes of a pool row a transfer takes whole: the vreg's lane tile
+ROW_LANES = 128
 # VMEM the kernel's own buffers may take (double-buffered K and V
 # blocks, query and output tiles, fp32 accumulators); the compiler is
 # given twice that for the score tiles it keeps beside them
@@ -281,7 +285,17 @@ def supports(pool_shape, interpret=None) -> bool:
         return False
     interp = _interpret() if interpret is None else interpret
     ps, d = pool_shape[3], pool_shape[4]
-    return interp or (d % 128 == 0 and ps % 8 == 0)
+    return interp or (d % ROW_LANES == 0 and ps % 8 == 0)
+
+
+def heads_per_row(head_dim, n_kv) -> int:
+    """K/V heads one pool row should hold so that the row fills the
+    kernel's ROW_LANES: heads narrower than it lie side by side, as many
+    as fill it where they divide n_kv (1 otherwise: a head a row)."""
+    if head_dim >= ROW_LANES or ROW_LANES % head_dim:
+        return 1
+    pack = ROW_LANES // head_dim
+    return pack if n_kv % pack == 0 else 1
 
 
 def paged_attention(q, k_pool, v_pool, page_table, pos, layer,

@@ -645,3 +645,67 @@ def test_grouped_matmul_is_lowered_once_per_shape(one_chip, for_chip,
         text = bat._step_fn(width, steps, record=False).lower(*args).as_text()
         bodies = text.count('kernel_name = "grouped_matmul"')
         assert bodies == 2 * len(rungs), (width, bodies)
+
+
+@pytest.mark.parametrize("stored", ["bf16", "f32"])
+@pytest.mark.parametrize("width", [1, 32], ids=["decode", "admit"])
+def test_ssm_scan(for_chip, width, stored):
+    """The state-space scan at the hybrid serve cell's widths (64 slots, 64
+    heads of 64, a state of 128, 36 state layers in the pool, stored in
+    bf16 as the cell's configuration states, or fp32), its pool donated:
+    the kernel is in, and the pool comes out aliased, not copied."""
+    from paddle_tpu.ops.pallas import ssm_scan
+    L, B, H, P, N = 36, 64, 64, 64, 128
+
+    def f(state, x, b, c, dt, a, d, reset, layer):
+        return ssm_scan.ssm_scan(state, x, b, c, dt, a, d, reset, layer)
+    bf, f32 = jnp.bfloat16, jnp.float32
+    text = for_chip(f, ((L, B, H, P, N), bf if stored == "bf16" else f32),
+                    ((B, width, H, P), bf),
+                    ((B, width, N), bf), ((B, width, N), bf),
+                    ((B, width, H), f32), ((H,), f32), ((H,), f32),
+                    ((B,), jnp.int32), ((), jnp.int32), donate=(0,))
+    assert "ssm_scan" in _kernels(text)
+    pool = L * B * H * P * N
+    assert not [r for op in ("copy", "dynamic-update-slice")
+                for r in _results(text, op) if math.prod(r[0]) == pool]
+
+
+@pytest.mark.parametrize("mixed", [False, True], ids=["decode", "admit"])
+def test_state_model_step_programs_copy_no_state(one_chip, for_chip, mixed):
+    """BOTH step programs of ContinuousBatcher over a hybrid model at the
+    cell's mixer widths (hidden 2048, 64 heads of 64, state 128 stored in
+    bf16; layers
+    mamba, attention of 64-wide heads packed two to a row, mamba; 6 slots x
+    256 rows, a vocabulary of 1024), compiled for the described chip: the
+    scan and paged kernels are in, and no state-sized `copy`, `slice`,
+    `dynamic-update-slice` or `transpose` of the SSM state pool."""
+    import paddle_tpu as paddle
+    from paddle_tpu.inference import ContinuousBatcher
+    from paddle_tpu.models.llama import LlamaConfig, LlamaForCausalLM
+    paddle.seed(5)
+    model = LlamaForCausalLM(LlamaConfig(
+        vocab_size=1024, hidden_size=2048, intermediate_size=512,
+        num_hidden_layers=3, num_attention_heads=32, num_key_value_heads=8,
+        head_dim=64, dtype="bfloat16", tie_word_embeddings=True,
+        layer_types=("mamba", "full_attention", "mamba"),
+        rope_layer_types=(), mamba_n_heads=64, mamba_d_head=64,
+        mamba_d_state=128, mamba_state_dtype="bfloat16",
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22, attention_multiplier=0.015625,
+        logits_scaling=8.0))
+    model.eval()
+    bat = ContinuousBatcher(model, max_batch_size=6, max_len=256)
+    width, steps = (bat.prefill_chunk, bat.admit_steps) if mixed \
+        else (1, bat.chunk)
+    fn = bat._step_fn(width, steps, record=False)
+    args = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        (bat._param_vals(), *bat._carry_args()))
+    text = fn.lower(*args).compile().as_text()
+    assert {"ssm_scan", "paged_attention"} <= _kernels(text)
+    ssm = bat._cache["ssm"].shape
+    big = {math.prod(ssm), math.prod(ssm[1:])}
+    for op in ("copy", "slice", "dynamic-update-slice", "transpose"):
+        found = [r for r in _results(text, op) if math.prod(r[0]) in big]
+        assert not found, f"state-sized {op}: {found}"

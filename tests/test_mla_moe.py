@@ -1140,8 +1140,9 @@ def test_paged_kv_bytes_takes_the_row_from_the_model(model, latent):
     from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny_config
     m = model if latent else LlamaForCausalLM(llama_tiny_config())
     spec = m.kv_row_spec()
+    # (the tiny llama's four 32-wide K/V heads lie in one 128-lane row)
     assert spec["pools"] == ({"kv": (40,)} if latent
-                             else {"k": (4, 32), "v": (4, 32)})
+                             else {"k": (1, 128), "v": (1, 128)})
     for dt in ("float32",) if latent else ("float32", "int8"):
         bat = ContinuousBatcher(m, max_batch_size=2, max_len=32,
                                 prefill_chunk=4, page_size=8, kv_dtype=dt)

@@ -73,6 +73,38 @@ def test_paged_matches_dense_bitexact(model):
         np.testing.assert_array_equal(pg, _isolated(model, p, 7))
 
 
+@pytest.mark.parametrize("head_dim,n_kv,kv_dtype,row", [
+    (128, 2, None, (2, 128)),          # a head fills the kernel's row
+    (64, 4, None, (2, 128)),           # two heads a row
+    (32, 4, None, (1, 128)),           # four heads a row
+    (64, 3, None, (3, 64)),            # the heads do not divide: a head a row
+    (64, 4, "int8", (4, 64))])         # a scale a (page, head): a head a row
+def test_pool_rows_fill_the_kernels_lanes(head_dim, n_kv, kv_dtype, row):
+    """kv_row_spec lays K/V heads narrower than the paged kernel's 128-lane
+    row side by side, and a batcher over such a pool serves the tokens of
+    the dense layout, request for request."""
+    paddle.seed(11)
+    m = LlamaForCausalLM(llama_tiny_config(
+        num_hidden_layers=2, hidden_size=2 * n_kv * head_dim,
+        intermediate_size=128, num_attention_heads=2 * n_kv,
+        num_key_value_heads=n_kv, head_dim=head_dim, vocab_size=128))
+    assert m.kv_row_spec(kv_dtype)["pools"]["k"] == row
+    if kv_dtype:
+        return
+    rng = np.random.RandomState(4)
+    prompts = [rng.randint(1, 128, L).astype(np.int32) for L in (5, 10)]
+    outs = {}
+    for layout in ("paged", "dense"):
+        bat = ContinuousBatcher(m, max_batch_size=2, max_len=48, chunk=4,
+                                prefill_chunk=4, kv_layout=layout,
+                                page_size=8)
+        rids = [bat.submit(p, 6) for p in prompts]
+        got = bat.run()
+        outs[layout] = [got[r] for r in rids]
+    for pg, dn in zip(outs["paged"], outs["dense"]):
+        np.testing.assert_array_equal(pg, dn)
+
+
 def test_paged_bf16_kv_deterministic(model):
     """Explicit kv_dtype plumbing: a bf16 pool reports its dtype and
     two identical runs produce identical tokens.  (The bit-exactness

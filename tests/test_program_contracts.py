@@ -624,3 +624,56 @@ def test_an_expert_model_calls_the_grouped_product_at_every_product(
         width = bat.prefill_chunk if mixed else bat._decode_width
         rungs = moe.sorted_lengths(_GEOMETRY["max_batch_size"] * width * 2)
         assert _grouped_products(lowered) == 2 * 2 * len(rungs)
+
+
+def _serve_cell_models():
+    """(name, build, knobs) of a tiny model of each kind the accepted serve
+    cells run: the four of _kind_models, one with sliding-window and full
+    layers, and one with sigmoid expert layers."""
+    def window(**kw):
+        paddle.seed(11)
+        return LlamaForCausalLM(llama_tiny_config(
+            num_hidden_layers=2, hidden_size=32, intermediate_size=64,
+            num_attention_heads=4, num_key_value_heads=2, vocab_size=64,
+            layer_types=("sliding_attention", "full_attention"),
+            sliding_window=8, rope_layer_types=("sliding_attention",), **kw))
+
+    def experts(**kw):
+        paddle.seed(11)
+        return LlamaForCausalLM(llama_tiny_config(
+            num_hidden_layers=2, hidden_size=32, intermediate_size=64,
+            num_attention_heads=4, num_key_value_heads=2, vocab_size=64,
+            moe_gate="sigmoid", moe_num_experts=4, moe_router_width=8,
+            moe_top_k=2, moe_shared_experts=1, moe_router_bias=True, **kw))
+    return _kind_models() + [("window", window, {}),
+                             ("sigmoid_experts", experts, {})]
+
+
+@pytest.mark.parametrize("which", range(6), ids=[
+    "dense_gqa", "mha_int8", "latent", "block", "window", "sigmoid_experts"])
+def test_state_layers_and_multipliers_leave_a_model_without_them_alone(
+        which, monkeypatch):
+    """A model without state-space layers or GraniteMoeHybrid's multipliers
+    compiles the step programs it always did: no state in the cache, no
+    `ssm.` scope, no `ssm_scan`, and the same text as when the three
+    multiplier helpers are the identity (a multiplier that is None adds no
+    operation).  The accepted cells' eight step programs were held to the
+    parent commit's text once, at their real shapes (PERF.md, section 6)."""
+    from paddle_tpu.models import llama
+    _, build, knobs = _serve_cell_models()[which]
+    bat = ContinuousBatcher(build(), **_GEOMETRY, **_PLAIN, **knobs)
+    assert not bat._state and not {"ssm", "conv"} & set(bat._cache)
+    texts = []
+    for mixed in (False, True):
+        lowered = bat.lower_step(mixed=mixed)
+        debug = lowered.as_text(debug_info=True)
+        assert "ssm." not in debug and "ssm_scan" not in debug
+        texts.append(lowered.as_text())
+    monkeypatch.setattr(llama.LlamaDecoderLayer, "_residual",
+                        lambda self, y: y)
+    monkeypatch.setattr(llama.LlamaModel, "_embed_scale", lambda self, x: x)
+    monkeypatch.setattr(llama.LlamaForCausalLM, "_logits_scale",
+                        lambda self, x: x)
+    bare = ContinuousBatcher(build(), **_GEOMETRY, **_PLAIN, **knobs)
+    assert [bare.lower_step(mixed=m).as_text() for m in (False, True)] \
+        == texts
